@@ -88,8 +88,7 @@ league-smoke:
 # exit-on-wedge off) must land wedge_report.json + stacks and doctor
 # the same way, and sealed flight records must surface as per-program
 # device-time rows in `cli perf --json`. Runs the doctor CLI in
-# subprocesses exactly as tpu_watch.sh does — JAX is never imported on
-# that path.
+# subprocesses — JAX is never imported on that path.
 doctor-smoke:
 	JAX_PLATFORMS=cpu $(PY) benchmarks/doctor_smoke.py
 

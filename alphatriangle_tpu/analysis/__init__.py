@@ -5,9 +5,8 @@ reintroduce: use-after-donation (PR 3), mixed-placement recompiles
 (PR 5), host syncs in hot loops (PR 6), unbracketed hot dispatches
 (PR 10's flight coverage), debug artifacts, and untracked RNG.
 
-JAX-free by contract — `cli lint` runs in CI images, in the
-tpu_watch.sh preflight, and beside a wedged chip, exactly like
-`cli mem` / `cli doctor` (pinned by a subprocess import-guard test).
+JAX-free by contract — `cli lint` runs in CI images and beside a
+process that holds the chip, exactly like `cli mem` / `cli doctor` (pinned by a subprocess import-guard test).
 """
 
 from .baseline import (

@@ -2,9 +2,9 @@
 
 The analyzer is a pure-stdlib `ast` pass (plus `telemetry.flight`'s
 family table, itself JAX-free): like `cli mem` and `cli doctor` it must
-run beside a wedged chip, inside the tpu_watch.sh preflight, and in CI
-images without an accelerator stack — importing jax here would defeat
-all three. tests/test_analysis.py pins the no-jax contract with a
+run beside a process that holds the chip and in CI images without an
+accelerator stack — importing jax here would defeat both.
+tests/test_analysis.py pins the no-jax contract with a
 subprocess import guard.
 """
 

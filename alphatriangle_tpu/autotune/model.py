@@ -45,9 +45,8 @@ logger = logging.getLogger(__name__)
 DEFAULT_EFFICIENCY = 0.014
 
 # Host-side cost of one program dispatch (seconds): queueing + transfer
-# + Python driver turnaround. Conservative for a local chip, an order
-# low for a tunneled dev VM; calibration cannot observe it directly, so
-# it stays a documented constant rather than a fitted one.
+# + Python driver turnaround. Calibration cannot observe it directly,
+# so it stays a documented constant rather than a fitted one.
 DEFAULT_DISPATCH_OVERHEAD_S = 0.01
 
 # Peak to assume when the device kind is unknown AND no override/

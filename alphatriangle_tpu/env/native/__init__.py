@@ -1,7 +1,9 @@
 """ctypes bindings + build for the native host engine (engine.cpp).
 
 The shared library is compiled on first use with g++ (no pybind11 in
-this image; plain C ABI + ctypes, cached next to the source). The
+this image; plain C ABI + ctypes, cached next to the source under a
+name made from the source's digest, so a library built from another
+engine.cpp is never loaded). The
 native engine consumes the SAME bitboard tables the JAX engine builds
 (`TriangleEnv._tables_np`), so there is exactly one source of truth
 for the game rules' geometry.
@@ -12,7 +14,9 @@ image, read-only filesystem, ...).
 """
 
 import ctypes
+import hashlib
 import logging
+import os
 import subprocess
 import threading
 from pathlib import Path
@@ -22,7 +26,7 @@ import numpy as np
 logger = logging.getLogger(__name__)
 
 _SRC = Path(__file__).parent / "engine.cpp"
-_LIB = Path(__file__).parent / "_libat_engine.so"
+_CXXFLAGS = ("-O3", "-march=native", "-shared", "-fPIC", "-std=c++17")
 _lock = threading.Lock()
 _lib: "ctypes.CDLL | None" = None
 _build_error: str | None = None
@@ -35,14 +39,25 @@ _u64p = np.ctypeslib.ndpointer(np.uint64, flags="C_CONTIGUOUS")
 _f32p = np.ctypeslib.ndpointer(np.float32, flags="C_CONTIGUOUS")
 
 
+def native_library_path() -> Path:
+    """Where the library built from the present engine.cpp (and the
+    present flags) lives. A file's mtime says nothing once a tree has
+    been copied; the digest does."""
+    digest = hashlib.sha256(
+        _SRC.read_bytes() + " ".join(_CXXFLAGS).encode()
+    ).hexdigest()[:12]
+    return _SRC.parent / f"_libat_engine-{digest}.so"
+
+
 def _build() -> "ctypes.CDLL | None":
     global _build_error
-    if _LIB.exists() and _LIB.stat().st_mtime >= _SRC.stat().st_mtime:
-        return ctypes.CDLL(str(_LIB))
-    cmd = [
-        "g++", "-O3", "-march=native", "-shared", "-fPIC", "-std=c++17",
-        str(_SRC), "-o", str(_LIB),
-    ]
+    lib = native_library_path()
+    if lib.exists():
+        return ctypes.CDLL(str(lib))
+    # Compile beside the target and rename: a second process never
+    # loads a half-written library.
+    tmp = lib.with_suffix(f".tmp{os.getpid()}.so")
+    cmd = ["g++", *_CXXFLAGS, str(_SRC), "-o", str(tmp)]
     try:
         proc = subprocess.run(cmd, capture_output=True, text=True, timeout=120)
     except (OSError, subprocess.TimeoutExpired) as exc:
@@ -53,7 +68,8 @@ def _build() -> "ctypes.CDLL | None":
         _build_error = proc.stderr.strip()[-500:]
         logger.warning("Native engine build failed: %s", _build_error)
         return None
-    return ctypes.CDLL(str(_LIB))
+    tmp.replace(lib)
+    return ctypes.CDLL(str(lib))
 
 
 def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:

@@ -372,7 +372,7 @@ def run_flywheel(
     )
     import jax
 
-    enable_persistent_compilation_cache(backend=jax.default_backend())
+    enable_persistent_compilation_cache()
 
     try:
         components = setup_training_components(

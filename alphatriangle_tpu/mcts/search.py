@@ -131,6 +131,28 @@ class SearchOutput:
     stats: Any = None
 
 
+def tree_geometry(config: MCTSConfig) -> tuple[int, int]:
+    """(node slots per tree, wave size) the search allocates for
+    `config` — the N and W of every (B, N, A) plane and (B, W) wave.
+
+    Subtree reuse widens the node budget: up to budget + 1 retained
+    rows (promoted subtree incl. its root) plus a full search's worth
+    of fresh insertions. Fresh-root (the default) keeps the original
+    max_simulations + 1 exactly. The wave is the largest divisor of
+    max_simulations <= mcts_batch_size, so waves tile the simulation
+    budget exactly.
+    """
+    sims = config.max_simulations
+    if config.tree_reuse:
+        reuse_slots = (config.tree_reuse_budget or sims) + 1
+    else:
+        reuse_slots = 1
+    w = max(1, min(config.mcts_batch_size, sims))
+    while sims % w:
+        w -= 1
+    return sims + reuse_slots, w
+
+
 class BatchedMCTS:
     """PUCT search bound to (env, features, model); `search` is jitted.
 
@@ -153,24 +175,10 @@ class BatchedMCTS:
         self.model = model
         self.config = config
         self.support = value_support
-        # Subtree reuse widens the node budget: up to `reuse_slots`
-        # retained rows (promoted subtree incl. its root) plus a full
-        # search's worth of fresh insertions. Fresh-root (the default)
-        # keeps the original max_simulations + 1 exactly.
-        if config.tree_reuse:
-            budget = config.tree_reuse_budget or config.max_simulations
-            self.reuse_slots = budget + 1
-        else:
-            self.reuse_slots = 1
-        self.num_nodes = config.max_simulations + self.reuse_slots
+        self.num_nodes, self.wave_size = tree_geometry(config)
+        self.reuse_slots = self.num_nodes - config.max_simulations
         self.action_dim = env.action_dim
-        # Wave size: largest divisor of max_simulations <= mcts_batch_size,
-        # so waves tile the simulation budget exactly.
-        w = max(1, min(config.mcts_batch_size, config.max_simulations))
-        while config.max_simulations % w:
-            w -= 1
-        self.wave_size = w
-        self.num_waves = config.max_simulations // w
+        self.num_waves = config.max_simulations // self.wave_size
         # Snapshot of the device-stats flag at construction: it shapes
         # the compiled programs (SearchOutput.stats leaf), so engines
         # fold it into their AOT cache extras and never flip it on a

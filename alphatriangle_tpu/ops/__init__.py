@@ -1,4 +1,4 @@
-"""Custom TPU ops (Pallas kernels with portable fallbacks).
+"""Custom TPU ops (Pallas kernels beside their XLA lowerings).
 
 Every op follows one pattern (docs/KERNELS.md): a Pallas TPU lowering
 plus interchangeable XLA lowerings, numerically pinned against each

@@ -21,13 +21,10 @@ import functools
 import jax
 import jax.numpy as jnp
 
-try:  # Pallas TPU lowering; interpret mode covers CPU tests.
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-    _HAS_PALLAS = True
-except Exception:  # pragma: no cover
-    _HAS_PALLAS = False
+from ._vmem import f32_block_bytes, vmem_params
 
 
 def gather_rows_einsum(stats: jax.Array, idx: jax.Array) -> jax.Array:
@@ -50,8 +47,8 @@ def _gather_kernel(idx_ref, stats_ref, out_ref):
     """One grid program per game: copy W dynamically-indexed rows."""
     w = out_ref.shape[1]
     for j in range(w):  # static unroll; W is small (<= wave size)
-        row = idx_ref[0, j]
-        out_ref[0, j, :] = stats_ref[0, row, :]
+        row = idx_ref[0, 0, j]
+        out_ref[0, pl.ds(j, 1), :] = stats_ref[0, pl.ds(row, 1), :]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -65,17 +62,17 @@ def gather_rows_pallas(
     touching the MXU. `interpret=True` runs the kernel in the Pallas
     interpreter (CPU tests).
     """
-    if not _HAS_PALLAS:  # pragma: no cover
-        return gather_rows_take(stats, idx)
     b, n, k = stats.shape
     w = idx.shape[1]
     return pl.pallas_call(
         _gather_kernel,
         grid=(b,),
         in_specs=[
+            # (B, 1, W): the TPU lowering wants a block's last two
+            # dims to be whole array dims (or multiples of 8 x 128).
             pl.BlockSpec(
-                (1, w),
-                lambda i: (i, 0),
+                (1, 1, w),
+                lambda i: (i, 0, 0),
                 memory_space=pltpu.SMEM,
             ),
             pl.BlockSpec(
@@ -90,8 +87,11 @@ def gather_rows_pallas(
             memory_space=pltpu.VMEM,
         ),
         out_shape=jax.ShapeDtypeStruct((b, w, k), stats.dtype),
+        compiler_params=vmem_params(
+            f32_block_bytes(n, k) + f32_block_bytes(w, k)
+        ),
         interpret=interpret,
-    )(idx.astype(jnp.int32), stats)
+    )(idx.astype(jnp.int32).reshape(b, 1, w), stats)
 
 
 def gather_rows(
@@ -101,8 +101,8 @@ def gather_rows(
     if mode == "einsum":
         return gather_rows_einsum(stats, idx)
     if mode == "pallas":
-        # The Pallas TPU lowering needs a TPU backend; everywhere else
-        # (CPU tests, CPU fallback runs) use the interpreter.
+        # Compiled on a TPU backend, interpreted everywhere else (CPU
+        # tests).
         interpret = jax.default_backend() != "tpu"
         return gather_rows_pallas(stats, idx, interpret=interpret)
     if mode == "take":

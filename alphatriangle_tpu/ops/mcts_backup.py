@@ -15,8 +15,10 @@ lowerings:
   game's four edge planes in VMEM, applies the W insertions and the
   W x depth backup updates as sequential one-hot row
   read-modify-writes, and emits the updated planes (this file). The
-  per-(level, member) update order matches the XLA scatter's update
-  order, so duplicate-edge accumulation associates identically.
+  per-(level, member) update order matches XLA:CPU's scatter order, so
+  duplicate-edge accumulation associates identically there; on a TPU
+  XLA orders a scatter-add's duplicates its own way and the value sums
+  agree to f32 rounding (docs/KERNELS.md).
 
 `MCTSConfig.backup_update` selects the lowering; parity tests pin
 them against each other on CPU interpret mode, including a
@@ -35,13 +37,10 @@ import functools
 import jax
 import jax.numpy as jnp
 
-try:  # Pallas TPU lowering; interpret mode covers CPU tests.
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-    _HAS_PALLAS = True
-except Exception:  # pragma: no cover
-    _HAS_PALLAS = False
+from ._vmem import f32_block_bytes, vmem_params
 
 
 def backup_update_xla(
@@ -104,7 +103,7 @@ def _backup_kernel(
     order-free, and the visit/value adds associate in the same order
     as the reference scatter-adds.
     """
-    w = parents_ref.shape[1]
+    w = parents_ref.shape[2]
     depth = rec_node_ref.shape[2]
     a = out_visits_ref.shape[2]
     out_visits_ref[...] = e_visits_ref[...]
@@ -113,15 +112,15 @@ def _backup_kernel(
     out_reward_ref[...] = e_reward_ref[...]
     lane = jax.lax.broadcasted_iota(jnp.int32, (1, a), 1)
     for j in range(w):  # static unroll; W is small (<= wave size)
-        p = parents_ref[0, j]
-        onehot = lane == actions_ref[0, j]
+        p = parents_ref[0, 0, j]
+        onehot = lane == actions_ref[0, 0, j]
         row = out_children_ref[0, pl.ds(p, 1), :]
         out_children_ref[0, pl.ds(p, 1), :] = jnp.where(
-            onehot, jnp.maximum(row, new_child_ref[0, j]), row
+            onehot, jnp.maximum(row, new_child_ref[0, 0, j]), row
         )
         row = out_reward_ref[0, pl.ds(p, 1), :]
         out_reward_ref[0, pl.ds(p, 1), :] = jnp.where(
-            onehot, rewards_ref[0, j], row
+            onehot, rewards_ref[0, 0, j], row
         )
     for lvl in range(depth):
         for j in range(w):
@@ -163,16 +162,13 @@ def backup_update_pallas(
     of 2*depth+2 full-plane scatters. `interpret=True` runs the
     kernel in the Pallas interpreter (CPU tests).
     """
-    if not _HAS_PALLAS:  # pragma: no cover
-        return backup_update_xla(
-            e_visits, e_value, children, e_reward, parents, actions,
-            new_child, rewards, rec_node, rec_action, rec_active, returns,
-        )
     b, n, a = e_visits.shape
     w = parents.shape[1]
     depth = rec_node.shape[-1]
+    # (B, 1, W): the TPU lowering wants a block's last two dims to be
+    # whole array dims (or multiples of 8 x 128).
     smem_row = pl.BlockSpec(
-        (1, w), lambda i: (i, 0), memory_space=pltpu.SMEM
+        (1, 1, w), lambda i: (i, 0, 0), memory_space=pltpu.SMEM
     )
     smem_rec = pl.BlockSpec(
         (1, w, depth), lambda i: (i, 0, 0), memory_space=pltpu.SMEM
@@ -187,12 +183,13 @@ def backup_update_pallas(
         in_specs=[smem_row] * 4 + [smem_rec] * 4 + [vmem_plane] * 4,
         out_specs=(vmem_plane,) * 4,
         out_shape=(plane,) * 4,
+        compiler_params=vmem_params(8 * f32_block_bytes(n, a)),
         interpret=interpret,
     )(
-        parents.astype(jnp.int32),
-        actions.astype(jnp.int32),
-        new_child.astype(jnp.float32),
-        rewards.astype(jnp.float32),
+        parents.astype(jnp.int32).reshape(b, 1, w),
+        actions.astype(jnp.int32).reshape(b, 1, w),
+        new_child.astype(jnp.float32).reshape(b, 1, w),
+        rewards.astype(jnp.float32).reshape(b, 1, w),
         rec_node.astype(jnp.int32),
         rec_action.astype(jnp.int32),
         rec_active.astype(jnp.int32),
@@ -227,8 +224,8 @@ def backup_update(
             new_child, rewards, rec_node, rec_action, rec_active, returns,
         )
     if mode == "pallas":
-        # The Pallas TPU lowering needs a TPU backend; everywhere else
-        # (CPU tests, CPU fallback runs) use the interpreter.
+        # Compiled on a TPU backend, interpreted everywhere else (CPU
+        # tests).
         interpret = jax.default_backend() != "tpu"
         return backup_update_pallas(
             e_visits, e_value, children, e_reward, parents, actions,

@@ -31,13 +31,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
-try:  # Pallas TPU lowering; interpret mode covers CPU tests.
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAS_PALLAS = True
-except Exception:  # pragma: no cover
-    _HAS_PALLAS = False
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 # Cumsum tile streamed per inner step: lane-width multiple so the
 # (b, _TILE) compare block stays small regardless of ring capacity.
@@ -50,19 +45,22 @@ def count_below_xla(cum: jax.Array, u: jax.Array) -> jax.Array:
 
 
 def _count_below_kernel(cum_ref, u_ref, out_ref):
-    """One grid program per step row: out[j] = #{i : cum[i] < u[j]}."""
+    """One grid program per step row: out[j] = #{i : cum[i] < u[j]}.
+
+    Every value stays 2-D for the TPU: the b draws sit on sublanes as a
+    (b, 1) column, each cumsum tile on lanes as a (1, _TILE) row, and
+    the count is a lane reduction of their (b, _TILE) compare."""
     b = u_ref.shape[1]
-    n_pad = cum_ref.shape[1]
-    u = u_ref[0, :]
+    u = u_ref[0]  # (b, 1)
 
     def tile(t, acc):
-        seg = cum_ref[0, pl.ds(t * _TILE, _TILE)]
+        seg = cum_ref[pl.ds(t, 1), :]  # (1, _TILE)
         return acc + jnp.sum(
-            (seg[None, :] < u[:, None]).astype(jnp.int32), axis=1
+            (seg < u).astype(jnp.int32), axis=1, keepdims=True
         )
 
-    out_ref[0, :] = jax.lax.fori_loop(
-        0, n_pad // _TILE, tile, jnp.zeros((b,), jnp.int32)
+    out_ref[0] = jax.lax.fori_loop(
+        0, cum_ref.shape[0], tile, jnp.zeros((b, 1), jnp.int32)
     )
 
 
@@ -73,39 +71,35 @@ def count_below_pallas(
     """(n,) sorted, (k, b) -> (k, b) int32 via a tiled compare-count.
 
     The cumsum is padded with +inf to a tile multiple (inf < u is
-    always False, so padding contributes zero) and kept whole in VMEM;
-    each program handles one step row's b strata. `interpret=True`
-    runs the kernel in the Pallas interpreter (CPU tests).
+    always False, so padding contributes zero), folded to
+    (n_pad / _TILE, _TILE) and kept whole in VMEM; each program handles
+    one step row's b strata. `interpret=True` runs the kernel in the
+    Pallas interpreter (CPU tests).
     """
-    if not _HAS_PALLAS:  # pragma: no cover
-        return count_below_xla(cum, u)
     n = cum.shape[0]
     k, b = u.shape
-    n_pad = -(-n // _TILE) * _TILE
-    cum_p = jnp.pad(cum, (0, n_pad - n), constant_values=jnp.inf)[None, :]
+    tiles = -(-n // _TILE)
+    cum_p = jnp.pad(
+        cum, (0, tiles * _TILE - n), constant_values=jnp.inf
+    ).reshape(tiles, _TILE)
+    # (k, b, 1): the TPU lowering wants a block's last two dims to be
+    # whole array dims (or multiples of 8 x 128).
+    row = pl.BlockSpec(
+        (1, b, 1), lambda i: (i, 0, 0), memory_space=pltpu.VMEM
+    )
     return pl.pallas_call(
         _count_below_kernel,
         grid=(k,),
         in_specs=[
             pl.BlockSpec(
-                (1, n_pad),
-                lambda i: (0, 0),
-                memory_space=pltpu.VMEM,
+                (tiles, _TILE), lambda i: (0, 0), memory_space=pltpu.VMEM
             ),
-            pl.BlockSpec(
-                (1, b),
-                lambda i: (i, 0),
-                memory_space=pltpu.VMEM,
-            ),
+            row,
         ],
-        out_specs=pl.BlockSpec(
-            (1, b),
-            lambda i: (i, 0),
-            memory_space=pltpu.VMEM,
-        ),
-        out_shape=jax.ShapeDtypeStruct((k, b), jnp.int32),
+        out_specs=row,
+        out_shape=jax.ShapeDtypeStruct((k, b, 1), jnp.int32),
         interpret=interpret,
-    )(cum_p, u)
+    )(cum_p, u.reshape(k, b, 1)).reshape(k, b)
 
 
 def per_sample(
@@ -138,8 +132,8 @@ def per_sample(
     if mode == "xla":
         idx = count_below_xla(cum, u)
     elif mode == "pallas":
-        # The Pallas TPU lowering needs a TPU backend; everywhere else
-        # (CPU tests, CPU fallback runs) use the interpreter.
+        # Compiled on a TPU backend, interpreted everywhere else (CPU
+        # tests).
         interpret = jax.default_backend() != "tpu"
         idx = count_below_pallas(cum, u, interpret=interpret)
     else:

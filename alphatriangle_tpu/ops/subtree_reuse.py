@@ -48,13 +48,10 @@ import functools
 import jax
 import jax.numpy as jnp
 
-try:  # Pallas TPU lowering; interpret mode covers CPU tests.
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-    _HAS_PALLAS = True
-except Exception:  # pragma: no cover
-    _HAS_PALLAS = False
+from ._vmem import f32_block_bytes, vmem_params
 
 
 def _promotion_plan(
@@ -161,10 +158,10 @@ def _promote_kernel(
     in a single VMEM pass; rows past `retained` are the zeroed frees
     (children rows -1)."""
     n = v_ref.shape[1]
-    ret = retained_ref[0, 0]
+    ret = retained_ref[0, 0, 0]
 
     def row(r, _):
-        src = order_ref[0, r]
+        src = order_ref[0, 0, r]
         take = r < ret
         ov_ref[0, pl.ds(r, 1), :] = jnp.where(
             take, v_ref[0, pl.ds(src, 1), :], 0.0
@@ -196,11 +193,13 @@ def _reorder_planes_pallas(
 ):
     """Fused per-game row reorder of the six edge planes (VMEM)."""
     b, n, a = e_visits.shape
+    # (B, 1, N) / (B, 1, 1): the TPU lowering wants a block's last two
+    # dims to be whole array dims (or multiples of 8 x 128).
     smem_order = pl.BlockSpec(
-        (1, n), lambda i: (i, 0), memory_space=pltpu.SMEM
+        (1, 1, n), lambda i: (i, 0, 0), memory_space=pltpu.SMEM
     )
     smem_ret = pl.BlockSpec(
-        (1, 1), lambda i: (i, 0), memory_space=pltpu.SMEM
+        (1, 1, 1), lambda i: (i, 0, 0), memory_space=pltpu.SMEM
     )
     vmem_plane = pl.BlockSpec(
         (1, n, a), lambda i: (i, 0, 0), memory_space=pltpu.VMEM
@@ -212,10 +211,11 @@ def _reorder_planes_pallas(
         in_specs=[smem_order, smem_ret] + [vmem_plane] * 6,
         out_specs=(vmem_plane,) * 6,
         out_shape=(plane,) * 6,
+        compiler_params=vmem_params(12 * f32_block_bytes(n, a)),
         interpret=interpret,
     )(
-        order.astype(jnp.int32),
-        retained.astype(jnp.int32).reshape(b, 1),
+        order.astype(jnp.int32).reshape(b, 1, n),
+        retained.astype(jnp.int32).reshape(b, 1, 1),
         e_visits,
         e_value,
         e_reward,
@@ -258,17 +258,12 @@ def subtree_promote(
             order, keep_mask, planes, (0.0, 0.0, 0.0, -1.0, 0.0, 0.0)
         )
     elif mode == "pallas":
-        if _HAS_PALLAS:
-            # The Pallas TPU lowering needs a TPU backend; everywhere
-            # else (CPU tests, CPU fallback runs) use the interpreter.
-            interpret = jax.default_backend() != "tpu"
-            out = _reorder_planes_pallas(
-                order, retained, *planes, interpret=interpret
-            )
-        else:  # pragma: no cover
-            out = _reorder_planes_xla(
-                order, keep_mask, planes, (0.0, 0.0, 0.0, -1.0, 0.0, 0.0)
-            )
+        # Compiled on a TPU backend, interpreted everywhere else (CPU
+        # tests).
+        interpret = jax.default_backend() != "tpu"
+        out = _reorder_planes_pallas(
+            order, retained, *planes, interpret=interpret
+        )
     else:
         raise ValueError(f"unknown subtree_promote mode: {mode!r}")
     # terminal is bool (and cheap): shared XLA epilogue for both modes.

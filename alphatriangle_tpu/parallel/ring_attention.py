@@ -186,14 +186,12 @@ def make_sp_attention(
     else:
         raise ValueError(f"Unknown sequence-parallel kind: {kind!r}")
 
-    from .sharding import shard_map_compat
-
-    sharded = shard_map_compat(
+    sharded = jax.shard_map(
         lambda q, k, v: inner(q, k, v),
         mesh=mesh,
         in_specs=(spec, spec, spec),
         out_specs=spec,
-        check=False,
+        check_vma=False,
     )
     dp_total = mesh.shape[dp_axis] if dp_axis is not None else 1
 

@@ -24,34 +24,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 logger = logging.getLogger(__name__)
 
 
-def shard_map_compat(fn, mesh: Mesh, in_specs, out_specs, check=False):
-    """`shard_map` across jax versions.
-
-    Newer jax exposes `jax.shard_map` (validation knob `check_vma`);
-    0.4.x only has `jax.experimental.shard_map.shard_map` (knob
-    `check_rep`). The computation is identical either way; `check`
-    defaults off because the older checker lacks replication rules for
-    some primitives these shard functions use (axis_index gathers).
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            fn,
-            mesh=mesh,
-            in_specs=in_specs,
-            out_specs=out_specs,
-            check_vma=check,
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
-        fn,
-        mesh=mesh,
-        in_specs=in_specs,
-        out_specs=out_specs,
-        check_rep=check,
-    )
-
-
 def replicated(mesh: Mesh) -> NamedSharding:
     """Fully-replicated sharding (params, opt state, scalars)."""
     return NamedSharding(mesh, P())

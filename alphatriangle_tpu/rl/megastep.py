@@ -48,7 +48,7 @@ sharded seams the codebase already has:
 
 - the rollout chunk runs lane-sharded under GSPMD (each device plays
   its B/dp games — lanes are independent, so no collectives appear);
-- ONE `shard_map` region (parallel/sharding.py::shard_map_compat) does
+- ONE `jax.shard_map` region does
   the per-shard replay work with no collectives except a weight-norm
   `pmax`: every shard ring-scatters ITS lanes' rows into ITS ring shard
   (`ShardedDeviceReplayBuffer.scatter_local`, cap_local slots + a trash
@@ -428,8 +428,6 @@ class MegastepRunner:
         return globally encoded (`shard * stride + slot`)."""
         from jax.sharding import PartitionSpec as P
 
-        from ..parallel.sharding import shard_map_compat
-
         buf = self.buffer
         dp_axis = buf.dp_axis
         b_local = self.batch_size // buf.dp
@@ -531,11 +529,12 @@ class MegastepRunner:
             idx,
             weights,
             rows,
-        ) = shard_map_compat(
+        ) = jax.shard_map(
             shard_body,
             mesh=buf.mesh,
             in_specs=(shd, shd, shd, shd, stk, stk, rep, rep, rep),
             out_specs=(shd, shd, shd, stk, stk, stk),
+            check_vma=False,
         )(storage, priorities, cursors, sizes, mat, flush,
           max_priority, k_sample, beta)
         emit_beacon("ring_scatter", state.step)
@@ -582,11 +581,12 @@ class MegastepRunner:
                     )
                 return p
 
-            priorities = shard_map_compat(
+            priorities = jax.shard_map(
                 write_prios,
                 mesh=buf.mesh,
                 in_specs=(shd, stk, stk),
                 out_specs=shd,
+                check_vma=False,
             )(priorities, idx, td_k)
 
         out = {

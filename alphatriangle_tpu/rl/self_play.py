@@ -775,7 +775,7 @@ class SelfPlayEngine:
         will dispatch — and either deserializes a cached executable or
         compiles + serializes one. Lowering never executes or donates;
         the carry is untouched. Returns True when an AOT executable is
-        ready (`cli warm`, benchmarks/tpu_watch.sh)."""
+        ready (`cli warm`)."""
         t = int(num_moves or self.config.ROLLOUT_CHUNK_MOVES)
         version = self.net.weights_version
         return self._chunk_fn(t).warm(

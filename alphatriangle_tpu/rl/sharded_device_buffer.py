@@ -114,14 +114,13 @@ class ShardedDeviceReplayBuffer(ExperienceBuffer):
         # Device program dispatches this ring made (telemetry gauge).
         self.dispatch_count = 0
 
-        from ..parallel.sharding import shard_map_compat
-
         self._ingest_jit = jax.jit(
-            shard_map_compat(
+            jax.shard_map(
                 self._ingest_local,
                 mesh=mesh,
                 in_specs=(P(dp_axis), P(dp_axis), P(None, dp_axis)),
                 out_specs=(P(dp_axis), P(dp_axis)),
+                check_vma=False,
             ),
             donate_argnums=(0,),
         )

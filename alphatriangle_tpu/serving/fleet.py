@@ -29,6 +29,14 @@ like any other.
 JAX never loads here: replica handles speak JSON lines over pipes,
 and the probe/doctor/policy/ledger stack is stdlib-only (the same
 contract `benchmarks/fleet_smoke.py` pins with an import guard).
+
+One chip belongs to one process. On an accelerator host the parent
+therefore hands every replica exactly one chip through its environment
+before the spawn (`replica_chip_env`), the same chip at every respawn,
+and refuses more replicas than chips at start-up. It learns the chip
+count from a short child that asks JAX and exits (`local_chip_count`),
+so no chip is held when the replicas start. On the CPU nothing is
+assigned.
 """
 
 import json
@@ -58,6 +66,38 @@ from .router import ReplicaError, ReplicaRouter
 logger = logging.getLogger(__name__)
 
 FLEET_FILENAME = "fleet.jsonl"
+
+
+def replica_chip_env(chip: int) -> dict:
+    """What libtpu reads at start-up to open chip `chip` of this host
+    and no other: the visible chip, and that this process is a whole
+    one-chip topology of its own (without the bounds it waits for the
+    host's other chips)."""
+    return {
+        "TPU_VISIBLE_CHIPS": str(chip),
+        "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+        "TPU_PROCESS_BOUNDS": "1,1,1",
+    }
+
+
+def local_chip_count(run=subprocess.run) -> "int | None":
+    """Accelerator chips on this host, or None for a CPU run. Asked of
+    JAX in a child that exits before any replica starts — this process
+    stays JAX-free, and nothing holds a chip afterwards."""
+    if os.environ.get("JAX_PLATFORMS", "").strip().lower() == "cpu":
+        return None
+    proc = run(
+        [
+            sys.executable,
+            "-c",
+            "import jax; print(jax.default_backend(), jax.local_device_count())",
+        ],
+        capture_output=True,
+        text=True,
+        check=True,
+    )
+    backend, count = proc.stdout.split()[-2:]
+    return None if backend == "cpu" else int(count)
 
 
 class _Pending:
@@ -273,12 +313,22 @@ class FleetSupervisor:
         probe_deadline_s: float = 10.0,
         poll_s: float = 0.25,
         spawn_timeout_s: float = 300.0,
+        chips: "int | None" = None,
         popen=subprocess.Popen,
         now=time.time,
         sleep=time.sleep,
     ) -> None:
         from .buckets import BucketLadder
 
+        # `chips`: accelerator chips on this host (`local_chip_count`);
+        # None on the CPU, where replicas share the host.
+        if chips is not None and replicas > chips:
+            raise ValueError(
+                f"{replicas} replicas need one chip each and this host "
+                f"has {chips}: a chip belongs to one process at a time. "
+                f"Run at most {chips} replica(s) here."
+            )
+        self.chips = chips
         self.run_dir = Path(run_dir)
         self.run_dir.mkdir(parents=True, exist_ok=True)
         self.slots = slots
@@ -427,13 +477,17 @@ class FleetSupervisor:
         # death events so one trace_id follows the incarnation.
         ctx = self.trace_ctx.child()
         self._spawn_ctx[handle.name] = ctx
+        env = tracectx.child_env(ctx)
+        if self.chips is not None:
+            # Replica r<i> owns chip i, at every incarnation.
+            env.update(replica_chip_env(self.handles.index(handle)))
         proc = self._popen(
             argv,
             stdin=subprocess.PIPE,
             stdout=subprocess.PIPE,
             stderr=stderr_log,
             text=True,
-            env=tracectx.child_env(ctx),
+            env=env,
         )
         stderr_log.close()
         self._spawn_t[handle.name] = self._now()

@@ -11,9 +11,8 @@ an operator heroic.
 
 Everything is logged to `runs/<run>/supervisor.jsonl` as crash-safe
 one-line events (`MetricsLedger` append discipline): spawn, death
-(with verdict + evidence + the action taken), give-up, complete.
-`tpu_watch.sh` archives the file per window and windows.jsonl keeps
-the death->verdict->restart chain forever.
+(with verdict + evidence + the action taken), give-up, complete: the
+death->verdict->restart chain of the run.
 
 JAX-free contract: like `cli doctor`, this module must keep working
 beside a wedged chip — it imports only stdlib + the telemetry readers

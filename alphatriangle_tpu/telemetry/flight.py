@@ -20,8 +20,8 @@ module closes it:
 - `DispatchWatchdog`: armed per intent, disarmed per seal. Past the
   deadline it dumps faulthandler stacks, runs the caller hook (span
   trace flush, wired in `RunTelemetry`), writes `wedge_report.json`,
-  and exits with `WEDGE_EXIT_CODE` so a supervisor (tpu_watch.sh)
-  reclassifies the window in minutes instead of hours.
+  and exits with `WEDGE_EXIT_CODE` so a supervisor (`cli supervise`)
+  classifies the death in minutes instead of hours.
 - Readers (`read_flight`, `summarize_flight`, `classify_run`): NO JAX
   anywhere on this path — `cli doctor` runs beside a wedged chip, like
   `cli mem`. Sealed per-program times feed `cli perf` (p50/p95 per
@@ -70,7 +70,7 @@ PREEMPT_EXIT_CODE = 114
 
 # Exit code `cli supervise` uses when its restart budget / circuit
 # breaker trips: the child is sick in a way restarts don't fix, and the
-# caller (tpu_watch.sh) should stop burning window on it.
+# caller should stop spending chip time on it.
 SUPERVISOR_GIVEUP_EXIT_CODE = 115
 
 # Memory pressure at/above this fraction of the device limit makes the

@@ -38,6 +38,8 @@ import os
 import time
 from pathlib import Path
 
+from ..utils.flops import peak_info
+
 logger = logging.getLogger(__name__)
 
 COST_KIND = "cost"
@@ -48,19 +50,21 @@ COST_KIND = "cost"
 PEAK_HBM_GBPS_ENV = "ALPHATRIANGLE_PEAK_HBM_GBPS"
 
 # "0" skips the setup-time cost pre-capture for AOT-bypassed programs
-# (training/setup.py). The pre-capture is a fresh lower+compile purely
-# for `cost_analysis()` — on accelerators it doubles as a warm-up, but
-# on CPU it's seconds of pure overhead per process, so the test suite
-# turns it off (tests/conftest.py; subprocess children inherit it).
-# Programs on the AOT dispatch path capture cost regardless.
+# (training/setup.py; CPU backend only — on an accelerator every
+# program is on the AOT dispatch path and captures its cost when it
+# compiles). The pre-capture is a fresh lower+compile purely for
+# `cost_analysis()`: seconds of pure overhead per process, so the test
+# suite turns it off (tests/conftest.py; subprocess children inherit
+# it).
 COST_PRECAPTURE_ENV = "ALPHATRIANGLE_COST_PRECAPTURE"
 
 
 def cost_precapture_enabled() -> bool:
     return os.environ.get(COST_PRECAPTURE_ENV, "1").strip() != "0"
 
-# Peak HBM bandwidth per chip, GB/s. Public figures: v4 1228, v5e
-# (v5 lite) 819, v5p 2765, v6e (Trillium) 1638.
+# Peak HBM bandwidth per chip, GB/s, keyed like the bf16 table in
+# utils/flops.py and from the same source (Google Cloud TPU
+# documentation, system-architecture page of each generation).
 _PEAK_HBM_GBPS = {
     "TPU v4": 1228.0,
     "TPU v5 lite": 819.0,
@@ -89,42 +93,10 @@ _SPAN_CATEGORY_KEYWORDS = (
 
 
 def peak_hbm_gbps_info(device_kind: str) -> "tuple[float | None, str]":
-    """(peak HBM GB/s, source) for a `jax.Device.device_kind`.
-
-    Source is "env" (ALPHATRIANGLE_PEAK_HBM_GBPS override — wins so
-    operators can assert a bandwidth for unlisted chips or CPU
-    smokes), "table" (known chip), or "unknown" (peak None — an
-    explicit marker, never a guessed denominator). Mirrors
-    `utils.flops.peak_bf16_tflops_info` including the space-insensitive
-    longest-prefix fallback over runtime device-kind variants.
-    """
-    override = os.environ.get(PEAK_HBM_GBPS_ENV, "").strip()
-    if override:
-        try:
-            value = float(override)
-            if value > 0:
-                return value, "env"
-            logger.warning(
-                "%s=%r is not positive; ignoring.", PEAK_HBM_GBPS_ENV,
-                override,
-            )
-        except ValueError:
-            logger.warning(
-                "%s=%r is not a number; ignoring.", PEAK_HBM_GBPS_ENV,
-                override,
-            )
-    kind = (device_kind or "").strip()
-    if kind in _PEAK_HBM_GBPS:
-        return _PEAK_HBM_GBPS[kind], "table"
-    norm = kind.lower().replace(" ", "")
-    best = None
-    for name, peak in _PEAK_HBM_GBPS.items():
-        key = name.lower().replace(" ", "")
-        if norm.startswith(key) and (best is None or len(key) > best[0]):
-            best = (len(key), peak)
-    if best:
-        return best[1], "table"
-    return None, "unknown"
+    """(peak HBM GB/s, source) for a `jax.Device.device_kind`: the
+    bandwidth twin of `utils.flops.peak_bf16_tflops_info`, same rules
+    (`utils.flops.peak_info`), override ALPHATRIANGLE_PEAK_HBM_GBPS."""
+    return peak_info(_PEAK_HBM_GBPS, PEAK_HBM_GBPS_ENV, device_kind)
 
 
 def machine_balance_flops_per_byte(

@@ -443,14 +443,21 @@ def setup_training_components(
     # Compiler cost ground truth for the learner-side family
     # (telemetry/roofline.py): on CPU those programs bypass the AOT
     # dispatch path (cpu_aot=False), so nothing would ever capture
-    # their `cost_analysis()` — analyze once at setup. On accelerators
-    # this doubles as a warm-up: the analyzed executable is the cached
-    # one the first dispatch reuses. Best-effort like the block above;
-    # ALPHATRIANGLE_COST_PRECAPTURE=0 skips it (the test suite — the
-    # compile is pure overhead in seconds-long throwaway runs).
+    # their `cost_analysis()` — analyze once at setup. On an
+    # accelerator every dispatched program captures its own cost when
+    # it compiles, and the program analyzed here is not always one the
+    # run dispatches (a fused-K sync run never calls the per-step
+    # program: 90 s of set-up compile on a v5e for nothing). Best-effort
+    # like the block above; ALPHATRIANGLE_COST_PRECAPTURE=0 skips it
+    # (the test suite — the compile is pure overhead in seconds-long
+    # throwaway runs).
     from ..telemetry.roofline import cost_precapture_enabled
 
-    if telemetry.enabled and cost_precapture_enabled():
+    if (
+        telemetry.enabled
+        and cost_precapture_enabled()
+        and jax.default_backend() == "cpu"
+    ):
         try:
             if megastep_runner is not None:
                 megastep_runner.analyze_megastep()

@@ -13,9 +13,8 @@ Conventions:
   + grad wrt weights), so a train step is ~3x forward; `nn.remat`
   recomputes the forward once more (~4x). `train_step_flops` applies
   the right multiplier from ModelConfig.REMAT.
-- Peak table covers the chips this framework targets; unknown device
-  kinds return None and the bench reports MFU as null rather than
-  guessing.
+- Peak table covers the chips this framework targets; the CPU has no
+  peak (MFU null) and any other unlisted device kind is an error.
 """
 
 import logging
@@ -113,12 +112,15 @@ def gather_einsum_flops(batch: int, wave: int, nodes: int, width: int) -> int:
     return 2 * batch * wave * nodes * width
 
 
-# Peak dense bf16 matmul throughput per chip, TFLOP/s. Public figures:
-# v4 275, v5e (v5 lite) 394, v5p 459, v6e (Trillium) 918.
+# Peak dense bf16 matmul throughput per chip, TFLOP/s, keyed by the
+# `device_kind` JAX reports. Source: Google Cloud TPU documentation,
+# the system-architecture page of each generation ("TPU v4", "TPU v5e",
+# "TPU v5p", "TPU v6e"). v5e is 197 in bf16; the 393-394 on the same
+# page is its int8 figure.
 _PEAK_BF16_TFLOPS = {
     "TPU v4": 275.0,
-    "TPU v5 lite": 394.0,
-    "TPU v5e": 394.0,
+    "TPU v5 lite": 197.0,
+    "TPU v5e": 197.0,
     "TPU v5": 459.0,
     "TPU v5p": 459.0,
     "TPU v6 lite": 918.0,
@@ -126,52 +128,53 @@ _PEAK_BF16_TFLOPS = {
 }
 
 
-def peak_bf16_tflops_info(device_kind: str) -> tuple[float | None, str]:
-    """(peak bf16 TFLOP/s, source) for a `jax.Device.device_kind`.
+def peak_info(
+    table: dict[str, float], env_name: str, device_kind: str
+) -> tuple[float | None, str]:
+    """(peak, source) of one peak table for a `jax.Device.device_kind`.
 
-    Source is "env" (ALPHATRIANGLE_PEAK_TFLOPS override — wins so
-    operators can assert a denominator for unlisted chips or CPU
-    smokes), "table" (known chip), or "unknown" (peak None — an
-    explicit marker, never a guessed denominator).
+    Source is "env" (the `env_name` override — wins, so an operator can
+    assert a denominator for a chip not yet listed or for a CPU smoke),
+    "table" (exact `device_kind` match), or "unknown" with peak None for
+    the CPU and for a record that names no device: an explicit marker,
+    never a number. Any other unlisted kind raises — a utilization over
+    a guessed peak is worse than none.
     """
-    override = os.environ.get(PEAK_TFLOPS_ENV, "").strip()
+    override = os.environ.get(env_name, "").strip()
     if override:
         try:
             value = float(override)
             if value > 0:
                 return value, "env"
-            logger.warning(
-                "%s=%r is not positive; ignoring.", PEAK_TFLOPS_ENV, override
-            )
+            logger.warning("%s=%r is not positive; ignoring.", env_name, override)
         except ValueError:
-            logger.warning(
-                "%s=%r is not a number; ignoring.", PEAK_TFLOPS_ENV, override
-            )
+            logger.warning("%s=%r is not a number; ignoring.", env_name, override)
     kind = (device_kind or "").strip()
-    if kind in _PEAK_BF16_TFLOPS:
-        return _PEAK_BF16_TFLOPS[kind], "table"
-    # Longest-prefix fallback, space-insensitive: device kinds vary
-    # across runtime versions ("TPU v5 lite" vs "TPU v5litepod-8").
-    norm = kind.lower().replace(" ", "")
-    best = None
-    for name, peak in _PEAK_BF16_TFLOPS.items():
-        key = name.lower().replace(" ", "")
-        if norm.startswith(key) and (best is None or len(key) > best[0]):
-            best = (len(key), peak)
-    if best:
-        return best[1], "table"
-    return None, "unknown"
+    if kind in table:
+        return table[kind], "table"
+    if kind.lower() in ("", "cpu"):
+        return None, "unknown"
+    raise ValueError(
+        f"no peak listed for device kind {device_kind!r}: add it, with "
+        f"its source, to the table that {env_name} overrides, or set "
+        f"{env_name}."
+    )
+
+
+def peak_bf16_tflops_info(device_kind: str) -> tuple[float | None, str]:
+    """(peak bf16 TFLOP/s, source) — see `peak_info`."""
+    return peak_info(_PEAK_BF16_TFLOPS, PEAK_TFLOPS_ENV, device_kind)
 
 
 def peak_bf16_tflops(device_kind: str) -> float | None:
-    """Peak bf16 TFLOP/s for a `jax.Device.device_kind`, or None
-    (honors the ALPHATRIANGLE_PEAK_TFLOPS override)."""
+    """Peak bf16 TFLOP/s for a `jax.Device.device_kind`; None on the
+    CPU (honors the ALPHATRIANGLE_PEAK_TFLOPS override)."""
     return peak_bf16_tflops_info(device_kind)[0]
 
 
 def mfu(achieved_flops_per_sec: float, device_kind: str) -> float | None:
-    """Fraction of the chip's bf16 peak actually achieved, or None for
-    unknown hardware (never guess a denominator)."""
+    """Fraction of the chip's bf16 peak actually achieved, or None on
+    the CPU (never guess a denominator)."""
     peak = peak_bf16_tflops(device_kind)
     if peak is None or achieved_flops_per_sec <= 0:
         return None

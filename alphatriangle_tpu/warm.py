@@ -16,15 +16,10 @@ program through `.warm()` in parallel threads — XLA compilation
 releases the GIL, so N programs compile concurrently, Podracer-style
 (arXiv:2104.06272 amortizes program build cost off the critical path).
 
-`benchmarks/tpu_watch.sh` runs `cli warm` after every successful chip
-probe: by the time a window is declared healthy and the sweep starts,
-the persistent + AOT caches already hold the sweep's programs.
-
 `cli warm <tuned_preset.json>` warms an autotuned configuration's
 shapes instead (the artifact rides in as BENCH_TUNED_PRESET through
-the same `resolve_bench_plan` path; docs/AUTOTUNE.md) — the watcher
-does this for the tuned preset it just produced, so a tuned run
-launched in the same healthy window starts hot.
+the same `resolve_bench_plan` path; docs/AUTOTUNE.md), so a tuned run
+launched afterwards starts hot.
 """
 
 import concurrent.futures

@@ -387,7 +387,7 @@ def stage_preempt(root: Path) -> int:
                 file=sys.stderr,
             )
             return 2
-        # The doctor invocation tpu_watch.sh makes must read the report.
+        # A JAX-free doctor invocation must read the report.
         code = (
             _NO_JAX_PREAMBLE
             + "from alphatriangle_tpu.cli import main\n"

@@ -21,8 +21,8 @@ accelerator, that the device telemetry plane
    hang (`hang-dispatch` fault) must die by the real watchdog's exit
    113 leaving crash-safe beacons.jsonl rows, a wedge_report.json whose
    frozen `last_beacon` names the phase, and a `cli doctor` dispatch-
-   hung verdict (run with jax imports hard-blocked, exactly as
-   tpu_watch.sh invokes it) that carries that same beacon.
+   hung verdict (run with jax imports hard-blocked) that carries that
+   same beacon.
 
 Exit 0 when every stage passes; the first failing stage's code
 otherwise.
@@ -63,7 +63,7 @@ _NO_JAX_PREAMBLE = (
 
 def run_doctor(run_dir: Path) -> "tuple[int, dict | None]":
     """`cli doctor <run_dir> --json` in a subprocess with jax imports
-    blocked — the exact invocation tpu_watch.sh's archive step makes."""
+    blocked."""
     code = (
         _NO_JAX_PREAMBLE
         + "from alphatriangle_tpu.cli import main\n"

@@ -1,15 +1,13 @@
 """CI window-forensics gate: torn flight ring -> `cli doctor` verdict.
 
 `make doctor-smoke` runs this. It proves, with no accelerator and no
-training run, that the postmortem pipeline the chip watcher depends on
-(benchmarks/tpu_watch.sh, docs/OBSERVABILITY.md "Flight recorder &
-forensics") still closes end to end:
+training run, that the postmortem pipeline (docs/OBSERVABILITY.md
+"Flight recorder & forensics") still closes end to end:
 
 1. a synthetic run dir with sealed flight records, a final UNSEALED
    intent and byte-torn trailing junk — the exact artifact a SIGKILLed
    run leaves — must classify as dispatch-hung naming the hung program,
-   via the `cli doctor` subprocess tpu_watch.sh invokes, with JAX
-   imports hard-blocked in that subprocess;
+   via a `cli doctor` subprocess with JAX imports hard-blocked;
 2. a simulated over-deadline dispatch (real `FlightRecorder` +
    `DispatchWatchdog` with a frozen clock and exit-on-wedge off) must
    dump stacks, write `wedge_report.json`, and doctor to the same
@@ -51,7 +49,7 @@ _NO_JAX_PREAMBLE = (
 
 def run_doctor(run_dir: Path) -> "tuple[int, dict | None]":
     """`cli doctor <run_dir> --json` in a subprocess with jax imports
-    blocked — the exact invocation tpu_watch.sh's archive step makes."""
+    blocked."""
     code = (
         _NO_JAX_PREAMBLE
         + "from alphatriangle_tpu.cli import main\n"

@@ -52,7 +52,7 @@ def main() -> None:
     # Re-call with the resolved backend: the unpinned-auto case defers
     # (utils/helpers.py), and the ladder compiles the flagship search
     # programs repeatedly across rungs.
-    enable_persistent_compilation_cache(backend=jax.default_backend())
+    enable_persistent_compilation_cache()
 
     import numpy as np
 

@@ -1,4 +1,4 @@
-"""Measure the MCTS design bets (VERDICT.md round-2 task 6).
+"""Measure the MCTS design bets.
 
 Three deliberate departures from the reference's C++ search are
 quantified here on the tiny board (fast enough for CPU; the relative

@@ -1,4 +1,4 @@
-"""Close the subtree-reuse bet at flagship scale (round-5 VERDICT #6).
+"""Close the subtree-reuse bet at flagship scale (round 5).
 
 `docs/MCTS_DESIGN.md` §a dropped the reference's subtree reuse
 (`alphatriangle/rl/self_play/worker.py:273-280`) on a measured
@@ -70,7 +70,7 @@ def main() -> int:
         enable_persistent_compilation_cache,
     )
 
-    enable_persistent_compilation_cache(backend=jax.default_backend())
+    enable_persistent_compilation_cache()
 
     # The run's OWN board/net configs (cli eval pattern).
     if args.run_name:

@@ -4,8 +4,8 @@ Every rule is pinned by one fixture true positive AND one near-miss
 true negative, so the analyzer's precision is a test contract. The
 engine tests pin the pragma/baseline semantics and the exit-code
 contract (0 clean / 1 findings-or-stale-baseline / 2 parse error);
-the CLI tests drive `cli lint` exactly as the Makefile and
-tpu_watch.sh preflight do, including the no-jax import guard.
+the CLI tests drive `cli lint` exactly as the Makefile does,
+including the no-jax import guard.
 """
 
 import json
@@ -625,8 +625,8 @@ class TestCliLint:
     def test_cli_lint_never_imports_jax(self):
         """Subprocess import guard: the lint path (CLI + analysis +
         telemetry.flight's family table) must stay JAX-free, exactly
-        like `cli mem`/`cli doctor` — it runs in the tpu_watch.sh
-        preflight beside a possibly-wedged chip."""
+        like `cli mem`/`cli doctor` — it runs beside a process that
+        holds the chip."""
         code = (
             "import builtins, sys\n"
             "real = builtins.__import__\n"

@@ -27,8 +27,8 @@ from alphatriangle_tpu.compile_cache import (
 
 @pytest.fixture(autouse=True)
 def no_xla_persistent_cache():
-    """Disable the XLA persistent cache for this module (conftest turns
-    it on for suite speed). This mirrors the real CPU environment —
+    """Keep the XLA persistent cache off for this module whatever an
+    earlier test did. This mirrors the real CPU environment —
     `enable_persistent_compilation_cache` skips CPU — and matters for
     correctness here: an executable that compile() loads FROM the
     persistent cache serializes to a truncated payload on XLA:CPU, so
@@ -78,6 +78,54 @@ class TestCachedProgram:
         warm = np.asarray(prog2(x))
         assert second.hits == 1 and second.misses == 0
         np.testing.assert_array_equal(cold, warm)
+
+    def test_one_device_program_reloads_for_one_device(self, fresh_cache):
+        """The `execution_devices` regression. jax 0.9's
+        `deserialize_and_load` loads for EVERY device of the backend
+        unless told otherwise, and a one-device program reloaded on this
+        8-device host then refused its first call: "Expected args to
+        execute_sharded_on_local_devices to have 8 shards, got: [1]".
+        A four-chip host does the same to every one-chip program."""
+        import pickle
+
+        assert len(jax.devices()) == 8
+        x = jnp.arange(6.0).reshape(2, 3)
+        fresh_cache.wrap("t/double", jax.jit(_double))(x)
+        (artifact,) = fresh_cache.cache_dir.glob("*.jaxexe")
+        with artifact.open("rb") as fh:
+            assert pickle.load(fh)["device_ids"] == [jax.devices()[0].id]
+
+        second = CompileCache(cache_dir=str(fresh_cache.cache_dir))
+        out = second.wrap("t/double", jax.jit(_double))(x)  # executes
+        assert second.stats()["hits"] == 1
+        assert second.stats()["deserialize_errors"] == 0
+        assert out.devices() == {jax.devices()[0]}
+        np.testing.assert_array_equal(np.asarray(out), np.asarray(x) * 2.0)
+
+    def test_mesh_program_reloads_on_its_own_mesh(self, fresh_cache):
+        """A dp=2 program on devices 2 and 3 — not the default device —
+        reloads for exactly that pair, executes there, and keys apart
+        from the same program on another pair."""
+        from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+        def on(devices):
+            mesh = Mesh(np.array(devices), ("dp",))
+            return jax.device_put(
+                jnp.arange(8.0), NamedSharding(mesh, P("dp"))
+            )
+
+        x = on(jax.devices()[2:4])
+        cold = fresh_cache.wrap("t/mesh", jax.jit(_double))(x)
+        second = CompileCache(cache_dir=str(fresh_cache.cache_dir))
+        prog = second.wrap("t/mesh", jax.jit(_double))
+        warm = prog(x)
+        assert (second.hits, second.misses) == (1, 0)
+        assert warm.devices() == set(jax.devices()[2:4])
+        np.testing.assert_array_equal(np.asarray(cold), np.asarray(warm))
+        # Same shapes and spec on devices 4 and 5: another executable.
+        other = prog(on(jax.devices()[4:6]))
+        assert (second.hits, second.misses) == (1, 1)
+        assert other.devices() == set(jax.devices()[4:6])
 
     def test_warm_populates_without_executing(self, fresh_cache):
         calls = []

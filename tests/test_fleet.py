@@ -782,3 +782,76 @@ def test_fleet_control_plane_is_jax_free():
         mod = sys.modules.get(name)
         assert mod is not None, f"{name} should be imported by this test"
         assert not getattr(mod, "jax", None), name
+
+
+class TestOneChipPerReplica:
+    """A chip belongs to one process: on an accelerator host the
+    JAX-free parent assigns chips through each child's environment."""
+
+    def spawn_all(self, tmp_path, replicas, chips):
+        envs: dict = {}
+
+        def popen(argv, env=None, **kw):
+            name = argv[argv.index("--name") + 1]
+            envs.setdefault(name, []).append(env)
+            return FakeProc(
+                [json.dumps({"kind": "ready", "name": name, "pid": 1}) + "\n"]
+            )
+
+        fleet = FleetSupervisor(
+            tmp_path / "fleet", replicas=replicas, chips=chips, popen=popen
+        )
+        for h in fleet.handles:
+            fleet._spawn(h, "spawn")
+        return fleet, envs
+
+    def test_each_replica_child_gets_its_own_chip(self, tmp_path):
+        fleet, envs = self.spawn_all(tmp_path, replicas=4, chips=4)
+        assert [envs[f"r{i}"][0]["TPU_VISIBLE_CHIPS"] for i in range(4)] == [
+            "0", "1", "2", "3",
+        ]
+        for env in (e[0] for e in envs.values()):
+            assert env["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "1,1,1"
+            assert env["TPU_PROCESS_BOUNDS"] == "1,1,1"
+        # A respawn lands on the chip the dead incarnation held.
+        fleet._spawn(fleet.handles[2], "respawn")
+        assert envs["r2"][1]["TPU_VISIBLE_CHIPS"] == "2"
+
+    def test_more_replicas_than_chips_is_refused(self, tmp_path):
+        with pytest.raises(ValueError, match="2 replicas need one chip each"):
+            FleetSupervisor(
+                tmp_path / "fleet", replicas=2, chips=1, popen=fleet_popen([])
+            )
+        assert not (tmp_path / "fleet").exists()  # refused before any spawn
+
+    def test_cpu_run_assigns_nothing(self, tmp_path, monkeypatch):
+        monkeypatch.delenv("TPU_VISIBLE_CHIPS", raising=False)
+        _, envs = self.spawn_all(tmp_path, replicas=3, chips=None)
+        for env in (e[0] for e in envs.values()):
+            assert "TPU_VISIBLE_CHIPS" not in env
+
+    @pytest.mark.parametrize(
+        "platforms,stdout,want",
+        [
+            ("cpu", None, None),  # asked for by name: no child at all
+            ("", "tpu 4\n", 4),
+            ("", "warning noise\ntpu 1\n", 1),
+            ("", "cpu 1\n", None),  # unpinned, and JAX found no chip
+        ],
+    )
+    def test_chip_count_comes_from_a_child_that_exits(
+        self, monkeypatch, platforms, stdout, want
+    ):
+        import types
+
+        from alphatriangle_tpu.serving.fleet import local_chip_count
+
+        monkeypatch.setenv("JAX_PLATFORMS", platforms)
+        ran = []
+
+        def run(argv, **kw):
+            ran.append(argv)
+            return types.SimpleNamespace(stdout=stdout)
+
+        assert local_chip_count(run=run) == want
+        assert len(ran) == (0 if stdout is None else 1)

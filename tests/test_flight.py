@@ -4,8 +4,8 @@
 Everything here is JAX-free and fast: the recorder/watchdog/classifier
 are pure host-side machinery, and the crash-path tests run real
 subprocesses (SIGKILL mid-dispatch, import-guarded doctor) — the same
-evidence chain `benchmarks/tpu_watch.sh` relies on when a chip window
-dies. Real-dispatch integration (the four hot sites actually sealing
+evidence chain `cli supervise` relies on when a run dies.
+Real-dispatch integration (the four hot sites actually sealing
 records) is gated by `make perf-smoke`, not here, to keep tier-1 fast.
 """
 
@@ -354,7 +354,7 @@ class TestCrashPath:
         assert v["program"] == "megastep/t4_k2"
 
     def test_cli_doctor_names_program_without_jax(self, killed_run):
-        """The full postmortem invocation tpu_watch.sh makes: `cli
+        """The full postmortem invocation: `cli
         doctor` in a subprocess whose import machinery refuses jax,
         exiting nonzero with the hung program named."""
         code = (
@@ -492,7 +492,7 @@ class TestCalibrationIntegration:
 
 class TestWedgeExitCodeContract:
     def test_exit_code_outside_shell_ranges(self):
-        """tpu_watch.sh branches on 113; it must stay clear of shell
+        """Supervisors branch on 113; it must stay clear of shell
         (1, 2, 126-165, 255) and doctor (0-6) codes."""
         assert WEDGE_EXIT_CODE == 113
         assert WEDGE_EXIT_CODE not in DOCTOR_EXIT_CODES.values()

@@ -174,10 +174,10 @@ class TestUtilizationMeter:
         assert rec["transfer_d2h_ms"] == pytest.approx(20.0)
         assert rec["compile_cache_hit_rate"] == pytest.approx(0.75)
 
-    def test_unknown_peak_yields_null_mfu_with_marker(self, monkeypatch):
+    def test_cpu_peak_yields_null_mfu_with_marker(self, monkeypatch):
         monkeypatch.delenv("ALPHATRIANGLE_PEAK_TFLOPS", raising=False)
         clock = FakeClock()
-        meter = make_meter(clock, device_kind="NPU weird9000")
+        meter = make_meter(clock, device_kind="cpu")
         assert meter.peak_tflops is None
         assert meter.peak_source == "unknown"
         meter.tick(step=0)
@@ -186,6 +186,11 @@ class TestUtilizationMeter:
         assert rec["mfu"] is None
         assert rec["peak_bf16_tflops"] is None
         assert rec["peak_source"] == "unknown"
+
+    def test_unlisted_accelerator_refuses_a_meter(self, monkeypatch):
+        monkeypatch.delenv("ALPHATRIANGLE_PEAK_TFLOPS", raising=False)
+        with pytest.raises(ValueError, match="no peak listed"):
+            make_meter(FakeClock(), device_kind="NPU weird9000")
 
     def test_known_chip_uses_table(self, monkeypatch):
         monkeypatch.delenv("ALPHATRIANGLE_PEAK_TFLOPS", raising=False)
@@ -484,15 +489,7 @@ class TestFlopsPeakOverride:
         monkeypatch.setenv("ALPHATRIANGLE_PEAK_TFLOPS", "not-a-number")
         assert peak_bf16_tflops_info("TPU v4") == (275.0, "table")
         monkeypatch.setenv("ALPHATRIANGLE_PEAK_TFLOPS", "-3")
-        assert peak_bf16_tflops_info("nope") == (None, "unknown")
-
-    def test_table_and_unknown(self, monkeypatch):
-        from alphatriangle_tpu.utils.flops import peak_bf16_tflops_info
-
-        monkeypatch.delenv("ALPHATRIANGLE_PEAK_TFLOPS", raising=False)
-        assert peak_bf16_tflops_info("TPU v5 lite") == (394.0, "table")
-        assert peak_bf16_tflops_info("TPU v5litepod-8") == (394.0, "table")
-        assert peak_bf16_tflops_info("Quantum Q1") == (None, "unknown")
+        assert peak_bf16_tflops_info("cpu") == (None, "unknown")
 
 
 class TestLegacyDeviceStatsTolerance:

@@ -1,5 +1,5 @@
 """Batched MCTS tests: helper contracts, search invariants on the tiny
-env, and the VERDICT.md #7 'Done =' bar — MCTS with an untrained net
+env, and the acceptance bar — MCTS with an untrained net
 beats uniform-random play on average score."""
 
 import jax
@@ -137,7 +137,7 @@ class TestSearch:
     def test_mcts_beats_random(
         self, mcts_world, tiny_env_config, tiny_mcts_config
     ):
-        """VERDICT #7 bar: untrained-net MCTS > uniform random play."""
+        """The bar: untrained-net MCTS > uniform random play."""
         env, _, net, mcts = mcts_world
         B, max_moves = 16, 40
         rng = np.random.default_rng(0)

@@ -524,7 +524,13 @@ def memory_smoke_run(
         PER_BETA_ANNEAL_STEPS=8,
         N_STEP_RETURNS=2,
         WORKER_UPDATE_FREQ_STEPS=2,
-        CHECKPOINT_SAVE_FREQ_STEPS=4,
+        # No save inside the run, only the final one (which the loop
+        # waits out before its last tick): while an async save is in
+        # flight orbax 0.11.32 holds a single-device copy of every
+        # state leaf per device, and on the CPU backend — where
+        # bytes-in-use is synthesized from jax.live_arrays() — those
+        # staging copies read as 5x the run's real resident set.
+        CHECKPOINT_SAVE_FREQ_STEPS=1000,
         MAX_EPISODE_MOVES=30,
         RANDOM_SEED=5,
     )

@@ -96,14 +96,19 @@ class TestPeakHbm:
         assert peak_hbm_gbps_info("TPU v5e") == (819.0, "table")
         assert peak_hbm_gbps_info("TPU v5p") == (2765.0, "table")
 
-    def test_prefix_fallback_matches_runtime_variants(self, monkeypatch):
+    @pytest.mark.parametrize(
+        "kind", ["TPU v5litepod-8", "TPU v4 megacore", "Quantum Q1"]
+    )
+    def test_unlisted_accelerator_raises_no_prefix_guess(
+        self, monkeypatch, kind
+    ):
         monkeypatch.delenv(PEAK_HBM_GBPS_ENV, raising=False)
-        assert peak_hbm_gbps_info("TPU v5litepod-8") == (819.0, "table")
-        assert peak_hbm_gbps_info("TPU v4 megacore") == (1228.0, "table")
+        with pytest.raises(ValueError, match="no peak listed"):
+            peak_hbm_gbps_info(kind)
 
-    def test_unknown_is_explicit_not_guessed(self, monkeypatch):
+    def test_cpu_is_explicit_unknown(self, monkeypatch):
         monkeypatch.delenv(PEAK_HBM_GBPS_ENV, raising=False)
-        assert peak_hbm_gbps_info("Quantum Q1") == (None, "unknown")
+        assert peak_hbm_gbps_info("cpu") == (None, "unknown")
         assert peak_hbm_gbps_info("") == (None, "unknown")
 
     def test_env_override_wins_with_provenance(self, monkeypatch):

@@ -1,5 +1,5 @@
 """Stats collector + Orbax persistence tests (trieye-equivalent surface;
-VERDICT.md #10 'Done =' bar: kill a run mid-training, rerun, resume)."""
+the resume bar: kill a run mid-training, rerun, resume)."""
 
 import numpy as np
 import pytest

@@ -381,7 +381,7 @@ class TestSupervisor:
     ],
 )
 def test_exit_code_registry(codes):
-    """The exit codes tpu_watch.sh branches on are a public contract."""
+    """The exit codes supervisors branch on are a public contract."""
     assert WEDGE_EXIT_CODE == codes["WEDGE_EXIT_CODE"]
     assert PREEMPT_EXIT_CODE == codes["PREEMPT_EXIT_CODE"]
     assert SUPERVISOR_GIVEUP_EXIT_CODE == codes["SUPERVISOR_GIVEUP_EXIT_CODE"]

@@ -1,5 +1,5 @@
 """Learner tests (reference matrix: `tests/rl/test_trainer.py:135-270`)
-plus the multi-device dp-sharding correctness story from VERDICT.md #3:
+plus the multi-device dp-sharding correctness story:
 an 8-virtual-device train step keeps replicas bit-identical and matches
 the single-device result."""
 
@@ -462,7 +462,7 @@ class TestBatchNormPath:
 
 
 class TestMultiDevice:
-    """VERDICT #3 'Done =' criteria: dp-sharded batch, params change,
+    """The multi-device criteria: dp-sharded batch, params change,
     replicas stay bit-identical, and the result matches single-device."""
 
     def test_8dev_step_matches_single_device(

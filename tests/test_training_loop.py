@@ -1,6 +1,6 @@
 """Loop/runner integration tests (reference
 `tests/training/test_loop_integration.py:328-428` — but with REAL
-components instead of mocks, as VERDICT.md #9 demands: a tiny-config
+components instead of mocks: a tiny-config
 end-to-end run on CPU, then kill + resume)."""
 
 import numpy as np
@@ -427,7 +427,7 @@ class TestAsyncLoop:
     ):
         """A TRANSIENT producer crash is healed by supervision: the
         stream respawns (fresh engine, shared compiled programs) and
-        the run completes (VERDICT r4 item 8; improves on reference
+        the run completes (improves on reference
         `worker_manager.py:153-159`, which only removes dead actors)."""
         from alphatriangle_tpu.rl.self_play import SelfPlayEngine
 
@@ -461,7 +461,7 @@ class TestAsyncLoop:
 class TestRunnerResume:
     @pytest.mark.slow
     def test_run_training_and_resume(self, tmp_path, tiny_world_configs):
-        """VERDICT #10 bar: run, 'kill', rerun -> resumes from latest."""
+        """The resume bar: run, 'kill', rerun -> resumes from latest."""
         env_cfg, model_cfg, mcts_cfg = tiny_world_configs
         pc = PersistenceConfig(ROOT_DATA_DIR=str(tmp_path), RUN_NAME="resume_run")
         tc = make_train_cfg("resume_run", str(tmp_path), MAX_TRAINING_STEPS=4)

@@ -1,5 +1,8 @@
 """SumTree + helper tests (reference analog: buffer/sumtree unit tests)."""
 
+import os
+from pathlib import Path
+
 import numpy as np
 import pytest
 
@@ -154,72 +157,152 @@ class TestFlops:
             4 * 8 * f_small
         )
 
-    def test_peak_table_and_mfu(self):
-        from alphatriangle_tpu.utils.flops import mfu, peak_bf16_tflops
+    def test_peak_table_and_mfu(self, monkeypatch):
+        from alphatriangle_tpu.utils.flops import mfu, peak_bf16_tflops_info
 
-        assert peak_bf16_tflops("TPU v5 lite") == 394.0
-        assert peak_bf16_tflops("TPU v5litepod-8") == 394.0
-        assert peak_bf16_tflops("cpu") is None
-        assert mfu(394e12 / 2, "TPU v5 lite") == 0.5
-        assert mfu(1.0, "unknown-chip") is None
+        monkeypatch.delenv("ALPHATRIANGLE_PEAK_TFLOPS", raising=False)
+        # bf16, not the int8 figure (394) the table once carried.
+        assert peak_bf16_tflops_info("TPU v5 lite") == (197.0, "table")
+        assert peak_bf16_tflops_info("TPU v5e") == (197.0, "table")
+        assert mfu(197e12 / 2, "TPU v5 lite") == 0.5
+
+    @pytest.mark.parametrize("kind", ["cpu", "CPU", ""])
+    def test_cpu_peak_stays_unknown(self, monkeypatch, kind):
+        from alphatriangle_tpu.utils.flops import mfu, peak_bf16_tflops_info
+
+        monkeypatch.delenv("ALPHATRIANGLE_PEAK_TFLOPS", raising=False)
+        assert peak_bf16_tflops_info(kind) == (None, "unknown")
+        assert mfu(1.0, kind) is None
+
+    @pytest.mark.parametrize(
+        "kind", ["unknown-chip", "TPU v5litepod-8", "TPU v9"]
+    )
+    def test_unlisted_accelerator_kind_raises(self, monkeypatch, kind):
+        """No prefix guess, no None: an accelerator the table does not
+        list is an error until someone adds it with its source."""
+        from alphatriangle_tpu.utils.flops import peak_bf16_tflops_info
+
+        monkeypatch.delenv("ALPHATRIANGLE_PEAK_TFLOPS", raising=False)
+        with pytest.raises(ValueError, match="no peak listed"):
+            peak_bf16_tflops_info(kind)
+
+
+def _stub_jax(monkeypatch, backend=None):
+    """Swap utils.helpers' view of jax for a stub that records
+    `config.update` calls and resolves to `backend`: the conftest pins
+    the CPU process-wide, a stub lets each case say what it sees."""
+    import types
+
+    from alphatriangle_tpu.utils import helpers
+
+    recorded: list = []
+    monkeypatch.setattr(
+        helpers,
+        "jax",
+        types.SimpleNamespace(
+            config=types.SimpleNamespace(
+                update=lambda k, v: recorded.append((k, v))
+            ),
+            default_backend=lambda: backend,
+        ),
+    )
+    return helpers, recorded
 
 
 class TestCompileCacheGate:
     """The persistent-cache gate must never enable for a CPU backend
-    (XLA:CPU AOT reloads log SIGILL-risk feature mismatches) — including
-    the auto-on-a-cpu-only-host path where no platform is pinned."""
+    (XLA:CPU AOT reloads log SIGILL-risk feature mismatches), must give
+    an accelerator run a cache whether or not a platform is pinned, and
+    must name a directory only when the environment names none."""
 
-    def _calls(self, monkeypatch, env_platforms=None):
-        import types
-
-        from alphatriangle_tpu.utils import helpers
-
-        recorded = []
-        # Stub the module's jax view: jax.config is read-only property
-        # soup, and the conftest pins jax_platforms=cpu process-wide —
-        # a stub lets each case control exactly what the gate sees.
-        config = types.SimpleNamespace(
-            jax_platforms="",
-            update=lambda k, v: recorded.append((k, v)),
-        )
-        monkeypatch.setattr(
-            helpers, "jax", types.SimpleNamespace(config=config)
-        )
-        if env_platforms is None:
-            monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    def _calls(self, monkeypatch, backend, env_dir=None):
+        monkeypatch.delenv("ALPHATRIANGLE_NO_COMPILE_CACHE", raising=False)
+        if env_dir is None:
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
         else:
-            monkeypatch.setenv("JAX_PLATFORMS", env_platforms)
-        return helpers, recorded
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+        return _stub_jax(monkeypatch, backend)
 
-    def test_resolved_cpu_backend_skips(self, monkeypatch):
-        helpers, calls = self._calls(monkeypatch)
-        helpers.enable_persistent_compilation_cache(backend="cpu")
-        assert calls == []
-
-    def test_resolved_tpu_backend_enables(self, monkeypatch):
-        helpers, calls = self._calls(monkeypatch)
-        helpers.enable_persistent_compilation_cache(backend="tpu")
-        assert any(k == "jax_compilation_cache_dir" for k, _ in calls)
-
-    def test_unpinned_auto_defers(self, monkeypatch):
-        # No pinned platform and no resolved backend: must NOT enable —
-        # the run may resolve to XLA:CPU (the SIGILL-risk path).
-        helpers, calls = self._calls(monkeypatch)
+    def test_cpu_backend_skips(self, monkeypatch):
+        helpers, calls = self._calls(monkeypatch, "cpu")
         helpers.enable_persistent_compilation_cache()
         assert calls == []
 
-    def test_pinned_cpu_skips(self, monkeypatch):
-        helpers, calls = self._calls(monkeypatch, env_platforms="cpu")
+    def test_unpinned_tpu_run_gets_the_in_checkout_cache(self, monkeypatch):
+        # No JAX_PLATFORMS at all (the chip machine's default run).
+        monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+        helpers, calls = self._calls(monkeypatch, "tpu")
         helpers.enable_persistent_compilation_cache()
-        assert calls == []
+        repo = Path(helpers.__file__).resolve().parents[2]
+        assert calls == [
+            ("jax_compilation_cache_dir", str(repo / ".cache" / "jax"))
+        ]
 
-    def test_pinned_tpu_enables(self, monkeypatch):
-        helpers, calls = self._calls(monkeypatch, env_platforms="tpu")
+    def test_env_dir_set_means_no_code_sets_another(
+        self, monkeypatch, tmp_path
+    ):
+        from alphatriangle_tpu import compile_cache
+
+        helpers, calls = self._calls(
+            monkeypatch, "tpu", env_dir=str(tmp_path)
+        )
+        monkeypatch.delenv("ALPHATRIANGLE_AOT_CACHE_DIR")
         helpers.enable_persistent_compilation_cache()
-        assert any(k == "jax_compilation_cache_dir" for k, _ in calls)
+        assert calls == []  # JAX read the variable itself
+        assert helpers.compilation_cache_root() == str(tmp_path)
+        assert compile_cache.default_cache_dir() == str(tmp_path / "aot")
 
     def test_opt_out_env_wins(self, monkeypatch):
-        helpers, calls = self._calls(monkeypatch)
+        helpers, calls = self._calls(monkeypatch, "tpu")
         monkeypatch.setenv("ALPHATRIANGLE_NO_COMPILE_CACHE", "1")
-        helpers.enable_persistent_compilation_cache(backend="tpu")
+        helpers.enable_persistent_compilation_cache()
         assert calls == []
+
+    def test_unset_dir_is_fixed_across_processes(self, monkeypatch):
+        """Unset, the cache sits at one in-checkout path: two fresh
+        processes name the same directory (the path is part of JAX's
+        cache key), and it is neither /tmp nor made from a pid."""
+        import subprocess
+        import sys
+
+        repo = Path(__file__).resolve().parents[1]
+        env = {
+            k: v
+            for k, v in os.environ.items()
+            if k
+            not in ("JAX_COMPILATION_CACHE_DIR", "ALPHATRIANGLE_AOT_CACHE_DIR")
+        }
+        env["PYTHONPATH"] = str(repo)
+        code = (
+            "from alphatriangle_tpu.compile_cache import default_cache_dir;"
+            "print(default_cache_dir())"
+        )
+        outs = [
+            subprocess.run(
+                [sys.executable, "-c", code],
+                env=env,
+                cwd=cwd,
+                capture_output=True,
+                text=True,
+                check=True,
+            ).stdout.strip()
+            for cwd in (str(repo), "/")
+        ]
+        assert outs == [str(repo / ".cache" / "jax" / "aot")] * 2
+
+
+class TestEnforcePlatform:
+    def test_auto_leaves_the_choice_to_jax(self, monkeypatch):
+        helpers, calls = _stub_jax(monkeypatch)
+        helpers.enforce_platform("auto")
+        assert calls == []
+
+    @pytest.mark.parametrize("device", ["cpu", "tpu"])
+    def test_explicit_device_pins_the_platform(self, monkeypatch, device):
+        """"tpu" pins too: with none attached backend start-up raises
+        instead of the run landing on the host."""
+        helpers, calls = _stub_jax(monkeypatch)
+        monkeypatch.setenv("JAX_PLATFORMS", "")
+        helpers.enforce_platform(device)
+        assert calls == [("jax_platforms", device)]
+        assert os.environ["JAX_PLATFORMS"] == ("cpu" if device == "cpu" else "")
