@@ -48,6 +48,7 @@ import os
 import pickle
 import threading
 import time
+import weakref
 from contextlib import nullcontext
 from pathlib import Path
 
@@ -218,6 +219,9 @@ class CompileCache:
         # accessed / transcendentals, persisted as `.cost.json`
         # sidecars and drained into run ledgers for `cli roofline`.
         self.cost_records: dict[str, dict] = {}
+        # The wrapped programs that are alive: `executables()` reads
+        # their compiled objects (profiling.py's phase table).
+        self._programs: "weakref.WeakSet[CachedProgram]" = weakref.WeakSet()
 
     # --- wiring -----------------------------------------------------------
 
@@ -255,10 +259,24 @@ class CompileCache:
         artifacts on ANY backend — for programs whose executables are
         not round-trippable, e.g. beacon-armed programs embedding
         `jax.debug.callback` closures (telemetry/device_stats.py)."""
-        return CachedProgram(
+        program = CachedProgram(
             self, name, jit_fn, extra=extra, cpu_aot=cpu_aot,
             serialize=serialize,
         )
+        self._programs.add(program)
+        return program
+
+    def executables(self) -> list[tuple[str, object]]:
+        """(program name, `jax.stages.Compiled`) of every AOT executable
+        a live wrapped program holds. The compiled text is where an
+        operation's `op_name` (and with it its phase) is kept; a TPU
+        trace names the instruction only."""
+        out = []
+        for program in list(self._programs):
+            for exe in list(program._execs.values()):
+                if exe is not _FALLBACK:
+                    out.append((program.name, exe))
+        return out
 
     # --- keying -----------------------------------------------------------
 

@@ -96,26 +96,28 @@ class GumbelMCTS(BatchedMCTS):
         rng, init_rng, gumbel_rng, wave_rng = jax.random.split(rng, 4)
         tree = self._init_tree(variables, root_states, init_rng)
 
-        valid = tree.valid[:, 0, :] > 0  # (B, A)
-        logits = jnp.where(
-            valid, jnp.log(jnp.maximum(tree.prior[:, 0, :], 1e-12)), -jnp.inf
-        )
-        g = (
-            jnp.zeros((batch, a))
-            if self.exploit
-            else jax.random.gumbel(gumbel_rng, (batch, a))
-        )
-        base_score = jnp.where(valid, g + logits, -jnp.inf)  # (B, A)
+        with jax.named_scope("gumbel/root"):
+            valid = tree.valid[:, 0, :] > 0  # (B, A)
+            logits = jnp.where(
+                valid, jnp.log(jnp.maximum(tree.prior[:, 0, :], 1e-12)), -jnp.inf
+            )
+            g = (
+                jnp.zeros((batch, a))
+                if self.exploit
+                else jax.random.gumbel(gumbel_rng, (batch, a))
+            )
+            base_score = jnp.where(valid, g + logits, -jnp.inf)  # (B, A)
 
-        # Initial candidates: top-m by g + logits among valid actions.
-        # m is clamped to the wave size so EVERY survivor receives at
-        # least one simulation per halving phase — otherwise arms could
-        # be halved (or even played) on sigma(q)=0 without ever being
-        # simulated.
-        m0 = min(self.m_candidates, w, a)
-        kth = jnp.sort(base_score, axis=-1)[:, -m0][:, None]
-        cand = valid & (base_score >= kth)  # (B, A) may hold < m0 rows
+            # Initial candidates: top-m by g + logits among valid actions.
+            # m is clamped to the wave size so EVERY survivor receives at
+            # least one simulation per halving phase — otherwise arms could
+            # be halved (or even played) on sigma(q)=0 without ever being
+            # simulated.
+            m0 = min(self.m_candidates, w, a)
+            kth = jnp.sort(base_score, axis=-1)[:, -m0][:, None]
+            cand = valid & (base_score >= kth)  # (B, A) may hold < m0 rows
 
+        @jax.named_scope("gumbel/root")
         def assign_roots(tree, cand_mask: jax.Array) -> jax.Array:
             """(B, A) mask -> (B, W) member root actions.
 
@@ -141,6 +143,7 @@ class GumbelMCTS(BatchedMCTS):
             force = (j < count) | expanded
             return jnp.where(force, roots, -1)
 
+        @jax.named_scope("gumbel/root")
         def halve(tree, cand_mask: jax.Array) -> jax.Array:
             """Keep the better half of the candidates by g+logits+sigma(q)."""
             q, visits = self._root_q(tree)
@@ -191,33 +194,34 @@ class GumbelMCTS(BatchedMCTS):
         tree, wasted, base = final[0], final[1], final[2]
         stats_tail, cand = final[3:-1], final[-1]
 
-        q, visits = self._root_q(tree)
-        final_score = jnp.where(
-            cand, base_score + self._sigma(q, visits), -jnp.inf
-        )
-        selected = jnp.argmax(final_score, axis=-1).astype(jnp.int32)
-        # Terminal roots have no meaningful selection; mirror PUCT's
-        # no-visit sentinel so the host-side guard logic stays shared.
-        selected = jnp.where(root_states.done, -1, selected)
+        with jax.named_scope("gumbel/root"):
+            q, visits = self._root_q(tree)
+            final_score = jnp.where(
+                cand, base_score + self._sigma(q, visits), -jnp.inf
+            )
+            selected = jnp.argmax(final_score, axis=-1).astype(jnp.int32)
+            # Terminal roots have no meaningful selection; mirror PUCT's
+            # no-visit sentinel so the host-side guard logic stays shared.
+            selected = jnp.where(root_states.done, -1, selected)
 
-        # Completed-Q improved policy (paper §4): unvisited actions
-        # take the root network value (simplified value mix).
-        q_completed = jnp.where(visits > 0, q, tree.root_value0[:, None])
-        improved_logits = jnp.where(
-            valid, logits + self._sigma(q_completed, visits), -jnp.inf
-        )
-        any_valid = valid.any(axis=-1, keepdims=True)
-        improved = jax.nn.softmax(
-            jnp.where(any_valid, improved_logits, 0.0), axis=-1
-        )
-        improved = jnp.where(valid, improved, 0.0)
-        norm = improved.sum(axis=-1, keepdims=True)
-        improved = improved / jnp.maximum(norm, 1e-9)
+            # Completed-Q improved policy (paper §4): unvisited actions
+            # take the root network value (simplified value mix).
+            q_completed = jnp.where(visits > 0, q, tree.root_value0[:, None])
+            improved_logits = jnp.where(
+                valid, logits + self._sigma(q_completed, visits), -jnp.inf
+            )
+            any_valid = valid.any(axis=-1, keepdims=True)
+            improved = jax.nn.softmax(
+                jnp.where(any_valid, improved_logits, 0.0), axis=-1
+            )
+            improved = jnp.where(valid, improved, 0.0)
+            norm = improved.sum(axis=-1, keepdims=True)
+            improved = improved / jnp.maximum(norm, 1e-9)
 
-        root_visits = 1.0 + visits.sum(axis=-1)
-        root_value = (
-            tree.root_value0 + tree.e_value[:, 0, :].sum(axis=-1)
-        ) / root_visits
+            root_visits = 1.0 + visits.sum(axis=-1)
+            root_value = (
+                tree.root_value0 + tree.e_value[:, 0, :].sum(axis=-1)
+            ) / root_visits
         stats = None
         if self.device_stats:
             stats = self._stat_pack(tree, wasted, base, stats_tail[0], batch)

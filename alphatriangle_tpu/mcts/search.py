@@ -188,6 +188,7 @@ class BatchedMCTS:
 
     # --- network evaluation ----------------------------------------------
 
+    @jax.named_scope("search/evaluate")
     def _evaluate(self, variables, states: EnvState):
         """Batched leaf eval: states (B-leading) -> (priors (B,A), values (B,)).
 
@@ -197,7 +198,10 @@ class BatchedMCTS:
         """
         from ..nn.precision import dequantize_params
 
-        grids, others = jax.vmap(self.extractor.extract)(states)
+        # The leaves' input features belong to expanding them, not to
+        # the net (innermost scope wins in the phase table).
+        with jax.named_scope("search/expand"):
+            grids, others = jax.vmap(self.extractor.extract)(states)
         # Int8 weight-only inference (nn/precision.py): marker-dict
         # leaves dequantize to bf16 here, at the one place every search
         # family evaluates the net; unquantized trees pass through.
@@ -222,6 +226,7 @@ class BatchedMCTS:
 
     # --- the search -------------------------------------------------------
 
+    @jax.named_scope("search/init")
     def _init_tree(self, variables, root_states: EnvState, rng) -> Tree:
         """Batched tree init: root eval + Dirichlet noise."""
         cfg = self.config
@@ -264,6 +269,7 @@ class BatchedMCTS:
             root_value0=root_value,
         )
 
+    @jax.named_scope("search/descend")
     def _descend_wave(
         self,
         tree: Tree,
@@ -418,118 +424,121 @@ class BatchedMCTS:
         parents, actions, existing = d["parents"], d["actions"], d["existing"]
         is_new = existing < 0
 
-        # Canonicalize within-wave duplicates: members that chose the
-        # same edge share one child node — the one belonging to the
-        # highest member index (matching the `.max()` scatter below).
-        key = parents * a + actions  # (B, W)
-        same = key[:, :, None] == key[:, None, :]  # (B, W, W)
-        later = warange[None, None, :] > warange[None, :, None]
-        is_canon = ~(same & later).any(axis=-1)  # (B, W)
+        with jax.named_scope("search/expand"):
+            # Canonicalize within-wave duplicates: members that chose the
+            # same edge share one child node — the one belonging to the
+            # highest member index (matching the `.max()` scatter below).
+            key = parents * a + actions  # (B, W)
+            same = key[:, :, None] == key[:, None, :]  # (B, W, W)
+            later = warange[None, None, :] > warange[None, :, None]
+            is_canon = ~(same & later).any(axis=-1)  # (B, W)
 
-        # 2. Expansion: one batched env.step over all B*W edges.
-        # (The engine is deterministic given the node's PRNG state, so
-        # duplicate/revisited edges reproduce the same child state.)
-        parent_states = jax.tree_util.tree_map(
-            lambda x: x[bcol, parents].reshape((batch * w,) + x.shape[2:]),
-            tree.node_state,
-        )
-        new_states, rewards, dones = jax.vmap(self.env.step)(
-            parent_states, actions.reshape(-1)
-        )
-        rewards = rewards.reshape(batch, w)
-        dones = dones.reshape(batch, w)
+            # 2. Expansion: one batched env.step over all B*W edges.
+            # (The engine is deterministic given the node's PRNG state, so
+            # duplicate/revisited edges reproduce the same child state.)
+            parent_states = jax.tree_util.tree_map(
+                lambda x: x[bcol, parents].reshape((batch * w,) + x.shape[2:]),
+                tree.node_state,
+            )
+            new_states, rewards, dones = jax.vmap(self.env.step)(
+                parent_states, actions.reshape(-1)
+            )
+            rewards = rewards.reshape(batch, w)
+            dones = dones.reshape(batch, w)
 
         # 3. Evaluation: ONE fused network call for all B*W leaves.
         priors, values, valid = self._evaluate(variables, new_states)
         leaf_values = jnp.where(dones, 0.0, values.reshape(batch, w))
 
-        # 4. Insert the wave's W node slots as one block at [base, base+W).
-        if jnp.ndim(base) == 0:
-            # Shared scalar base (fresh-root search): a dynamic-slice
-            # block write, the original lowering verbatim.
-            def insert(buf, block):
-                return jax.lax.dynamic_update_slice_in_dim(
-                    buf, block.astype(buf.dtype), base, axis=1
+        with jax.named_scope("search/expand"):
+            # 4. Insert the wave's W node slots as one block at [base, base+W).
+            if jnp.ndim(base) == 0:
+                # Shared scalar base (fresh-root search): a dynamic-slice
+                # block write, the original lowering verbatim.
+                def insert(buf, block):
+                    return jax.lax.dynamic_update_slice_in_dim(
+                        buf, block.astype(buf.dtype), base, axis=1
+                    )
+
+                slot_ids = (base + warange[None, :]).astype(jnp.float32)  # (1, W)
+            else:
+                # Per-game base (subtree reuse: each game retained a
+                # different row count): scatter rows [base_b, base_b + W).
+                slots = base[:, None] + warange[None, :]  # (B, W)
+
+                def insert(buf, block):
+                    return buf.at[bcol, slots].set(block.astype(buf.dtype))
+
+                slot_ids = slots.astype(jnp.float32)
+
+            ns = jax.tree_util.tree_map(
+                lambda buf, x: insert(buf, x.reshape((batch, w) + x.shape[1:])),
+                tree.node_state,
+                new_states,
+            )
+            live = is_new & is_canon
+            tree = tree.replace(
+                node_state=ns,
+                prior=insert(tree.prior, priors.reshape(batch, w, a)),
+                valid=insert(
+                    tree.valid, valid.reshape(batch, w, a).astype(jnp.float32)
+                ),
+                terminal=insert(tree.terminal, dones),
+            )
+
+        with jax.named_scope("search/backup"):
+            # 5. Insertion + backup along the recorded paths as one fused
+            # edge-plane update (ops/mcts_backup.py; lowering per config).
+            # Suffix returns first: G_d = r_d + discount * G_{d+1}, where
+            # the deepest active level's reward is the fresh step reward (a
+            # new edge has no stored reward yet; for revisits the stored
+            # value is identical by determinism).
+            rec_node, rec_action = d["rec_node"], d["rec_action"]
+            rec_active = d["rec_active"]  # (B, W, D)
+            last_idx = rec_active.sum(axis=-1) - 1  # (B, W) deepest level
+            if hist is not None:
+                # Leaf-depth histogram: one count per simulation at its
+                # descent depth (terminal-root sims land in bin 0; depths
+                # past the last bin clip into it). A (B*W, BINS) one-hot
+                # sum — vector math on data already in registers.
+                d_bin = jnp.clip(last_idx, 0, DEPTH_BINS - 1).reshape(-1)
+                hist = hist + jax.nn.one_hot(
+                    d_bin, DEPTH_BINS, dtype=jnp.float32
+                ).sum(axis=0)
+            g = leaf_values  # (B, W)
+            contrib = []
+            for lvl in range(depth - 1, -1, -1):
+                is_last = rec_active[:, :, lvl] & (last_idx == lvl)
+                r_lvl = jnp.where(
+                    is_last, rewards, d["rec_reward"][:, :, lvl]
                 )
+                g = jnp.where(
+                    rec_active[:, :, lvl], r_lvl + cfg.discount * g, g
+                )
+                contrib.append(g)
+            contrib.reverse()  # contrib[lvl] = G at level lvl, (B, W)
 
-            slot_ids = (base + warange[None, :]).astype(jnp.float32)  # (1, W)
-        else:
-            # Per-game base (subtree reuse: each game retained a
-            # different row count): scatter rows [base_b, base_b + W).
-            slots = base[:, None] + warange[None, :]  # (B, W)
-
-            def insert(buf, block):
-                return buf.at[bcol, slots].set(block.astype(buf.dtype))
-
-            slot_ids = slots.astype(jnp.float32)
-
-        ns = jax.tree_util.tree_map(
-            lambda buf, x: insert(buf, x.reshape((batch, w) + x.shape[1:])),
-            tree.node_state,
-            new_states,
-        )
-        live = is_new & is_canon
-        tree = tree.replace(
-            node_state=ns,
-            prior=insert(tree.prior, priors.reshape(batch, w, a)),
-            valid=insert(
-                tree.valid, valid.reshape(batch, w, a).astype(jnp.float32)
-            ),
-            terminal=insert(tree.terminal, dones),
-        )
-
-        # 5. Insertion + backup along the recorded paths as one fused
-        # edge-plane update (ops/mcts_backup.py; lowering per config).
-        # Suffix returns first: G_d = r_d + discount * G_{d+1}, where
-        # the deepest active level's reward is the fresh step reward (a
-        # new edge has no stored reward yet; for revisits the stored
-        # value is identical by determinism).
-        rec_node, rec_action = d["rec_node"], d["rec_action"]
-        rec_active = d["rec_active"]  # (B, W, D)
-        last_idx = rec_active.sum(axis=-1) - 1  # (B, W) deepest level
-        if hist is not None:
-            # Leaf-depth histogram: one count per simulation at its
-            # descent depth (terminal-root sims land in bin 0; depths
-            # past the last bin clip into it). A (B*W, BINS) one-hot
-            # sum — vector math on data already in registers.
-            d_bin = jnp.clip(last_idx, 0, DEPTH_BINS - 1).reshape(-1)
-            hist = hist + jax.nn.one_hot(
-                d_bin, DEPTH_BINS, dtype=jnp.float32
-            ).sum(axis=0)
-        g = leaf_values  # (B, W)
-        contrib = []
-        for lvl in range(depth - 1, -1, -1):
-            is_last = rec_active[:, :, lvl] & (last_idx == lvl)
-            r_lvl = jnp.where(
-                is_last, rewards, d["rec_reward"][:, :, lvl]
+            e_visits, e_value, children, e_reward = backup_update(
+                tree.e_visits,
+                tree.e_value,
+                tree.children,
+                tree.e_reward,
+                parents,
+                actions,
+                jnp.where(is_new, slot_ids, -1.0),
+                rewards,
+                rec_node,
+                rec_action,
+                rec_active,
+                jnp.stack(contrib, axis=-1),
+                mode=cfg.backup_update,
             )
-            g = jnp.where(
-                rec_active[:, :, lvl], r_lvl + cfg.discount * g, g
+            tree = tree.replace(
+                e_visits=e_visits,
+                e_value=e_value,
+                children=children,
+                e_reward=e_reward,
             )
-            contrib.append(g)
-        contrib.reverse()  # contrib[lvl] = G at level lvl, (B, W)
-
-        e_visits, e_value, children, e_reward = backup_update(
-            tree.e_visits,
-            tree.e_value,
-            tree.children,
-            tree.e_reward,
-            parents,
-            actions,
-            jnp.where(is_new, slot_ids, -1.0),
-            rewards,
-            rec_node,
-            rec_action,
-            rec_active,
-            jnp.stack(contrib, axis=-1),
-            mode=cfg.backup_update,
-        )
-        tree = tree.replace(
-            e_visits=e_visits,
-            e_value=e_value,
-            children=children,
-            e_reward=e_reward,
-        )
 
         wasted = wasted + (w - live.sum(axis=1, dtype=jnp.int32))
         if hist is not None:
@@ -729,6 +738,7 @@ class BatchedMCTS:
             )
         return out, tree, reused
 
+    @jax.named_scope("rollout/promote")
     def promote(self, tree: Tree, actions: jax.Array) -> CarriedTree:
         """Batched root promotion: compact each game's chosen child's
         subtree into the leading rows (ops/subtree_reuse.py; lowering

@@ -23,6 +23,7 @@ TPU-first redesign, not a translation:
 
 from collections.abc import Callable
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 from flax import linen as nn
@@ -201,76 +202,86 @@ class AlphaTriangleNet(nn.Module):
         dtype = jnp.dtype(cfg.COMPUTE_DTYPE)
         act = _ACTIVATIONS[cfg.ACTIVATION_FUNCTION]
 
-        x = jnp.transpose(grid, (0, 2, 3, 1)).astype(dtype)  # NCHW -> NHWC
+        # The four named scopes are the phases of `telemetry/phases.py`:
+        # a device trace's time divides by them (flax's own module
+        # scopes sit inside).
+        with jax.named_scope("net/conv"):
+            x = jnp.transpose(grid, (0, 2, 3, 1)).astype(dtype)  # NCHW -> NHWC
 
-        for f, k, s in zip(
-            cfg.CONV_FILTERS, cfg.CONV_KERNEL_SIZES, cfg.CONV_STRIDES, strict=True
-        ):
-            x = ConvBlock(f, k, s, cfg.NORM_TYPE, act, dtype)(x, train)
+            for f, k, s in zip(
+                cfg.CONV_FILTERS,
+                cfg.CONV_KERNEL_SIZES,
+                cfg.CONV_STRIDES,
+                strict=True,
+            ):
+                x = ConvBlock(f, k, s, cfg.NORM_TYPE, act, dtype)(x, train)
 
         if cfg.NUM_RESIDUAL_BLOCKS > 0:
-            if x.shape[-1] != cfg.RESIDUAL_BLOCK_FILTERS:
-                x = ConvBlock(
-                    cfg.RESIDUAL_BLOCK_FILTERS, 1, 1, cfg.NORM_TYPE, act, dtype
-                )(x, train)
-            block = ResidualBlock
-            if cfg.REMAT:
-                block = nn.remat(ResidualBlock, static_argnums=(2,))
-            for _ in range(cfg.NUM_RESIDUAL_BLOCKS):
-                x = block(cfg.RESIDUAL_BLOCK_FILTERS, cfg.NORM_TYPE, act, dtype)(
-                    x, train
-                )
+            with jax.named_scope("net/residual"):
+                if x.shape[-1] != cfg.RESIDUAL_BLOCK_FILTERS:
+                    x = ConvBlock(
+                        cfg.RESIDUAL_BLOCK_FILTERS, 1, 1, cfg.NORM_TYPE, act, dtype
+                    )(x, train)
+                block = ResidualBlock
+                if cfg.REMAT:
+                    block = nn.remat(ResidualBlock, static_argnums=(2,))
+                for _ in range(cfg.NUM_RESIDUAL_BLOCKS):
+                    x = block(cfg.RESIDUAL_BLOCK_FILTERS, cfg.NORM_TYPE, act, dtype)(
+                        x, train
+                    )
 
         if cfg.USE_TRANSFORMER and cfg.TRANSFORMER_LAYERS > 0:
-            if x.shape[-1] != cfg.TRANSFORMER_DIM:
-                x = nn.Conv(cfg.TRANSFORMER_DIM, (1, 1), dtype=dtype)(x)
-            b, h, w, d = x.shape
-            tokens = x.reshape(b, h * w, d)
-            pe = jnp.asarray(
-                sinusoidal_positional_encoding(h * w, d), dtype=dtype
-            )
-            tokens = tokens + pe[None, :, :]
-            layer = TransformerEncoderLayer
-            if cfg.REMAT:
-                layer = nn.remat(TransformerEncoderLayer, static_argnums=(2,))
-            for _ in range(cfg.TRANSFORMER_LAYERS):
-                tokens = layer(
-                    cfg.TRANSFORMER_DIM,
-                    cfg.TRANSFORMER_HEADS,
-                    cfg.TRANSFORMER_FC_DIM,
-                    act,
-                    dtype,
-                    attention_fn=self.attention_fn,
-                )(tokens, train)
-            tokens = nn.LayerNorm(dtype=dtype)(tokens)
-            flat = tokens.reshape(b, -1)
+            with jax.named_scope("net/encoder"):
+                if x.shape[-1] != cfg.TRANSFORMER_DIM:
+                    x = nn.Conv(cfg.TRANSFORMER_DIM, (1, 1), dtype=dtype)(x)
+                b, h, w, d = x.shape
+                tokens = x.reshape(b, h * w, d)
+                pe = jnp.asarray(
+                    sinusoidal_positional_encoding(h * w, d), dtype=dtype
+                )
+                tokens = tokens + pe[None, :, :]
+                layer = TransformerEncoderLayer
+                if cfg.REMAT:
+                    layer = nn.remat(TransformerEncoderLayer, static_argnums=(2,))
+                for _ in range(cfg.TRANSFORMER_LAYERS):
+                    tokens = layer(
+                        cfg.TRANSFORMER_DIM,
+                        cfg.TRANSFORMER_HEADS,
+                        cfg.TRANSFORMER_FC_DIM,
+                        act,
+                        dtype,
+                        attention_fn=self.attention_fn,
+                    )(tokens, train)
+                tokens = nn.LayerNorm(dtype=dtype)(tokens)
+                flat = tokens.reshape(b, -1)
         else:
             flat = x.reshape(x.shape[0], -1)
 
-        combined = jnp.concatenate(
-            [flat, other_features.astype(dtype)], axis=-1
-        )
+        with jax.named_scope("net/heads"):
+            combined = jnp.concatenate(
+                [flat, other_features.astype(dtype)], axis=-1
+            )
 
-        shared = combined
-        for hdim in cfg.FC_DIMS_SHARED:
-            shared = nn.Dense(hdim, dtype=dtype)(shared)
-            shared = _Norm(cfg.NORM_TYPE, dtype)(shared, train)
-            shared = act(shared)
+            shared = combined
+            for hdim in cfg.FC_DIMS_SHARED:
+                shared = nn.Dense(hdim, dtype=dtype)(shared)
+                shared = _Norm(cfg.NORM_TYPE, dtype)(shared, train)
+                shared = act(shared)
 
-        policy_logits = MLPHead(
-            tuple(cfg.POLICY_HEAD_DIMS),
-            self.action_dim,
-            cfg.NORM_TYPE,
-            act,
-            dtype,
-        )(shared, train)
-        value_logits = MLPHead(
-            tuple(cfg.VALUE_HEAD_DIMS),
-            cfg.NUM_VALUE_ATOMS,
-            cfg.NORM_TYPE,
-            act,
-            dtype,
-        )(shared, train)
+            policy_logits = MLPHead(
+                tuple(cfg.POLICY_HEAD_DIMS),
+                self.action_dim,
+                cfg.NORM_TYPE,
+                act,
+                dtype,
+            )(shared, train)
+            value_logits = MLPHead(
+                tuple(cfg.VALUE_HEAD_DIMS),
+                cfg.NUM_VALUE_ATOMS,
+                cfg.NORM_TYPE,
+                act,
+                dtype,
+            )(shared, train)
         return policy_logits.astype(jnp.float32), value_logits.astype(jnp.float32)
 
 
@@ -289,6 +300,4 @@ def expected_value_from_logits(value_logits: Array, support: Array) -> Array:
 
 def count_parameters(params) -> int:
     """Total scalar parameter count of a params pytree."""
-    import jax
-
     return sum(int(np.prod(p.shape)) for p in jax.tree_util.tree_leaves(params))

@@ -91,6 +91,7 @@ def gather_rows_pallas(
             f32_block_bytes(n, k) + f32_block_bytes(w, k)
         ),
         interpret=interpret,
+        name="gather_rows",
     )(idx.astype(jnp.int32).reshape(b, 1, w), stats)
 
 

@@ -185,6 +185,7 @@ def backup_update_pallas(
         out_shape=(plane,) * 4,
         compiler_params=vmem_params(8 * f32_block_bytes(n, a)),
         interpret=interpret,
+        name="mcts_backup",
     )(
         parents.astype(jnp.int32).reshape(b, 1, w),
         actions.astype(jnp.int32).reshape(b, 1, w),

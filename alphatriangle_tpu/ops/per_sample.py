@@ -99,6 +99,7 @@ def count_below_pallas(
         out_specs=row,
         out_shape=jax.ShapeDtypeStruct((k, b, 1), jnp.int32),
         interpret=interpret,
+        name="per_count_below",
     )(cum_p, u.reshape(k, b, 1)).reshape(k, b)
 
 

@@ -213,6 +213,7 @@ def _reorder_planes_pallas(
         out_shape=(plane,) * 6,
         compiler_params=vmem_params(12 * f32_block_bytes(n, a)),
         interpret=interpret,
+        name="subtree_promote",
     )(
         order.astype(jnp.int32).reshape(b, 1, n),
         retained.astype(jnp.int32).reshape(b, 1, 1),

@@ -12,18 +12,29 @@ TPU-native equivalent of the reference's worker profiling
   train / checkpoint) kept for the WHOLE run, exported as metrics each
   stats tick and dumped to `phase_timers.json` at exit.
 - `analyze_profile_dir`: prints a per-phase summary table from the
-  dump, replacing the reference's pstats top-N listing.
+  dump, replacing the reference's pstats top-N listing, and for every
+  device trace the device seconds per named phase of each program
+  (`telemetry/phases.py`), read with `jax.profiler.ProfileData`.
 """
 
+import bisect
+import functools
 import json
 import logging
+import re
 import threading
 import time
 from collections import defaultdict
 from contextlib import contextmanager
 from pathlib import Path
 
+from .telemetry.phases import PHASES
+from .telemetry.tracer import ANNOTATION_PREFIX
+
 logger = logging.getLogger(__name__)
+
+OP_NAMES_FILENAME = "op_names.json"
+OTHER = "other"
 
 
 class PhaseTimers:
@@ -153,6 +164,14 @@ class ProfileSession:
         self._tracing = False
         jax.profiler.stop_trace()
         logger.info("Profiling: device trace written to %s.", self.profile_dir)
+        # A TPU trace names an operation by its HLO instruction alone;
+        # the `op_name` that carries the phase is in the executable.
+        try:
+            (self.profile_dir / OP_NAMES_FILENAME).write_text(
+                json.dumps(program_op_names())
+            )
+        except Exception:
+            logger.exception("Profiling: %s not written.", OP_NAMES_FILENAME)
 
     def close(self) -> None:
         if self._tracing:
@@ -190,10 +209,13 @@ def analyze_profile_dir(profile_dir: str, top: int = 20) -> int:
 
     traces = sorted(root.glob("**/*.xplane.pb"))
     if traces:
+        op_names = {}
+        if (root / OP_NAMES_FILENAME).exists():
+            op_names = json.loads((root / OP_NAMES_FILENAME).read_text())
         print(f"\n{len(traces)} device trace(s):")
         for t in traces[:top]:
             print(f"  {t}")
-            summarize_xplane_trace(t, top=top)
+            summarize_xplane_trace(t, op_names=op_names, top=top)
         print(
             "View with: tensorboard --logdir "
             f"{root} (PROFILE tab)"
@@ -203,58 +225,187 @@ def analyze_profile_dir(profile_dir: str, top: int = 20) -> int:
     return 0
 
 
-def summarize_xplane_trace(path: Path, top: int = 20) -> None:
-    """Top ops per plane of a jax.profiler xplane trace, in-terminal.
+# --- device phases -----------------------------------------------------
 
-    The image's tensorboard profile plugin can't load this TF build
-    (pywrap converter mismatch), so aggregate the raw XSpace protobuf
-    directly: per plane (device core / host), sum event durations by op
-    name. This is the table that says where self-play MFU actually goes
-    (network matmuls vs tree-op gathers vs dispatch gaps) — the bench's
-    BENCH_PROFILE section and the sweep's flagship_profile row feed it.
-    Gracefully degrades when the TF tsl protos aren't importable.
-    """
+_CONTAINERS = ("while", "conditional", "call")
+_INSTRUCTION = re.compile(r"\s*(?:ROOT )?(%[\w.\-]+) = ")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_REFERENCE = re.compile(r"%[\w.\-]+")
+
+
+def parse_op_names(hlo_text: str) -> dict[str, str]:
+    """HLO instruction name -> `op_name`, from `compiled.as_text()`.
+
+    An instruction the compiler added (a layout `copy`, an async pair)
+    carries no metadata: it takes the name of the first named
+    instruction that uses it, else of its first named operand — a copy
+    belongs to the phase that needs it."""
+    named: dict[str, str] = {}
+    operands: dict[str, list[str]] = {}
+    for line in hlo_text.splitlines():
+        found = _INSTRUCTION.match(line)
+        if not found:
+            continue
+        name = found.group(1)
+        operands[name] = _REFERENCE.findall(line[found.end():])
+        op_name = _OP_NAME.search(line)
+        # A parameter, and a copy the compiler makes of one, carry the
+        # argument's name (`storage['policy_target']`): not a place in
+        # the program, which always reads `jit(f)/...`.
+        if op_name and "/" in op_name.group(1):
+            named[name] = op_name.group(1)
+    users: dict[str, list[str]] = defaultdict(list)
+    for name, refs in operands.items():
+        for ref in refs:
+            if ref in operands:
+                users[ref].append(name)
+    for _ in range(4):  # copy -> tuple -> while: a few rounds reach the named
+        grown = dict(named)
+        for name, refs in operands.items():
+            if name in named:
+                continue
+            near = [u for u in users[name] if u in named] + [
+                r for r in refs if r in named
+            ]
+            if near:
+                grown[name] = named[near[0]]
+        if len(grown) == len(named):
+            break
+        named = grown
+    return named
+
+
+def program_op_names() -> dict[str, dict[str, str]]:
+    """{HLO module name: {instruction: op_name}} of every executable the
+    compile cache's live programs hold."""
+    from .compile_cache import get_compile_cache
+
+    out: dict[str, dict[str, str]] = {}
+    for _, compiled in get_compile_cache().executables():
+        text = compiled.as_text()
+        module = re.match(r"HloModule ([^\s,]+)", text)
+        if module:
+            out.setdefault(module.group(1), {}).update(parse_op_names(text))
+    return out
+
+
+@functools.lru_cache(maxsize=None)  # a trace repeats a few thousand names
+def phase_of(op_name: str) -> str:
+    """The innermost phase name in an `op_name`; autodiff's transpose
+    of the forward pass is `learner/backward`."""
+    if "transpose(" in op_name and "learner/forward_loss" in op_name:
+        return "learner/backward"
+    at, phase = -1, OTHER
+    for name in PHASES:
+        found = op_name.rfind(name)
+        if found > at:
+            at, phase = found, name
+    return phase
+
+
+def _instruction(event_name: str) -> str:
+    return event_name.split(" = ", 1)[0]
+
+
+def _is_container(event_name: str) -> bool:
+    """`while`, `conditional` and `call` contain operations that the
+    line lists too: counting both would count the time twice."""
+    if _instruction(event_name).lstrip("%").split(".")[0] in _CONTAINERS:
+        return True
+    return bool(re.search(r"\s(?:while|conditional|call)\(", event_name))
+
+
+def phase_seconds(events, op_names: dict[str, str]) -> dict[str, float]:
+    """Device seconds per phase of one program's operations.
+
+    `events` are (name, start_ns, duration_ns) of an `XLA Ops` line, a
+    name being the operation's `op_name` itself or its HLO line (a TPU
+    trace), which `op_names` maps by instruction. Containers are left
+    out; what maps to no phase is `other`, last."""
+    total: dict[str, float] = defaultdict(float)
+    for name, _, duration in events:
+        if _is_container(name):
+            continue
+        op_name = op_names.get(_instruction(name), name)
+        total[phase_of(op_name)] += duration / 1e9
+    out = {p: total[p] for p in PHASES if p in total}
+    out[OTHER] = total.get(OTHER, 0.0)
+    return out
+
+
+def _by_program(modules, ops) -> dict[str, list]:
+    """The operations under the program execution that holds them
+    (`XLA Modules` names a run `jit_f(<fingerprint>)`)."""
+    runs = sorted(modules, key=lambda e: e[1])
+    starts = [e[1] for e in runs]
+    out: dict[str, list] = defaultdict(list)
+    for op in ops:
+        i = bisect.bisect_right(starts, op[1]) - 1
+        inside = i >= 0 and op[1] < runs[i][1] + runs[i][2]
+        program = runs[i][0].split("(")[0] if inside else "-"
+        out[program].append(op)
+    return out
+
+
+def _listed(line) -> list:
+    return [(e.name, int(e.start_ns), int(e.duration_ns)) for e in line.events]
+
+
+def summarize_xplane_trace(
+    path: Path, op_names: "dict | None" = None, top: int = 20
+) -> None:
+    """Per device plane and program: device seconds and share per named
+    phase, `other` last; then the program's own host spans (`at:`).
+
+    Reads the xplane with `jax.profiler.ProfileData` alone. `op_names`
+    is `op_names.json` as `ProfileSession` wrote it beside the trace
+    ({module: {instruction: op_name}}); without it every operation of a
+    TPU trace is `other`."""
+    import jax
+
     try:
-        from tensorflow.tsl.profiler.protobuf import xplane_pb2
-    except Exception as exc:
-        print(f"  (xplane summary unavailable: {exc})")
-        return
-    xs = xplane_pb2.XSpace()
-    try:
-        xs.ParseFromString(path.read_bytes())
+        data = jax.profiler.ProfileData.from_file(str(path))
+        planes = list(data.planes)
     except Exception as exc:
         print(f"  (unreadable trace: {exc})")
         return
-    for plane in xs.planes:
-        meta = {m.id: m.name for m in plane.event_metadata.values()}
-        # Aggregate PER LINE: a device plane carries hierarchical lines
-        # ("XLA Modules" spans everything its "XLA Ops" line itemizes),
-        # so summing across lines would double-count and crown the
-        # module name as the top "op".
-        for line in plane.lines:
-            if not line.events:
-                continue
-            total_ps: dict[str, int] = defaultdict(int)
-            count: dict[str, int] = defaultdict(int)
-            for ev in line.events:
-                name = meta.get(ev.metadata_id, f"op#{ev.metadata_id}")
-                total_ps[name] += ev.duration_ps
-                count[name] += 1
-            grand_ps = sum(total_ps.values())
-            rows = sorted(
-                total_ps.items(), key=lambda kv: kv[1], reverse=True
-            )
-            line_name = line.name or f"line#{line.id}"
+    op_names = op_names or {}
+    host: dict[str, list[float]] = defaultdict(lambda: [0.0, 0])
+    devices = 0
+    for plane in planes:
+        lines = {line.name: line for line in plane.lines}
+        if not plane.name.startswith("/device:"):
+            for line in lines.values():
+                for e in line.events:
+                    if e.name.startswith(ANNOTATION_PREFIX):
+                        host[e.name][0] += e.duration_ns / 1e6
+                        host[e.name][1] += 1
+            continue
+        if "XLA Ops" not in lines:
+            continue
+        devices += 1
+        ops = _listed(lines["XLA Ops"])
+        modules = (
+            _listed(lines["XLA Modules"]) if "XLA Modules" in lines else []
+        )
+        for program, events in sorted(_by_program(modules, ops).items()):
+            seconds = phase_seconds(events, op_names.get(program, {}))
+            busy = sum(seconds.values())
             print(
-                f"\n  plane {plane.name} / {line_name}: "
-                f"{len(line.events)} events, {grand_ps / 1e12:.3f}s "
-                "summed op time"
+                f"\n  plane {plane.name} / program {program}: "
+                f"{len(events)} operations, {busy:.3f} s"
             )
-            print(f"    {'op':<52} {'total ms':>10} {'count':>8} {'%':>6}")
-            for name, ps in rows[:top]:
-                pct = 100.0 * ps / max(grand_ps, 1)
-                label = name if len(name) <= 52 else name[:49] + "..."
+            print(f"    {'phase':<24} {'seconds':>10} {'share':>7}")
+            for phase, sec in seconds.items():
                 print(
-                    f"    {label:<52} {ps / 1e9:>10.2f} "
-                    f"{count[name]:>8d} {pct:>5.1f}%"
+                    f"    {phase:<24} {sec:>10.4f} "
+                    f"{100.0 * sec / max(busy, 1e-12):>6.1f}%"
                 )
+    if not devices:
+        print("  (no device plane with an XLA Ops line: a CPU trace)")
+    if host:
+        print(f"\n  host spans ({ANNOTATION_PREFIX}):")
+        print(f"    {'span':<32} {'total ms':>10} {'count':>8}")
+        rows = sorted(host.items(), key=lambda kv: -kv[1][0])[:top]
+        for name, (ms, count) in rows:
+            print(f"    {name:<32} {ms:>10.2f} {count:>8d}")

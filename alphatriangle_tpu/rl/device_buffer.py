@@ -66,6 +66,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..config.train_config import TrainConfig
+from ..telemetry.tracer import default_tracer
 from .buffer import ExperienceBuffer
 
 logger = logging.getLogger(__name__)
@@ -75,6 +76,7 @@ logger = logging.getLogger(__name__)
 _BLOCK_FIELDS = ("grid", "other", "policy", "ret", "pw")
 
 
+@jax.named_scope("replay/ingest_scatter")
 def ring_scatter(
     storage: dict[str, jax.Array],
     cursor: jax.Array,
@@ -205,20 +207,28 @@ class DeviceReplayBuffer(ExperienceBuffer):
         self, blocks: "tuple[dict[str, Any], ...]"
     ) -> tuple[int, np.ndarray]:
         """Run the jitted ingest; returns (rows written, their slots)."""
-        self.storage, _, count_dev = self._ingest_jit(
-            self.storage, jnp.int32(self._pos), blocks
-        )
-        self.dispatch_count += 1
-        count = int(count_dev)  # the one blocking scalar fetch
-        slots = (self._pos + np.arange(count)) % self.capacity
-        if self.tree is not None and count:
-            self.tree.update_batch(
-                slots, np.full(count, self.tree.max_priority, dtype=np.float64)
+        tracer = default_tracer()
+        with tracer.span("replay.ingest_dispatch"):
+            self.storage, _, count_dev = self._ingest_jit(
+                self.storage, jnp.int32(self._pos), blocks
             )
-            self.tree.data_pointer = int((self._pos + count) % self.capacity)
-            self.tree.n_entries = min(self._size + count, self.capacity)
-        self._pos = int((self._pos + count) % self.capacity)
-        self._size = min(self._size + count, self.capacity)
+            self.dispatch_count += 1
+        with tracer.span("replay.ingest_wait") as args:
+            count = int(count_dev)  # the one blocking scalar fetch
+            args["rows"] = count
+        with tracer.span("replay.tree_update", rows=count):
+            slots = (self._pos + np.arange(count)) % self.capacity
+            if self.tree is not None and count:
+                self.tree.update_batch(
+                    slots,
+                    np.full(count, self.tree.max_priority, dtype=np.float64),
+                )
+                self.tree.data_pointer = int(
+                    (self._pos + count) % self.capacity
+                )
+                self.tree.n_entries = min(self._size + count, self.capacity)
+            self._pos = int((self._pos + count) % self.capacity)
+            self._size = min(self._size + count, self.capacity)
         return count, slots
 
     def ingest_payload(self, payload: dict[str, Any]) -> int:
@@ -299,11 +309,19 @@ class DeviceReplayBuffer(ExperienceBuffer):
         on device (`Trainer.train_steps_from`). The sampling math is
         the parent's `_sample_indices` (shared, not duplicated).
         """
-        sampled = self._sample_indices(batch_size, current_train_step)
-        if sampled is None:
-            return None
-        slots, weights = sampled
-        return {"indices": slots.astype(np.int64), "weights": weights}
+        with default_tracer().span("replay.sample"):
+            sampled = self._sample_indices(batch_size, current_train_step)
+            if sampled is None:
+                return None
+            slots, weights = sampled
+            return {"indices": slots.astype(np.int64), "weights": weights}
+
+    def update_priorities(
+        self, indices: np.ndarray, td_errors: np.ndarray
+    ) -> None:
+        """The parent's SumTree write-back, under its span."""
+        with default_tracer().span("replay.priorities"):
+            super().update_priorities(indices, td_errors)
 
     # --- persistence ------------------------------------------------------
 

@@ -63,6 +63,7 @@ from ..telemetry.device_stats import (
     rollout_chunk_stats,
 )
 from ..telemetry.flight import flight_span
+from ..telemetry.tracer import default_tracer
 from ..mcts.search import BatchedMCTS
 from ..nn.network import NeuralNetwork
 from ..nn.precision import cast_params_for_inference, inference_dtype
@@ -275,17 +276,22 @@ class SelfPlayEngine:
                 + device_stats_signature()
                 + beacon_signature()
             )
-            self._chunk_fn = functools.lru_cache(maxsize=None)(
-                lambda num_moves: get_compile_cache().wrap(
+
+            def chunk_program(num_moves: int):
+                def self_play_chunk(variables, carry, version):
+                    return self._chunk(num_moves, variables, carry, version)
+
+                # The HLO module's name: what a device trace calls the
+                # program (a `functools.partial` reads `jit__unknown`).
+                self_play_chunk.__name__ = f"self_play_chunk_t{num_moves}"
+                return get_compile_cache().wrap(
                     f"self_play_chunk/t{num_moves}",
-                    jax.jit(
-                        functools.partial(self._chunk, num_moves),
-                        donate_argnums=(1,),
-                    ),
+                    jax.jit(self_play_chunk, donate_argnums=(1,)),
                     extra=chunk_extra,
                     serialize=not beacons_armed(),
                 )
-            )
+
+            self._chunk_fn = functools.lru_cache(maxsize=None)(chunk_program)
 
         # Oldest weights version contributing to the current harvest
         # window (conservative chunk-level tag; per-episode tags ride in
@@ -433,7 +439,8 @@ class SelfPlayEngine:
         # prob `full_search_prob`, else the cheap fast search — a
         # per-move (not per-game) draw, which keeps the batch lanes in
         # lockstep while matching KataGo's per-move distribution.
-        grids, others = jax.vmap(self.extractor.extract)(states)
+        with jax.named_scope("rollout/features"):
+            grids, others = jax.vmap(self.extractor.extract)(states)
         final_tree = None
         reused = None
         if self.mcts_config.tree_reuse:
@@ -463,122 +470,127 @@ class SelfPlayEngine:
                 self.mcts_config.max_simulations,
                 self.mcts_config.fast_simulations,
             ).astype(jnp.int32)
-        valid = jax.vmap(self.env.valid_action_mask)(states)
-        if self.mcts_config.root_selection == "gumbel":
-            # Completed-Q improved policy (mcts/gumbel.py) — a policy-
-            # improvement operator, not a visit histogram.
-            policy = out.improved_policy
-        else:
-            policy = policy_target_from_visits(out.visit_counts, valid)
-        pweight = jnp.where(is_full, 1.0, 0.0)
+        with jax.named_scope("rollout/targets"):
+            valid = jax.vmap(self.env.valid_action_mask)(states)
+            if self.mcts_config.root_selection == "gumbel":
+                # Completed-Q improved policy (mcts/gumbel.py) — a
+                # policy-improvement operator, not a visit histogram.
+                policy = out.improved_policy
+            else:
+                policy = policy_target_from_visits(out.visit_counts, valid)
+            pweight = jnp.where(is_full, 1.0, 0.0)
 
-        # 3. Mature the slot added n moves ago: bootstrap with this
-        # search's root value (the MCTS estimate of V(s_t) = V(s_{t-n+n})).
-        mat_mask = carry.pend_active[:, w]
-        if (
-            self.mcts_fast is not None
-            and not self.mcts_config.pcr_record_fast_rows
-        ):
-            # KataGo-faithful playout cap randomization: positions
-            # searched cheaply never become training rows (their
-            # targets — noisy fast-search policy AND the n-step value
-            # whose bootstrap is a fast root — are below training
-            # quality; measured in docs/MCTS_DESIGN.md §e).
-            mat_mask = mat_mask & (carry.pend_pweight[:, w] > 0.5)
-        mat = {
-            "grid": carry.pend_grid[:, w],
-            "other": carry.pend_other[:, w],
-            "policy": carry.pend_policy[:, w],
-            "pw": carry.pend_pweight[:, w],
-            "ret": carry.pend_return[:, w]
-            + carry.pend_discount[:, w] * out.root_value,
-            "mask": mat_mask,
-        }
-        pend_active = carry.pend_active.at[:, w].set(False)
+            # 3. Mature the slot added n moves ago: bootstrap with this
+            # search's root value (the MCTS estimate of V(s_t) =
+            # V(s_{t-n+n})).
+            mat_mask = carry.pend_active[:, w]
+            if (
+                self.mcts_fast is not None
+                and not self.mcts_config.pcr_record_fast_rows
+            ):
+                # KataGo-faithful playout cap randomization: positions
+                # searched cheaply never become training rows (their
+                # targets — noisy fast-search policy AND the n-step
+                # value whose bootstrap is a fast root — are below
+                # training quality; measured in docs/MCTS_DESIGN.md §e).
+                mat_mask = mat_mask & (carry.pend_pweight[:, w] > 0.5)
+            mat = {
+                "grid": carry.pend_grid[:, w],
+                "other": carry.pend_other[:, w],
+                "policy": carry.pend_policy[:, w],
+                "pw": carry.pend_pweight[:, w],
+                "ret": carry.pend_return[:, w]
+                + carry.pend_discount[:, w] * out.root_value,
+                "mask": mat_mask,
+            }
+            pend_active = carry.pend_active.at[:, w].set(False)
 
-        # 4. Select actions and step all games in one vmapped
-        # transition. PUCT: temperature-scheduled sampling from visit
-        # counts; Gumbel: the search already resolved the argmax of
-        # g + logits + sigma(q) (exploration IS the Gumbel sample).
-        if self.mcts_config.root_selection == "gumbel":
-            actions = out.selected_action
-        else:
-            temps = self._temperatures(states.step_count)
-            if self.mcts_fast is not None:
-                # Playout-cap fast moves play GREEDILY (KataGo §3.1):
-                # they exist to advance the game with the best cheap
-                # decision, not to explore — temperature on a handful
-                # of visits is near-uniform noise, and training on the
-                # resulting near-random trajectories degrades the value
-                # head (measured: greedy eval 7.53 -> 6.82 before this
-                # guard). Exploration stays on full-search moves.
-                temps = jnp.where(is_full, temps, 0.0)
-            actions = select_action_from_visits(
-                out.visit_counts, temps, k_select
+        with jax.named_scope("rollout/env_step"):
+            # 4. Select actions and step all games in one vmapped
+            # transition. PUCT: temperature-scheduled sampling from visit
+            # counts; Gumbel: the search already resolved the argmax of
+            # g + logits + sigma(q) (exploration IS the Gumbel sample).
+            if self.mcts_config.root_selection == "gumbel":
+                actions = out.selected_action
+            else:
+                temps = self._temperatures(states.step_count)
+                if self.mcts_fast is not None:
+                    # Playout-cap fast moves play GREEDILY (KataGo §3.1):
+                    # they exist to advance the game with the best cheap
+                    # decision, not to explore — temperature on a handful
+                    # of visits is near-uniform noise, and training on the
+                    # resulting near-random trajectories degrades the value
+                    # head (measured: greedy eval 7.53 -> 6.82 before this
+                    # guard). Exploration stays on full-search moves.
+                    temps = jnp.where(is_full, temps, 0.0)
+                actions = select_action_from_visits(
+                    out.visit_counts, temps, k_select
+                )
+            # Sentinel guard: -1 (zero root visits) only happens for finished
+            # games, where step() is a no-op; count live-game sentinels so the
+            # host can surface the anomaly instead of silently clamping.
+            sentinel_live = ((actions < 0) & ~states.done).sum(dtype=jnp.int32)
+            actions = jnp.maximum(actions, 0)
+            new_states, rewards, dones = jax.vmap(self.env.step)(states, actions)
+
+        with jax.named_scope("rollout/targets"):
+            # 5. Add this move's experience into window slot w.
+            pend_grid = carry.pend_grid.at[:, w].set(grids)
+            pend_other = carry.pend_other.at[:, w].set(others)
+            pend_policy = carry.pend_policy.at[:, w].set(policy)
+            pend_pweight = carry.pend_pweight.at[:, w].set(pweight)
+            pend_return = carry.pend_return.at[:, w].set(0.0)
+            pend_discount = carry.pend_discount.at[:, w].set(1.0)
+            pend_active = pend_active.at[:, w].set(True)
+
+            # 6. Fold this move's reward into every pending experience.
+            pend_return = pend_return + jnp.where(
+                pend_active, pend_discount * rewards[:, None], 0.0
             )
-        # Sentinel guard: -1 (zero root visits) only happens for finished
-        # games, where step() is a no-op; count live-game sentinels so the
-        # host can surface the anomaly instead of silently clamping.
-        sentinel_live = ((actions < 0) & ~states.done).sum(dtype=jnp.int32)
-        actions = jnp.maximum(actions, 0)
-        new_states, rewards, dones = jax.vmap(self.env.step)(states, actions)
+            pend_discount = jnp.where(
+                pend_active, pend_discount * self.gamma, 1.0
+            )
 
-        # 5. Add this move's experience into window slot w.
-        pend_grid = carry.pend_grid.at[:, w].set(grids)
-        pend_other = carry.pend_other.at[:, w].set(others)
-        pend_policy = carry.pend_policy.at[:, w].set(policy)
-        pend_pweight = carry.pend_pweight.at[:, w].set(pweight)
-        pend_return = carry.pend_return.at[:, w].set(0.0)
-        pend_discount = carry.pend_discount.at[:, w].set(1.0)
-        pend_active = pend_active.at[:, w].set(True)
+            # 7. Trailing flush for finished (or move-capped) games: emit all
+            # pending slots without bootstrap (`worker.py:466-485`).
+            step_counts = new_states.step_count
+            truncated = (~dones) & (step_counts >= self.config.MAX_EPISODE_MOVES)
+            ending = dones | truncated
+            flush_mask = pend_active & ending[:, None]
+            if (
+                self.mcts_fast is not None
+                and not self.mcts_config.pcr_record_fast_rows
+            ):
+                flush_mask = flush_mask & (pend_pweight > 0.5)
+            flush = {
+                "grid": pend_grid,
+                "other": pend_other,
+                "policy": pend_policy,
+                "pw": pend_pweight,
+                "ret": pend_return,
+                "mask": flush_mask,
+            }
+            pend_active = pend_active & ~ending[:, None]
 
-        # 6. Fold this move's reward into every pending experience.
-        pend_return = pend_return + jnp.where(
-            pend_active, pend_discount * rewards[:, None], 0.0
-        )
-        pend_discount = jnp.where(
-            pend_active, pend_discount * self.gamma, 1.0
-        )
+            episode = {
+                "ending": ending,
+                # Truncated = hit MAX_EPISODE_MOVES rather than a natural
+                # game over; a high fraction means the cap is biting (the
+                # health signal the reference's get_game_over_reason
+                # served, `worker.py:196`).
+                "truncated": truncated,
+                "score": new_states.score,
+                "length": step_counts,
+                "start_version": carry.episode_start_version,
+            }
 
-        # 7. Trailing flush for finished (or move-capped) games: emit all
-        # pending slots without bootstrap (`worker.py:466-485`).
-        step_counts = new_states.step_count
-        truncated = (~dones) & (step_counts >= self.config.MAX_EPISODE_MOVES)
-        ending = dones | truncated
-        flush_mask = pend_active & ending[:, None]
-        if (
-            self.mcts_fast is not None
-            and not self.mcts_config.pcr_record_fast_rows
-        ):
-            flush_mask = flush_mask & (pend_pweight > 0.5)
-        flush = {
-            "grid": pend_grid,
-            "other": pend_other,
-            "policy": pend_policy,
-            "pw": pend_pweight,
-            "ret": pend_return,
-            "mask": flush_mask,
-        }
-        pend_active = pend_active & ~ending[:, None]
-
-        episode = {
-            "ending": ending,
-            # Truncated = hit MAX_EPISODE_MOVES rather than a natural
-            # game over; a high fraction means the cap is biting (the
-            # health signal the reference's get_game_over_reason
-            # served, `worker.py:196`).
-            "truncated": truncated,
-            "score": new_states.score,
-            "length": step_counts,
-            "start_version": carry.episode_start_version,
-        }
-
-        # 8. Reset finished games in place; batch shape never changes.
-        new_states = new_states.replace(done=ending)
-        reset_states = self.env.reset_where_done(new_states, k_reset)
-        episode_start_version = jnp.where(
-            ending, version, carry.episode_start_version
-        )
+        with jax.named_scope("rollout/reset"):
+            # 8. Reset finished games in place; batch shape never changes.
+            new_states = new_states.replace(done=ending)
+            reset_states = self.env.reset_where_done(new_states, k_reset)
+            episode_start_version = jnp.where(
+                ending, version, carry.episode_start_version
+            )
 
         # 9. Root promotion for the next move (subtree reuse): compact
         # the played action's subtree into the leading rows; ending
@@ -668,62 +680,67 @@ class SelfPlayEngine:
             if self._min_weights_version is None
             else min(self._min_weights_version, version)
         )
+        tracer = default_tracer()
+        lanes = self.batch_size
         with flight_span(
             self.flight,
             "rollout",
             f"self_play_chunk/t{t}",
             avals=f"B{self.batch_size}xT{t}",
         ):
-            note_dispatch(f"self_play_chunk/t{t}")
-            self._carry, outputs = self._chunk_fn(t)(
-                self._place_variables(
-                    self._inference_variables(self.net.variables, version),
-                    version,
-                ),
-                self._carry,
-                jnp.int32(version),
-            )
+            with tracer.span("rollout.dispatch", t=t, lanes=lanes):
+                note_dispatch(f"self_play_chunk/t{t}")
+                self._carry, outputs = self._chunk_fn(t)(
+                    self._place_variables(
+                        self._inference_variables(self.net.variables, version),
+                        version,
+                    ),
+                    self._carry,
+                    jnp.int32(version),
+                )
             payload: dict | None = None
             t0 = time.perf_counter()
-            if fetch_experiences:
-                host = jax.device_get(outputs)  # graftlint: allow(host-sync-in-hot-path) the one transfer per chunk
-            else:
-                payload = {
-                    "mat": outputs.pop("mat"),
-                    "flush": outputs.pop("flush"),
-                }
-                host = jax.device_get(outputs)  # graftlint: allow(host-sync-in-hot-path) stats + trace only (small)
+            with tracer.span("rollout.wait", t=t, lanes=lanes):
+                if fetch_experiences:
+                    host = jax.device_get(outputs)  # graftlint: allow(host-sync-in-hot-path) the one transfer per chunk
+                else:
+                    payload = {
+                        "mat": outputs.pop("mat"),
+                        "flush": outputs.pop("flush"),
+                    }
+                    host = jax.device_get(outputs)  # graftlint: allow(host-sync-in-hot-path) stats + trace only (small)
         dt = time.perf_counter() - t0
         with self._transfer_lock:
             self.transfer_d2h_seconds += dt
             self.dispatch_count += 1
-        # Under playout cap randomization the per-move sim count varies;
-        # the trace records what actually ran.
-        self._total_simulations += (
-            int(host["trace"]["sims"].sum()) * self.batch_size
-        )
-        self._total_reused_visits += int(host["trace"]["reused"].sum())
-
-        self.last_trace = host["trace"]
-        if self.device_stats:
-            # Search leg folded from the fetched stat-pack; rollout leg
-            # is a pure host fold over arrays the fetch ALREADY carried
-            # (per-step-of-T terminations, reward extremes).
-            self.last_device_stats = {
-                "search": fold_search_stats(host.get("device_stats")),
-                "rollout": rollout_chunk_stats(
-                    host["episode"]["ending"], host["trace"]["reward"]
-                ),
-            }
-        episode = host["episode"]
-        self._fold_episode_stats(episode)
-        sentinels = int(host["sentinel_live"].sum())
-        if sentinels:
-            logger.warning(
-                "SelfPlay: %d zero-visit sentinel actions on LIVE games "
-                "(clamped to action 0) — root search produced no visits.",
-                sentinels,
+        with tracer.span("rollout.fold", t=t, lanes=lanes):
+            # Under playout cap randomization the per-move sim count
+            # varies; the trace records what actually ran.
+            self._total_simulations += (
+                int(host["trace"]["sims"].sum()) * self.batch_size
             )
+            self._total_reused_visits += int(host["trace"]["reused"].sum())
+
+            self.last_trace = host["trace"]
+            if self.device_stats:
+                # Search leg folded from the fetched stat-pack; rollout
+                # leg is a pure host fold over arrays the fetch ALREADY
+                # carried (per-step-of-T terminations, reward extremes).
+                self.last_device_stats = {
+                    "search": fold_search_stats(host.get("device_stats")),
+                    "rollout": rollout_chunk_stats(
+                        host["episode"]["ending"], host["trace"]["reward"]
+                    ),
+                }
+            episode = host["episode"]
+            self._fold_episode_stats(episode)
+            sentinels = int(host["sentinel_live"].sum())
+            if sentinels:
+                logger.warning(
+                    "SelfPlay: %d zero-visit sentinel actions on LIVE games "
+                    "(clamped to action 0) — root search produced no visits.",
+                    sentinels,
+                )
         if not fetch_experiences:
             return payload
         mat, flush = host["mat"], host["flush"]

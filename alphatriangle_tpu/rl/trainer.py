@@ -36,6 +36,7 @@ from ..config.train_config import TrainConfig
 from ..nn.network import NeuralNetwork
 from ..telemetry.device_stats import emit_beacon
 from ..telemetry.flight import flight_span
+from ..telemetry.tracer import default_tracer
 from ..parallel.sharding import (
     batch_sharding,
     local_rows,
@@ -281,9 +282,14 @@ class Trainer:
         # K*B int32 indices. One compiled program per distinct K (the
         # cache wrapper keys executables per input signature, so the
         # distinct-K programs each get their own AOT cache entry).
+        def learner_fused_from_ring(state, storage, idx, weights):
+            # Named as the cache names it: the HLO module's name is what
+            # a device trace calls the program.
+            return self._train_steps_from_impl(state, storage, idx, weights)
+
         self._from_fn = cache.wrap(
             "learner_fused_from_ring",
-            jax.jit(self._train_steps_from_impl, donate_argnums=(0,)),
+            jax.jit(learner_fused_from_ring, donate_argnums=(0,)),
             extra=self._cache_extra,
             cpu_aot=False,
             serialize=not beacons_armed(),
@@ -303,6 +309,7 @@ class Trainer:
 
     # --- pure core --------------------------------------------------------
 
+    @jax.named_scope("learner/forward_loss")
     def _loss_fn(self, params, batch_stats, rng, batch: DenseBatch):
         cfg = self.config
         variables = {"params": params}
@@ -333,11 +340,14 @@ class Trainer:
         pw = batch["policy_weight"]
         policy_ce = pw * policy_ce
 
-        target_dist = project_to_support(
-            batch["value_target"], self.num_atoms, self.v_min, self.v_max
-        )
-        log_value = jax.nn.log_softmax(value_logits, axis=-1)
-        value_ce = -(target_dist * log_value).sum(axis=-1)  # (B,)
+        # The value cross-entropy doubles as the per-row TD error PER
+        # gets back, hence the phase's name.
+        with jax.named_scope("learner/td"):
+            target_dist = project_to_support(
+                batch["value_target"], self.num_atoms, self.v_min, self.v_max
+            )
+            log_value = jax.nn.log_softmax(value_logits, axis=-1)
+            value_ce = -(target_dist * log_value).sum(axis=-1)  # (B,)
 
         probs = jnp.exp(log_policy)
         # Entropy regularizes the policy, so it follows the policy mask.
@@ -378,10 +388,13 @@ class Trainer:
             lambda p: self._loss_fn(p, state.batch_stats, step_rng, batch),
             has_aux=True,
         )(state.params)
-        updates, opt_state = self.optimizer.update(
-            grads, state.opt_state, state.params
-        )
-        params = optax.apply_updates(state.params, updates)
+        with jax.named_scope("learner/optimizer"):
+            updates, opt_state = self.optimizer.update(
+                grads, state.opt_state, state.params
+            )
+            params = optax.apply_updates(state.params, updates)
+            grad_norm = optax.global_norm(grads)
+            update_norm = optax.global_norm(updates)
         new_state = TrainState(
             params=params,
             batch_stats=aux["batch_stats"],
@@ -394,12 +407,12 @@ class Trainer:
             "policy_loss": aux["policy_loss"],
             "value_loss": aux["value_loss"],
             "entropy": aux["entropy"],
-            "grad_norm": optax.global_norm(grads),
+            "grad_norm": grad_norm,
             # Post-transform step size: grad_norm tells you what the
             # loss surface did, update_norm what the optimizer actually
             # applied — the pair separates "gradient explosion" from
             # "adaptive-moment blowup" per fused step.
-            "update_norm": optax.global_norm(updates),
+            "update_norm": update_norm,
         }
         return new_state, metrics, aux["td_errors"]
 
@@ -439,6 +452,7 @@ class Trainer:
         return state, metrics_k, td_k
 
     @staticmethod
+    @jax.named_scope("learner/gather")
     def _stacked_rows_batch(rows, weights) -> DenseBatch:
         """(K, B, ...) ring rows -> the stacked DenseBatch the fused
         steps consume. The grid int8->float32 cast reproduces the host
@@ -466,6 +480,7 @@ class Trainer:
             stride = buffer.stride
             dp_axis = buffer.dp_axis
 
+            @jax.named_scope("learner/gather")
             def gather_local(storage_local, idx_local):
                 base = jax.lax.axis_index(dp_axis) * stride
                 local = idx_local - base  # global encoding -> local slot
@@ -505,7 +520,8 @@ class Trainer:
         replay ring: `idx` is (K, B) int32 slot indices, `weights` the
         matching (K, B) IS weights. Bit-identical to `_train_steps_impl`
         on the same rows."""
-        rows = {name: v[idx] for name, v in storage.items()}
+        with jax.named_scope("learner/gather"):
+            rows = {name: v[idx] for name, v in storage.items()}
         return self._train_steps_impl(
             state, self._stacked_rows_batch(rows, weights)
         )
@@ -605,53 +621,54 @@ class Trainer:
             return None
         self._check_local_batch(n)
         batches = [self._with_policy_weight(dict(b), n) for b in batches]
-        if len(batches) == 1:
-            # Single-step groups reuse the per-step program (a fused
-            # K=1 program would recompile for nothing).
-            t0 = time.perf_counter()
-            device_batch = shard_batch(self.mesh, batches[0], self.dp_axis)
-            self.transfer_h2d_seconds += time.perf_counter() - t0
-            span = (
-                self.flight.begin("learner", "learner_step", avals=f"B{n}")
-                if self.flight is not None
-                else None
-            )
-            self.state, metrics, td = self._step_fn(self.state, device_batch)
-            self.dispatch_count += 1
-            handle: dict = {"k": 1, "metrics": metrics, "td": td}
-        else:
-            t0 = time.perf_counter()
-            stacked_host = {
-                key: np.stack([np.asarray(b[key]) for b in batches])
-                for key in batches[0]
-            }
-            if jax.process_count() > 1:
-                stacked = jax.tree_util.tree_map(
-                    lambda x: jax.make_array_from_process_local_data(
-                        self._stacked_shard, x
-                    ),
-                    stacked_host,
+        with default_tracer().span("learner.dispatch", k=len(batches)):
+            if len(batches) == 1:
+                # Single-step groups reuse the per-step program (a fused
+                # K=1 program would recompile for nothing).
+                t0 = time.perf_counter()
+                device_batch = shard_batch(self.mesh, batches[0], self.dp_axis)
+                self.transfer_h2d_seconds += time.perf_counter() - t0
+                span = (
+                    self.flight.begin("learner", "learner_step", avals=f"B{n}")
+                    if self.flight is not None
+                    else None
                 )
+                self.state, metrics, td = self._step_fn(self.state, device_batch)
+                self.dispatch_count += 1
+                handle: dict = {"k": 1, "metrics": metrics, "td": td}
             else:
-                stacked = jax.tree_util.tree_map(
-                    lambda x: jax.device_put(x, self._stacked_shard),
-                    stacked_host,
+                t0 = time.perf_counter()
+                stacked_host = {
+                    key: np.stack([np.asarray(b[key]) for b in batches])
+                    for key in batches[0]
+                }
+                if jax.process_count() > 1:
+                    stacked = jax.tree_util.tree_map(
+                        lambda x: jax.make_array_from_process_local_data(
+                            self._stacked_shard, x
+                        ),
+                        stacked_host,
+                    )
+                else:
+                    stacked = jax.tree_util.tree_map(
+                        lambda x: jax.device_put(x, self._stacked_shard),
+                        stacked_host,
+                    )
+                self.transfer_h2d_seconds += time.perf_counter() - t0
+                span = (
+                    self.flight.begin(
+                        "learner",
+                        "learner_fused_steps",
+                        avals=f"K{len(batches)}xB{n}",
+                    )
+                    if self.flight is not None
+                    else None
                 )
-            self.transfer_h2d_seconds += time.perf_counter() - t0
-            span = (
-                self.flight.begin(
-                    "learner",
-                    "learner_fused_steps",
-                    avals=f"K{len(batches)}xB{n}",
+                self.state, metrics_k, td_k = self._multi_step_fn(
+                    self.state, stacked
                 )
-                if self.flight is not None
-                else None
-            )
-            self.state, metrics_k, td_k = self._multi_step_fn(
-                self.state, stacked
-            )
-            self.dispatch_count += 1
-            handle = {"k": len(batches), "metrics": metrics_k, "td": td_k}
+                self.dispatch_count += 1
+                handle = {"k": len(batches), "metrics": metrics_k, "td": td_k}
         # The group stays in flight until train_steps_finish fetches;
         # the seal there gives the dispatch->fetch wall for the record.
         handle["flight"] = span
@@ -686,32 +703,33 @@ class Trainer:
         """
         if not samples:
             return None
-        idx = np.stack(
-            [np.asarray(s["indices"], dtype=np.int32) for s in samples]
-        )
-        weights = np.stack(
-            [np.asarray(s["weights"], dtype=np.float32) for s in samples]
-        )
-        sharded = getattr(buffer, "is_sharded", False)
-        from_fn = (
-            self._get_from_sharded_fn(buffer) if sharded else self._from_fn
-        )
-        program = (
-            "learner_fused_from_sharded_ring"
-            if sharded
-            else "learner_fused_from_ring"
-        )
-        span = (
-            self.flight.begin(
-                "learner", program, avals=f"K{len(samples)}"
+        with default_tracer().span("learner.dispatch", k=len(samples)):
+            idx = np.stack(
+                [np.asarray(s["indices"], dtype=np.int32) for s in samples]
             )
-            if self.flight is not None
-            else None
-        )
-        self.state, metrics_k, td_k = from_fn(
-            self.state, buffer.storage, idx, weights
-        )
-        self.dispatch_count += 1
+            weights = np.stack(
+                [np.asarray(s["weights"], dtype=np.float32) for s in samples]
+            )
+            sharded = getattr(buffer, "is_sharded", False)
+            from_fn = (
+                self._get_from_sharded_fn(buffer) if sharded else self._from_fn
+            )
+            program = (
+                "learner_fused_from_sharded_ring"
+                if sharded
+                else "learner_fused_from_ring"
+            )
+            span = (
+                self.flight.begin(
+                    "learner", program, avals=f"K{len(samples)}"
+                )
+                if self.flight is not None
+                else None
+            )
+            self.state, metrics_k, td_k = from_fn(
+                self.state, buffer.storage, idx, weights
+            )
+            self.dispatch_count += 1
         handle = {
             "k": len(samples),
             "metrics": metrics_k,
@@ -733,32 +751,37 @@ class Trainer:
         per-step (metrics, local TD errors) list, in execution order.
         """
         k = handle["k"]
+        tracer = default_tracer()
         metrics_k, td_k = handle["metrics"], handle["td"]
         t0 = time.perf_counter()
-        host_metrics_k, td_host = jax.device_get(  # graftlint: allow(host-sync-in-hot-path) the one blocking fetch per fused group
-            (metrics_k, td_k if jax.process_count() == 1 else None)
-        )
+        with tracer.span("learner.wait", k=k):
+            host_metrics_k, td_host = jax.device_get(  # graftlint: allow(host-sync-in-hot-path) the one blocking fetch per fused group
+                (metrics_k, td_k if jax.process_count() == 1 else None)
+            )
         self.transfer_d2h_seconds += time.perf_counter() - t0
-        span = handle.pop("flight", None)
-        if span is not None:
-            span.seal()
-        if td_host is None:
-            td_host = local_rows(
-                td_k, axis=1 if (k > 1 or handle.get("stacked")) else 0
-            )
-        td_host = np.asarray(td_host)
-        if k == 1 and not handle.get("stacked"):
-            host_metrics_k = {
-                key: np.asarray(v)[None] for key, v in host_metrics_k.items()
-            }
-            td_host = td_host[None]
-        results = []
-        for i in range(k):
-            m = {key: float(v[i]) for key, v in host_metrics_k.items()}
-            m["learning_rate"] = float(
-                self.schedule(handle["start_step"] + i + 1)
-            )
-            results.append((m, td_host[i]))
+        # Everything from here on runs after the device has finished.
+        with tracer.span("learner.results", k=k):
+            span = handle.pop("flight", None)
+            if span is not None:
+                span.seal()
+            if td_host is None:
+                td_host = local_rows(
+                    td_k, axis=1 if (k > 1 or handle.get("stacked")) else 0
+                )
+            td_host = np.asarray(td_host)
+            if k == 1 and not handle.get("stacked"):
+                host_metrics_k = {
+                    key: np.asarray(v)[None]
+                    for key, v in host_metrics_k.items()
+                }
+                td_host = td_host[None]
+            results = []
+            for i in range(k):
+                m = {key: float(v[i]) for key, v in host_metrics_k.items()}
+                m["learning_rate"] = float(
+                    self.schedule(handle["start_step"] + i + 1)
+                )
+                results.append((m, td_host[i]))
         return results
 
     # --- AOT warming (compile_cache.py; cli warm) -------------------------

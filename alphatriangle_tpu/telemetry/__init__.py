@@ -95,7 +95,12 @@ from .slo import (
     write_fleet_prometheus,
 )
 from .tracectx import TraceContext, TRACEPARENT_ENV
-from .tracer import SpanTracer, summarize_trace_file
+from .tracer import (
+    SpanTracer,
+    default_tracer,
+    set_default_tracer,
+    summarize_trace_file,
+)
 from . import tracectx
 
 logger = logging.getLogger(__name__)
@@ -112,6 +117,8 @@ __all__ = [
     "SLO_EXIT_CODES",
     "RunTelemetry",
     "SpanTracer",
+    "default_tracer",
+    "set_default_tracer",
     "TelemetryConfig",
     "TraceContext",
     "TRACEPARENT_ENV",
@@ -180,8 +187,12 @@ class RunTelemetry:
         self.stats = stats
         self.run_name = run_name
         enabled = self.config.ENABLED
-        self.tracer = SpanTracer(
-            capacity=self.config.SPAN_BUFFER_SIZE, enabled=enabled
+        # The run's tracer is the process's default: the spans drawn
+        # inside Trainer / DeviceReplayBuffer / SelfPlayEngine land in
+        # it, as children of the loop phase that is open (a disabled
+        # one where telemetry is off, so nothing records anywhere).
+        self.tracer = set_default_tracer(
+            SpanTracer(capacity=self.config.SPAN_BUFFER_SIZE, enabled=enabled)
         )
         self.health = HealthMonitor(
             self.run_dir / HEALTH_FILENAME,

@@ -1,0 +1,37 @@
+"""The names `jax.named_scope` gives the phases of the device programs.
+
+The one list: the code sites spell these strings, the phase reader
+(`profiling.phase_seconds`) maps a device operation to the innermost of
+them in its `op_name`, the tests hold the lowered programs to them, and
+docs/OBSERVABILITY.md lists them. `learner/backward` is drawn by no
+site: it is what autodiff's transpose of `learner/forward_loss` reads
+as (`transpose(jvp(learner/forward_loss))`).
+"""
+
+PHASES = (
+    # rollout chunk (rl/self_play.py, mcts/search.py, mcts/gumbel.py)
+    "rollout/features",
+    "search/init",
+    "search/descend",
+    "search/expand",
+    "search/evaluate",
+    "search/backup",
+    "gumbel/root",
+    "rollout/targets",
+    "rollout/env_step",
+    "rollout/reset",
+    "rollout/promote",
+    # the net, inside search/evaluate and learner/forward_loss (nn/model.py)
+    "net/conv",
+    "net/residual",
+    "net/encoder",
+    "net/heads",
+    # fused learner (rl/trainer.py)
+    "learner/gather",
+    "learner/forward_loss",
+    "learner/td",
+    "learner/backward",
+    "learner/optimizer",
+    # ring ingest (rl/device_buffer.py)
+    "replay/ingest_scatter",
+)

@@ -4,16 +4,28 @@
 over the whole run" — a lossy mean that cannot say *where* a specific
 stall happened. The tracer keeps the individual spans: every rollout
 chunk, sample, learner dispatch/finish, weight sync and checkpoint is
-recorded with its real wall-clock begin/end and thread id, ring-buffered
-in memory (O(1) append under a lock, no IO on the hot path) and exported
-as Chrome trace events into the run dir. Wall-clock timestamps line up
-with the `jax.profiler` xplane traces written under `--profile`, so the
-host timeline and the device timeline can be read side by side.
+recorded with its begin/end and thread id, ring-buffered in memory
+(O(1) append under a lock, no IO on the hot path) and exported as
+Chrome trace events into the run dir.
+
+Spans are stamped on the monotonic clock (`time.perf_counter_ns`); one
+`wall - perf` offset taken at construction turns them into epoch time
+at export, so `trace.json` and the cross-process merge
+(`telemetry/merge.py`) still see wall-clock microseconds. Every span
+also enters `jax.profiler.TraceAnnotation("at:" + name)`: a flag test
+while no profiler session is open, and while one is open (`--profile`,
+the benchmark's `--trace 1`) the span lands in the xplane's host planes
+on the profiler's own clock, beside the device's `XLA Ops`.
+
+One tracer per process is the default (`default_tracer()`): components
+built without a `RunTelemetry` (the benchmark's drivers, tests) record
+into it; `RunTelemetry` installs its own with `set_default_tracer`.
 
 Load `trace.json` in chrome://tracing or https://ui.perfetto.dev, or
 summarize it in-terminal with `alphatriangle-tpu trace <run>`.
 """
 
+import itertools
 import json
 import logging
 import os
@@ -25,17 +37,36 @@ from pathlib import Path
 
 logger = logging.getLogger(__name__)
 
-# A span record: (name, begin_ns, duration_ns, thread_id, thread_name,
-# args-or-None). `kind` "X" (complete span) or "i" (instant event,
-# duration 0) per the Chrome trace event format.
+# A span record: (kind, name, begin_ns, duration_ns, thread_id,
+# thread_name, args-or-None, id, parent_id). `kind` "X" (complete span)
+# or "i" (instant event, duration 0) per the Chrome trace event format;
+# begin_ns is on the monotonic clock; parent_id 0 = no span was open on
+# the recording thread.
 _COMPLETE = "X"
 _INSTANT = "i"
 
+# The program's spans in the profiler's trace carry this prefix, so a
+# reader tells them from the profiler's own events and the harness's.
+ANNOTATION_PREFIX = "at:"
+
+_annotation = None
+
+
+def _trace_annotation(name: str):
+    """`jax.profiler.TraceAnnotation`, imported on first use: this
+    module is read by CLI paths that never import JAX."""
+    global _annotation
+    if _annotation is None:
+        from jax.profiler import TraceAnnotation
+
+        _annotation = TraceAnnotation
+    return _annotation(ANNOTATION_PREFIX + name)
+
 
 class SpanTracer:
-    """Thread-aware ring buffer of named wall-clock spans.
+    """Thread-aware ring buffer of named spans on the monotonic clock.
 
-    Ingestion is a timestamp read plus one deque append under a lock —
+    Ingestion is two clock reads plus one deque append under a lock —
     safe from any thread (rollout producers, the learner/consumer, the
     watchdog) and cheap enough to run always-on. The ring bounds memory:
     a multi-day run keeps the most recent `capacity` spans, which is
@@ -47,27 +78,52 @@ class SpanTracer:
         self._lock = threading.Lock()
         self._spans: deque = deque(maxlen=max(1, capacity))
         self.recorded = 0  # total ever recorded (ring may have evicted)
+        # The wall clock steps; spans are stamped on the monotonic one
+        # and `export` adds this back.
+        self.wall_offset_ns = time.time_ns() - time.perf_counter_ns()
+        self._ids = itertools.count(1)  # next() is atomic under the GIL
+        self._open = threading.local()  # per-thread stack of open span ids
 
     # --- ingestion (any thread, O(1)) ---------------------------------
 
+    def _open_stack(self) -> list:
+        stack = getattr(self._open, "stack", None)
+        if stack is None:
+            stack = self._open.stack = []
+        return stack
+
+    def _record(self, kind, name, t0, dur, args, span_id=None) -> None:
+        """Append one record; its parent is the span open on this
+        thread (a finished `span` has popped itself already)."""
+        stack = self._open_stack()
+        thread = threading.current_thread()
+        with self._lock:
+            self._spans.append(
+                (kind, name, t0, dur, thread.ident, thread.name,
+                 args or None, span_id or next(self._ids),
+                 stack[-1] if stack else 0)
+            )
+            self.recorded += 1
+
     @contextmanager
     def span(self, name: str, **args):
-        """Record one complete span around the with-body."""
+        """Record one complete span around the with-body, as a child of
+        the span open on this thread. Yields the span's args, so a count
+        known only at the end (rows written) can be added inside."""
         if not self.enabled:
-            yield
+            yield args
             return
-        t0 = time.time_ns()
+        stack = self._open_stack()
+        span_id = next(self._ids)
+        stack.append(span_id)
+        t0 = time.perf_counter_ns()
         try:
-            yield
+            with _trace_annotation(name):
+                yield args
         finally:
-            dur = time.time_ns() - t0
-            thread = threading.current_thread()
-            with self._lock:
-                self._spans.append(
-                    (_COMPLETE, name, t0, dur, thread.ident, thread.name,
-                     args or None)
-                )
-                self.recorded += 1
+            dur = time.perf_counter_ns() - t0
+            stack.pop()
+            self._record(_COMPLETE, name, t0, dur, args, span_id)
 
     def complete(
         self, name: str, begin_ns: int, end_ns: int, **args
@@ -79,30 +135,25 @@ class SpanTracer:
         clamped non-negative so a torn clock can't corrupt the trace."""
         if not self.enabled:
             return
-        thread = threading.current_thread()
-        with self._lock:
-            self._spans.append(
-                (_COMPLETE, name, int(begin_ns),
-                 max(0, int(end_ns) - int(begin_ns)), thread.ident,
-                 thread.name, args or None)
-            )
-            self.recorded += 1
+        self._record(
+            _COMPLETE,
+            name,
+            int(begin_ns) - self.wall_offset_ns,
+            max(0, int(end_ns) - int(begin_ns)),
+            args,
+        )
 
     def instant(self, name: str, **args) -> None:
         """Record a zero-duration marker (e.g. a watchdog stall)."""
         if not self.enabled:
             return
-        thread = threading.current_thread()
-        with self._lock:
-            self._spans.append(
-                (_INSTANT, name, time.time_ns(), 0, thread.ident,
-                 thread.name, args or None)
-            )
-            self.recorded += 1
+        self._record(_INSTANT, name, time.perf_counter_ns(), 0, args)
 
     # --- export / summary ---------------------------------------------
 
-    def _snapshot(self) -> list:
+    def records(self) -> list:
+        """The buffered records, oldest first (the tuple's layout is at
+        the top of this module); begin_ns on `time.perf_counter_ns`."""
         with self._lock:
             return list(self._spans)
 
@@ -111,19 +162,22 @@ class SpanTracer:
         count. Atomic (tmp + rename) so a reader never sees a torn file;
         IO failures are logged, never raised (observability is not
         allowed to kill a run)."""
-        spans = self._snapshot()
+        spans = self.records()
         pid = os.getpid()
         events = []
         thread_names: dict[int, str] = {}
-        for kind, name, t0_ns, dur_ns, tid, tname, args in spans:
+        for kind, name, t0_ns, dur_ns, tid, tname, args, sid, parent in spans:
             thread_names.setdefault(tid, tname)
             ev = {
                 "name": name,
                 "ph": kind,
-                "ts": t0_ns // 1000,  # Chrome traces use microseconds
+                # Epoch microseconds (Chrome traces use microseconds).
+                "ts": (t0_ns + self.wall_offset_ns) // 1000,
                 "pid": pid,
                 "tid": tid,
                 "cat": "host",
+                "id": sid,
+                "parent": parent,
             }
             if kind == _COMPLETE:
                 ev["dur"] = dur_ns // 1000
@@ -169,7 +223,7 @@ class SpanTracer:
         total_ns: dict[str, int] = defaultdict(int)
         max_ns: dict[str, int] = defaultdict(int)
         count: dict[str, int] = defaultdict(int)
-        for kind, name, _t0, dur_ns, _tid, _tname, _args in self._snapshot():
+        for kind, name, _t0, dur_ns, *_ in self.records():
             if kind != _COMPLETE:
                 continue
             total_ns[name] += dur_ns
@@ -184,6 +238,32 @@ class SpanTracer:
             }
             for name in sorted(total_ns)
         }
+
+
+# --- process-wide default ---------------------------------------------------
+
+_default_tracer: SpanTracer | None = None
+_default_lock = threading.Lock()
+
+
+def default_tracer() -> SpanTracer:
+    """The tracer the program's own spans go to: the one a
+    `RunTelemetry` installed, else a process-wide ring made on first
+    use (the benchmark's drivers and the tests build components with no
+    `RunTelemetry`)."""
+    global _default_tracer
+    with _default_lock:
+        if _default_tracer is None:
+            _default_tracer = SpanTracer()
+        return _default_tracer
+
+
+def set_default_tracer(tracer: SpanTracer) -> SpanTracer:
+    """Install `tracer` as the process-wide default; returns it."""
+    global _default_tracer
+    with _default_lock:
+        _default_tracer = tracer
+        return tracer
 
 
 def summarize_trace_file(path: Path, top: int = 20) -> list[dict]:

@@ -204,7 +204,7 @@ class TestCachedProgram:
         tracer = SpanTracer(enabled=True)
         fresh_cache.set_tracer(tracer)
         fresh_cache.wrap("t/double", jax.jit(_double))(jnp.ones((2,)))
-        names = {s[1] for s in tracer._snapshot()}
+        names = {s[1] for s in tracer.records()}
         assert "compile/t/double" in names
 
     def test_config_digest_ignores_run_name(self, tiny_train_config):

@@ -1,0 +1,468 @@
+"""The program's own spans and phase names (docs/OBSERVABILITY.md
+"Spans", "Profiling"): the default `SpanTracer` on the profiler's clock,
+the spans inside `Trainer` / `DeviceReplayBuffer` / `SelfPlayEngine`,
+the `jax.named_scope` phases of the three device programs, and the
+phase reader `profiling.phase_seconds`.
+
+One file, so the tiny programs compile once in one worker.
+"""
+
+import json
+import threading
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from alphatriangle_tpu import profiling
+from alphatriangle_tpu.telemetry import (
+    RunTelemetry,
+    SpanTracer,
+    default_tracer,
+    set_default_tracer,
+)
+from alphatriangle_tpu.telemetry.phases import PHASES
+
+
+@pytest.fixture
+def tracer():
+    """A fresh default tracer; the process's own comes back after."""
+    before = default_tracer()
+    fresh = set_default_tracer(SpanTracer())
+    yield fresh
+    set_default_tracer(before)
+
+
+def _names(tracer) -> list[str]:
+    return [r[1] for r in tracer.records()]
+
+
+def _host_events(trace_dir) -> list:
+    path = sorted(trace_dir.glob("**/*.xplane.pb"))[-1]
+    data = jax.profiler.ProfileData.from_file(str(path))
+    return [
+        e
+        for plane in data.planes
+        if not plane.name.startswith("/device:")
+        for line in plane.lines
+        for e in line.events
+    ]
+
+
+# --- (a) the profiler's trace ------------------------------------------------
+
+
+class TestProfilerAnnotation:
+    def test_span_lands_in_the_xplane_host_plane(self, tmp_path):
+        tr = SpanTracer()
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            with tr.span("x", k=3):
+                jnp.square(jnp.arange(8.0)).block_until_ready()
+        finally:
+            jax.profiler.stop_trace()
+        found = [e for e in _host_events(tmp_path) if e.name == "at:x"]
+        assert len(found) == 1
+        # On the profiler's clock, as long as the tracer's own record.
+        (_, _, _, dur_ns, *_), = tr.records()
+        assert abs(found[0].duration_ns - dur_ns) < 5e6
+
+    def test_no_session_no_effect(self):
+        tr = SpanTracer()
+        with tr.span("x"):
+            pass
+        off = SpanTracer(enabled=False)
+        with off.span("x") as args:
+            args["rows"] = 1  # a count added inside never raises
+        assert _names(tr) == ["x"] and off.recorded == 0
+
+
+# --- (b) nesting, ids, the clock ---------------------------------------------
+
+
+class TestNestingAndClock:
+    def test_parent_is_the_open_span(self):
+        tr = SpanTracer()
+        with tr.span("train"):
+            with tr.span("learner.results", k=2):
+                pass
+            tr.instant("mark")
+        with tr.span("alone"):
+            pass
+        by_name = {r[1]: r for r in tr.records()}
+        train_id = by_name["train"][7]
+        assert by_name["learner.results"][8] == train_id
+        assert by_name["mark"][8] == train_id
+        assert by_name["train"][8] == 0 and by_name["alone"][8] == 0
+
+    def test_ids_unique_across_threads_and_stacks_are_per_thread(self):
+        tr = SpanTracer()
+        started = threading.Event()
+
+        def work():
+            for _ in range(200):
+                with tr.span("worker"):
+                    started.set()
+
+        t = threading.Thread(target=work)
+        with tr.span("main_outer"):
+            t.start()
+            started.wait(5)
+            for _ in range(200):
+                with tr.span("main_inner"):
+                    pass
+            t.join()
+        records = tr.records()
+        ids = [r[7] for r in records]
+        assert len(set(ids)) == len(ids) == 401
+        outer = next(r[7] for r in records if r[1] == "main_outer")
+        # The other thread's spans are nobody's children here.
+        assert {r[8] for r in records if r[1] == "worker"} == {0}
+        assert {r[8] for r in records if r[1] == "main_inner"} == {outer}
+
+    def test_args_added_inside_are_recorded(self):
+        tr = SpanTracer()
+        with tr.span("replay.ingest_wait") as args:
+            args["rows"] = 7
+        assert tr.records()[0][6] == {"rows": 7}
+
+    def test_export_is_epoch_microseconds(self, tmp_path):
+        tr = SpanTracer()
+        with tr.span("outer"):
+            with tr.span("inner"):
+                pass
+        wall_ns = time.time_ns()
+        tr.complete("late", wall_ns - 5_000_000, wall_ns)
+        tr.export(tmp_path / "trace.json")
+        events = [
+            e
+            for e in json.loads((tmp_path / "trace.json").read_text())[
+                "traceEvents"
+            ]
+            if e["ph"] == "X"
+        ]
+        now_us = time.time() * 1e6
+        for e in events:
+            assert abs(e["ts"] - now_us) < 1e6, e
+        by_name = {e["name"]: e for e in events}
+        assert by_name["inner"]["parent"] == by_name["outer"]["id"]
+        assert 4000 <= by_name["late"]["dur"] <= 6000
+
+    def test_run_telemetry_installs_its_tracer(self, tmp_path, tracer):
+        from alphatriangle_tpu.config import TelemetryConfig
+
+        on = RunTelemetry(
+            TelemetryConfig(WATCHDOG_ENABLED=False, FLIGHT_ENABLED=False),
+            run_dir=tmp_path,
+        )
+        assert default_tracer() is on.tracer and on.tracer.enabled
+        off = RunTelemetry(TelemetryConfig(ENABLED=False), run_dir=tmp_path)
+        assert default_tracer() is off.tracer and not off.tracer.enabled
+
+
+# --- a tiny world for (c) and (d) --------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def world(
+    tiny_env_config, tiny_model_config, tiny_per_train_config, tiny_mcts_config
+):
+    from alphatriangle_tpu.env.engine import TriangleEnv
+    from alphatriangle_tpu.features.core import get_feature_extractor
+    from alphatriangle_tpu.nn.network import NeuralNetwork
+    from alphatriangle_tpu.rl.device_buffer import DeviceReplayBuffer
+    from alphatriangle_tpu.rl.self_play import SelfPlayEngine
+    from alphatriangle_tpu.rl.trainer import Trainer
+
+    # A net with every stage, so that every `net/` phase exists.
+    model = tiny_model_config.model_copy(
+        update={
+            "NUM_RESIDUAL_BLOCKS": 1,
+            "USE_TRANSFORMER": True,
+            "TRANSFORMER_LAYERS": 1,
+        }
+    )
+    train = tiny_per_train_config.model_copy(
+        update={"SELF_PLAY_BATCH_SIZE": 4, "N_STEP_RETURNS": 3}
+    )
+    # The flagship's search: Gumbel root, playout cap.
+    mcts = tiny_mcts_config.model_copy(
+        update={
+            "root_selection": "gumbel",
+            "gumbel_m": 4,
+            "fast_simulations": 4,
+            "full_search_prob": 0.5,
+        }
+    )
+    env = TriangleEnv(tiny_env_config)
+    extractor = get_feature_extractor(env, model)
+    net = NeuralNetwork(model, tiny_env_config, seed=5)
+    engine = SelfPlayEngine(env, extractor, net, mcts, train, seed=7)
+    buffer = DeviceReplayBuffer(
+        train,
+        grid_shape=(model.GRID_INPUT_CHANNELS, 3, 4),
+        other_dim=extractor.other_dim,
+        action_dim=tiny_env_config.action_dim,
+        seed=0,
+    )
+    return {
+        "engine": engine,
+        "buffer": buffer,
+        "trainer": Trainer(net, train),
+        "env": env,
+        "extractor": extractor,
+        "net": net,
+        "mcts": mcts,
+        "train": train,
+    }
+
+
+# --- (c) the spans the hot path leaves ----------------------------------------
+
+
+class TestProgramSpans:
+    def test_rollout_and_ingest_spans_in_order(self, world, tracer):
+        engine, buffer = world["engine"], world["buffer"]
+        rows = 0
+        while rows < 16:  # enough rows for the learner's test below
+            mark = len(tracer.records())
+            _, payload = engine.play_moves_device(4)
+            added = buffer.ingest_payload(payload)
+            rows += added
+        records = tracer.records()[mark:]
+        assert [r[1] for r in records] == [
+            "rollout.dispatch",
+            "rollout.wait",
+            "rollout.fold",
+            "replay.ingest_dispatch",
+            "replay.ingest_wait",
+            "replay.tree_update",
+        ]
+        args = {r[1]: r[6] for r in records}
+        for name in ("rollout.dispatch", "rollout.wait", "rollout.fold"):
+            assert args[name] == {"t": 4, "lanes": 4}
+        assert args["replay.ingest_wait"] == {"rows": added}
+        assert args["replay.tree_update"] == {"rows": added}
+        assert args["replay.ingest_dispatch"] is None
+
+    def test_learner_group_spans_under_the_open_phase(self, world, tracer):
+        trainer, buffer = world["trainer"], world["buffer"]
+        assert len(buffer) >= 8
+        with tracer.span("train"):
+            samples = [
+                buffer.sample(4, current_train_step=trainer.global_step)
+                for _ in range(2)
+            ]
+            outs = trainer.train_steps_from(buffer, samples)
+            for sample, (_, td) in zip(samples, outs):
+                buffer.update_priorities(sample["indices"], td)
+        records = tracer.records()
+        assert [r[1] for r in records] == [
+            "replay.sample",
+            "replay.sample",
+            "learner.dispatch",
+            "learner.wait",
+            "learner.results",
+            "replay.priorities",
+            "replay.priorities",
+            "train",
+        ]
+        train_id = records[-1][7]
+        assert {r[8] for r in records[:-1]} == {train_id}
+        args = {r[1]: r[6] for r in records}
+        for name in ("learner.dispatch", "learner.wait", "learner.results"):
+            assert args[name] == {"k": 2}
+
+    def test_host_batches_group_has_the_same_three(self, world, tracer):
+        trainer = world["trainer"]
+        rng = np.random.default_rng(0)
+        n = 4
+        batch = {
+            "grid": rng.integers(-1, 2, size=(n, 1, 3, 4)).astype(np.float32),
+            "other_features": rng.random(
+                (n, world["extractor"].other_dim), dtype=np.float32
+            ),
+            "policy_target": np.full((n, 12), 1 / 12, np.float32),
+            "value_target": np.zeros(n, np.float32),
+            "weights": np.ones(n, np.float32),
+        }
+        trainer.train_steps([batch])
+        assert _names(tracer) == [
+            "learner.dispatch", "learner.wait", "learner.results"
+        ]
+        assert tracer.records()[0][6] == {"k": 1}
+
+
+# --- (d) the names in the lowered programs ------------------------------------
+
+
+def _lowered_text(jitted, *args) -> str:
+    return jitted.lower(*args).as_text(debug_info=True)
+
+
+def _phases(*prefixes) -> list[str]:
+    return [p for p in PHASES if p.startswith(prefixes)]
+
+
+class TestPhaseNamesInPrograms:
+    def test_chunk_program(self, world):
+        engine = world["engine"]
+        text = _lowered_text(
+            engine._chunk_fn(2)._jit_fn,
+            engine.net.variables,
+            engine._carry,
+            jnp.int32(0),
+        )
+        # `rollout/promote` is the subtree-reuse chunk's (below).
+        wanted = set(_phases("rollout/", "search/", "gumbel/", "net/"))
+        wanted.remove("rollout/promote")
+        assert {p for p in wanted if p not in text} == set()
+
+    def test_promote_phase_in_the_reuse_chunk(self, world, tiny_mcts_config):
+        from alphatriangle_tpu.rl.self_play import SelfPlayEngine
+
+        engine = SelfPlayEngine(
+            world["env"],
+            world["extractor"],
+            world["net"],
+            tiny_mcts_config.model_copy(update={"tree_reuse": True}),
+            world["train"],
+            seed=7,
+        )
+        text = _lowered_text(
+            engine._chunk_fn(2)._jit_fn,
+            engine.net.variables,
+            engine._carry,
+            jnp.int32(0),
+        )
+        assert "rollout/promote" in text and "gumbel/root" not in text
+
+    def test_fused_learner_program(self, world):
+        trainer, buffer = world["trainer"], world["buffer"]
+        text = _lowered_text(
+            trainer._from_fn._jit_fn,
+            trainer.state,
+            buffer.storage,
+            np.zeros((2, 4), np.int32),
+            np.ones((2, 4), np.float32),
+        )
+        wanted = set(_phases("learner/", "net/"))
+        wanted.remove("learner/backward")  # drawn by autodiff:
+        assert "transpose(jvp(learner/forward_loss))" in text
+        assert {p for p in wanted if p not in text} == set()
+        assert profiling.phase_of(
+            "jit(f)/transpose(jvp(learner/forward_loss))/net/encoder/dot_general"
+        ) == "learner/backward"
+
+    def test_ingest_program(self, world):
+        buffer = world["buffer"]
+        _, payload = world["engine"].play_moves_device(4)
+        text = _lowered_text(
+            buffer._ingest_jit,
+            buffer.storage,
+            jnp.int32(0),
+            (payload["mat"], payload["flush"]),
+        )
+        assert _phases("replay/") == ["replay/ingest_scatter"]
+        assert "replay/ingest_scatter" in text
+
+    def test_every_phase_is_held_by_some_test_above(self):
+        covered = _phases(
+            "rollout/", "search/", "gumbel/", "net/", "learner/", "replay/"
+        )
+        assert covered == list(PHASES)
+
+
+# --- (e) the phase reader -----------------------------------------------------
+
+
+OP_NAMES = {
+    "%fusion.1": "jit(chunk)/while/body/search/descend/dot_general",
+    "%fusion.2": "jit(chunk)/while/body/search/evaluate/net/encoder/dot_general",
+    "%fusion.3": "jit(chunk)/while/body/search/evaluate/search/expand/gather",
+    "%fusion.4": "jit(f)/transpose(jvp(learner/forward_loss))/net/conv/conv",
+    "%fusion.5": "jit(f)/jvp(learner/forward_loss)/learner/td/reduce_sum",
+    "%copy.9": "jit(f)/convert_element_type",
+    "%while.7": "jit(chunk)/while",
+}
+
+
+class TestPhaseSeconds:
+    def test_containers_innermost_transpose_and_other(self):
+        ms = 1_000_000
+        events = [
+            ("%while.7 = (s32[]) while((s32[]) %tuple.1)", 0, 100 * ms),
+            ("%conditional.2 = f32[4] conditional(...)", 0, 50 * ms),
+            ("%call.3 = f32[4] call(f32[4] %x)", 0, 25 * ms),
+            ("%fusion.1 = f32[4] fusion(...)", 0, 10 * ms),
+            ("%fusion.1 = f32[4] fusion(...)", 20, 10 * ms),
+            ("%fusion.2 = f32[4] fusion(...)", 40, 30 * ms),
+            ("%fusion.3 = f32[4] fusion(...)", 50, 5 * ms),
+            ("%fusion.4 = f32[4] fusion(...)", 60, 7 * ms),
+            ("%fusion.5 = f32[4] fusion(...)", 70, 2 * ms),
+            ("%copy.9 = f32[4] copy(...)", 80, 3 * ms),
+            ("%unknown.1 = f32[4] add(...)", 90, 4 * ms),
+        ]
+        seconds = profiling.phase_seconds(events, OP_NAMES)
+        assert seconds == pytest.approx(
+            {
+                "search/descend": 0.020,
+                "search/expand": 0.005,
+                "net/encoder": 0.030,
+                "learner/td": 0.002,
+                "learner/backward": 0.007,
+                "other": 0.007,
+            }
+        )
+        assert list(seconds)[-1] == "other"
+        # In the order of `telemetry/phases.py`.
+        named = [p for p in seconds if p != "other"]
+        assert named == [p for p in PHASES if p in named]
+
+    def test_an_op_name_as_the_event_name(self):
+        seconds = profiling.phase_seconds(
+            [("jit(f)/learner/optimizer/mul", 0, 2_000_000)], {}
+        )
+        assert seconds == pytest.approx({"learner/optimizer": 0.002, "other": 0})
+
+    def test_parse_op_names_and_inheritance(self):
+        text = "\n".join(
+            [
+                "HloModule jit_f, is_scheduled=true",
+                "%fused_computation (p: f32[4]) -> f32[4] {",
+                "  %p = f32[4]{0} parameter(0)",
+                '  ROOT %add.1 = f32[4]{0} add(%p, %p), metadata={op_name="jit(f)/learner/gather/add"}',
+                "}",
+                "ENTRY %main (x: f32[4]) -> f32[4] {",
+                '  %x = f32[4]{0} parameter(0), metadata={op_name="x"}',
+                "  %copy.1 = f32[4]{0} copy(%x), metadata={op_name=\"storage['policy_target']\"}",
+                "  %bitcast.2 = f32[4]{0} bitcast(%copy.1)",
+                '  %fusion.3 = f32[4]{0} fusion(%bitcast.2), kind=kLoop, calls=%fused_computation, metadata={op_name="jit(f)/learner/gather/gather"}',
+                "  ROOT %copy.4 = f32[4]{0} copy(%fusion.3)",
+                "}",
+            ]
+        )
+        names = profiling.parse_op_names(text)
+        assert names["%fusion.3"] == "jit(f)/learner/gather/gather"
+        # The compiler's own copies: from the user, through a bitcast;
+        # else from the operand. An argument's name is no place, on the
+        # parameter or on the copy of it (the learner's 4.3 GB one).
+        assert names["%copy.1"] == names["%copy.4"] == names["%fusion.3"]
+        assert names.get("%x") != "x"
+        assert profiling.phase_of(names["%copy.1"]) == "learner/gather"
+
+
+class TestProgramOpNames:
+    def test_compile_cache_hands_out_live_executables(self, world):
+        """`ProfileSession` writes `op_names.json` from these."""
+        from alphatriangle_tpu.compile_cache import get_compile_cache
+
+        world["engine"].play_moves_device(4)
+        names = [n for n, _ in get_compile_cache().executables()]
+        assert "self_play_chunk/t4" in names
+        op_names = profiling.program_op_names()
+        chunk = next(v for k, v in op_names.items() if "chunk" in k)
+        phases = {profiling.phase_of(v) for v in chunk.values()}
+        assert {"search/descend", "search/evaluate", "gumbel/root"} <= phases
