@@ -817,11 +817,9 @@ class MegastepRunner:
 
         # --- trainer-side results (train_steps_finish contract) ------
         trainer._host_step += k
-        results = []
-        for i in range(k):
-            m = {key: float(v[i]) for key, v in host["metrics"].items()}
-            m["learning_rate"] = float(trainer.schedule(start_step + i + 1))
-            results.append((m, np.asarray(host["td"][i])))
+        results = trainer.group_results(
+            start_step, host["metrics"], np.asarray(host["td"])
+        )
         return results, count
 
     # --- AOT warming / memory analysis (cli warm / cli fit) ---------------

@@ -13,14 +13,18 @@ TPU-native redesign:
   ICI collectives because the loss reduces over a sharded axis (GSPMD).
   The reference's single-device `backward()` (`trainer.py:274-286`)
   becomes multi-chip for free.
-- Optimizer/schedule are optax transforms; LR is recomputed from the
-  schedule, not read from mutable optimizer state.
+- Optimizer/schedule are optax transforms; the optax schedule lives
+  only inside the compiled programs. The `learning_rate` label of a
+  finished step is recomputed on the host from the step number by the
+  schedule's numpy twin (`make_host_lr_schedule`): no JAX call, not
+  read from mutable optimizer state.
 - The C51 projection of a *scalar* return is a two-hot scatter
   (`trainer.py:159-202` does the same dance with torch index math).
 """
 
 import logging
 import time
+from collections.abc import Callable
 from typing import Any
 
 import jax
@@ -52,13 +56,16 @@ logger = logging.getLogger(__name__)
 # --- optimizer / schedule factories --------------------------------------
 
 
+def _cosine_t_max(cfg: TrainConfig) -> int:
+    return cfg.LR_SCHEDULER_T_MAX or (cfg.MAX_TRAINING_STEPS or 100_000)
+
+
 def make_lr_schedule(cfg: TrainConfig) -> optax.Schedule:
     """LR schedule per `TrainConfig` (reference `trainer.py:66-102`)."""
     if cfg.LR_SCHEDULER_TYPE == "CosineAnnealingLR":
-        t_max = cfg.LR_SCHEDULER_T_MAX or (cfg.MAX_TRAINING_STEPS or 100_000)
         return optax.cosine_decay_schedule(
             init_value=cfg.LEARNING_RATE,
-            decay_steps=t_max,
+            decay_steps=_cosine_t_max(cfg),
             alpha=cfg.LR_SCHEDULER_ETA_MIN / cfg.LEARNING_RATE,
         )
     if cfg.LR_SCHEDULER_TYPE == "StepLR":
@@ -69,6 +76,38 @@ def make_lr_schedule(cfg: TrainConfig) -> optax.Schedule:
             staircase=True,
         )
     return optax.constant_schedule(cfg.LEARNING_RATE)
+
+
+def make_host_lr_schedule(cfg: TrainConfig) -> Callable[[Any], np.ndarray]:
+    """`make_lr_schedule`'s host twin: step numbers -> float32 LRs.
+
+    Plain numpy, vectorised over an array of steps (a scalar gives a
+    0-d result), with optax's own float32 operation order so the two
+    agree to rounding. It labels the metrics stream; the optimizer
+    inside the compiled programs evaluates `make_lr_schedule`'s copy.
+    """
+    f32 = np.float32
+    init = f32(cfg.LEARNING_RATE)
+    if cfg.LR_SCHEDULER_TYPE == "CosineAnnealingLR":
+        t_max = f32(_cosine_t_max(cfg))
+        alpha = cfg.LR_SCHEDULER_ETA_MIN / cfg.LEARNING_RATE
+        keep, floor = f32(1 - alpha), f32(alpha)
+
+        def cosine(steps):
+            count = np.minimum(np.asarray(steps, dtype=f32), t_max)
+            decay = f32(0.5) * (f32(1) + np.cos(f32(np.pi) * count / t_max))
+            return init * (keep * decay + floor)
+
+        return cosine
+    if cfg.LR_SCHEDULER_TYPE == "StepLR":
+        size, gamma = cfg.LR_SCHEDULER_STEP_SIZE, f32(cfg.LR_SCHEDULER_GAMMA)
+
+        def staircase(steps):
+            stairs = (np.asarray(steps, dtype=np.int64) // size).astype(f32)
+            return init * np.power(gamma, stairs)
+
+        return staircase
+    return lambda steps: np.full(np.shape(steps), init)
 
 
 def make_optimizer(cfg: TrainConfig) -> optax.GradientTransformation:
@@ -192,6 +231,7 @@ class Trainer:
         self.num_atoms = mc.NUM_VALUE_ATOMS
         self.v_min, self.v_max = mc.VALUE_MIN, mc.VALUE_MAX
         self.schedule = make_lr_schedule(train_config)
+        self.host_schedule = make_host_lr_schedule(train_config)
         self.optimizer = make_optimizer(train_config)
 
         # Deep-copy the wrapper's variables: the jitted step donates its
@@ -775,13 +815,24 @@ class Trainer:
                     for key, v in host_metrics_k.items()
                 }
                 td_host = td_host[None]
-            results = []
-            for i in range(k):
-                m = {key: float(v[i]) for key, v in host_metrics_k.items()}
-                m["learning_rate"] = float(
-                    self.schedule(handle["start_step"] + i + 1)
-                )
-                results.append((m, td_host[i]))
+            return self.group_results(
+                handle["start_step"], host_metrics_k, td_host
+            )
+
+    def group_results(
+        self, start_step: int, metrics_k: dict, td_k: np.ndarray
+    ) -> list[tuple[dict[str, float], np.ndarray]]:
+        """Per-step (metrics, TD errors) of a fetched group whose first
+        step is `start_step + 1`, from host arrays with a leading step
+        axis. Host only: nothing here may dispatch to the device (the
+        device idles until the next group is sampled and dispatched)."""
+        k = len(td_k)
+        lrs = self.host_schedule(start_step + 1 + np.arange(k)).tolist()
+        results = []
+        for i in range(k):
+            m = {key: float(v[i]) for key, v in metrics_k.items()}
+            m["learning_rate"] = lrs[i]
+            results.append((m, td_k[i]))
         return results
 
     # --- AOT warming (compile_cache.py; cli warm) -------------------------
@@ -895,7 +946,7 @@ class Trainer:
 
     def get_current_lr(self) -> float:
         """LR at the current step (reference `trainer.py:312-323`)."""
-        return float(self.schedule(self.global_step))
+        return float(self.host_schedule(self.global_step))
 
     def get_variables(self) -> dict:
         """Current model variables (for pushing into the eval wrapper)."""

@@ -267,8 +267,15 @@ class TestTrainEquivalence:
         assert len(outs1) == 2 and len(outs2) == 1
         assert outs1[0][1].shape == (4,)  # per-step TD rows
         assert tr.global_step == 3
+        want = [float(tr.schedule(i)) for i in range(1, 6)]
         lrs = [m["learning_rate"] for m, _ in outs1 + outs2]
-        assert lrs == [float(tr.schedule(i)) for i in (1, 2, 3)]
+        assert lrs == pytest.approx(want[:3], rel=1e-6)
+        # A from-ring group labels its steps without the optax schedule
+        # (tests/test_trainer.py holds the other learner paths to that).
+        tr.schedule = None
+        outs3 = tr.train_steps_from(dev, [dev.sample(4), dev.sample(4)])
+        lrs = [m["learning_rate"] for m, _ in outs3]
+        assert lrs == pytest.approx(want[3:], rel=1e-6)
 
 
 class TestSelfPlayIntegration:

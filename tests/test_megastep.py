@@ -97,6 +97,47 @@ def build(tmp_path, cfgs, run_name="mega_run", mcts_kw=None, **kw):
     )
 
 
+def direct_runner(cfgs, train_cfg):
+    """A `MegastepRunner` over components built directly (no loop, no
+    run directory); its ring is empty: `fill_ring` makes it sampleable."""
+    from alphatriangle_tpu.env.engine import TriangleEnv
+    from alphatriangle_tpu.features.core import get_feature_extractor
+    from alphatriangle_tpu.nn.network import NeuralNetwork
+    from alphatriangle_tpu.rl import MegastepRunner, SelfPlayEngine, Trainer
+    from alphatriangle_tpu.rl.device_buffer import DeviceReplayBuffer
+
+    env_cfg, model_cfg, mcts_cfg = cfgs
+    env = TriangleEnv(env_cfg)
+    extractor = get_feature_extractor(env, model_cfg)
+    net = NeuralNetwork(model_cfg, env_cfg, seed=0)
+    engine = SelfPlayEngine(env, extractor, net, mcts_cfg, train_cfg, seed=0)
+    trainer = Trainer(net, train_cfg)
+    buffer = DeviceReplayBuffer(
+        train_cfg,
+        grid_shape=(model_cfg.GRID_INPUT_CHANNELS, env_cfg.ROWS, env_cfg.COLS),
+        other_dim=extractor.other_dim,
+        action_dim=env_cfg.action_dim,
+    )
+    return MegastepRunner(engine, trainer, buffer, train_cfg)
+
+
+def fill_ring(buffer, n: int) -> None:
+    """`n` synthetic rows with fixed targets (a stationary distribution)."""
+    rng = np.random.default_rng(0)
+    grid, other, policy = (
+        (n, *buffer.storage[key].shape[1:])
+        for key in ("grid", "other_features", "policy_target")
+    )
+    policy = rng.random(policy).astype(np.float32)
+    policy /= policy.sum(axis=1, keepdims=True)
+    buffer.add_dense(
+        rng.integers(-1, 2, size=grid).astype(np.float32),
+        rng.random(other).astype(np.float32),
+        policy,
+        rng.uniform(-2, 2, n).astype(np.float32),
+    )
+
+
 def _priorities_sides(c):
     """(device priority array, host SumTree mirror leaves) for the
     first `size` ring slots."""
@@ -303,17 +344,6 @@ class TestMegastepLoop:
         megasteps must drive the loss down. Marked slow — the tier-1
         end-to-end test already pins that params update; this adds the
         loss-decrease bar on stationary data."""
-        from alphatriangle_tpu.env.engine import TriangleEnv
-        from alphatriangle_tpu.features.core import get_feature_extractor
-        from alphatriangle_tpu.nn.network import NeuralNetwork
-        from alphatriangle_tpu.rl import (
-            MegastepRunner,
-            SelfPlayEngine,
-            Trainer,
-        )
-        from alphatriangle_tpu.rl.device_buffer import DeviceReplayBuffer
-
-        env_cfg, model_cfg, mcts_cfg = tiny_world_configs
         tc = make_cfg(
             "learning_probe",
             MAX_TRAINING_STEPS=100,
@@ -321,35 +351,8 @@ class TestMegastepLoop:
             BATCH_SIZE=16,
             LEARNING_RATE=3e-3,
         )
-        env = TriangleEnv(env_cfg)
-        extractor = get_feature_extractor(env, model_cfg)
-        net = NeuralNetwork(model_cfg, env_cfg, seed=0)
-        engine = SelfPlayEngine(env, extractor, net, mcts_cfg, tc, seed=0)
-        trainer = Trainer(net, tc)
-        buf = DeviceReplayBuffer(
-            tc,
-            grid_shape=(
-                model_cfg.GRID_INPUT_CHANNELS,
-                env_cfg.ROWS,
-                env_cfg.COLS,
-            ),
-            other_dim=extractor.other_dim,
-            action_dim=env_cfg.action_dim,
-        )
-        rng = np.random.default_rng(0)
-        n = 512  # dominates the trickle of live rollout rows
-        policy = rng.random((n, env_cfg.action_dim)).astype(np.float32)
-        policy /= policy.sum(axis=1, keepdims=True)
-        buf.add_dense(
-            rng.integers(
-                -1, 2, size=(n, model_cfg.GRID_INPUT_CHANNELS,
-                             env_cfg.ROWS, env_cfg.COLS)
-            ).astype(np.float32),
-            rng.random((n, extractor.other_dim)).astype(np.float32),
-            policy,
-            rng.uniform(-2, 2, n).astype(np.float32),
-        )
-        runner = MegastepRunner(engine, trainer, buf, tc)
+        runner = direct_runner(tiny_world_configs, tc)
+        fill_ring(runner.buffer, 512)  # dominates the live rollout rows
         losses = []
         for _ in range(12):
             outs, _added = runner.run_megastep(2, 2)
@@ -359,6 +362,20 @@ class TestMegastepLoop:
         assert late < early, (
             f"megastep loss did not decrease ({early:.4f} -> {late:.4f})"
         )
+
+    def test_lr_labels_never_call_optax(self, tiny_world_configs):
+        """A megastep group labels its steps from the numpy twin of the
+        schedule: nothing is dispatched op by op after the one fetch."""
+        tc = make_cfg("lr_probe", ROLLOUT_CHUNK_MOVES=2)
+        runner = direct_runner(tiny_world_configs, tc)
+        fill_ring(runner.buffer, 64)
+        trainer = runner.trainer
+        want = [float(trainer.schedule(i)) for i in (1, 2)]
+        trainer.schedule = None  # a call on the host path would raise
+        outs, _added = runner.run_megastep(2, 2)
+        assert trainer.global_step == 2
+        lrs = [m["learning_rate"] for m, _td in outs]
+        assert lrs == pytest.approx(want, rel=1e-6)
 
     @pytest.mark.slow
     def test_run_training_and_resume(self, tmp_path, tiny_world_configs):
@@ -406,33 +423,6 @@ class TestMegastepLoop:
 
 
 class TestMegastepCompileCache:
-    def _runner(self, cfgs, train_cfg):
-        from alphatriangle_tpu.env.engine import TriangleEnv
-        from alphatriangle_tpu.features.core import get_feature_extractor
-        from alphatriangle_tpu.nn.network import NeuralNetwork
-        from alphatriangle_tpu.rl import MegastepRunner, SelfPlayEngine, Trainer
-        from alphatriangle_tpu.rl.device_buffer import DeviceReplayBuffer
-
-        env_cfg, model_cfg, mcts_cfg = cfgs
-        env = TriangleEnv(env_cfg)
-        extractor = get_feature_extractor(env, model_cfg)
-        net = NeuralNetwork(model_cfg, env_cfg, seed=0)
-        engine = SelfPlayEngine(
-            env, extractor, net, mcts_cfg, train_cfg, seed=0
-        )
-        trainer = Trainer(net, train_cfg)
-        buffer = DeviceReplayBuffer(
-            train_cfg,
-            grid_shape=(
-                model_cfg.GRID_INPUT_CHANNELS,
-                env_cfg.ROWS,
-                env_cfg.COLS,
-            ),
-            other_dim=extractor.other_dim,
-            action_dim=env_cfg.action_dim,
-        )
-        return MegastepRunner(engine, trainer, buffer, train_cfg)
-
     @pytest.mark.slow
     def test_analyze_registers_record_and_sidecar(
         self, tmp_path, tiny_world_configs
@@ -444,7 +434,7 @@ class TestMegastepCompileCache:
         train_cfg = make_cfg("cache_probe", MAX_TRAINING_STEPS=2)
         try:
             cache = reset_compile_cache(cache_dir=str(tmp_path / "aot"))
-            runner = self._runner(tiny_world_configs, train_cfg)
+            runner = direct_runner(tiny_world_configs, train_cfg)
             rec = runner.analyze_megastep(2, 1)
             assert rec is not None
             assert rec["program"] == "megastep/t2_k1"

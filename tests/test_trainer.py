@@ -12,6 +12,7 @@ from alphatriangle_tpu.config import MeshConfig, TrainConfig
 from alphatriangle_tpu.nn.network import NeuralNetwork
 from alphatriangle_tpu.rl.trainer import (
     Trainer,
+    make_host_lr_schedule,
     make_lr_schedule,
     make_optimizer,
     project_to_support,
@@ -40,7 +41,48 @@ def make_batch(n=B, seed=0, weights=None):
     }
 
 
+T_MAX, STEP_SIZE = 100_000, 10_000
+SCHEDULE_CASES = {
+    "cosine_t_max": dict(
+        LR_SCHEDULER_TYPE="CosineAnnealingLR", LR_SCHEDULER_T_MAX=T_MAX
+    ),
+    # T_MAX left out: TrainConfig derives it from the horizon.
+    "cosine_horizon": dict(
+        LR_SCHEDULER_TYPE="CosineAnnealingLR", MAX_TRAINING_STEPS=T_MAX
+    ),
+    "step_lr": dict(
+        LR_SCHEDULER_TYPE="StepLR",
+        LR_SCHEDULER_STEP_SIZE=STEP_SIZE,
+        LR_SCHEDULER_GAMMA=0.9,
+    ),
+    "constant": dict(LR_SCHEDULER_TYPE=None),
+}
+SCHEDULE_STEPS = [
+    0, 1, 2, STEP_SIZE - 1, STEP_SIZE, T_MAX - 1, T_MAX, T_MAX + 1, 10 * T_MAX
+]
+
+
+def raising_schedule(step):
+    raise AssertionError(f"optax schedule called on a host path (step {step})")
+
+
 class TestSchedules:
+    @pytest.mark.parametrize("step", SCHEDULE_STEPS)
+    @pytest.mark.parametrize("case", SCHEDULE_CASES)
+    def test_host_twin_matches_optax(self, case, step):
+        """The numpy twin that labels finished steps equals the optax
+        schedule the optimizer compiles, scalar and vectorised."""
+        cfg = TrainConfig(RUN_NAME="t", **SCHEDULE_CASES[case])
+        want = float(make_lr_schedule(cfg)(step))
+        host = make_host_lr_schedule(cfg)
+        scalar = host(step)
+        assert scalar.dtype == np.float32 and scalar.shape == ()
+        assert float(scalar) == pytest.approx(want, rel=1e-6)
+        around = host(np.array([step + 3, step, 7]))
+        assert around.dtype == np.float32 and around.shape == (3,)
+        assert float(around[1]) == pytest.approx(want, rel=1e-6)
+        assert float(around[2]) == float(host(7))
+
     def test_cosine_endpoints(self):
         cfg = TrainConfig(
             MAX_TRAINING_STEPS=1000,
@@ -439,6 +481,21 @@ class TestPipelinedSteps:
             assert m["learning_rate"] == pytest.approx(
                 float(trainer.schedule(i + 1))
             )
+
+    def test_lr_labels_never_call_optax(self, network, tiny_train_config):
+        """No learner path evaluates the optax schedule on the host
+        (op-by-op device programs in the gap between two groups): the
+        labels come from the numpy twin alone."""
+        trainer = Trainer(network, tiny_train_config)
+        want = [float(trainer.schedule(i)) for i in range(1, 7)]
+        trainer.schedule = raising_schedule
+        got = [trainer.train_step(make_batch())[0]["learning_rate"]]
+        assert trainer.get_current_lr() == got[0]
+        outs = trainer.train_steps([make_batch(seed=i) for i in range(3)])
+        outs += trainer.train_steps([make_batch(seed=9)])  # unstacked K=1
+        got += [m["learning_rate"] for m, _ in outs]
+        assert trainer.global_step == 5
+        assert got == pytest.approx(want[:5], rel=1e-6)
 
 
 class TestBatchNormPath:
