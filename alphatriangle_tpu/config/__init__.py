@@ -5,7 +5,7 @@ from alphatriangle_tpu.config.env_config import EnvConfig
 from alphatriangle_tpu.config.league_config import LeagueConfig
 from alphatriangle_tpu.config.mcts_config import AlphaTriangleMCTSConfig, MCTSConfig
 from alphatriangle_tpu.config.mesh_config import MeshConfig
-from alphatriangle_tpu.config.model_config import ModelConfig
+from alphatriangle_tpu.config.model_config import ModelConfig, TrunkConfig
 from alphatriangle_tpu.config.persistence_config import PersistenceConfig
 from alphatriangle_tpu.config.presets import (
     GEOMETRY_PRESETS,
@@ -31,6 +31,7 @@ __all__ = [
     "MCTSConfig",
     "MeshConfig",
     "ModelConfig",
+    "TrunkConfig",
     "PRESET_DESCRIPTIONS",
     "PersistenceConfig",
     "TUNED_PRESET_SCHEMA",

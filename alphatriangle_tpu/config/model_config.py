@@ -14,6 +14,75 @@ from typing import Literal
 from pydantic import BaseModel, Field, model_validator
 
 
+class TrunkConfig(BaseModel):
+    """A decoder stack in the encoder's place (nn/trunk.py): RMSNorm,
+    rotary positions, grouped-query attention under a causal window or
+    a causal full mask by layer, SwiGLU, and routed experts with a
+    shared expert. The keys are a published `config.json`'s, under its
+    names; layer l is `layer_types[l]` with `mlp_layer_types[l]`.
+
+    `experts_held` = (first, count): the router scores all
+    `num_experts`; this process computes the experts it holds for the
+    tokens routed to them and adds nothing for the others (one chip's
+    share under expert parallelism; (0, num_experts) is the whole
+    layer). The last four keys are what such a file leaves to the
+    family's convention."""
+
+    hidden_size: int = Field(gt=0)
+    num_attention_heads: int = Field(gt=0)
+    num_key_value_heads: int = Field(gt=0)
+    head_dim: int = Field(gt=0)
+    intermediate_size: int = Field(gt=0)
+    moe_intermediate_size: int = Field(gt=0)
+    num_experts: int = Field(gt=0)
+    num_experts_per_tok: int = Field(gt=0)
+    num_shared_experts: int = Field(default=1, ge=0)
+    routed_scaling_factor: float = Field(default=1.0)
+    sliding_window: int = Field(gt=0)
+    layer_types: list[Literal["sliding_attention", "full_attention"]]
+    mlp_layer_types: list[Literal["dense", "sparse"]]
+    rope_theta: float = Field(default=1e6, gt=0)
+    rms_norm_eps: float = Field(default=1e-5, gt=0)
+    experts_held: tuple[int, int]
+
+    # The one placement implemented: x + norm(f(x)) for attention and
+    # MLP alike, an RMSNorm on q and k per head, rotary positions on the
+    # sliding layers only. The keys say so; another value is refused.
+    norm_position: Literal["post"] = Field(default="post")
+    qk_norm: Literal[True] = Field(default=True)
+    rope_layers: Literal["sliding"] = Field(default="sliding")
+    # A per-expert float32 parameter added to the scores for the choice
+    # alone (the weights stay the raw scores'): how a router balanced by
+    # bias selects. Noughts as initialised; a checkpoint brings its own.
+    router_bias: bool = Field(default=False)
+    # Boards the net takes at a time where a search evaluates a leaf
+    # batch (cut into such blocks inside the program); None = all at once.
+    block_boards: int | None = Field(default=None, gt=0)
+
+    @model_validator(mode="after")
+    def _check(self) -> "TrunkConfig":
+        if len(self.layer_types) != len(self.mlp_layer_types):
+            raise ValueError(
+                "layer_types and mlp_layer_types must name the same layers."
+            )
+        if self.num_attention_heads % self.num_key_value_heads:
+            raise ValueError(
+                f"num_attention_heads ({self.num_attention_heads}) must be a "
+                f"multiple of num_key_value_heads ({self.num_key_value_heads})."
+            )
+        if self.head_dim % 2:
+            raise ValueError("head_dim must be even (rotary pairs).")
+        if self.num_experts_per_tok > self.num_experts:
+            raise ValueError("num_experts_per_tok exceeds num_experts.")
+        first, count = self.experts_held
+        if first < 0 or count < 1 or first + count > self.num_experts:
+            raise ValueError(
+                f"experts_held {self.experts_held} lies outside the "
+                f"{self.num_experts} experts."
+            )
+        return self
+
+
 class ModelConfig(BaseModel):
     """Policy/value network hyperparameters (pydantic)."""
 
@@ -34,6 +103,9 @@ class ModelConfig(BaseModel):
     TRANSFORMER_HEADS: int = Field(default=4, gt=0)
     TRANSFORMER_LAYERS: int = Field(default=2, ge=0)
     TRANSFORMER_FC_DIM: int = Field(default=256, gt=0)
+    # A decoder stack in the encoder layers' place; None = the encoder
+    # above, key for key.
+    TRUNK: TrunkConfig | None = Field(default=None)
 
     # --- Heads ---
     FC_DIMS_SHARED: list[int] = Field(default=[128])
@@ -57,7 +129,9 @@ class ModelConfig(BaseModel):
 
     # --- TPU-specific ---
     COMPUTE_DTYPE: Literal["bfloat16", "float32"] = Field(default="bfloat16")
-    PARAM_DTYPE: Literal["float32"] = Field(default="float32")
+    # "bfloat16": parameters are made and held in bfloat16 (a trunk
+    # published in it, too large to keep a float32 original beside).
+    PARAM_DTYPE: Literal["float32", "bfloat16"] = Field(default="float32")
     # jax.checkpoint the residual + transformer blocks to trade FLOPs for HBM.
     REMAT: bool = Field(default=False)
     # Param dtype the INFERENCE family (rollout chunk, serve dispatch,

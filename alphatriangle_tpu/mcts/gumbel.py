@@ -234,4 +234,5 @@ class GumbelMCTS(BatchedMCTS):
             selected_action=selected,
             improved_policy=improved,
             stats=stats,
+            net_counters=tree.net_counters,
         )

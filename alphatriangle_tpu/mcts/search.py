@@ -84,6 +84,10 @@ class Tree:
     valid: jax.Array  # (B, N, A) f32 1.0 where the action is valid
     terminal: jax.Array  # (B, N) bool
     root_value0: jax.Array  # (B,) f32 network value of the root at init
+    # What the net counted over this search's evaluations (a routed
+    # trunk's expert assignments, nn/trunk.py); None for a net that
+    # counts nothing — an empty pytree node, the program unchanged.
+    net_counters: Any = None
 
 
 @struct.dataclass
@@ -129,6 +133,8 @@ class SearchOutput:
     # (PUCT and Gumbel) produce the same structure so the playout-cap
     # lax.cond branches keep matching pytrees.
     stats: Any = None
+    # `Tree.net_counters` at the search's end (None: nothing counted).
+    net_counters: Any = None
 
 
 def tree_geometry(config: MCTSConfig) -> tuple[int, int]:
@@ -190,7 +196,8 @@ class BatchedMCTS:
 
     @jax.named_scope("search/evaluate")
     def _evaluate(self, variables, states: EnvState):
-        """Batched leaf eval: states (B-leading) -> (priors (B,A), values (B,)).
+        """Batched leaf eval: states (B-leading) -> (priors (B,A), values
+        (B,), valid (B,A), what the net counted or None).
 
         Priors are masked to valid actions and renormalized (uniform over
         valid when the network mass on valid actions vanishes — the
@@ -205,9 +212,43 @@ class BatchedMCTS:
         # Int8 weight-only inference (nn/precision.py): marker-dict
         # leaves dequantize to bf16 here, at the one place every search
         # family evaluates the net; unquantized trees pass through.
-        policy_logits, value_logits = self.model.apply(
-            dequantize_params(variables), grids, others, train=False
-        )
+        counters = None
+        if self.model.config.TRUNK is None:
+            policy_logits, value_logits = self.model.apply(
+                dequantize_params(variables), grids, others, train=False
+            )
+        else:
+            # A routed trunk (nn/trunk.py) counts its expert assignments,
+            # and at its width a whole leaf batch does not fit: the net
+            # takes `block_boards` boards at a time inside the program.
+            from ..nn.trunk import block_size, counters_of
+
+            def net(block):
+                out, sown = self.model.apply(
+                    dequantize_params(variables),
+                    *block,
+                    train=False,
+                    mutable=["counters"],
+                )
+                return out, counters_of(sown)
+
+            batch = grids.shape[0]
+            size = block_size(batch, self.model.config.TRUNK.block_boards)
+            if size == batch:
+                (policy_logits, value_logits), counters = net((grids, others))
+            else:
+                (policy_logits, value_logits), counters = jax.lax.map(
+                    net,
+                    jax.tree_util.tree_map(
+                        lambda x: x.reshape(batch // size, size, *x.shape[1:]),
+                        (grids, others),
+                    ),
+                )
+                policy_logits = policy_logits.reshape(batch, -1)
+                value_logits = value_logits.reshape(batch, -1)
+                counters = jax.tree_util.tree_map(
+                    lambda x: x.sum(axis=0), counters
+                )
         valid = jax.vmap(self.env.valid_action_mask)(states)  # (B, A)
         masked_logits = jnp.where(valid, policy_logits, -jnp.inf)
         # Softmax over valid actions only; all-invalid rows -> zeros.
@@ -222,7 +263,7 @@ class BatchedMCTS:
         priors = jnp.where(norm > 1e-9, priors / jnp.maximum(norm, 1e-9), uniform)
         value_probs = jax.nn.softmax(value_logits, axis=-1)
         values = jnp.sum(value_probs * self.support, axis=-1)
-        return priors, values, valid
+        return priors, values, valid, counters
 
     # --- the search -------------------------------------------------------
 
@@ -233,7 +274,7 @@ class BatchedMCTS:
         batch = root_states.done.shape[0]
         n, a = self.num_nodes, self.action_dim
 
-        priors, values, valid = self._evaluate(variables, root_states)
+        priors, values, valid, counters = self._evaluate(variables, root_states)
         root_terminal = root_states.done
         root_value = jnp.where(root_terminal, 0.0, values)
 
@@ -267,6 +308,7 @@ class BatchedMCTS:
             valid=zeros_na.at[:, 0].set(valid.astype(jnp.float32)),
             terminal=jnp.zeros((batch, n), dtype=bool).at[:, 0].set(root_terminal),
             root_value0=root_value,
+            net_counters=counters,
         )
 
     @jax.named_scope("search/descend")
@@ -447,7 +489,7 @@ class BatchedMCTS:
             dones = dones.reshape(batch, w)
 
         # 3. Evaluation: ONE fused network call for all B*W leaves.
-        priors, values, valid = self._evaluate(variables, new_states)
+        priors, values, valid, counters = self._evaluate(variables, new_states)
         leaf_values = jnp.where(dones, 0.0, values.reshape(batch, w))
 
         with jax.named_scope("search/expand"):
@@ -484,6 +526,9 @@ class BatchedMCTS:
                     tree.valid, valid.reshape(batch, w, a).astype(jnp.float32)
                 ),
                 terminal=insert(tree.terminal, dones),
+                net_counters=jax.tree_util.tree_map(
+                    jnp.add, tree.net_counters, counters
+                ),
             )
 
         with jax.named_scope("search/backup"):
@@ -647,6 +692,7 @@ class BatchedMCTS:
             wasted_slots=wasted,
             selected_action=jnp.full((batch,), -1, jnp.int32),
             improved_policy=jnp.zeros_like(visit_counts),
+            net_counters=tree.net_counters,
         )
 
     def _search(
@@ -721,6 +767,7 @@ class BatchedMCTS:
                 fresh.terminal,
             ),
             root_value0=fresh.root_value0,
+            net_counters=fresh.net_counters,
         )
         reused = jnp.where(ok, ct.e_visits[:, 0, :].sum(axis=-1), 0.0)
         base0 = jnp.where(ok, jnp.maximum(carried.base, 1), 1).astype(

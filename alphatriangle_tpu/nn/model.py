@@ -30,6 +30,7 @@ from flax import linen as nn
 from jax import Array
 
 from ..config.model_config import ModelConfig
+from .trunk import DecoderTrunk
 
 _ACTIVATIONS: dict[str, Callable[[Array], Array]] = {
     "ReLU": nn.relu,
@@ -65,18 +66,18 @@ class _Norm(nn.Module):
 
     norm_type: str
     dtype: jnp.dtype
+    param_dtype: jnp.dtype = jnp.float32
 
     @nn.compact
     def __call__(self, x: Array, train: bool) -> Array:
+        kw = {"dtype": self.dtype, "param_dtype": self.param_dtype}
         if self.norm_type == "group":
-            return nn.GroupNorm(
-                num_groups=_group_count(x.shape[-1]), dtype=self.dtype
-            )(x)
+            return nn.GroupNorm(num_groups=_group_count(x.shape[-1]), **kw)(x)
         if self.norm_type == "layer":
-            return nn.LayerNorm(dtype=self.dtype)(x)
+            return nn.LayerNorm(**kw)(x)
         if self.norm_type == "batch":
             return nn.BatchNorm(
-                use_running_average=not train, dtype=self.dtype, axis_name=None
+                use_running_average=not train, axis_name=None, **kw
             )(x)
         return x  # "none"
 
@@ -90,6 +91,7 @@ class ConvBlock(nn.Module):
     norm_type: str
     act: Callable[[Array], Array]
     dtype: jnp.dtype
+    param_dtype: jnp.dtype = jnp.float32
 
     @nn.compact
     def __call__(self, x: Array, train: bool) -> Array:
@@ -99,8 +101,9 @@ class ConvBlock(nn.Module):
             strides=(self.stride, self.stride),
             padding="SAME",
             dtype=self.dtype,
+            param_dtype=self.param_dtype,
         )(x)
-        x = _Norm(self.norm_type, self.dtype)(x, train)
+        x = _Norm(self.norm_type, self.dtype, self.param_dtype)(x, train)
         return self.act(x)
 
 
@@ -111,15 +114,17 @@ class ResidualBlock(nn.Module):
     norm_type: str
     act: Callable[[Array], Array]
     dtype: jnp.dtype
+    param_dtype: jnp.dtype = jnp.float32
 
     @nn.compact
     def __call__(self, x: Array, train: bool) -> Array:
+        kw = {"dtype": self.dtype, "param_dtype": self.param_dtype}
         residual = x
-        x = nn.Conv(self.features, (3, 3), padding="SAME", dtype=self.dtype)(x)
-        x = _Norm(self.norm_type, self.dtype)(x, train)
+        x = nn.Conv(self.features, (3, 3), padding="SAME", **kw)(x)
+        x = _Norm(self.norm_type, **kw)(x, train)
         x = self.act(x)
-        x = nn.Conv(self.features, (3, 3), padding="SAME", dtype=self.dtype)(x)
-        x = _Norm(self.norm_type, self.dtype)(x, train)
+        x = nn.Conv(self.features, (3, 3), padding="SAME", **kw)(x)
+        x = _Norm(self.norm_type, **kw)(x, train)
         return self.act(x + residual)
 
 
@@ -139,13 +144,15 @@ class TransformerEncoderLayer(nn.Module):
     dtype: jnp.dtype
     dropout_rate: float = 0.1
     attention_fn: Callable | None = None
+    param_dtype: jnp.dtype = jnp.float32
 
     @nn.compact
     def __call__(self, x: Array, train: bool) -> Array:
-        y = nn.LayerNorm(dtype=self.dtype)(x)
+        kw = {"dtype": self.dtype, "param_dtype": self.param_dtype}
+        y = nn.LayerNorm(**kw)(x)
         y = nn.MultiHeadDotProductAttention(
             num_heads=self.heads,
-            dtype=self.dtype,
+            **kw,
             dropout_rate=(
                 0.0 if self.attention_fn is not None else self.dropout_rate
             ),
@@ -153,11 +160,11 @@ class TransformerEncoderLayer(nn.Module):
             attention_fn=self.attention_fn or nn.dot_product_attention,
         )(y, y)
         x = x + nn.Dropout(self.dropout_rate, deterministic=not train)(y)
-        y = nn.LayerNorm(dtype=self.dtype)(x)
-        y = nn.Dense(self.mlp_dim, dtype=self.dtype)(y)
+        y = nn.LayerNorm(**kw)(x)
+        y = nn.Dense(self.mlp_dim, **kw)(y)
         y = self.act(y)
         y = nn.Dropout(self.dropout_rate, deterministic=not train)(y)
-        y = nn.Dense(self.dim, dtype=self.dtype)(y)
+        y = nn.Dense(self.dim, **kw)(y)
         return x + nn.Dropout(self.dropout_rate, deterministic=not train)(y)
 
 
@@ -169,15 +176,18 @@ class MLPHead(nn.Module):
     norm_type: str
     act: Callable[[Array], Array]
     dtype: jnp.dtype
+    param_dtype: jnp.dtype = jnp.float32
 
     @nn.compact
     def __call__(self, x: Array, train: bool) -> Array:
         for h in self.hidden_dims:
-            x = nn.Dense(h, dtype=self.dtype)(x)
-            x = _Norm(self.norm_type, self.dtype)(x, train)
+            x = nn.Dense(h, dtype=self.dtype, param_dtype=self.param_dtype)(x)
+            x = _Norm(self.norm_type, self.dtype, self.param_dtype)(x, train)
             x = self.act(x)
         # Output layer in float32 for stable softmax/loss.
-        return nn.Dense(self.out_dim, dtype=jnp.float32)(x)
+        return nn.Dense(
+            self.out_dim, dtype=jnp.float32, param_dtype=self.param_dtype
+        )(x)
 
 
 class AlphaTriangleNet(nn.Module):
@@ -200,6 +210,7 @@ class AlphaTriangleNet(nn.Module):
         (B, NUM_VALUE_ATOMS) value-distribution logits (both float32)."""
         cfg = self.config
         dtype = jnp.dtype(cfg.COMPUTE_DTYPE)
+        pdtype = jnp.dtype(cfg.PARAM_DTYPE)
         act = _ACTIVATIONS[cfg.ACTIVATION_FUNCTION]
 
         # The four named scopes are the phases of `telemetry/phases.py`:
@@ -214,26 +225,48 @@ class AlphaTriangleNet(nn.Module):
                 cfg.CONV_STRIDES,
                 strict=True,
             ):
-                x = ConvBlock(f, k, s, cfg.NORM_TYPE, act, dtype)(x, train)
+                x = ConvBlock(f, k, s, cfg.NORM_TYPE, act, dtype, pdtype)(x, train)
 
         if cfg.NUM_RESIDUAL_BLOCKS > 0:
             with jax.named_scope("net/residual"):
                 if x.shape[-1] != cfg.RESIDUAL_BLOCK_FILTERS:
                     x = ConvBlock(
-                        cfg.RESIDUAL_BLOCK_FILTERS, 1, 1, cfg.NORM_TYPE, act, dtype
+                        cfg.RESIDUAL_BLOCK_FILTERS,
+                        1,
+                        1,
+                        cfg.NORM_TYPE,
+                        act,
+                        dtype,
+                        pdtype,
                     )(x, train)
                 block = ResidualBlock
                 if cfg.REMAT:
                     block = nn.remat(ResidualBlock, static_argnums=(2,))
                 for _ in range(cfg.NUM_RESIDUAL_BLOCKS):
-                    x = block(cfg.RESIDUAL_BLOCK_FILTERS, cfg.NORM_TYPE, act, dtype)(
-                        x, train
-                    )
+                    x = block(
+                        cfg.RESIDUAL_BLOCK_FILTERS, cfg.NORM_TYPE, act, dtype, pdtype
+                    )(x, train)
 
-        if cfg.USE_TRANSFORMER and cfg.TRANSFORMER_LAYERS > 0:
+        if cfg.TRUNK is not None:
+            # The decoder stack in the encoder's place (nn/trunk.py): the
+            # stem's 1x1 projection stands where a language model has its
+            # embedding; the cell's row-major index is its position, so
+            # no sinusoidal table is added.
+            with jax.named_scope("net/trunk"):
+                d = cfg.TRUNK.hidden_size
+                if x.shape[-1] != d:
+                    x = nn.Conv(d, (1, 1), dtype=dtype, param_dtype=pdtype)(x)
+                b, h, w, _ = x.shape
+                tokens = DecoderTrunk(cfg.TRUNK, dtype, pdtype)(
+                    x.reshape(b, h * w, d)
+                )
+                flat = tokens.reshape(b, -1)
+        elif cfg.USE_TRANSFORMER and cfg.TRANSFORMER_LAYERS > 0:
             with jax.named_scope("net/encoder"):
                 if x.shape[-1] != cfg.TRANSFORMER_DIM:
-                    x = nn.Conv(cfg.TRANSFORMER_DIM, (1, 1), dtype=dtype)(x)
+                    x = nn.Conv(
+                        cfg.TRANSFORMER_DIM, (1, 1), dtype=dtype, param_dtype=pdtype
+                    )(x)
                 b, h, w, d = x.shape
                 tokens = x.reshape(b, h * w, d)
                 pe = jnp.asarray(
@@ -251,8 +284,9 @@ class AlphaTriangleNet(nn.Module):
                         act,
                         dtype,
                         attention_fn=self.attention_fn,
+                        param_dtype=pdtype,
                     )(tokens, train)
-                tokens = nn.LayerNorm(dtype=dtype)(tokens)
+                tokens = nn.LayerNorm(dtype=dtype, param_dtype=pdtype)(tokens)
                 flat = tokens.reshape(b, -1)
         else:
             flat = x.reshape(x.shape[0], -1)
@@ -264,8 +298,8 @@ class AlphaTriangleNet(nn.Module):
 
             shared = combined
             for hdim in cfg.FC_DIMS_SHARED:
-                shared = nn.Dense(hdim, dtype=dtype)(shared)
-                shared = _Norm(cfg.NORM_TYPE, dtype)(shared, train)
+                shared = nn.Dense(hdim, dtype=dtype, param_dtype=pdtype)(shared)
+                shared = _Norm(cfg.NORM_TYPE, dtype, pdtype)(shared, train)
                 shared = act(shared)
 
             policy_logits = MLPHead(
@@ -274,6 +308,7 @@ class AlphaTriangleNet(nn.Module):
                 cfg.NORM_TYPE,
                 act,
                 dtype,
+                pdtype,
             )(shared, train)
             value_logits = MLPHead(
                 tuple(cfg.VALUE_HEAD_DIMS),
@@ -281,6 +316,7 @@ class AlphaTriangleNet(nn.Module):
                 cfg.NORM_TYPE,
                 act,
                 dtype,
+                pdtype,
             )(shared, train)
         return policy_logits.astype(jnp.float32), value_logits.astype(jnp.float32)
 

@@ -135,11 +135,14 @@ def dequantize_params(variables):
 def cast_params_for_inference(variables, model_config: ModelConfig):
     """Apply the inference precision policy to a variables pytree:
     identity (same object, no copy) under f32, bf16 cast of floating
-    leaves under bf16, weight-only int8 quantization under int8."""
+    leaves under bf16, weight-only int8 quantization under int8. A
+    tree made in the inference dtype (`PARAM_DTYPE` = the precision) is
+    served as it was made, the same object: no second copy, and what its
+    module keeps in float32 (a router's selection bias) stays so."""
     if model_config.INFERENCE_PRECISION == "int8":
         return quantize_params_for_inference(variables)
     dtype = inference_dtype(model_config)
-    if dtype == jnp.float32:
+    if dtype == jnp.float32 or jnp.dtype(model_config.PARAM_DTYPE) == dtype:
         return variables
     return jax.tree_util.tree_map(
         lambda x: x.astype(dtype)

@@ -1,0 +1,423 @@
+"""A decoder stack as the net's trunk (`ModelConfig.TRUNK`).
+
+The layers of a routed-expert language model stand where the encoder
+layers stand: tokens are the board's cells in row-major order, the
+cell's index is its position. Per layer (l = 0..):
+
+- attention: q, k, v without biases, an RMSNorm on q and k per head,
+  rotary positions over the whole head on the sliding layers, every
+  `num_attention_heads / num_key_value_heads` query heads sharing one
+  key/value head, scores in float32 over sqrt(head_dim), masked to
+  j <= i and on a sliding layer to i - j < `sliding_window`;
+- a SwiGLU of `intermediate_size` on a dense layer; on a sparse one a
+  sigmoid router over all `num_experts`, the `num_experts_per_tok` of
+  highest score, weights `routed_scaling_factor` x score / (sum of the
+  chosen scores), every expert and the shared expert a SwiGLU of
+  `moe_intermediate_size`;
+- x + norm(f(x)) for attention and MLP alike; a final RMSNorm after
+  the last layer.
+
+One process holds the experts `experts_held` = (first, count). It
+routes over all of them, sorts the token-expert assignments that fall
+on its own by expert, runs them through one grouped product
+(`jax.lax.ragged_dot`) and adds nothing for the experts held
+elsewhere: no token is dropped, no capacity is set, nothing stands in
+for the other holders or their exchange. The assignments it computed
+are counted per expert (`counters` collection, sown only where the
+caller asks for it).
+
+The layers are plain functions of a parameter dict; `DecoderTrunk`
+declares the parameters, in `param_dtype`, straight from the key. A leaf
+batch too large for the device is cut into blocks of `block_boards`
+boards by the caller that has it (the search's `_evaluate`): the whole
+net runs on a block at a time, so no activation of the full batch at
+this width ever exists.
+"""
+
+import math
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from flax import linen as nn
+from jax import Array
+
+from ..config.model_config import TrunkConfig
+
+# A share sees num_experts_per_tok x count / num_experts of a block's
+# assignments if routing is even. The expert layer's buffers hold this
+# many times that; a block that routes more here goes round again.
+USUAL_ROOM = 2
+
+
+def sparse_layers(cfg: TrunkConfig) -> list[int]:
+    return [i for i, kind in enumerate(cfg.mlp_layer_types) if kind == "sparse"]
+
+
+def param_shapes(cfg: TrunkConfig) -> dict[str, tuple[tuple[int, ...], int]]:
+    """name -> (shape, fan_in); fan_in 0 marks a norm's weight (ones),
+    -1 the router's selection bias (noughts)."""
+    d, hd = cfg.hidden_size, cfg.head_dim
+    q_out = cfg.num_attention_heads * hd
+    kv_out = cfg.num_key_value_heads * hd
+    held = cfg.experts_held[1]
+    im = cfg.moe_intermediate_size
+    shapes: dict[str, tuple[tuple[int, ...], int]] = {}
+    for i, kind in enumerate(cfg.mlp_layer_types):
+        p = f"l{i}_"
+        shapes[p + "attn_norm"] = ((d,), 0)
+        shapes[p + "mlp_norm"] = ((d,), 0)
+        shapes[p + "wq"] = ((d, q_out), d)
+        shapes[p + "wk"] = ((d, kv_out), d)
+        shapes[p + "wv"] = ((d, kv_out), d)
+        shapes[p + "wo"] = ((q_out, d), q_out)
+        shapes[p + "q_norm"] = ((hd,), 0)
+        shapes[p + "k_norm"] = ((hd,), 0)
+        if kind == "dense":
+            wide = cfg.intermediate_size
+            shapes[p + "w_gate"] = ((d, wide), d)
+            shapes[p + "w_up"] = ((d, wide), d)
+            shapes[p + "w_down"] = ((wide, d), wide)
+            continue
+        shapes[p + "w_router"] = ((d, cfg.num_experts), d)
+        if cfg.router_bias:
+            shapes[p + "router_bias"] = ((cfg.num_experts,), -1)
+        shapes[p + "e_gate"] = ((held, d, im), d)
+        shapes[p + "e_up"] = ((held, d, im), d)
+        shapes[p + "e_down"] = ((held, im, d), im)
+        if cfg.num_shared_experts:
+            wide = im * cfg.num_shared_experts
+            shapes[p + "s_gate"] = ((d, wide), d)
+            shapes[p + "s_up"] = ((d, wide), d)
+            shapes[p + "s_down"] = ((wide, d), wide)
+    shapes["norm"] = ((d,), 0)
+    return shapes
+
+
+def forward_flops(cfg: TrunkConfig, seq: int) -> int:
+    """Matmul FLOP (1 MAC = 2) of the stack on one board of `seq` tokens
+    as this share computes it if routing is even: every layer's
+    projections, the score products over the pairs the mask keeps, the
+    dense layer, the router, the shared expert, and
+    `num_experts_per_tok` x held / `num_experts` experts a token."""
+    d = cfg.hidden_size
+    q_out = cfg.num_attention_heads * cfg.head_dim
+    kv_out = cfg.num_key_value_heads * cfg.head_dim
+    expert = 2 * 3 * d * cfg.moe_intermediate_size
+    here = cfg.num_experts_per_tok * cfg.experts_held[1] / cfg.num_experts
+    total = 0.0
+    for kind, mlp in zip(cfg.layer_types, cfg.mlp_layer_types):
+        window = cfg.sliding_window if kind == "sliding_attention" else None
+        total += seq * 2 * (d * (q_out + 2 * kv_out) + q_out * d)
+        total += 2 * 2 * q_out * int(causal_mask(seq, window).sum())
+        if mlp == "dense":
+            total += seq * 2 * 3 * d * cfg.intermediate_size
+        else:
+            total += seq * (
+                2 * d * cfg.num_experts
+                + (cfg.num_shared_experts + here) * expert
+            )
+    return int(total)
+
+
+# --- the layers, as functions of their parameters --------------------------
+
+
+def _dot(x: Array, w: Array, dtype) -> Array:
+    """x @ w with operands in `dtype`, accumulated and returned in float32."""
+    return jnp.dot(
+        x.astype(dtype), w.astype(dtype), preferred_element_type=jnp.float32
+    )
+
+
+def rms_norm(x: Array, weight: Array, eps: float) -> Array:
+    xf = x.astype(jnp.float32)
+    y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
+    return (y * weight.astype(jnp.float32)).astype(x.dtype)
+
+
+def rotary_table(seq: int, head_dim: int, theta: float) -> tuple[np.ndarray, np.ndarray]:
+    """(cos, sin), each (seq, head_dim): the default rotary type, the
+    head's halves paired."""
+    inv = theta ** (-np.arange(0, head_dim, 2, dtype=np.float64) / head_dim)
+    angle = np.arange(seq, dtype=np.float64)[:, None] * inv[None, :]
+    angle = np.concatenate([angle, angle], axis=-1)
+    return np.cos(angle).astype(np.float32), np.sin(angle).astype(np.float32)
+
+
+def rotate(x: Array, cos: np.ndarray, sin: np.ndarray) -> Array:
+    """x (b, s, ..., head_dim) turned by its position along axis 1."""
+    shape = (1, x.shape[1]) + (1,) * (x.ndim - 3) + (x.shape[-1],)
+    xf = x.astype(jnp.float32)
+    half = x.shape[-1] // 2
+    turned = jnp.concatenate([-xf[..., half:], xf[..., :half]], axis=-1)
+    return (xf * cos.reshape(shape) + turned * sin.reshape(shape)).astype(x.dtype)
+
+
+def causal_mask(seq: int, window: int | None) -> np.ndarray:
+    """(seq, seq) bool: query i sees key j <= i, within `window` if given."""
+    i, j = np.arange(seq)[:, None], np.arange(seq)[None, :]
+    seen = j <= i
+    if window is not None:
+        seen &= i - j < window
+    return seen
+
+
+def attention(p: dict, x: Array, cfg: TrunkConfig, sliding: bool, dtype) -> Array:
+    b, s, _ = x.shape
+    hkv, hd = cfg.num_key_value_heads, cfg.head_dim
+    rep = cfg.num_attention_heads // hkv
+    q = _dot(x, p["wq"], dtype).astype(dtype).reshape(b, s, hkv, rep, hd)
+    k = _dot(x, p["wk"], dtype).astype(dtype).reshape(b, s, hkv, hd)
+    v = _dot(x, p["wv"], dtype).astype(dtype).reshape(b, s, hkv, hd)
+    q = rms_norm(q, p["q_norm"], cfg.rms_norm_eps)
+    k = rms_norm(k, p["k_norm"], cfg.rms_norm_eps)
+    if sliding:
+        cos, sin = rotary_table(s, hd, cfg.rope_theta)
+        q, k = rotate(q, cos, sin), rotate(k, cos, sin)
+    scores = jnp.einsum(
+        "bqkgd,bskd->bkgqs", q, k, preferred_element_type=jnp.float32
+    ) / math.sqrt(hd)
+    seen = causal_mask(s, cfg.sliding_window if sliding else None)
+    scores = jnp.where(seen[None, None, None], scores, -jnp.inf)
+    weights = jax.nn.softmax(scores, axis=-1).astype(dtype)
+    ctx = jnp.einsum(
+        "bkgqs,bskd->bqkgd", weights, v, preferred_element_type=jnp.float32
+    )
+    ctx = ctx.astype(dtype).reshape(b, s, hkv * rep * hd)
+    return _dot(ctx, p["wo"], dtype).astype(dtype)
+
+
+def swiglu(x: Array, gate: Array, up: Array, down: Array, dtype) -> Array:
+    hidden = jax.nn.silu(_dot(x, gate, dtype)) * _dot(x, up, dtype)
+    return _dot(hidden.astype(dtype), down, dtype)
+
+
+def route(p: dict, x: Array, cfg: TrunkConfig, dtype):
+    """x (T, d) -> chosen experts (T, k) int32 and their weights (T, k).
+    A selection bias (`router_bias`) moves the choice, not the weights."""
+    scores = jax.nn.sigmoid(_dot(x, p["w_router"], dtype))
+    if cfg.router_bias:
+        biased = scores + p["router_bias"].astype(jnp.float32)
+        _, chosen = jax.lax.top_k(biased, cfg.num_experts_per_tok)
+        top = jnp.take_along_axis(scores, chosen, axis=-1)
+    else:
+        top, chosen = jax.lax.top_k(scores, cfg.num_experts_per_tok)
+    weight = cfg.routed_scaling_factor * top / top.sum(axis=-1, keepdims=True)
+    return chosen.astype(jnp.int32), weight
+
+
+def _ragged(x: Array, w: Array, sizes: Array, dtype) -> Array:
+    return jax.lax.ragged_dot(
+        x.astype(dtype), w.astype(dtype), sizes,
+        preferred_element_type=jnp.float32,
+    )
+
+
+def routed_experts(p: dict, x: Array, chosen: Array, weight: Array,
+                   cfg: TrunkConfig, dtype):
+    """The held experts' part of the routed sum for tokens x (T, d),
+    float32, and how many tokens each held expert computed (count,).
+
+    The assignments that fall on held experts are sorted by expert and
+    taken `rows` at a time through the grouped products, `rows` being
+    `USUAL_ROOM` times what even routing would bring here: one round as
+    a rule, as many as the block's routing needs otherwise, each with
+    the same buffers. Nothing is dropped."""
+    t, d = x.shape
+    k = cfg.num_experts_per_tok
+    first, held = cfg.experts_held
+    local = chosen - first
+    here = (local >= 0) & (local < held)
+    group = jnp.where(here, local, held).reshape(-1)  # elsewhere sorts last
+    order = jnp.argsort(group, stable=True)
+    rank = jnp.argsort(order)  # where each assignment stands in `order`
+    sizes = jnp.zeros((held + 1,), jnp.int32).at[group].add(1)[:held]
+    ends = jnp.cumsum(sizes)
+    share = jnp.where(here, weight, 0.0).reshape(-1)
+
+    most = t * min(k, held)
+    rows = min(most, -(-USUAL_ROOM * t * k * held // cfg.num_experts))
+    rounds = -(-most // rows)
+    order = jnp.pad(order, (0, rounds * rows - order.shape[0]))
+
+    def one_round(i, y):
+        low = i * rows
+        inside = jnp.clip(ends - low, 0, rows) - jnp.clip(
+            ends - sizes - low, 0, rows
+        )
+        taken = jax.lax.dynamic_slice_in_dim(order, low, rows)
+        xs = x[taken // k]
+        hidden = jax.nn.silu(_ragged(xs, p["e_gate"], inside, dtype)) * _ragged(
+            xs, p["e_up"], inside, dtype
+        )
+        out = _ragged(hidden, p["e_down"], inside, dtype).astype(dtype)
+        # Rows past the round's assignments hold whatever the product
+        # left there: nought, so that nothing stray reaches the sum.
+        out = jnp.where(jnp.arange(rows)[:, None] < inside.sum(), out, 0)
+        # Each assignment fetches its row of this round, if it has one.
+        at = rank - low
+        mine = (at >= 0) & (at < rows)
+        picked = out[jnp.clip(at, 0, rows - 1)].reshape(t, k, d)
+        return y + jnp.einsum(
+            "tkd,tk->td",
+            picked,
+            jnp.where(mine, share, 0.0).reshape(t, k),
+            preferred_element_type=jnp.float32,
+        )
+
+    def maybe(i, y):
+        return jax.lax.cond(i * rows < ends[-1], one_round, lambda _, y: y, i, y)
+
+    y = jnp.zeros((t, d), jnp.float32)
+    if rounds == 1:
+        return one_round(0, y), sizes
+    return jax.lax.fori_loop(0, rounds, maybe, y), sizes
+
+
+def sparse_mlp(p: dict, x: Array, cfg: TrunkConfig, dtype):
+    b, s, d = x.shape
+    flat = x.reshape(b * s, d)
+    with jax.named_scope("net/trunk/router"):
+        chosen, weight = route(p, flat, cfg, dtype)
+    with jax.named_scope("net/trunk/experts"):
+        y, sizes = routed_experts(p, flat, chosen, weight, cfg, dtype)
+    if cfg.num_shared_experts:
+        with jax.named_scope("net/trunk/shared_expert"):
+            y = y + swiglu(flat, p["s_gate"], p["s_up"], p["s_down"], dtype)
+    return y.astype(dtype).reshape(b, s, d), sizes
+
+
+def _residual(f, norm: Array, x: Array, cfg: TrunkConfig) -> Array:
+    return x + rms_norm(f(x), norm, cfg.rms_norm_eps)
+
+
+def attention_block(p: dict, x: Array, cfg: TrunkConfig, i: int, dtype) -> Array:
+    sliding = cfg.layer_types[i] == "sliding_attention"
+    with jax.named_scope(
+        "net/trunk/attn_window" if sliding else "net/trunk/attn_full"
+    ):
+        return _residual(
+            lambda y: attention(p, y, cfg, sliding, dtype), p["attn_norm"], x, cfg
+        )
+
+
+def mlp_block(p: dict, x: Array, cfg: TrunkConfig, i: int, dtype):
+    """The layer's second half on x (b, s, d); the second value is a
+    sparse layer's per-expert count, None on a dense layer."""
+    if cfg.mlp_layer_types[i] == "dense":
+        with jax.named_scope("net/trunk/dense_mlp"):
+            return _residual(
+                lambda y: swiglu(
+                    y, p["w_gate"], p["w_up"], p["w_down"], dtype
+                ).astype(dtype),
+                p["mlp_norm"],
+                x,
+                cfg,
+            ), None
+    sizes = None
+
+    def mlp(y):
+        nonlocal sizes
+        out, sizes = sparse_mlp(p, y, cfg, dtype)
+        return out
+
+    return _residual(mlp, p["mlp_norm"], x, cfg), sizes
+
+
+def decoder_layer(p: dict, x: Array, cfg: TrunkConfig, i: int, dtype):
+    return mlp_block(p, attention_block(p, x, cfg, i, dtype), cfg, i, dtype)
+
+
+def layer_params(params: dict, i: int) -> dict:
+    prefix = f"l{i}_"
+    return {
+        name[len(prefix):]: value
+        for name, value in params.items()
+        if name.startswith(prefix)
+    }
+
+
+def block_size(batch: int, block_boards: int | None) -> int:
+    """The largest divisor of `batch` within `block_boards`."""
+    if block_boards is None or batch <= block_boards:
+        return batch
+    size = block_boards
+    while batch % size:
+        size -= 1
+    return size
+
+
+def apply(params: dict, tokens: Array, cfg: TrunkConfig, dtype):
+    """tokens (B, S, d) through every layer and the final norm; also the
+    assignments each held expert computed, (sparse layers, count) int32
+    (a (0, count) array where no layer is sparse)."""
+    x, counted = tokens, []
+    for i in range(len(cfg.layer_types)):
+        x, sizes = decoder_layer(layer_params(params, i), x, cfg, i, dtype)
+        if sizes is not None:
+            counted.append(sizes)
+    x = rms_norm(x, params["norm"], cfg.rms_norm_eps)
+    if counted:
+        return x, jnp.stack(counted)
+    return x, jnp.zeros((0, cfg.experts_held[1]), jnp.int32)
+
+
+# --- the module: parameters, then the functions above ----------------------
+
+
+def _normal(fan_in: int):
+    """N(0, 1/fan_in) drawn in float32 and stored in the parameter's type."""
+
+    def init(key, shape, dtype):
+        draw = jax.random.normal(key, shape, jnp.float32)
+        return (draw / math.sqrt(fan_in)).astype(dtype)
+
+    return init
+
+
+_INITS = {0: nn.initializers.ones, -1: nn.initializers.zeros}
+
+
+class DecoderTrunk(nn.Module):
+    config: TrunkConfig
+    dtype: jnp.dtype
+    param_dtype: jnp.dtype
+
+    @nn.compact
+    def __call__(self, tokens: Array) -> Array:
+        cfg = self.config
+        params = {
+            name: self.param(
+                name,
+                _INITS[fan_in] if fan_in <= 0 else _normal(fan_in),
+                shape,
+                # A selection bias settles ties between scores that
+                # differ in the fourth decimal: float32 whatever the rest.
+                jnp.float32 if fan_in == -1 else self.param_dtype,
+            )
+            for name, (shape, fan_in) in param_shapes(cfg).items()
+        }
+        out, counts = apply(params, tokens.astype(self.dtype), cfg, self.dtype)
+        # Read by the search, which makes the collection mutable; no-ops
+        # otherwise: the assignments computed here, (sparse layers,
+        # held), and all the assignments the router made.
+        if not self.is_initializing():
+            routed = tokens.shape[0] * tokens.shape[1] * (
+                cfg.num_experts_per_tok * len(sparse_layers(cfg))
+            )
+            for name, value in (
+                ("expert_tokens", counts), ("routed", jnp.int32(routed))
+            ):
+                self.sow(
+                    "counters", name, value,
+                    reduce_fn=lambda _, new: new, init_fn=lambda: None,
+                )
+        return out
+
+
+def counters_of(state: dict) -> dict:
+    """{"expert_tokens", "routed"} out of what `apply(...,
+    mutable=["counters"])` returned beside the net's outputs."""
+    (sown,) = state["counters"].values()
+    return dict(sown)

@@ -298,7 +298,9 @@ def phase_of(op_name: str) -> str:
     at, phase = -1, OTHER
     for name in PHASES:
         found = op_name.rfind(name)
-        if found > at:
+        # Of two names that start at one place (`net/trunk`,
+        # `net/trunk/experts`) the longer is the inner one.
+        if found > at or (found == at >= 0 and len(name) > len(phase)):
             at, phase = found, name
     return phase
 

@@ -308,6 +308,11 @@ class SelfPlayEngine:
         # summed with simulations this gives leaf-equivalent search
         # effort (leaf-evals/s in telemetry/perf.py).
         self._total_reused_visits = 0
+        # A routed trunk's counters (nn/trunk.py), summed like the
+        # simulations: assignments each held expert computed, (sparse
+        # layers, held), and the assignments routed to any expert.
+        self._expert_tokens: np.ndarray | None = None
+        self._routed_assignments = 0
         # Cumulative host-blocking harvest-fetch seconds (the chunk's
         # device_get — includes any wait for the chunk to finish, i.e.
         # the host-visible round-trip cost telemetry/perf.py reports).
@@ -647,6 +652,11 @@ class SelfPlayEngine:
             # (T,·)-stacked by the scan; rides the chunk's one fetch.
             "device_stats": out.stats,
         }
+        if out.net_counters is not None:
+            # A routed trunk's counters for this move's search
+            # (nn/trunk.py): the assignments each held expert computed,
+            # (sparse layers, held) int32, and all the router made.
+            outputs["trace"].update(out.net_counters)
         return new_carry, outputs
 
     def _chunk(self, num_moves: int, variables, carry: RolloutCarry, version):
@@ -720,6 +730,16 @@ class SelfPlayEngine:
                 int(host["trace"]["sims"].sum()) * self.batch_size
             )
             self._total_reused_visits += int(host["trace"]["reused"].sum())
+            if "expert_tokens" in host["trace"]:
+                tokens = host["trace"]["expert_tokens"].sum(axis=0, dtype=np.int64)
+                self._expert_tokens = (
+                    tokens
+                    if self._expert_tokens is None
+                    else self._expert_tokens + tokens
+                )
+                self._routed_assignments += int(
+                    host["trace"]["routed"].sum(dtype=np.int64)
+                )
 
             self.last_trace = host["trace"]
             if self.device_stats:
@@ -868,6 +888,8 @@ class SelfPlayEngine:
             num_truncated=self._episodes_truncated,
             total_simulations=self._total_simulations,
             total_reused_visits=self._total_reused_visits,
+            expert_tokens=self._expert_tokens,
+            routed_assignments=self._routed_assignments,
             trainer_step_at_episode_start=(
                 self._min_weights_version
                 if self._min_weights_version is not None
@@ -882,5 +904,7 @@ class SelfPlayEngine:
         self._episodes_truncated = 0
         self._total_simulations = 0
         self._total_reused_visits = 0
+        self._expert_tokens = None
+        self._routed_assignments = 0
         self._min_weights_version = None
         return result

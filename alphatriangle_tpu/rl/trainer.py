@@ -234,6 +234,25 @@ class Trainer:
         self.host_schedule = make_host_lr_schedule(train_config)
         self.optimizer = make_optimizer(train_config)
 
+        # A net whose training state cannot lie on the devices that share
+        # it is refused here, by its bytes, before anything is copied.
+        from ..telemetry.memory import resolve_bytes_limit
+
+        from ..nn.model import count_parameters
+
+        count = count_parameters(nn.variables["params"])
+        state_bytes = 4 * sum(
+            int(np.prod(p.shape)) * jnp.dtype(p.dtype).itemsize
+            for p in jax.tree_util.tree_leaves(nn.variables["params"])
+        )
+        limit, _ = resolve_bytes_limit(None)
+        if limit is not None and state_bytes > limit * self.tp_size:
+            raise ValueError(
+                f"Trainer: {count:,} parameters need {state_bytes:,} B of "
+                "training state (parameters, gradients and two Adam moments) "
+                f"and {self.tp_size} device(s) of {int(limit):,} B hold it: "
+                "this net can be served (INFERENCE_PRECISION), not trained here."
+            )
         # Deep-copy the wrapper's variables: the jitted step donates its
         # input state, and a donated buffer aliased by `nn.variables`
         # would leave the eval wrapper holding deleted arrays.

@@ -45,6 +45,12 @@ class SelfPlayResult(BaseModel):
     # 0 with reuse off. simulations + reused = leaf-equivalent search
     # effort per harvest (telemetry leaf-evals/s).
     total_reused_visits: int = 0
+    # A routed trunk's counters over the harvest (nn/trunk.py): the
+    # token-expert assignments each held expert computed, (sparse
+    # layers, held) int64, and the assignments routed to any expert.
+    # None / 0 for a net that routes nothing.
+    expert_tokens: np.ndarray | None = None
+    routed_assignments: int = 0
     # Weight version the producing rollout ran with (staleness tag,
     # reference `rl/types.py:22` / `worker.py:136-139`).
     trainer_step_at_episode_start: int = 0

@@ -26,6 +26,16 @@ PHASES = (
     "net/residual",
     "net/encoder",
     "net/heads",
+    # a decoder stack as the trunk (nn/trunk.py), in net/encoder's
+    # place; net/trunk itself keeps the projection, the norms and the
+    # residual sums
+    "net/trunk",
+    "net/trunk/attn_window",
+    "net/trunk/attn_full",
+    "net/trunk/dense_mlp",
+    "net/trunk/router",
+    "net/trunk/experts",
+    "net/trunk/shared_expert",
     # fused learner (rl/trainer.py)
     "learner/gather",
     "learner/forward_loss",

@@ -64,8 +64,17 @@ def forward_flops(model: ModelConfig, env: EnvConfig, action_dim: int) -> int:
             h, w, rf, rf, 3, 1
         )
 
+    # A decoder stack in the encoder's place (nn/trunk.py counts it).
+    if model.TRUNK is not None:
+        from ..nn.trunk import forward_flops as trunk_flops
+
+        d = model.TRUNK.hidden_size
+        if cin != d:
+            total += _conv2d_flops(h, w, cin, d, 1, 1)
+            cin = d
+        total += trunk_flops(model.TRUNK, h * w)
     # Transformer over the S = h*w token sequence.
-    if model.USE_TRANSFORMER and model.TRANSFORMER_LAYERS > 0:
+    elif model.USE_TRANSFORMER and model.TRANSFORMER_LAYERS > 0:
         d = model.TRANSFORMER_DIM
         if cin != d:
             total += _conv2d_flops(h, w, cin, d, 1, 1)

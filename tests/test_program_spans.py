@@ -315,10 +315,47 @@ class TestPhaseNamesInPrograms:
             engine._carry,
             jnp.int32(0),
         )
-        # `rollout/promote` is the subtree-reuse chunk's (below).
+        # `rollout/promote` is the subtree-reuse chunk's, `net/trunk*`
+        # the chunk's of a net with a decoder stack (both below).
         wanted = set(_phases("rollout/", "search/", "gumbel/", "net/"))
         wanted.remove("rollout/promote")
+        wanted -= set(_phases("net/trunk"))
         assert {p for p in wanted if p not in text} == set()
+        assert "net/trunk" not in text
+
+    def test_trunk_phases_in_the_chunk_of_a_decoder_stack(
+        self, world, tiny_mcts_config
+    ):
+        from alphatriangle_tpu.config import TrunkConfig
+        from alphatriangle_tpu.features.core import get_feature_extractor
+        from alphatriangle_tpu.nn.network import NeuralNetwork
+        from alphatriangle_tpu.rl.self_play import SelfPlayEngine
+
+        trunk = TrunkConfig(
+            hidden_size=32, num_attention_heads=2, num_key_value_heads=1,
+            head_dim=16, intermediate_size=48, moe_intermediate_size=16,
+            num_experts=4, num_experts_per_tok=2, sliding_window=4,
+            layer_types=["sliding_attention", "full_attention"],
+            mlp_layer_types=["dense", "sparse"], experts_held=(0, 2),
+        )
+        env = world["env"]
+        model = world["net"].model_config.model_copy(update={"TRUNK": trunk})
+        net = NeuralNetwork(model, env.cfg, seed=0)
+        engine = SelfPlayEngine(
+            env, get_feature_extractor(env, model), net, tiny_mcts_config,
+            world["train"], seed=7,
+        )
+        text = _lowered_text(
+            engine._chunk_fn(2)._jit_fn, net.variables, engine._carry, jnp.int32(0)
+        )
+        assert {p for p in _phases("net/trunk") if p not in text} == set()
+        assert len(_phases("net/trunk")) == 7 and "net/encoder" not in text
+        assert profiling.phase_of(
+            "jit(chunk)/search/evaluate/net/trunk/net/trunk/experts/ragged_dot"
+        ) == "net/trunk/experts"
+        assert profiling.phase_of(
+            "jit(chunk)/search/evaluate/net/trunk/add"
+        ) == "net/trunk"
 
     def test_promote_phase_in_the_reuse_chunk(self, world, tiny_mcts_config):
         from alphatriangle_tpu.rl.self_play import SelfPlayEngine
@@ -348,7 +385,7 @@ class TestPhaseNamesInPrograms:
             np.zeros((2, 4), np.int32),
             np.ones((2, 4), np.float32),
         )
-        wanted = set(_phases("learner/", "net/"))
+        wanted = set(_phases("learner/", "net/")) - set(_phases("net/trunk"))
         wanted.remove("learner/backward")  # drawn by autodiff:
         assert "transpose(jvp(learner/forward_loss))" in text
         assert {p for p in wanted if p not in text} == set()
