@@ -21,6 +21,7 @@ TPU-first redesign, not a translation:
   a trace-time constant (reference: `nn/model.py:63-106`).
 """
 
+import functools
 from collections.abc import Callable
 
 import jax
@@ -30,6 +31,12 @@ from flax import linen as nn
 from jax import Array
 
 from ..config.model_config import ModelConfig
+from ..ops.encoder_attention import (
+    attention_path,
+    encoder_attention,
+    partitioned,
+)
+from ..telemetry.tracer import default_tracer
 from .trunk import DecoderTrunk
 
 _ACTIVATIONS: dict[str, Callable[[Array], Array]] = {
@@ -128,6 +135,19 @@ class ResidualBlock(nn.Module):
         return self.act(x + residual)
 
 
+def _in_attention_phase(attention_fn: Callable) -> Callable:
+    """`attention_fn` under the phase name `net/encoder/attention`.
+    Flax forwards only the keywords the function's signature names:
+    `wraps` lends the wrapper `attention_fn`'s."""
+
+    @functools.wraps(attention_fn)
+    def attend(*args, **kwargs):
+        with jax.named_scope("net/encoder/attention"):
+            return attention_fn(*args, **kwargs)
+
+    return attend
+
+
 class TransformerEncoderLayer(nn.Module):
     """Pre-norm encoder layer (reference model.py:179-202, norm_first=True).
 
@@ -135,6 +155,8 @@ class TransformerEncoderLayer(nn.Module):
     sequence-parallel one (`parallel/ring_attention.make_sp_attention`);
     attention-weight dropout is disabled in that case (blockwise
     kernels don't support it) — the residual dropouts still apply.
+
+    Either runs inside the phase `net/encoder/attention`.
     """
 
     dim: int
@@ -157,7 +179,9 @@ class TransformerEncoderLayer(nn.Module):
                 0.0 if self.attention_fn is not None else self.dropout_rate
             ),
             deterministic=not train,
-            attention_fn=self.attention_fn or nn.dot_product_attention,
+            attention_fn=_in_attention_phase(
+                self.attention_fn or nn.dot_product_attention
+            ),
         )(y, y)
         x = x + nn.Dropout(self.dropout_rate, deterministic=not train)(y)
         y = nn.LayerNorm(**kw)(x)
@@ -276,6 +300,21 @@ class AlphaTriangleNet(nn.Module):
                 layer = TransformerEncoderLayer
                 if cfg.REMAT:
                     layer = nn.remat(TransformerEncoderLayer, static_argnums=(2,))
+                # With none handed in, the attention of an inference
+                # call on a TPU is the fused kernel (scores kept in
+                # VMEM), everywhere else Flax's function: one choice for
+                # all layers, from what this trace can observe.
+                fused = "fused" == attention_path(
+                    train=train,
+                    handed_in=self.attention_fn is not None,
+                    masked=False,  # the layer attends to every cell
+                    partitioned=partitioned(tokens),
+                    backend=jax.default_backend(),
+                    dtype=dtype,
+                    seq=h * w,
+                    heads=cfg.TRANSFORMER_HEADS,
+                    head_dim=d // cfg.TRANSFORMER_HEADS,
+                )
                 for _ in range(cfg.TRANSFORMER_LAYERS):
                     tokens = layer(
                         cfg.TRANSFORMER_DIM,
@@ -283,9 +322,20 @@ class AlphaTriangleNet(nn.Module):
                         cfg.TRANSFORMER_FC_DIM,
                         act,
                         dtype,
-                        attention_fn=self.attention_fn,
+                        attention_fn=(
+                            encoder_attention if fused else self.attention_fn
+                        ),
                         param_dtype=pdtype,
                     )(tokens, train)
+                # Once each time the net is traced into a program (a
+                # reloaded executable is not traced again).
+                default_tracer().instant(
+                    "net.attention",
+                    fused_layers=cfg.TRANSFORMER_LAYERS if fused else 0,
+                    flax_layers=0 if fused else cfg.TRANSFORMER_LAYERS,
+                    batch=b,
+                    seq=h * w,
+                )
                 tokens = nn.LayerNorm(dtype=dtype, param_dtype=pdtype)(tokens)
                 flat = tokens.reshape(b, -1)
         else:

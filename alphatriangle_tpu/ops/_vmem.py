@@ -17,10 +17,16 @@ def f32_block_bytes(rows: int, cols: int) -> int:
     return (-(-rows // 8) * 8) * (-(-cols // 128) * 128) * 4
 
 
-def vmem_params(block_bytes: int) -> pltpu.CompilerParams:
+def vmem_params(
+    block_bytes: int,
+    value_bytes: int = 0,
+    dimension_semantics: "tuple[str, ...] | None" = None,
+) -> pltpu.CompilerParams:
     """Compiler params whose scoped-VMEM limit holds `block_bytes` of
-    blocks double-buffered, plus room for the kernel's own values."""
-    need = 2 * block_bytes + (2 << 20)
+    blocks double-buffered and `value_bytes` of values the body keeps,
+    plus room for the kernel's own."""
+    need = 2 * block_bytes + value_bytes + (2 << 20)
     return pltpu.CompilerParams(
-        vmem_limit_bytes=max(need, _DEFAULT_SCOPED_BYTES)
+        vmem_limit_bytes=max(need, _DEFAULT_SCOPED_BYTES),
+        dimension_semantics=dimension_semantics,
     )

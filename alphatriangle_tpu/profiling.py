@@ -353,6 +353,26 @@ def _listed(line) -> list:
     return [(e.name, int(e.start_ns), int(e.duration_ns)) for e in line.events]
 
 
+def device_operations(planes) -> list[tuple[str, list, list]]:
+    """(plane name, operations, program runs) of every device plane
+    with an `XLA Ops` line, each event as (name, start_ns, duration_ns);
+    `planes` are `jax.profiler.ProfileData.from_file(path).planes`. A
+    CPU trace has none."""
+    out = []
+    for plane in planes:
+        lines = {line.name: line for line in plane.lines}
+        if plane.name.startswith("/device:") and "XLA Ops" in lines:
+            modules = lines.get("XLA Modules")
+            out.append(
+                (
+                    plane.name,
+                    _listed(lines["XLA Ops"]),
+                    _listed(modules) if modules is not None else [],
+                )
+            )
+    return out
+
+
 def summarize_xplane_trace(
     path: Path, op_names: "dict | None" = None, top: int = 20
 ) -> None:
@@ -373,28 +393,21 @@ def summarize_xplane_trace(
         return
     op_names = op_names or {}
     host: dict[str, list[float]] = defaultdict(lambda: [0.0, 0])
-    devices = 0
     for plane in planes:
-        lines = {line.name: line for line in plane.lines}
-        if not plane.name.startswith("/device:"):
-            for line in lines.values():
-                for e in line.events:
-                    if e.name.startswith(ANNOTATION_PREFIX):
-                        host[e.name][0] += e.duration_ns / 1e6
-                        host[e.name][1] += 1
+        if plane.name.startswith("/device:"):
             continue
-        if "XLA Ops" not in lines:
-            continue
-        devices += 1
-        ops = _listed(lines["XLA Ops"])
-        modules = (
-            _listed(lines["XLA Modules"]) if "XLA Modules" in lines else []
-        )
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith(ANNOTATION_PREFIX):
+                    host[e.name][0] += e.duration_ns / 1e6
+                    host[e.name][1] += 1
+    devices = device_operations(planes)
+    for plane_name, ops, modules in devices:
         for program, events in sorted(_by_program(modules, ops).items()):
             seconds = phase_seconds(events, op_names.get(program, {}))
             busy = sum(seconds.values())
             print(
-                f"\n  plane {plane.name} / program {program}: "
+                f"\n  plane {plane_name} / program {program}: "
                 f"{len(events)} operations, {busy:.3f} s"
             )
             print(f"    {'phase':<24} {'seconds':>10} {'share':>7}")

@@ -25,6 +25,9 @@ PHASES = (
     "net/conv",
     "net/residual",
     "net/encoder",
+    # the attention of an encoder layer, from q, k, v to the heads'
+    # output: ops/encoder_attention.py's kernel or Flax's function
+    "net/encoder/attention",
     "net/heads",
     # a decoder stack as the trunk (nn/trunk.py), in net/encoder's
     # place; net/trunk itself keeps the projection, the norms and the

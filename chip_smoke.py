@@ -109,13 +109,23 @@ def _train_config(base, **overrides):
 
 
 def kernel_shapes(cfgs: dict) -> dict:
-    """The operand shapes the four kernels see under `cfgs`: B games, a
+    """The operand shapes the five kernels see under `cfgs`: B games, a
     tree of N nodes x A actions searched W leaves at a time to depth D,
-    a ring of `capacity` rows sampled k x b at a time."""
+    a ring of `capacity` rows sampled k x b at a time, and the encoder's
+    attention over the leaf wave of a fast search (of a full one where
+    the playout cap is off): `leaves` boards of `tokens` cells."""
     from alphatriangle_tpu.mcts.search import tree_geometry
 
-    mcts, train = cfgs["mcts"], cfgs["train"]
+    mcts, train, model = cfgs["mcts"], cfgs["train"], cfgs["model"]
     nodes, wave = tree_geometry(mcts)
+    _, fast_wave = tree_geometry(
+        mcts.model_copy(
+            update={
+                "max_simulations": mcts.fast_simulations or mcts.max_simulations,
+                "fast_simulations": None,
+            }
+        )
+    )
     # The carried tree of MCTSConfig.tree_reuse at this budget.
     reuse_nodes, _ = tree_geometry(mcts.model_copy(update={"tree_reuse": True}))
     return {
@@ -128,6 +138,11 @@ def kernel_shapes(cfgs: dict) -> dict:
         "capacity": train.BUFFER_CAPACITY,
         "learner_steps": train.FUSED_LEARNER_STEPS,
         "batch_size": train.BATCH_SIZE,
+        "leaves": train.SELF_PLAY_BATCH_SIZE * fast_wave,
+        "tokens": cfgs["env"].ROWS * cfgs["env"].COLS,
+        "heads": model.TRANSFORMER_HEADS,
+        "head_dim": model.TRANSFORMER_DIM // model.TRANSFORMER_HEADS,
+        "compute_dtype": model.COMPUTE_DTYPE,
     }
 
 
@@ -135,11 +150,15 @@ def kernel_cases(shapes: dict) -> list[dict]:
     """One case per kernel of ops/: `run(mode, *operands)` calls its
     dispatcher, `operands(key)` makes seeded inputs at `shapes` (valid
     node/action indices, a real forest for the promotion), `xla` names
-    the reference lowering. docs/KERNELS.md: all four are exact."""
+    the reference lowering. docs/KERNELS.md: the first four are exact;
+    `encoder_attention` rounds where Flax's function rounds (`tolerance`)
+    and is `timed` beside it."""
     # gather_rows is held to "take", a pure copy. Whether the default
     # one-hot einsum is exact on the MXU too is reported, not required.
     import jax
     import jax.numpy as jnp
+
+    from flax import linen as nn
 
     from alphatriangle_tpu.ops import (
         backup_update,
@@ -147,6 +166,7 @@ def kernel_cases(shapes: dict) -> list[dict]:
         per_sample,
         subtree_promote,
     )
+    from alphatriangle_tpu.ops.encoder_attention import encoder_attention
 
     b, n, w = shapes["batch"], shapes["nodes"], shapes["wave"]
     a, d = shapes["actions"], shapes["depth"]
@@ -228,6 +248,22 @@ def kernel_cases(shapes: dict) -> list[dict]:
             root_actions,
         )
 
+    def attention_operands(key):
+        shape = (
+            shapes["leaves"], shapes["tokens"], shapes["heads"], shapes["head_dim"]
+        )
+        return tuple(
+            jax.random.normal(k, shape, jnp.dtype(shapes["compute_dtype"]))
+            for k in jax.random.split(key, 3)
+        )
+
+    def run_attention(mode, query, key, value):
+        if mode == "flax":
+            return nn.dot_product_attention(query, key, value)
+        return encoder_attention(
+            query, key, value, interpret=jax.default_backend() != "tpu"
+        )
+
     def run_per(mode, priorities, key):
         return per_sample(priorities, cap, k, bs, key, mode=mode)
 
@@ -265,6 +301,17 @@ def kernel_cases(shapes: dict) -> list[dict]:
             "xla": "xla",
             "run": run_promote,
             "operands": promote_operands,
+        },
+        {
+            "name": "encoder_attention",
+            "xla": "flax",
+            "run": run_attention,
+            "operands": attention_operands,
+            # Unit normal inputs: the two paths round their
+            # probabilities and outputs to the compute type
+            # (bfloat16: 2^-8 of values up to 4), Flax its softmax too.
+            "tolerance": 0.06,
+            "timed": True,
         },
     ]
 
@@ -635,6 +682,38 @@ def phase_serve(
     }
 
 
+def _device_ops_ms(fn, operands, calls: int = 5, top: int = 6) -> dict:
+    """Device milliseconds a call of `fn`, by operation, dearest first
+    (`calls` executions under the profiler; the instruction's name and
+    output shape): the kernel's custom call beside the fusions XLA
+    makes of the same work. Empty where the trace has no `XLA Ops`
+    line (a CPU run)."""
+    import tempfile
+
+    import jax
+
+    from alphatriangle_tpu.profiling import device_operations
+
+    jax.block_until_ready(fn(*operands))
+    with tempfile.TemporaryDirectory() as trace_dir:
+        jax.profiler.start_trace(trace_dir)
+        try:
+            for _ in range(calls):
+                out = fn(*operands)
+            jax.block_until_ready(out)
+        finally:
+            jax.profiler.stop_trace()
+        found = sorted(Path(trace_dir).rglob("*.xplane.pb"))
+        data = jax.profiler.ProfileData.from_file(str(found[-1]))
+        total: dict = {}
+        for _, ops, _ in device_operations(data.planes):
+            for event, _, duration_ns in ops:
+                name = event.split("(", 1)[0].strip()
+                total[name] = total.get(name, 0.0) + duration_ns
+    dearest = sorted(total.items(), key=lambda kv: -kv[1])[:top]
+    return {name: round(ns / calls / 1e6, 3) for name, ns in dearest}
+
+
 def phase_kernels(cfgs: dict) -> dict:
     """Each Pallas kernel at the shapes of `cfgs`, compiled for this
     backend and compared with its XLA lowering on the same operands.
@@ -659,10 +738,9 @@ def phase_kernels(cfgs: dict) -> dict:
         )
         if ("tpu_custom_call" in compiled.as_text()) != on_tpu:
             wrong.append(f"{case['name']}: kernel in compiled text != {on_tpu}")
+        reference = jax.jit(functools.partial(case["run"], case["xla"]))
         got = jax.tree_util.tree_leaves(compiled(*operands))
-        want = jax.tree_util.tree_leaves(
-            jax.jit(functools.partial(case["run"], case["xla"]))(*operands)
-        )
+        want = jax.tree_util.tree_leaves(reference(*operands))
         _check(len(got) == len(want), f"{case['name']}: output count")
         verdict = "exact"
         for j, (g, x) in enumerate(zip(got, want)):
@@ -674,6 +752,8 @@ def phase_kernels(cfgs: dict) -> dict:
                 g, x, rtol=1e-5, atol=1e-5
             ):
                 verdict = f"f32 rounding (output {j}: max |diff| {gap:.3g})"
+            elif gap <= case.get("tolerance", -1.0):
+                verdict = f"{g.dtype} rounding (output {j}: max |diff| {gap:.3g})"
             else:
                 verdict = f"output {j} differs (max |diff| {gap:.3g})"
                 wrong.append(f"{case['name']}: {verdict}")
@@ -682,6 +762,11 @@ def phase_kernels(cfgs: dict) -> dict:
             "parity": verdict,
             "seconds": round(time.monotonic() - t0, 1),
         }
+        if case.get("timed"):
+            parity[case["name"]]["device_ms_a_call"] = {
+                "pallas": _device_ops_ms(compiled, operands),
+                case["xla"]: _device_ops_ms(reference, operands),
+            }
     _check(not wrong, f"kernels: {wrong}; parity so far: {parity}")
     from alphatriangle_tpu.ops import gather_rows
 

@@ -4,8 +4,8 @@ libtpu compiles for a chip that is described and not attached
 (`jax.experimental.topologies`). Interpret-mode tests (tests/test_ops.py)
 pin what the kernels compute; they cannot see what the compiler refuses
 — a block that is not a whole (8, 128) tile, a scalar operand laid out
-for VMEM, more VMEM than the scoped limit. All four kernels had passed
-every interpret-mode test and none compiled. These cases hold the
+for VMEM, more VMEM than the scoped limit. The first four kernels had
+passed every interpret-mode test and none compiled. These cases hold the
 compiled-for-v5e property at the widths the chip runs: preset 3 (the
 flagship, what `chip_smoke.py` executes on the chip) and preset 4's
 400-simulation tree (the largest planes).
@@ -24,13 +24,16 @@ import pytest  # noqa: E402
 import chip_smoke  # noqa: E402
 from alphatriangle_tpu.config.presets import baseline_preset  # noqa: E402
 
-KERNELS = ("gather_rows", "backup_update", "per_sample", "subtree_promote")
+KERNELS = (
+    "gather_rows", "backup_update", "per_sample", "subtree_promote",
+    "encoder_attention",
+)
 
 
 @pytest.fixture(scope="module")
-def one_v5e_chip():
+def v5e_2x2():
+    """The four described chips of one host."""
     from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
 
     try:
         topo = topologies.get_topology_desc(
@@ -38,7 +41,14 @@ def one_v5e_chip():
         )
     except Exception as exc:  # no libtpu in this image
         pytest.skip(f"cannot describe a v5e topology here: {exc}")
-    return SingleDeviceSharding(topo.devices[0])
+    return topo.devices
+
+
+@pytest.fixture(scope="module")
+def one_v5e_chip(v5e_2x2):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(v5e_2x2[0])
 
 
 @pytest.fixture(autouse=True)
@@ -86,3 +96,84 @@ def test_kernel_compiles_for_v5e(
         .compile()
     )
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_encoder_attention_compiles_at_252_tokens(one_v5e_chip, monkeypatch):
+    """Preset 5's board: 252 tokens, a wave of 1,024 lanes x 32 leaves."""
+    shapes = chip_smoke.kernel_shapes(baseline_preset(5))
+    assert (shapes["leaves"], shapes["tokens"]) == (32768, 252)
+    case = chip_smoke.kernel_cases(shapes)[-1]
+    assert case["name"] == "encoder_attention"
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    operands = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_v5e_chip),
+        jax.eval_shape(case["operands"], jax.random.PRNGKey(0)),
+    )
+    compiled = (
+        jax.jit(functools.partial(case["run"], "pallas"))
+        .lower(*operands)
+        .compile()
+    )
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("chips,fused", [(4, False), (1, True)])
+def test_lane_sharded_chunk_compiles_for_v5e(
+    v5e_2x2, monkeypatch, chips, fused
+):
+    """The rollout chunk of the flagship's net with its lanes sharded
+    over dp on the described 2x2 (`SelfPlayEngine(mesh=)`, the
+    megastep's rollout half; a small tree, one move). The compiler
+    refuses to lower a Mosaic call it would have to partition, so the
+    net keeps Flax's attention there and the kernel on one chip."""
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    from alphatriangle_tpu.env.engine import TriangleEnv
+    from alphatriangle_tpu.features.core import get_feature_extractor
+    from alphatriangle_tpu.nn.network import NeuralNetwork
+    from alphatriangle_tpu.rl import SelfPlayEngine
+
+    cfgs = chip_smoke.flagship_configs()
+    env = TriangleEnv(cfgs["env"])
+    net = NeuralNetwork(cfgs["model"], cfgs["env"], seed=0)
+    engine = SelfPlayEngine(
+        env,
+        get_feature_extractor(env, cfgs["model"]),
+        net,
+        cfgs["mcts"].model_copy(
+            update={
+                "max_simulations": 8,
+                "fast_simulations": None,
+                "mcts_batch_size": 4,
+            }
+        ),
+        chip_smoke._train_config(cfgs["train"], SELF_PLAY_BATCH_SIZE=8),
+        seed=0,
+    )
+    # The engine as `mesh=` would place it, on devices that are
+    # described and cannot hold an array.
+    mesh = Mesh(np.array(v5e_2x2[:chips]), ("dp",))
+    engine._lane_sharding = NamedSharding(mesh, PartitionSpec("dp"))
+    engine._replicated = NamedSharding(mesh, PartitionSpec())
+
+    def described(x, sharding):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding)
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled = (
+        jax.jit(functools.partial(engine._chunk, 1))
+        .lower(
+            jax.tree_util.tree_map(
+                lambda x: described(x, engine._replicated),
+                engine._inference_variables(net.variables, 0),
+            ),
+            jax.tree_util.tree_map(
+                described, engine._carry, engine._carry_shardings()
+            ),
+            described(jnp.int32(0), engine._replicated),
+        )
+        .compile()
+    )
+    assert ("encoder_attention" in compiled.as_text()) == fused

@@ -106,7 +106,12 @@ def test_kernels_phase_matches_xla(tiny_cfgs):
     }
     out = chip_smoke.phase_kernels(small)
     assert out["compiled"] is False  # interpreted here, and it says so
+    attention = out["parity"].pop("encoder_attention")
     assert {k["parity"] for k in out["parity"].values()} == {"exact"}
+    # Not exact: a float32 softmax against Flax's; timed beside it (a
+    # CPU trace has no device operations to list).
+    assert attention["vs"] == "flax" and "rounding" in attention["parity"]
+    assert attention["device_ms_a_call"] == {"pallas": {}, "flax": {}}
 
 
 def test_native_engine_phase(tiny_cfgs):
@@ -177,6 +182,9 @@ def test_flagship_shapes_are_preset_three():
         "batch": 512, "nodes": 65, "reuse_nodes": 129, "wave": 32,
         "actions": 360, "depth": 8, "capacity": 250_000,
         "learner_steps": 16, "batch_size": 256,
+        # The leaf wave of a fast search: 512 lanes x 16 simulations.
+        "leaves": 8192, "tokens": 120, "heads": 4, "head_dim": 32,
+        "compute_dtype": "bfloat16",
     }
 
 

@@ -184,3 +184,150 @@ def test_count_parameters(network):
         for p in jax.tree_util.tree_leaves(network.variables["params"])
     )
     assert n == total
+
+
+# --- the encoder's attention: which path, and what says so ---------------------
+
+
+@pytest.fixture(scope="module")
+def flagship():
+    """Preset 3's net as the benchmark builds it, at batch 2."""
+    from alphatriangle_tpu.config.presets import baseline_preset
+
+    cfgs = baseline_preset(3)
+    model, env = cfgs["model"], cfgs["env"]
+    module = AlphaTriangleNet(model, env.action_dim)
+    keys = jax.random.split(jax.random.PRNGKey(5), 3)
+    grid = jax.random.normal(
+        keys[0], (2, model.GRID_INPUT_CHANNELS, env.ROWS, env.COLS)
+    )
+    other = jax.random.normal(keys[1], (2, model.OTHER_NN_INPUT_FEATURES_DIM))
+    variables = module.init(keys[2], grid, other, train=False)
+    return module, variables, grid, other
+
+
+def _attention_instant() -> dict:
+    from alphatriangle_tpu.telemetry.tracer import default_tracer
+
+    found = [r for r in default_tracer().records() if r[1] == "net.attention"]
+    kind, _, _, duration, *_ = found[-1]
+    assert (kind, duration) == ("i", 0)
+    return found[-1][6]
+
+
+@pytest.mark.parametrize(
+    "backend,train,fused",
+    [("cpu", False, 0), ("tpu", False, 4), ("tpu", True, 0)],
+)
+def test_net_attention_instant_says_which_path(
+    flagship, monkeypatch, backend, train, fused
+):
+    """One instant a traced net program: how many encoder layers took
+    the fused kernel and how many Flax's function, at what batch."""
+    module, variables, grid, other = flagship
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    # Traced, not lowered: the kernel's TPU lowering needs no chip here.
+    jax.eval_shape(
+        lambda v, g, o: module.apply(
+            v, g, o, train=train, rngs={"dropout": jax.random.PRNGKey(0)}
+        ),
+        variables, grid, other,
+    )
+    assert _attention_instant() == {
+        "fused_layers": fused, "flax_layers": 4 - fused, "batch": 2, "seq": 120,
+    }
+
+
+def test_a_net_placed_on_a_mesh_keeps_flax(flagship, monkeypatch):
+    """Lanes sharded over dp, weights replicated (`SelfPlayEngine(mesh=)`,
+    the megastep's rollout half): the compiler partitions that program
+    and cannot split a Mosaic call, so every layer takes Flax's einsums.
+    A mesh of one device is a program of one device."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    module, variables, grid, other = flagship
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    for devices, fused in ((2, 0), (1, 4)):
+        mesh = Mesh(np.array(jax.devices()[:devices]), ("dp",))
+        lanes = NamedSharding(mesh, PartitionSpec("dp"))
+        jax.eval_shape(
+            lambda v, g, o: module.apply(v, g, o, train=False),
+            jax.device_put(variables, NamedSharding(mesh, PartitionSpec())),
+            jax.device_put(grid, lanes),
+            jax.device_put(other, lanes),
+        )
+        assert _attention_instant()["fused_layers"] == fused
+
+
+def test_a_handed_in_attention_fn_keeps_precedence(flagship, monkeypatch):
+    from flax import linen as nn
+
+    module, variables, grid, other = flagship
+    calls = []
+
+    def handed_in(query, key, value, **kwargs):
+        calls.append(query.shape)
+        return nn.dot_product_attention(query, key, value)
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    jax.eval_shape(
+        lambda v, g, o: module.clone(attention_fn=handed_in).apply(
+            v, g, o, train=False
+        ),
+        variables, grid, other,
+    )
+    assert calls == [(2, 120, 4, 32)] * 4
+    assert _attention_instant()["fused_layers"] == 0
+
+
+def test_fused_inference_forward_matches_flax(flagship, monkeypatch):
+    """The flagship's eval forward through the kernel (interpreted; the
+    backend said to be a TPU) against the same weights through Flax's
+    attention, in the configuration's bfloat16."""
+    import functools
+
+    from alphatriangle_tpu.nn import model as nn_model
+
+    module, variables, grid, other = flagship
+    flax = module.apply(variables, grid, other, train=False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(
+        nn_model,
+        "encoder_attention",
+        functools.partial(nn_model.encoder_attention, interpret=True),
+    )
+    fused = module.apply(variables, grid, other, train=False)
+    assert _attention_instant()["fused_layers"] == 4
+    for got, want in zip(fused, flax):
+        assert got.dtype == jnp.float32
+        assert float(jnp.max(jnp.abs(got - want))) < 0.05
+
+
+def test_train_lowering_is_the_parents():
+    """`train=True` keeps Flax's attention with its dropout: the lowered
+    forward is the parent commit's text (digest taken on be19ed3 with
+    this test's code), so the learner's program did not change."""
+    import hashlib
+
+    from chipbench import manifest
+
+    cfg = manifest.load_json(manifest.HERE / "configs" / "flagship-p3.json")
+    configs = manifest.program_configs(cfg)
+    model, env = configs["model"], configs["env"]
+    module = AlphaTriangleNet(model, env.action_dim)
+    grid = jnp.zeros((2, model.GRID_INPUT_CHANNELS, env.ROWS, env.COLS))
+    other = jnp.zeros((2, model.OTHER_NN_INPUT_FEATURES_DIM))
+    variables = module.init(jax.random.PRNGKey(5), grid, other, train=False)
+    text = (
+        jax.jit(
+            lambda v, g, o, key: module.apply(
+                v, g, o, train=True, rngs={"dropout": key}
+            )
+        )
+        .lower(variables, grid, other, jax.random.PRNGKey(1))
+        .as_text()
+    )
+    assert hashlib.sha256(text.encode()).hexdigest() == (
+        "5d5f7fe70fb0eb4cfd36a49bdee70f0cfcfba10e875199b59cc73abc3ba90580"
+    )

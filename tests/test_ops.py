@@ -16,6 +16,12 @@ from alphatriangle_tpu.ops import (
     per_sample,
     subtree_promote,
 )
+from alphatriangle_tpu.ops.encoder_attention import (
+    attention_path,
+    block_boards,
+    encoder_attention,
+    partitioned,
+)
 
 
 class TestGatherRows:
@@ -46,6 +52,113 @@ class TestGatherRows:
                 np.asarray(out),
                 np.stack([stats[b][idx[b]] for b in range(3)]),
             )
+
+
+def _max_gap(a, b) -> float:
+    return float(
+        jnp.max(jnp.abs(a.astype(jnp.float32) - b.astype(jnp.float32)))
+    )
+
+
+class TestEncoderAttention:
+    """The fused kernel (interpreted) against Flax's function, and the
+    choice between the two."""
+
+    # The flagship's boards, preset 5's 252 tokens, and a batch the
+    # block of 32 boards does not divide (a padded last step).
+    @pytest.mark.parametrize(
+        "shape", [(2, 120, 4, 32), (2, 252, 4, 32), (35, 24, 4, 32)]
+    )
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    def test_kernel_matches_flax(self, shape, dtype):
+        from flax import linen as nn
+
+        assert block_boards(35, 24, 128, 2) == 32  # 35 = 32 + 3
+        q, k, v = (
+            jax.random.normal(key, shape, dtype)
+            for key in jax.random.split(jax.random.PRNGKey(0), 3)
+        )
+        got = encoder_attention(q, k, v, interpret=True)
+        assert got.shape == shape and got.dtype == dtype
+        flax = nn.dot_product_attention(q, k, v)
+        exact = nn.dot_product_attention(
+            *(x.astype(jnp.float32) for x in (q, k, v))
+        )
+        if dtype == jnp.float32:
+            assert _max_gap(got, flax) < 1e-5
+            return
+        # Unit normal inputs: Flax's bfloat16 path lies up to 0.017 from
+        # the float32 answer here; the kernel's float32 softmax must
+        # not lie further, and the two lie within their rounding.
+        assert _max_gap(got, exact) <= max(0.008, _max_gap(flax, exact))
+        assert _max_gap(got, flax) < 0.03
+
+    FUSED = dict(
+        train=False, handed_in=False, masked=False, partitioned=False,
+        backend="tpu", dtype=jnp.bfloat16, seq=120, heads=4, head_dim=32,
+    )
+
+    @pytest.mark.parametrize(
+        "change,path",
+        [
+            ({}, "fused"),
+            ({"dtype": jnp.float32}, "fused"),
+            ({"seq": 252}, "fused"),
+            ({"backend": "cpu"}, "flax"),
+            ({"backend": "gpu"}, "flax"),
+            ({"train": True}, "flax"),
+            ({"masked": True}, "flax"),
+            ({"handed_in": True}, "flax"),
+            ({"partitioned": True}, "flax"),
+            ({"dtype": jnp.float16}, "flax"),
+            ({"heads": 2, "head_dim": 16}, "flax"),  # 32 lanes of 128
+            ({"seq": 8192}, "flax"),  # one board's scores: 268 MB
+        ],
+    )
+    def test_path_is_chosen_by_what_the_call_observes(self, change, path):
+        assert attention_path(**{**self.FUSED, **change}) == path
+
+    @pytest.mark.parametrize(
+        "placed,manual,want",
+        [
+            (None, (), False),  # a program of one device
+            ((1, 1), (), False),  # a mesh of one device
+            ((4, 2), (), True),  # split by the compiler
+            ((4, 2), ("dp",), True),  # some axis still the compiler's
+            ((4, 2), ("dp", "tp"), False),  # all under a shard_map
+        ],
+    )
+    def test_partitioned_reads_the_mesh_of_the_trace(self, placed, manual, want):
+        """What the choice observes of a mesh: a value derived from
+        operands placed on one (here the weight alone) carries it."""
+        from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+        seen = []
+
+        def program(x, w):
+            y = x @ w
+            seen.append(partitioned(jax.lax.scan(lambda c, _: (c, None), y, None, 2)[0]))
+            return y
+
+        x, w = jnp.ones((8, 4)), jnp.ones((4, 4))
+        if placed is not None:
+            n = placed[0] * placed[1]
+            mesh = Mesh(np.array(jax.devices()[:n]).reshape(placed), ("dp", "tp"))
+            w = jax.device_put(w, NamedSharding(mesh, PartitionSpec()))
+            if manual:
+                program = jax.shard_map(
+                    program, mesh=mesh, in_specs=PartitionSpec(),
+                    out_specs=PartitionSpec(), axis_names=set(manual),
+                    check_vma=False,
+                )
+        jax.eval_shape(program, x, w)
+        assert seen == [want]
+
+    def test_a_board_that_does_not_fit_is_refused(self):
+        x = jax.ShapeDtypeStruct((1, 8192, 4, 32), jnp.bfloat16)
+        assert block_boards(1, 8192, 128, 2) == 0
+        with pytest.raises(ValueError, match="does not fit"):
+            jax.eval_shape(encoder_attention, x, x, x)
 
 
 class TestPerSample:

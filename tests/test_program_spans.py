@@ -258,7 +258,8 @@ class TestProgramSpans:
             outs = trainer.train_steps_from(buffer, samples)
             for sample, (_, td) in zip(samples, outs):
                 buffer.update_priorities(sample["indices"], td)
-        records = tracer.records()
+        instants = [r for r in tracer.records() if r[0] == "i"]
+        records = [r for r in tracer.records() if r[0] == "X"]
         assert [r[1] for r in records] == [
             "replay.sample",
             "replay.sample",
@@ -274,6 +275,17 @@ class TestProgramSpans:
         args = {r[1]: r[6] for r in records}
         for name in ("learner.dispatch", "learner.wait", "learner.results"):
             assert args[name] == {"k": 2}
+        # The group's program is traced inside its first dispatch, and
+        # the net says there which attention its layers took: a
+        # learner's keep Flax's function, on any backend.
+        layers = world["net"].model_config.TRANSFORMER_LAYERS
+        assert [(r[1], r[8], r[6]) for r in instants] == [
+            (
+                "net.attention",
+                records[2][7],
+                {"fused_layers": 0, "flax_layers": layers, "batch": 4, "seq": 12},
+            )
+        ]
 
     def test_host_batches_group_has_the_same_three(self, world, tracer):
         trainer = world["trainer"]
@@ -289,10 +301,11 @@ class TestProgramSpans:
             "weights": np.ones(n, np.float32),
         }
         trainer.train_steps([batch])
-        assert _names(tracer) == [
+        spans = [r for r in tracer.records() if r[0] == "X"]
+        assert [r[1] for r in spans] == [
             "learner.dispatch", "learner.wait", "learner.results"
         ]
-        assert tracer.records()[0][6] == {"k": 1}
+        assert spans[0][6] == {"k": 1}
 
 
 # --- (d) the names in the lowered programs ------------------------------------
@@ -322,6 +335,13 @@ class TestPhaseNamesInPrograms:
         wanted -= set(_phases("net/trunk"))
         assert {p for p in wanted if p not in text} == set()
         assert "net/trunk" not in text
+        # The attention's phase lies inside the encoder's and wins.
+        assert "net/encoder/attention" in wanted
+        assert profiling.phase_of(
+            "jit(chunk)/search/evaluate/net/encoder/TransformerEncoderLayer_0/"
+            "MultiHeadDotProductAttention_0/net/encoder/attention/"
+            "jit(encoder_attention)/encoder_attention/pallas_call"
+        ) == "net/encoder/attention"
 
     def test_trunk_phases_in_the_chunk_of_a_decoder_stack(
         self, world, tiny_mcts_config
