@@ -9,17 +9,17 @@
 
 PY ?= python
 
-.PHONY: check lint type test bench-smoke perf-smoke serve-smoke tune-smoke doctor-smoke ops-smoke league-smoke chaos-smoke fleet-smoke trace-smoke reuse-smoke devstats-smoke roofline-smoke
+.PHONY: check lint type test perf-smoke serve-smoke tune-smoke doctor-smoke league-smoke chaos-smoke fleet-smoke
 
 check: lint type test
 
 lint:
 	@if $(PY) -c "import ruff" 2>/dev/null; then \
 		echo "== ruff check =="; \
-		$(PY) -m ruff check alphatriangle_tpu tests bench.py; \
+		$(PY) -m ruff check alphatriangle_tpu tests; \
 	else \
 		echo "== ruff unavailable; syntax gate via compileall =="; \
-		$(PY) -m compileall -q alphatriangle_tpu tests bench.py __graft_entry__.py; \
+		$(PY) -m compileall -q alphatriangle_tpu tests __graft_entry__.py; \
 	fi
 	@echo "== graftlint (docs/ANALYSIS.md) =="
 	@$(PY) -m alphatriangle_tpu.cli lint
@@ -41,13 +41,10 @@ test:
 		$(PY) -m pytest tests/ -q; \
 	fi
 
-bench-smoke:
-	BENCH_SMOKE=1 JAX_PLATFORMS=cpu $(PY) bench.py
-
 # Metrics-ledger pipeline gate: a short CPU training run must produce a
 # parseable metrics.jsonl carrying memory-attribution + live-memory
 # records, `cli perf` must summarize it (exit 2 = the ledger schema
-# broke), `cli fit cpu` must compose the static memory budget and exit
+# broke), `cli fit 1` must compose the static memory budget and exit
 # 0 (the OOM pre-flight gate), and `cli compare` must hold against the
 # checked-in reference summary (generous threshold — CI hosts vary in
 # speed; the hard signal is schema alignment + "not catastrophically
@@ -63,10 +60,7 @@ perf-smoke:
 # zero recompiles after the all-rung warm, zero lost requests,
 # admit/retire churn mid-run — land per-request p50/p95 move-latency
 # records plus the serve_bucket/serve_fill gauges in the serve run's
-# metrics ledger, summarize them via `cli perf --json`, and hold the
-# serve SLO rows of `cli compare` against the checked-in reference.
-# Regenerate the serve rows after intentional schema changes:
-#   $(PY) benchmarks/serve_smoke.py --write-reference
+# metrics ledger, and summarize them via `cli perf --json`.
 serve-smoke:
 	JAX_PLATFORMS=cpu $(PY) benchmarks/serve_smoke.py
 
@@ -114,66 +108,9 @@ chaos-smoke:
 fleet-smoke:
 	JAX_PLATFORMS=cpu $(PY) benchmarks/fleet_smoke.py
 
-# Distributed-tracing + SLO gate (docs/OBSERVABILITY.md "Distributed
-# tracing & SLOs"): a 2-replica CPU storm with an aggressive hedge
-# trigger and an injected hang-serve wedge must leave trace_ids
-# consistent across fleet.jsonl, the replica flight rings, and the
-# `cli trace --fleet` merged Perfetto timeline — with flow arrows for
-# >= 1 hedged and >= 1 retried request in causal order — and the
-# `cli slo` exit-code contract (0 within budget / 1 burning / 2 no
-# data) must hold on pinned healthy/brownout/empty windows. Every
-# reader runs with jax imports hard-blocked.
-trace-smoke:
-	JAX_PLATFORMS=cpu $(PY) benchmarks/trace_smoke.py
-
-# Kernel-library gate (docs/KERNELS.md): every interchangeable lowering
-# in alphatriangle_tpu/ops/ (gather_rows, backup_update, per_sample)
-# must match its reference backend bit-for-bit across a shape grid
-# before it is timed; a parity break fails the target. CPU runs the
-# Pallas rows in interpret mode — set OPS_BENCH_FULL=1 on a TPU host
-# for decision-grade timings at flagship shapes.
-ops-smoke:
-	JAX_PLATFORMS=cpu $(PY) benchmarks/ops_bench.py
-
-# Subtree-reuse gate (docs/KERNELS.md "subtree_promote"): the batched
-# root-promotion pass over a REAL search tree must match an eager NumPy
-# BFS reference node for node with the Pallas lowering bit-identical to
-# XLA; reuse ON at equal sims must deliver >= 1.15x leaf-evals/s over
-# fresh-root; a short reuse training run must land leaf_evals_per_sec +
-# mcts_reused_visit_fraction (> 0) on the ledger and in `cli perf
-# --json`; and a fixed-seed paired arena through the PolicyService path
-# must show reuse at REDUCED sims score-neutral-or-better vs fresh-root
-# at full sims.
-reuse-smoke:
-	JAX_PLATFORMS=cpu $(PY) benchmarks/reuse_smoke.py
-
-# Device-telemetry gate (docs/OBSERVABILITY.md "Device telemetry
-# plane"): a short megastep CPU run with stat-packs on must land
-# `kind:"device_stats"` ledger records surfaced as ds_* fields by
-# `cli perf --json` while the one-dispatch gauge still reads 1.0;
-# stat-packs timed OFF vs ON on the same megastep shape must cost <3%
-# wall (they ride the existing fetch — no extra dispatches); and a
-# beacon-armed child with an injected dispatch hang must die by the
-# watchdog's 113 leaving beacons.jsonl + a wedge report whose frozen
-# last_beacon the jax-blocked `cli doctor` verdict names.
-devstats-smoke:
-	JAX_PLATFORMS=cpu $(PY) benchmarks/devstats_smoke.py
-
-# Roofline-attribution gate (docs/OBSERVABILITY.md "Roofline & gap
-# attribution"): a short CPU training run must leave `.cost.json`
-# sidecars + ledger `kind:"cost"` records for the chunk/learner/
-# megastep/serve program families, `cli roofline` (jax-free) must
-# classify every hot family and attribute >= 95% of the run's wall
-# across dispatch + named gap categories, the chip-idle gauge must
-# ride util records into `cli perf --json`/`cli compare`, and the
-# perf reference must still hold with dispatches_per_iteration
-# unchanged. Regenerate the reference after intentional changes:
-#   $(PY) benchmarks/perf_smoke.py --write-reference
-roofline-smoke:
-	JAX_PLATFORMS=cpu $(PY) benchmarks/roofline_smoke.py
-
-# Fit-driven autotuner gate (docs/AUTOTUNE.md): `cli tune cpu --smoke`
-# under a host-RAM byte limit must emit a tuned_preset.json that
+# Fit-driven autotuner gate (docs/AUTOTUNE.md): the search under
+# `cli tune`, over the script's own tiny configs and lattice under a
+# host-RAM byte limit, must emit a tuned_preset.json that
 # `cli fit` independently confirms fits, whose winner out-predicts every
 # feasible rejected candidate, that `cli train --preset <artifact>
 # --dry-setup` can construct components from, and whose short real run

@@ -7,8 +7,8 @@ K, dp, geometry preset)` space with `estimate_fit`/`compose_budget`
 AOT-analyzed, never executed — and an analytic throughput model
 (utils/flops.py + device peak, calibrated against ledger history) as
 the objective. `cli tune` drives it and emits `tuned_preset.json`
-artifacts that `cli train --preset`, `cli warm`, `cli fit` and
-`bench.py` consume directly."""
+artifacts that `cli train --preset`, `cli warm` and `cli fit` consume
+directly."""
 
 from .artifact import (
     TUNE_OUTCOME_KIND,

@@ -5,8 +5,8 @@
 config bundle plus the prediction, composed budget, calibration
 provenance and the search table. `config.presets.load_tuned_preset`
 round-trips it into a `baseline_preset`-shaped bundle that
-`cli train --preset <path>`, `cli warm <path>`, `cli fit <path>` and
-`bench.py` (BENCH_TUNED_PRESET) consume directly.
+`cli train --preset <path>`, `cli warm <path>` and `cli fit <path>`
+consume directly.
 
 After a run that consumed a tuned preset completes,
 `ledger_tune_outcome` appends a `kind:"tune_outcome"` record to the
@@ -95,6 +95,15 @@ def build_tuned_preset(
             "evaluated": result.evaluated,
         },
     }
+
+
+def serve_ladder(bundle: dict) -> "str | None":
+    """The serve-shape ladder (a serving/buckets.py CSV spec) a config
+    bundle carries: the `kernels.serve_buckets` its winner was scored
+    with when `load_tuned_preset` made it from an artifact; None (one
+    fixed rung at the lane count) for a BASELINE preset."""
+    kernels = (bundle.get("tuned") or {}).get("kernels") or {}
+    return kernels.get("serve_buckets") or None
 
 
 def write_tuned_preset(payload: dict, out_path) -> Path:

@@ -38,9 +38,9 @@ from ..utils.flops import forward_flops, train_step_flops
 
 logger = logging.getLogger(__name__)
 
-# Achieved-MFU prior when no ledger history exists: the flagship bench
-# measured ~1.4% self-play MFU at B=512 (bench_config.py notes), so an
-# uncalibrated search assumes roughly that. Any comparable run in the
+# Achieved-MFU prior when no ledger history exists: a conservative
+# 1.4% of peak for self-play at B=512 (a pre-chip figure; the on-chip
+# benchmark's `mfu.rollout` is in PERF.md). Any comparable run in the
 # ledger replaces it.
 DEFAULT_EFFICIENCY = 0.014
 
@@ -126,7 +126,7 @@ def expected_simulations(mcts_config) -> float:
 
 def calibration_from_summary(summary: dict) -> "Calibration | None":
     """Calibration terms from one comparable perf summary (a run ledger
-    or bench snapshot normalized by `load_comparable`). None when the
+    or run normalized by `load_comparable`). None when the
     summary carries nothing usable."""
     if not isinstance(summary, dict):
         return None
@@ -223,8 +223,8 @@ def calibration_from_targets(
     targets: list, root_dir: "str | None" = None
 ) -> Calibration:
     """Calibration from ledger history: each target goes through
-    `load_comparable` (run name / run dir / metrics.jsonl / perf or
-    bench JSON), then any `tune_outcome` records in resolvable run
+    `load_comparable` (run name / run dir / metrics.jsonl / perf-summary
+    JSON), then any `tune_outcome` records in resolvable run
     ledgers fold in as an observed/predicted scale. Unreadable targets
     are skipped with a log line, never fatal — an empty history just
     means defaults."""

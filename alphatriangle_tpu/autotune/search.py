@@ -62,7 +62,7 @@ class TuneResult:
 def materialize_candidate(candidate, base_env, base_model, base_train, mode):
     """(env, model, train) configs for one candidate.
 
-    Geometry "plan" keeps the resolved plan's board; a named geometry
+    Geometry "plan" keeps the base configuration's board; a named geometry
     swaps the board in and re-derives the model's feature-dim contract
     (`expected_other_features_dim`) exactly as the presets do. The
     train config rebuilds through the constructor so every validator

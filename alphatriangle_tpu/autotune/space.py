@@ -57,7 +57,7 @@ class Candidate:
     per_sample: str = "xla"  # TrainConfig.PER_SAMPLE_BACKEND
     inference_precision: str = "float32"  # ModelConfig.INFERENCE_PRECISION
     # Serve-shape ladder spec (serving/buckets.py): CSV rung list, ""
-    # meaning a single fixed rung at the plan's serve batch. A serve-
+    # meaning a single fixed rung at the lane count. A serve-
     # side axis — it never changes training residency, so it is absent
     # from oracle_key() (free axis: ladders share feasibility answers).
     serve_buckets: str = ""
@@ -136,7 +136,7 @@ class Candidate:
 class SearchSpace:
     """Axis values the tuner enumerates (geometry names must exist in
     `config.presets.GEOMETRY_PRESETS` or equal the sentinel "plan",
-    meaning the resolved bench plan's own board)."""
+    meaning the base configuration's own board)."""
 
     geometries: list = field(default_factory=lambda: ["plan"])
     batches: list = field(default_factory=lambda: [256, 512, 1024])

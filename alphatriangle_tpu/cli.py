@@ -204,6 +204,36 @@ def merge_train_overrides(base_config, overrides: dict):
     return TrainConfig(**base)
 
 
+_PRESET_TARGET_HELP = (
+    "1..5 = the BASELINE preset `cli train --preset N` runs "
+    "(config/presets.py), or a tuned_preset.json path from `cli tune`."
+)
+
+
+def resolve_preset(target: str, run_name: "str | None" = None) -> dict:
+    """The config bundle {env, model, mcts, train, mesh, ...} a preset
+    names: 1..5 is a BASELINE preset (config/presets.py), a path is a
+    `tuned_preset.json` from `cli tune` (the bundle then carries the
+    artifact as `tuned`). `train --preset`, `warm`, `fit` and `tune`
+    all resolve through here, so the shapes they answer for are the
+    ones `cli train --preset <target>` runs."""
+    from .config import baseline_preset, load_tuned_preset
+
+    target = str(target)
+    try:
+        if target.isdigit():
+            return baseline_preset(int(target), run_name=run_name)
+        if Path(target).is_file():
+            return load_tuned_preset(target)
+    except ValueError as exc:
+        raise SystemExit(f"preset {target}: {exc}") from exc
+    raise SystemExit(
+        f"Unknown preset {target!r}: expected 1..5 (the BASELINE.md "
+        "configurations, config/presets.py) or a tuned_preset.json "
+        "path (emitted by `cli tune`)."
+    )
+
+
 def cmd_train(args: argparse.Namespace) -> int:
     from .config import PersistenceConfig, TrainConfig
     from .parallel.distributed import DistributedConfig
@@ -267,19 +297,8 @@ def cmd_train(args: argparse.Namespace) -> int:
     env_config = model_config = mcts_config = mesh_config = None
     tuned_payload = None
     if args.preset is not None:
-        preset = str(args.preset)
-        if preset.isdigit():
-            from .config import baseline_preset
-
-            bundle = baseline_preset(int(preset), run_name=args.run_name)
-        else:
-            from .config import load_tuned_preset
-
-            try:
-                bundle = load_tuned_preset(preset)
-            except ValueError as exc:
-                raise SystemExit(f"--preset: {exc}") from exc
-            tuned_payload = bundle.get("tuned")
+        bundle = resolve_preset(args.preset, run_name=args.run_name)
+        tuned_payload = bundle.get("tuned")
         env_config = bundle["env"]
         model_config = bundle["model"]
         mcts_config = bundle["mcts"]
@@ -433,7 +452,7 @@ def _resolve_run_dir(
 def cmd_health(args: argparse.Namespace) -> int:
     """Heartbeat check for a run: pretty-print `health.json` + a
     staleness verdict. Exit 0 = live, 1 = stalled/stale, 2 = no
-    heartbeat — so the bench supervisor (or a cron) can gate on it
+    heartbeat — so a supervisor (or a cron) can gate on it
     without parsing anything."""
     from .telemetry.health import health_verdict, read_health
 
@@ -962,9 +981,9 @@ def cmd_perf(args: argparse.Namespace) -> int:
 
 def cmd_compare(args: argparse.Namespace) -> int:
     """Aligned-metric regression report between two runs (or a run and
-    a BENCH_*.json / perf-summary snapshot). Exit 0 = parity or better,
+    a `cli perf --json` summary snapshot). Exit 0 = parity or better,
     1 = at least one metric regressed past --threshold, 2 = either side
-    unreadable — so a CI job or the bench supervisor can gate on it."""
+    unreadable — so a CI job or a supervisor can gate on it."""
     import json as _json
 
     from .telemetry.perf import compare_summaries, load_comparable
@@ -1411,65 +1430,28 @@ def cmd_play(args: argparse.Namespace) -> int:
         print(f"reward {reward:+.1f}")
 
 
-_BENCH_TARGETS = ("auto", "smoke", "cpu", "1", "2", "3", "4", "5")
-
-
-def _apply_bench_target(target: "str | None", environ: dict) -> None:
-    """Map a warm/fit/tune target onto the bench-plan env knobs:
-    digits 1..5 select a BASELINE preset (BENCH_CONFIG), a path to a
-    `cli tune` artifact selects the tuned shapes (BENCH_TUNED_PRESET);
-    auto/smoke/cpu leave the ambient BENCH_* knobs in charge."""
-    if not target or target in ("auto", "smoke", "cpu"):
-        return
-    if target.isdigit():
-        environ["BENCH_CONFIG"] = target
-        return
-    if Path(target).is_file():
-        environ["BENCH_TUNED_PRESET"] = target
-        return
-    raise SystemExit(
-        f"Unknown target {target!r}: expected one of "
-        f"{'|'.join(_BENCH_TARGETS)} or a tuned_preset.json path "
-        "(emitted by `cli tune`)."
-    )
-
-
 def cmd_warm(args: argparse.Namespace) -> int:
-    """AOT-precompile the hot bench/training programs for a preset so a
-    later bench/run starts measuring in seconds instead of paying the
-    first-chunk compiles (docs/COMPILE_CACHE.md): afterwards the
-    persistent + AOT executable caches hold the bench's exact shapes.
+    """AOT-precompile the hot programs of the run `cli train --preset
+    <target>` starts, so that run begins in seconds instead of paying
+    the first-dispatch compiles (docs/COMPILE_CACHE.md): afterwards the
+    persistent + AOT executable caches hold that run's exact programs.
     Exit 0 when every requested program is AOT-ready, 1 when any fell
     back or failed.
     """
     import json as _json
-    import os as _os
 
     from .utils.helpers import enforce_platform
 
-    # `warm cpu` pins the CPU backend (warming the bench's CPU smoke
-    # shapes without taking the accelerator).
-    device = args.device or ("cpu" if args.target == "cpu" else "auto")
-    enforce_platform(device)
+    bundle = resolve_preset(args.target)
+    enforce_platform(args.device or "auto")
 
-    import jax
-
-    from .bench_config import resolve_bench_plan
     from .utils.helpers import enable_persistent_compilation_cache
-    from .warm import warm_bench_programs
+    from .warm import warm_programs
 
     enable_persistent_compilation_cache()
-    backend = jax.default_backend()
-
-    environ = dict(_os.environ)
-    smoke = args.target == "smoke" or environ.get("BENCH_SMOKE") == "1"
-    # target auto/cpu/smoke: honor ambient BENCH_* knobs as bench does;
-    # digits select a BASELINE preset, a path selects a tuned preset.
-    _apply_bench_target(args.target, environ)
-    plan = resolve_bench_plan(smoke, backend, environ=environ)
     programs = set(args.programs.split(",")) if args.programs else None
-    report = warm_bench_programs(
-        plan,
+    report = warm_programs(
+        bundle,
         jobs=args.jobs,
         programs=programs,
         progress=lambda msg: print(msg, file=sys.stderr, flush=True),
@@ -2089,7 +2071,8 @@ def cmd_league(args: argparse.Namespace) -> int:
 
 def cmd_fit(args: argparse.Namespace) -> int:
     """OOM pre-flight gate (docs/OBSERVABILITY.md "Memory"): compose
-    the static per-device memory budget for a bench/preset scale —
+    the static per-device memory budget of the run `cli train --preset
+    <target>` starts —
     train-state tree bytes + replay-ring bytes + AOT-analyzed program
     memory (`compiled.memory_analysis()`, never executed) — and check
     it against the device byte limit BEFORE a scarce accelerator
@@ -2101,53 +2084,58 @@ def cmd_fit(args: argparse.Namespace) -> int:
 
     from .utils.helpers import enforce_platform
 
-    device = args.device or ("cpu" if args.target == "cpu" else "auto")
-    enforce_platform(device)
+    bundle = resolve_preset(args.target)
+    enforce_platform(args.device or "auto")
 
     import jax
 
-    from .bench_config import resolve_bench_plan
+    from .autotune.artifact import serve_ladder
     from .telemetry.memory import (
         estimate_fit,
         fit_verdict,
         fmt_bytes,
         resolve_bytes_limit,
     )
+    from .training.setup import wants_device_ring
     from .utils.helpers import enable_persistent_compilation_cache
 
     backend = jax.default_backend()
     enable_persistent_compilation_cache()
     environ = dict(_os.environ)
-    smoke = args.target == "smoke" or environ.get("BENCH_SMOKE") == "1"
-    _apply_bench_target(args.target, environ)
-    plan = resolve_bench_plan(smoke, backend, environ=environ)
+    train = bundle["train"]
+    device_replay = wants_device_ring(train)
+    fused_k = max(1, train.FUSED_LEARNER_STEPS)
+    if train.FUSED_MEGASTEP:
+        # One megastep holds a rollout's whole learner share.
+        fused_k = train.LEARNER_STEPS_PER_ROLLOUT or fused_k
+    label = bundle["description"]
     print(
-        f"fit: backend={backend} scale={plan.scale} batch={plan.sp_batch} "
-        f"chunk={plan.chunk} lbatch={plan.lbatch} "
-        f"device_replay={plan.device_replay}",
+        f"fit: backend={backend} {label}: "
+        f"batch={train.SELF_PLAY_BATCH_SIZE} "
+        f"chunk={train.ROLLOUT_CHUNK_MOVES} lbatch={train.BATCH_SIZE} "
+        f"k={fused_k} ring={train.BUFFER_CAPACITY} "
+        f"device_replay={device_replay}",
         file=sys.stderr,
         flush=True,
     )
     report = estimate_fit(
-        plan.env,
-        plan.model,
-        plan.mcts,
-        plan.train,
-        fused_k=plan.fused_k,
-        device_replay=plan.device_replay,
-        # Bench-plan ring capacities are small (10k rows), so the
-        # megastep program — whose argument list includes the ring —
-        # is analyzed here too (rl/megastep.py).
-        megastep=True,
+        bundle["env"],
+        bundle["model"],
+        bundle["mcts"],
+        train,
+        fused_k=fused_k,
+        device_replay=device_replay,
+        # The megastep's argument list includes the ring, so analyzing
+        # it allocates the run's own ring (rl/megastep.py): done for
+        # the runs that dispatch it.
+        megastep=train.FUSED_MEGASTEP,
         # --serve additionally analyzes the policy service's
-        # `serve/b<B>` search program and persists its .mem.json
-        # sidecar (serving/service.py; docs/SERVING.md).
+        # `serve/b<B>` search program at the lane count and persists
+        # its .mem.json sidecar (serving/service.py; docs/SERVING.md);
+        # a tuned artifact's ladder is analyzed rung by rung, since the
+        # micro-batcher can dispatch any of them.
         serve=args.serve,
-        serve_batch=plan.serve_batch,
-        # Every ladder rung is analyzed (BENCH_SERVE_BUCKETS /
-        # serving/buckets.py): the micro-batcher can dispatch any of
-        # them, so the budget covers the whole rung set.
-        serve_buckets=plan.serve_buckets,
+        serve_buckets=serve_ladder(bundle),
         progress=lambda msg: print(msg, file=sys.stderr, flush=True),
     )
     budget = report["budget"]
@@ -2160,7 +2148,7 @@ def cmd_fit(args: argparse.Namespace) -> int:
             _json.dumps(
                 {
                     "schema": "alphatriangle.fit.v1",
-                    "scale": plan.scale,
+                    "scale": label,
                     "backend": backend,
                     "budget": budget,
                     "bytes_limit": limit,
@@ -2172,7 +2160,7 @@ def cmd_fit(args: argparse.Namespace) -> int:
             )
         )
         return code
-    print(f"fit {plan.scale} on {backend}")
+    print(f"fit {label} on {backend}")
     for label, key in (
         ("train state", "train_state_bytes"),
         ("replay ring (device)", "replay_ring_bytes"),
@@ -2697,37 +2685,23 @@ def cmd_supervise(args: argparse.Namespace) -> int:
     return Supervisor(argv, run_dir, policy=policy).run()
 
 
-def _tune_axes(
-    scale: str, plan, smoke: bool, device_count: int
-) -> "tuple[list, list, list, list, list]":
-    """Default (batches, capacities, chunks, fused_ks, dps) per scale.
+def _tune_axes(train, fused_k: int, device_count: int) -> tuple:
+    """Default (batches, capacities, chunks, fused_ks, dps) around a
+    base TrainConfig.
 
-    Grids bracket the scale's plan shapes: the point of the search is
-    to discover how much LARGER than the hand-picked config the chip
-    can actually go, so each axis extends above the plan value. Smoke
-    keeps the lattice tiny — `make tune-smoke` pays a couple of
-    estimate_fit compiles, not a sweep."""
-    b0 = plan.sp_batch
-    cap0 = plan.train.BUFFER_CAPACITY
-    t0 = plan.chunk
-    k0 = plan.fused_k
-    if smoke:
-        batches = [max(4, b0 // 2), b0]
-        capacities = [cap0]
-        chunks = [t0]
-        fused_ks = [k0]
-    elif scale == "cpu":
-        batches = [b0 // 2, b0, b0 * 2]
-        capacities = [cap0, cap0 * 2]
-        chunks = [t0, t0 * 2]
-        fused_ks = [k0]
-    else:
-        batches = [b0 // 2, b0, b0 * 2, b0 * 4]
-        capacities = [cap0, cap0 * 5, cap0 * 10]
-        chunks = [t0, t0 * 2]
-        fused_ks = [k0, k0 * 2]
+    Grids bracket the base shapes: the point of the search is to
+    discover how much LARGER than the hand-picked config the chip can
+    actually go, so each axis extends above the base value. Every axis
+    has a flag that replaces it."""
+    b0 = train.SELF_PLAY_BATCH_SIZE
+    cap0 = train.BUFFER_CAPACITY
+    t0 = train.ROLLOUT_CHUNK_MOVES
+    batches = [max(1, b0 // 2), b0, b0 * 2, b0 * 4]
+    capacities = [cap0, cap0 * 5, cap0 * 10]
+    chunks = [t0, t0 * 2]
+    fused_ks = [fused_k, fused_k * 2]
     dps = [1]
-    if device_count > 1 and not smoke:
+    if device_count > 1:
         dps.append(device_count)
     return batches, capacities, chunks, fused_ks, dps
 
@@ -2742,7 +2716,7 @@ def cmd_tune(args: argparse.Namespace) -> int:
     window is burned), the objective from the analytic FLOPs model
     calibrated against ledger history (`--calibrate`). Emits
     `runs/<run>/tuned_preset.json`, consumable by `cli train --preset`,
-    `cli warm`, `cli fit` and `bench.py` (BENCH_TUNED_PRESET).
+    `cli warm` and `cli fit`.
 
     Exit 0: winner found + artifact written. Exit 1: no feasible
     candidate under the limit. Exit 2: no device byte limit known
@@ -2753,8 +2727,8 @@ def cmd_tune(args: argparse.Namespace) -> int:
 
     from .utils.helpers import enforce_platform
 
-    device = args.device or ("cpu" if args.target == "cpu" else "auto")
-    enforce_platform(device)
+    bundle = resolve_preset(args.target)
+    enforce_platform(args.device or "auto")
 
     import jax
 
@@ -2766,26 +2740,25 @@ def cmd_tune(args: argparse.Namespace) -> int:
         run_search,
         write_tuned_preset,
     )
-    from .bench_config import resolve_bench_plan
     from .telemetry.memory import (
         FIT_OVER,
         FIT_UNKNOWN,
         fmt_bytes,
         resolve_bytes_limit,
     )
+    from .training.setup import wants_device_ring
     from .utils.flops import peak_bf16_tflops_info
     from .utils.helpers import enable_persistent_compilation_cache
 
     backend = jax.default_backend()
     enable_persistent_compilation_cache()
     environ = dict(_os.environ)
-    smoke = (
-        args.target == "smoke"
-        or args.smoke
-        or environ.get("BENCH_SMOKE") == "1"
+    base_train = bundle["train"]
+    scale = (
+        f"tuned_{bundle['tuned'].get('scale', 'preset')}"
+        if "tuned" in bundle
+        else f"preset_{args.target}"
     )
-    _apply_bench_target(args.target, environ)
-    plan = resolve_bench_plan(smoke, backend, environ=environ)
 
     limit, limit_source = resolve_bytes_limit(args.limit_gb, environ)
     if limit is None:
@@ -2801,16 +2774,17 @@ def cmd_tune(args: argparse.Namespace) -> int:
     peak, peak_source = peak_bf16_tflops_info(device_kind)
     device_count = jax.device_count()
 
-    # Loop mode being tuned: the fused megastep when the plan would run
-    # it (device ring available), else the sync loop. CPU/smoke tunes
-    # sync — the megastep still dispatches on CPU but its learner
-    # programs cannot AOT there (rl/trainer.py cpu_aot).
+    # Loop mode being tuned: the fused megastep when the base config
+    # gets the device ring on this backend, else the sync loop. On the
+    # CPU that is sync — the megastep still dispatches there but its
+    # learner programs cannot AOT (rl/trainer.py cpu_aot).
+    device_replay = wants_device_ring(base_train)
     mode = args.mode
     if mode == "auto":
-        mode = "megastep" if plan.device_replay else "sync"
+        mode = "megastep" if device_replay else "sync"
 
     batches, capacities, chunks, fused_ks, dps = _tune_axes(
-        plan.scale, plan, smoke, device_count
+        base_train, max(1, base_train.FUSED_LEARNER_STEPS), device_count
     )
     if args.batches:
         batches = [int(v) for v in args.batches.split(",")]
@@ -2866,7 +2840,7 @@ def cmd_tune(args: argparse.Namespace) -> int:
         print(msg, file=sys.stderr, flush=True)
 
     say(
-        f"tune: backend={backend} scale={plan.scale} mode={mode} "
+        f"tune: backend={backend} scale={scale} mode={mode} "
         f"space={space.size()} candidates limit={fmt_bytes(limit)} "
         f"[{limit_source}] peak={peak or 'unknown'} TFLOP/s "
         f"[{peak_source}] calibration={','.join(calibration.sources)}"
@@ -2874,35 +2848,35 @@ def cmd_tune(args: argparse.Namespace) -> int:
 
     result = run_search(
         space,
-        plan.env,
-        plan.model,
-        plan.mcts,
-        plan.train,
+        bundle["env"],
+        bundle["model"],
+        bundle["mcts"],
+        base_train,
         limit,
         calibration=calibration,
         peak_tflops=peak,
         mode=mode,
-        device_replay=plan.device_replay or mode == "megastep",
+        device_replay=device_replay or mode == "megastep",
         progress=say,
     )
 
-    run_name = args.run_name or f"tune_{plan.scale}"
+    run_name = args.run_name or f"tune_{scale}"
     payload = None
     out_path = None
     if result.best is not None:
         from .autotune.search import candidate_mcts, materialize_candidate
 
         env_cfg, model_cfg, train_cfg = materialize_candidate(
-            result.best, plan.env, plan.model, plan.train, mode
+            result.best, bundle["env"], bundle["model"], base_train, mode
         )
         train_cfg = train_cfg.model_copy(update={"RUN_NAME": run_name})
         payload = build_tuned_preset(
             result,
             env_cfg,
             model_cfg,
-            candidate_mcts(plan.mcts, result.best),
+            candidate_mcts(bundle["mcts"], result.best),
             train_cfg,
-            scale=plan.scale,
+            scale=scale,
             mode=mode,
             backend=backend,
             device_kind=device_kind,
@@ -2922,7 +2896,7 @@ def cmd_tune(args: argparse.Namespace) -> int:
             _json.dumps(
                 {
                     "schema": "alphatriangle.tune_report.v1",
-                    "scale": plan.scale,
+                    "scale": scale,
                     "backend": backend,
                     "mode": mode,
                     "bytes_limit": limit,
@@ -2941,7 +2915,7 @@ def cmd_tune(args: argparse.Namespace) -> int:
             f"{'geometry':<9} {'B':>6} {'cap':>8} {'T':>4} {'K':>4} "
             f"{'dp':>3} {'pred games/h':>13} {'budget':>10}  status"
         )
-        print(f"tune {plan.scale} on {backend} (mode {mode})")
+        print(f"tune {scale} on {backend} (mode {mode})")
         print(hdr)
         for row in result.rows:
             pred = row["predicted"] or {}
@@ -2970,7 +2944,7 @@ def cmd_tune(args: argparse.Namespace) -> int:
             print(f"tune: wrote {out_path}")
             print(
                 f"tune: consume with `cli train --preset {out_path}`, "
-                f"`cli warm {out_path}`, or BENCH_TUNED_PRESET={out_path}"
+                f"`cli warm {out_path}` or `cli fit {out_path}`"
             )
         else:
             print(
@@ -3186,15 +3160,15 @@ def main(argv: list[str] | None = None) -> int:
     comp = sub.add_parser(
         "compare",
         help="Aligned-metric regression report between two runs (or a "
-        "run and a BENCH_*.json / perf-summary snapshot); exit 0 "
+        "run and a `cli perf --json` summary snapshot); exit 0 "
         "parity, 1 regression, 2 unreadable.",
     )
     comp.add_argument(
         "run_a", help="Candidate: run name/dir, metrics.jsonl, or JSON."
     )
     comp.add_argument(
-        "run_b", help="Baseline: run name/dir, metrics.jsonl, or JSON "
-        "(e.g. BENCH_r05.json).",
+        "run_b", help="Baseline: run name/dir, metrics.jsonl, or a "
+        "perf-summary JSON.",
     )
     comp.add_argument("--root-dir", default=None)
     comp.add_argument(
@@ -3276,18 +3250,15 @@ def main(argv: list[str] | None = None) -> int:
 
     warm = sub.add_parser(
         "warm",
-        help="AOT-precompile the hot bench/training programs (rollout "
-        "chunk, learner step, fused groups) into the executable cache "
-        "so the next bench/run skips first-dispatch compiles.",
+        help="AOT-precompile the hot programs of a preset's run "
+        "(rollout chunk, fused learner group, megastep, serve rungs) "
+        "into the executable cache so `cli train --preset` with the "
+        "same target skips its first-dispatch compiles.",
     )
     warm.add_argument(
         "target",
-        nargs="?",
-        default="auto",
-        help="What to warm: 'auto' = the bench scale for this backend "
-        "(honors ambient BENCH_* knobs), 'smoke'/'cpu' = the reduced "
-        "scales, 1..5 = a BASELINE preset (config/presets.py), or a "
-        "tuned_preset.json path from `cli tune`.",
+        metavar="N|PATH",
+        help="What to warm: " + _PRESET_TARGET_HELP,
     )
     warm.add_argument(
         "--jobs",
@@ -3317,12 +3288,8 @@ def main(argv: list[str] | None = None) -> int:
     )
     fit.add_argument(
         "target",
-        nargs="?",
-        default="auto",
-        help="Scale to check: 'auto' = the bench scale for this "
-        "backend (honors ambient BENCH_* knobs), 'smoke'/'cpu' = the "
-        "reduced scales, 1..5 = a BASELINE preset, or a "
-        "tuned_preset.json path from `cli tune`.",
+        metavar="N|PATH",
+        help="The run to check: " + _PRESET_TARGET_HELP,
     )
     fit.add_argument(
         "--limit-gb",
@@ -3785,11 +3752,8 @@ def main(argv: list[str] | None = None) -> int:
     )
     tune.add_argument(
         "target",
-        nargs="?",
-        default="auto",
-        help="Base scale to search around: 'auto' = the bench scale "
-        "for this backend, 'smoke'/'cpu' = the reduced scales, "
-        "1..5 = a BASELINE preset.",
+        metavar="N|PATH",
+        help="Base configuration to search around: " + _PRESET_TARGET_HELP,
     )
     tune.add_argument(
         "--limit-gb",
@@ -3799,12 +3763,6 @@ def main(argv: list[str] | None = None) -> int:
         help="Per-device byte limit (GiB) the search must fit under "
         "(default: backend-reported; also "
         "ALPHATRIANGLE_DEVICE_BYTES_LIMIT, bytes).",
-    )
-    tune.add_argument(
-        "--smoke",
-        action="store_true",
-        help="Tiny lattice for CI: a couple of oracle compiles, not a "
-        "sweep (make tune-smoke).",
     )
     tune.add_argument(
         "--json",
@@ -3849,7 +3807,8 @@ def main(argv: list[str] | None = None) -> int:
         "--geometries",
         default=None,
         help="Board geometry presets to search (comma-separated names "
-        "from config.GEOMETRY_PRESETS, or 'plan' = the scale's board).",
+        "from config.GEOMETRY_PRESETS, or 'plan' = the base "
+        "configuration's board).",
     )
     tune.add_argument(
         "--kernel-backends",
@@ -3900,8 +3859,8 @@ def main(argv: list[str] | None = None) -> int:
         "--mode",
         default="auto",
         choices=["auto", "sync", "megastep"],
-        help="Loop shape being tuned (auto = megastep when the bench "
-        "plan would run device replay).",
+        help="Loop shape being tuned (auto = megastep when the base "
+        "configuration gets the device ring on this backend).",
     )
     tune.add_argument(
         "--device", default=None, choices=["auto", "tpu", "cpu"]

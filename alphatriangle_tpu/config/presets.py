@@ -1,7 +1,7 @@
 """The five BASELINE benchmark configurations as first-class presets.
 
-BASELINE.md lists the driver-mandated configs to measure (derived from
-BASELINE.json; the reference publishes no numbers of its own):
+BASELINE.md lists the configs to measure (the reference publishes no
+numbers of its own):
 
 1. Default TrainConfig, CNN-only net, 50 MCTS sims — CPU smoke.
 2. CNN-only net, 200 MCTS sims, batched leaf-eval on one TPU core.
@@ -19,8 +19,8 @@ count 1:1). Mesh sizes state the intended hardware; on fewer devices
 `MeshConfig(DP_SIZE=-1)` resolves to whatever is present, so every
 preset also runs single-chip or on the virtual CPU mesh.
 
-`bench.py` selects a preset via BENCH_CONFIG=1..5; the CLI via
-`train --preset N`.
+The CLI selects a preset via `train --preset N`; `cli warm N`,
+`cli fit N` and `cli tune N` answer for that run's shapes.
 """
 
 from .env_config import EnvConfig
@@ -197,7 +197,7 @@ def baseline_preset(
         # The flagship preset runs the measured-best training recipe:
         # Gumbel sequential-halving root + playout cap randomization
         # converged +11% above every other arm at under half the
-        # search cost (BASELINE.md A/Bs; docs/MCTS_DESIGN.md §d-e).
+        # search cost (BASELINE.md, config 3; docs/MCTS_DESIGN.md §d-e).
         # The other presets keep reference-parity PUCT so the BASELINE
         # table stays comparable config-for-config.
         mcts_kw.update(

@@ -84,8 +84,8 @@ class TelemetryConfig(BaseModel):
     # PER skew; per-fused-step grad/update norms) computed inside the
     # hot programs and returned through the EXISTING single
     # per-iteration fetch — no extra dispatch, no host sync. Ledgered
-    # as kind:"device_stats" records (`cli perf`, `cli watch`,
-    # bench.py) and fed to AnomalyDetector.observe_search.
+    # as kind:"device_stats" records (`cli perf`, `cli watch`) and fed
+    # to AnomalyDetector.observe_search.
     DEVICE_STATS: bool = Field(default=True)
     # Progress beacons (`jax.debug.callback` phase markers appended to
     # runs/<run>/beacons.jsonl) are OFF on hot paths by default; they

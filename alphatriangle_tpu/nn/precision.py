@@ -154,8 +154,7 @@ def cast_params_for_inference(variables, model_config: ModelConfig):
 
 def quantized_param_bytes(variables) -> int:
     """Total bytes of a variables pytree as the serve program reads it
-    (marker dicts count their int8 + scale buffers) — the
-    param-bytes-read number bench's precision A/B section reports."""
+    (marker dicts count their int8 + scale buffers)."""
     total = 0
     for leaf in jax.tree_util.tree_leaves(variables):
         total += int(leaf.size) * int(leaf.dtype.itemsize)

@@ -1,7 +1,7 @@
 """Anakin-style fused megastep: rollout chunk + ring ingest + K learner
 steps as ONE device program (Podracer, arXiv:2104.06272 §2 "Anakin").
 
-The round-5 bench showed the cost of host-orchestrated phases: the
+A pre-chip CPU measurement showed the cost of host-orchestrated phases: the
 overlapped loop ran at 0.774x of serialized self-play and fused learner
 steps gained nothing (0.44 -> 0.45 steps/s), because every iteration
 pays per-phase host round trips — dispatch chunk, fetch, fold, sample,
@@ -688,7 +688,7 @@ class MegastepRunner:
         # call sees all-committed outputs of the previous megastep — and
         # jit keys compiled executables on that placement mapping, so
         # without this the SECOND megastep silently recompiles the whole
-        # program (measured: a 48s duplicate compile at bench smoke
+        # program (measured: a 48s duplicate compile at a tiny CPU
         # scale). device_put is a no-op for anything already resident.
         return jax.device_put(args, jax.devices()[0])
 

@@ -255,7 +255,7 @@ class SelfPlayEngine:
         else:
             # Each distinct chunk length wraps its jitted program in
             # the AOT compile cache: a warm cache (cli warm, a prior
-            # bench/run with these shapes) deserializes the serialized
+            # run with these configs) deserializes the serialized
             # executable instead of paying the full first-chunk compile
             # — the heaviest program in the codebase, and the one that
             # burned every short healthy chip window in rounds 1-5.

@@ -1,7 +1,7 @@
 """Simulated concurrent-session load for the policy service.
 
-The serving smoke story (`cli serve --smoke`, `make serve-smoke`,
-bench's serve section): drive N concurrent simulated game sessions
+The serving smoke story (`cli serve --smoke`, `make serve-smoke`):
+drive N concurrent simulated game sessions
 through the continuous batcher with real churn — sessions retire as
 their games end and replacements are admitted mid-run, exactly the
 fluctuating-load shape the slot-array + padding design exists for.

@@ -13,8 +13,8 @@ lane-isolation argument), the Podracer acting-path pattern
 Composition of existing training plumbing, per the ROADMAP item:
 
 - **AOT warm start** — the search program is wrapped in the compile
-  cache as `serve/b<B>` (`cli warm` precompiles it alongside the bench
-  plan; a warmed `cli serve` starts answering in ~0.5 s instead of
+  cache as `serve/b<B>` (`cli warm` precompiles it alongside a preset's
+  training programs; a warmed `cli serve` starts answering in ~0.5 s instead of
   after a flagship-scale search compile).
 - **OOM pre-flight** — `analyze()` AOT-analyzes the serve program's
   HBM footprint without executing it (`estimate_fit(serve=True)`,

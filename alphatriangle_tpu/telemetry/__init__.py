@@ -29,8 +29,7 @@ to:
 
 Podracer-style stacks (arXiv:2104.06272) treat this visibility as a
 prerequisite for scaling an async producer/learner loop; the repo's own
-round-5 "10.3h with zero healthy windows" (BASELINE.md) is the local
-proof.
+pre-chip "10.3h with zero healthy windows" is the local proof.
 """
 
 import logging

@@ -15,7 +15,7 @@ data already lives — inside the search waves, the rollout chunk, the
 PER sample and the fused learner steps — and returned through the
 EXISTING single per-iteration fetch as one more leaf of the output
 pytree. The host folds them into ``kind:"device_stats"`` ledger records
-(`cli perf`, `cli watch`, `bench.py extra.device_stats`) and feeds them
+(`cli perf`, `cli watch`) and feeds them
 to `AnomalyDetector.observe_search` so a value explosion or an entropy
 collapse is attributed to the exact fused step, not the iteration
 aggregate.
@@ -39,7 +39,6 @@ readers here beside a wedged chip. Only `emit_beacon` (called from
 traced code) imports jax, lazily.
 """
 
-import json
 import logging
 import os
 import threading
@@ -463,24 +462,3 @@ def summarize_device_stats(records: list) -> "dict | None":
         "ds_update_norm_max": _max(leg("learner", "update_norm_max")),
         "ds_serve_root_entropy": _mean(leg("serve", "root_entropy")),
     }
-
-
-def device_stats_json(records: list) -> "dict | None":
-    """The `bench.py extra.device_stats` block: the perf-summary fold
-    plus the newest raw record (depth histogram included) — enough for
-    a BENCH snapshot to show what the searches actually did."""
-    summary = summarize_device_stats(records)
-    if summary is None:
-        return None
-    newest = next(
-        (
-            r
-            for r in reversed(records)
-            if isinstance(r, dict) and r.get("kind") == DEVICE_STATS_KIND
-        ),
-        None,
-    )
-    if newest is not None:
-        # deep-copy through json so callers can mutate freely
-        summary["last_record"] = json.loads(json.dumps(newest, default=str))
-    return summary

@@ -3,7 +3,7 @@ dispatch, plus the postmortem readers behind `cli doctor`.
 
 Everything built before this module (tracer, ledger, heartbeat)
 observes a *live* process; round 5 burned 10.3 h on a wedged chip that
-left no record of what it was doing when it died (BASELINE.md). The
+left no record of what it was doing when it died. The
 fused megastep makes the blind spot worse: the whole
 rollout+ingest+K-step iteration is ONE opaque device program. This
 module closes it:
@@ -103,8 +103,8 @@ def program_family(program: str) -> str:
     if head == "reuse":
         # Standalone subtree-promotion programs (`reuse/promote_*`,
         # ops/subtree_reuse.py): the training/serve paths fuse the
-        # promotion into their own dispatches, but the parity bench and
-        # smoke run it as its own hot program — same forensics contract.
+        # promotion into their own dispatches, but the parity tests
+        # run it as its own hot program — same forensics contract.
         return "reuse"
     return head
 

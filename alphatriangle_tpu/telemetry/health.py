@@ -1,7 +1,7 @@
 """Run liveness: heartbeat file + stall watchdog.
 
 The round-5 baseline recorded a 10.3-hour window in which nothing
-progressed and nothing said so (BASELINE.md). This module turns that
+progressed and nothing said so. This module turns that
 silent failure mode into a diagnosable artifact:
 
 - `HealthMonitor`: subsystems beat it (learner step landed, rollout
@@ -10,7 +10,7 @@ silent failure mode into a diagnosable artifact:
   learner step, last-progress ages, buffer size, per-device memory via
   `jax.local_devices()[*].memory_stats()`, wall + monotonic stamps.
   Written atomically, readable by processes that never import JAX
-  (`alphatriangle-tpu health`, `cli watch`, the bench supervisor).
+  (`alphatriangle-tpu health`, `cli watch`, `cli supervise`).
 - `Watchdog`: a daemon thread that compares monotonic now against the
   last recorded progress; past the deadline it fires ONCE per stall —
   dumping every thread's stack via `faulthandler` into the run dir,

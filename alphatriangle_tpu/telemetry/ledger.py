@@ -1,9 +1,8 @@
 """Durable per-run metrics ledger: `metrics.jsonl` in the run dir.
 
 The StatsCollector's series live in memory and die with the process;
-TensorBoard event files need TensorBoard to read back; the five
-`BENCH_r0*.json` snapshots are the entire cross-run record. This module
-is the persistence tier under all of them: every processed metric batch
+TensorBoard event files need TensorBoard to read back. This module is
+the persistence tier under both: every processed metric batch
 (`kind: "tick"`), every derived utilization record (`kind: "util"`,
 telemetry/perf.py — including per-device memory in-use/peak fields) and
 every memory-attribution record (`kind: "memory"`, telemetry/memory.py

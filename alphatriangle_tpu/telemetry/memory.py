@@ -428,9 +428,8 @@ def sharded_megastep_dp(train_config) -> int:
     """dp width the sharded megastep family (`megastep/dp<D>_t<T>_k<K>`)
     would run at in THIS process: the device count when the geometry
     divides like the training-time gate (training/setup.py's
-    `_make_buffer`), else 1 (the single-device family). Shared by
-    `estimate_fit` and `cli warm` so pre-flight and warm target the
-    program the run will actually dispatch."""
+    `_make_buffer`), else 1 (the single-device family): `estimate_fit`
+    analyzes the program the run will actually dispatch."""
     import jax
 
     dp = jax.device_count()
@@ -476,8 +475,8 @@ def estimate_fit(
     gather's transient is bounded by the fused program's. `megastep`
     additionally analyzes the fused-megastep program (rl/megastep.py) —
     this one DOES allocate the configured ring (its storage is a
-    program argument), so it is opt-in; `cli fit` enables it since its
-    bench-plan capacities are small. `serve` additionally analyzes the
+    program argument), so it is opt-in; `cli fit` enables it for the
+    configs that run it (`FUSED_MEGASTEP`). `serve` additionally analyzes the
     policy service's `serve/b<B>` search program (serving/service.py;
     B = `serve_batch`, default the self-play lane count) and persists
     its `.mem.json` sidecar — the OOM pre-flight `cli serve` runs

@@ -1,8 +1,7 @@
 """Live utilization accounting + cross-run performance comparison.
 
-Before this module, MFU/FLOPs accounting ran only inside one-shot
-`bench.py` snapshots; a real training run reported throughput but never
-what fraction of the chip it used, and nothing could compare two runs.
+Without this module a training run reports throughput but never what
+fraction of the chip it used, and nothing can compare two runs.
 Podracer (arXiv:2104.06272) and KataGo (arXiv:1902.10565) both treat
 continuous utilization accounting as the steering signal for
 accelerator-RL work — this is that tier:
@@ -18,7 +17,7 @@ accelerator-RL work — this is that tier:
   windowed summary `cli perf` prints (p50/p95 step time, MFU,
   throughput trend).
 - `load_comparable` + `compare_summaries` align two runs (or a run
-  and a `BENCH_*.json` snapshot) metric-by-metric and report
+  and a perf-summary snapshot) metric-by-metric and report
   regressions against a threshold — the CI/supervisor gate
   `cli compare` exposes as exit codes.
 
@@ -650,39 +649,15 @@ def summarize_fleet(records: list) -> "dict | None":
 # --- cross-run comparison ----------------------------------------------
 
 
-def _summary_from_bench(payload: dict, label: str) -> "dict | None":
-    """Normalize one `bench.py` JSON line into compare metrics."""
-    if payload.get("metric") != "self_play_games_per_hour":
-        return None
-    extra = payload.get("extra") or {}
-    flops = extra.get("flops") or {}
-    return {
-        "schema": SUMMARY_SCHEMA,
-        "source": label,
-        "games_per_hour": payload.get("value"),
-        "moves_per_sec": extra.get("moves_per_sec"),
-        "leaf_evals_per_sec": extra.get("leaf_evals_per_sec"),
-        "mcts_reused_visit_fraction": extra.get(
-            "mcts_reused_visit_fraction"
-        ),
-        "learner_steps_per_sec": (
-            extra.get("learner_steps_per_sec_fused")
-            or extra.get("learner_steps_per_sec")
-        ),
-        "mfu": flops.get("self_play_mfu"),
-        "device_kind": extra.get("device_kind"),
-    }
-
-
 def load_comparable(
     target: str, root_dir: "str | None" = None
 ) -> "tuple[dict | None, str]":
     """(normalized summary, label) for one side of `cli compare`.
 
     Accepts, in resolution order: a perf-summary JSON file (from
-    `cli perf --json`), a bench JSON line file (`BENCH_*.json`), a
-    `metrics.jsonl` path, a run directory, or a run name under the
-    runs root. Returns (None, reason) when nothing usable exists.
+    `cli perf --json`), a `metrics.jsonl` path, a run directory, or a
+    run name under the runs root. Returns (None, reason) when nothing
+    usable exists.
     """
     from .ledger import read_ledger, resolve_ledger_path
 
@@ -696,10 +671,7 @@ def load_comparable(
             if payload.get("schema") == SUMMARY_SCHEMA:
                 payload.setdefault("source", str(path))
                 return payload, str(path)
-            bench = _summary_from_bench(payload, str(path))
-            if bench is not None:
-                return bench, str(path)
-        return None, f"{target}: not a perf summary or bench JSON"
+        return None, f"{target}: not a perf summary"
     if path.exists():
         ledger = resolve_ledger_path(path)
     else:

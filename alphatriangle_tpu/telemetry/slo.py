@@ -220,7 +220,7 @@ def evaluate_slos(
     `now` defaults to the newest sample time across all SLOs (a
     finished run's budget is judged at the moment it ended); pass an
     explicit epoch time to replay the alert state at a point in time
-    (the brownout-window check in benchmarks/trace_smoke.py).
+    (the brownout-window check in tests/test_tracing.py).
     """
     run_dir = Path(run_dir)
     slos = collect_slos(

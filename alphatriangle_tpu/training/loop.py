@@ -122,7 +122,7 @@ class TrainingLoop:
             run_name=components.persistence_config.RUN_NAME,
         )
         components.telemetry = self.telemetry
-        # Manually assembled components (tests, bench harnesses) skip
+        # Manually assembled components (tests, the benchmark's drivers) skip
         # training/setup.py's flight attach; wire the recorder here so
         # every construction path records dispatches.
         for c in (components.self_play, components.trainer):

@@ -9,6 +9,7 @@ are no actors to tear down on failure.
 
 import logging
 import os
+from typing import NamedTuple
 
 from ..config.env_config import EnvConfig
 from ..config.mcts_config import MCTSConfig
@@ -80,6 +81,24 @@ def clamp_self_play_workers(requested: int) -> int:
     return requested
 
 
+def wants_device_ring(train_config: TrainConfig) -> bool:
+    """Whether a run of `train_config` on this backend asks for the
+    device-resident replay ring (`_make_buffer` grants it when the mesh
+    allows). "auto" requires an accelerator backend: on the CPU backend
+    host NumPy and "device" memory are the same RAM, so the scatter
+    program would add overhead for nothing. The megastep requires the
+    ring wherever it runs (the CPU backend included), exactly like an
+    explicit "on"."""
+    import jax
+
+    mode = train_config.DEVICE_REPLAY
+    return (
+        mode == "on"
+        or (mode == "auto" and jax.default_backend() != "cpu")
+        or train_config.FUSED_MEGASTEP
+    )
+
+
 def _make_buffer(
     train_config: TrainConfig,
     env_config: EnvConfig,
@@ -99,10 +118,8 @@ def _make_buffer(
       experience path;
     - anything else -> host buffer.
 
-    "auto" additionally requires an accelerator backend: on the CPU
-    backend host NumPy and "device" memory are the same RAM, so the
-    scatter program would add overhead for nothing ("on" still forces
-    it there — tests do).
+    Whether the run asks for a device ring at all: `wants_device_ring`
+    ("on" forces it on the CPU backend too — tests do).
     """
     import jax
 
@@ -139,14 +156,7 @@ def _make_buffer(
             f"(got {dict(mesh.shape)}, {jax.process_count()} "
             "processes)."
         )
-    want = (
-        mode == "on"
-        or (mode == "auto" and jax.default_backend() != "cpu")
-        # Megastep requires the device ring wherever it runs (the CPU
-        # backend included — the smoke/parity tier), exactly like an
-        # explicit "on".
-        or train_config.FUSED_MEGASTEP
-    )
+    want = wants_device_ring(train_config)
     if mode == "on" and not (single or sharded_ok):
         # An explicit force that can't be honored must not silently
         # substitute the other code path.
@@ -198,44 +208,41 @@ def _make_buffer(
     return ExperienceBuffer(train_config, action_dim=env_config.action_dim)
 
 
-def setup_training_components(
-    train_config: TrainConfig | None = None,
-    env_config: EnvConfig | None = None,
-    model_config: ModelConfig | None = None,
-    mcts_config: MCTSConfig | None = None,
-    mesh_config: MeshConfig | None = None,
-    persistence_config: PersistenceConfig | None = None,
-    telemetry_config: TelemetryConfig | None = None,
-    use_tensorboard: bool = True,
-) -> TrainingComponents:
-    """Validate configs and build every training component."""
-    configs = print_config_info_and_validate(
-        env=env_config,
-        model=model_config,
-        train=train_config,
-        mcts=mcts_config,
-        mesh=mesh_config,
-        persistence=persistence_config,
-    )
-    env_config = configs["env"]
-    model_config = configs["model"]
-    train_config = configs["train"]
-    mcts_config = configs["mcts"]
-    mesh_config = configs["mesh"]
-    persistence_config = configs["persistence"]
-    # The run's artifacts live under its RUN_NAME.
-    if persistence_config.RUN_NAME != train_config.RUN_NAME:
-        persistence_config = persistence_config.model_copy(
-            update={"RUN_NAME": train_config.RUN_NAME}
-        )
+class RunPrograms(NamedTuple):
+    """The objects that own a run's compiled programs."""
 
+    mesh: object
+    env: TriangleEnv
+    extractor: object
+    net: NeuralNetwork
+    trainer: Trainer
+    buffer: ExperienceBuffer
+    self_play: SelfPlayEngine
+    megastep: object
+
+
+def build_run_programs(
+    env_config: EnvConfig,
+    model_config: ModelConfig,
+    mcts_config: MCTSConfig,
+    train_config: TrainConfig,
+    mesh_config: MeshConfig,
+    telemetry_config: TelemetryConfig,
+) -> RunPrograms:
+    """Mesh, net, trainer, replay ring, rollout engine and (under
+    FUSED_MEGASTEP) the megastep runner for one set of configs.
+
+    `setup_training_components` and `warm.warm_programs` both build
+    through here: a program's AOT cache key covers its configs, the
+    device-stats flag, the mesh and the ring it takes as an argument, so
+    `cli warm` pays off only if it constructs exactly what the run
+    constructs."""
     # Resolve the telemetry config FIRST and publish the device-stats
     # flag process-wide: engines snapshot it at CONSTRUCTION (it shapes
     # their compiled programs and joins the AOT cache digests), so the
     # flag must be settled before SelfPlayEngine/Trainer exist. Set on
     # every process unconditionally — a primary-only gate would compile
     # DIFFERENT programs per process and deadlock a multi-host mesh.
-    telemetry_config = telemetry_config or TelemetryConfig()
     from ..telemetry.device_stats import set_device_stats
 
     set_device_stats(
@@ -337,6 +344,53 @@ def setup_training_components(
             or max(1, train_config.FUSED_LEARNER_STEPS),
             megastep_runner.dp,
         )
+    return RunPrograms(
+        mesh, env, extractor, net, trainer, buffer, self_play, megastep_runner
+    )
+
+
+def setup_training_components(
+    train_config: TrainConfig | None = None,
+    env_config: EnvConfig | None = None,
+    model_config: ModelConfig | None = None,
+    mcts_config: MCTSConfig | None = None,
+    mesh_config: MeshConfig | None = None,
+    persistence_config: PersistenceConfig | None = None,
+    telemetry_config: TelemetryConfig | None = None,
+    use_tensorboard: bool = True,
+) -> TrainingComponents:
+    """Validate configs and build every training component."""
+    configs = print_config_info_and_validate(
+        env=env_config,
+        model=model_config,
+        train=train_config,
+        mcts=mcts_config,
+        mesh=mesh_config,
+        persistence=persistence_config,
+    )
+    env_config = configs["env"]
+    model_config = configs["model"]
+    train_config = configs["train"]
+    mcts_config = configs["mcts"]
+    mesh_config = configs["mesh"]
+    persistence_config = configs["persistence"]
+    # The run's artifacts live under its RUN_NAME.
+    if persistence_config.RUN_NAME != train_config.RUN_NAME:
+        persistence_config = persistence_config.model_copy(
+            update={"RUN_NAME": train_config.RUN_NAME}
+        )
+
+    telemetry_config = telemetry_config or TelemetryConfig()
+    mesh, env, extractor, net, trainer, buffer, self_play, megastep_runner = (
+        build_run_programs(
+            env_config,
+            model_config,
+            mcts_config,
+            train_config,
+            mesh_config,
+            telemetry_config,
+        )
+    )
     # TensorBoard and the live-console JSONL are singleton host-side
     # work: process 0 only (N processes appending one shared file would
     # interleave diverging step/episode lines and corrupt `cli watch`'s
@@ -350,7 +404,6 @@ def setup_training_components(
     # Telemetry (spans + heartbeat + watchdog + anomaly screening) is a
     # primary-process concern like the live file: N hosts rewriting one
     # shared health.json would interleave diverging heartbeats.
-    telemetry_config = telemetry_config or TelemetryConfig()
     if not is_primary():
         telemetry_config = telemetry_config.model_copy(
             update={"ENABLED": False}

@@ -1,25 +1,26 @@
-"""AOT precompilation of the hot bench/training programs (`cli warm`).
+"""AOT precompilation of a run's hot programs (`cli warm`).
 
-The compile-latency story (docs/COMPILE_CACHE.md): every program the
-bench dispatches inside its measurement window — the self-play rollout
-chunk (with its embedded PUCT/Gumbel search), the learner step, the
-fused K-step group, the device-replay gather variant, the overlapped
-dispatch's bigger fused group — can be lowered and compiled BEFORE a
-healthy chip window opens, with the executables serialized through
-`compile_cache.CompileCache`. A later bench/training process with the
-same shapes then deserializes in milliseconds instead of compiling for
-the better part of a minute per program.
+The compile-latency story (docs/COMPILE_CACHE.md): every program a
+training run dispatches in its loop — the self-play rollout chunk (with
+its embedded PUCT/Gumbel search), the fused K-step learner group and
+its K=1 tail, or the megastep that holds both — can be lowered and
+compiled BEFORE a healthy chip window opens, with the executables
+serialized through `compile_cache.CompileCache`. A later process with
+the same configs then deserializes in milliseconds instead of compiling
+for the better part of a minute per program.
 
-`warm_bench_programs` builds the exact objects `bench.py` builds (via
-the shared `bench_config.resolve_bench_plan`) and pushes each hot
-program through `.warm()` in parallel threads — XLA compilation
-releases the GIL, so N programs compile concurrently, Podracer-style
-(arXiv:2104.06272 amortizes program build cost off the critical path).
-
-`cli warm <tuned_preset.json>` warms an autotuned configuration's
-shapes instead (the artifact rides in as BENCH_TUNED_PRESET through
-the same `resolve_bench_plan` path; docs/AUTOTUNE.md), so a tuned run
-launched afterwards starts hot.
+`warm_programs` takes the config bundle `cli train --preset` runs
+(`config.presets.baseline_preset(n)` or a `cli tune` artifact through
+`load_tuned_preset`) and builds its objects through
+`training.setup.build_run_programs`, the constructor sequence
+`setup_training_components` itself uses: a program's cache key covers
+its whole config digest, the device-stats flag, the mesh and the ring
+it takes as an argument, so only the run's own construction yields the
+run's own keys (held by tests/test_compile_cache.py
+`test_warm_then_setup_hits`). Each program goes through `.warm()` in
+parallel threads — XLA compilation releases the GIL, so N programs
+compile concurrently, Podracer-style (arXiv:2104.06272 amortizes
+program build cost off the critical path).
 """
 
 import concurrent.futures
@@ -29,26 +30,28 @@ import time
 logger = logging.getLogger(__name__)
 
 
-def warm_bench_programs(
-    plan,
+def warm_programs(
+    bundle: dict,
     jobs: int = 4,
     programs: "set[str] | None" = None,
     progress=None,
 ) -> dict:
-    """AOT-compile the hot programs for one bench plan.
+    """AOT-compile the programs a run of `bundle` dispatches.
 
-    `programs`: optional name filter (substring match against the rows
-    below). `progress`: optional callable(str) for per-program lines.
-    Returns {"programs": [...rows...], "stats": CompileCache.stats(),
-    "seconds": total wall}.
+    `bundle`: {env, model, mcts, train, mesh} configs, plus `tuned` (a
+    `cli tune` artifact's payload, whose `kernels.serve_buckets` names
+    the serve ladder) when it came from one. `programs`: optional name
+    filter (substring match against the rows below). `progress`:
+    optional callable(str) for per-program lines. Returns {"programs":
+    [...rows...], "stats": CompileCache.stats(), "seconds": total wall}.
     """
     import jax
 
+    from .autotune.artifact import serve_ladder
     from .compile_cache import get_compile_cache
-    from .env.engine import TriangleEnv
-    from .features.core import get_feature_extractor
-    from .nn.network import NeuralNetwork
-    from .rl import SelfPlayEngine, Trainer
+    from .config import TelemetryConfig
+    from .config.validation import print_config_info_and_validate
+    from .training.setup import build_run_programs
 
     def say(msg: str) -> None:
         logger.info(msg)
@@ -56,205 +59,128 @@ def warm_bench_programs(
             progress(msg)
 
     t_start = time.time()
-    backend = jax.default_backend()
     cache = get_compile_cache()
+    configs = print_config_info_and_validate(
+        env=bundle["env"],
+        model=bundle["model"],
+        train=bundle["train"],
+        mcts=bundle["mcts"],
+        mesh=bundle.get("mesh"),
+    )
+    train, mcts_config = configs["train"], configs["mcts"]
+    chunk = train.ROLLOUT_CHUNK_MOVES
+    lbatch = train.BATCH_SIZE
+    fused_k = max(1, train.FUSED_LEARNER_STEPS)
     say(
-        f"warm: backend={backend} scale={plan.scale} "
-        f"batch={plan.sp_batch} chunk={plan.chunk} sims={plan.sims} "
-        f"cache={cache.cache_dir}"
+        f"warm: backend={jax.default_backend()} "
+        f"batch={train.SELF_PLAY_BATCH_SIZE} chunk={chunk} "
+        f"sims={mcts_config.max_simulations} k={fused_k} "
+        f"ring={train.BUFFER_CAPACITY} cache={cache.cache_dir}"
     )
-
-    # Exactly the construction sequence run_bench performs — the cache
-    # signatures must match the bench's dispatch arguments bit for bit.
-    env = TriangleEnv(plan.env)
-    extractor = get_feature_extractor(env, plan.model)
-    net = NeuralNetwork(plan.model, plan.env, seed=0)
-    engine = SelfPlayEngine(
-        env, extractor, net, plan.mcts, plan.train, seed=0
+    # `cli train` runs with the default TelemetryConfig unless told
+    # otherwise; its DEVICE_STATS flag is part of the chunk's key.
+    built = build_run_programs(
+        configs["env"],
+        configs["model"],
+        mcts_config,
+        train,
+        configs["mesh"],
+        TelemetryConfig(),
     )
-    trainer = Trainer(net, plan.train)
+    trainer, buffer = built.trainer, built.buffer
 
     # Learner programs cannot AOT-cache on the CPU backend (reloaded
     # executables return the donated train state unchanged — see the
     # cpu_aot note in rl/trainer.py); report them as skipped instead of
-    # as failures so `cli warm cpu/smoke` still exits 0 when everything
-    # warmable is warm.
+    # as failures so a CPU warm still exits 0 when everything warmable
+    # is warm.
     learner_fn = (lambda fn: fn) if trainer.aot_enabled else (lambda fn: None)
     targets: list[tuple[str, object]] = [
         (
-            f"self_play_chunk/t{plan.chunk}",
-            lambda: engine.warm_chunk(plan.chunk),
-        ),
-        (
-            f"learner_step/b{plan.lbatch}",
-            learner_fn(lambda: trainer.warm_step(plan.lbatch)),
-        ),
-        (
-            f"learner_fused/k{plan.fused_k}",
-            learner_fn(
-                lambda: trainer.warm_steps(plan.fused_k, plan.lbatch)
-            ),
-        ),
+            f"self_play_chunk/t{chunk}",
+            lambda: built.self_play.warm_chunk(chunk),
+        )
     ]
-    if plan.overlap_k != plan.fused_k and not plan.device_replay:
-        targets.append(
-            (
-                f"learner_fused/k{plan.overlap_k}",
-                learner_fn(
-                    lambda: trainer.warm_steps(plan.overlap_k, plan.lbatch)
-                ),
-            )
-        )
-    if plan.device_replay:
-        from .rl.device_buffer import DeviceReplayBuffer
-
-        dev_buffer = DeviceReplayBuffer(
-            plan.train,
-            grid_shape=(
-                plan.model.GRID_INPUT_CHANNELS,
-                plan.env.ROWS,
-                plan.env.COLS,
-            ),
-            other_dim=extractor.other_dim,
-            action_dim=plan.env.action_dim,
+    # The learner's side, by the loop mode the configs select
+    # (training/loop.py): the megastep alone; else fused groups of K
+    # from the device ring or from host batches, and the K=1 program a
+    # group shorter than K falls back to.
+    if built.megastep is not None:
+        runner = built.megastep
+        mega_k = train.LEARNER_STEPS_PER_ROLLOUT or fused_k
+        name = (
+            f"megastep/dp{runner.dp}_t{chunk}_k{mega_k}"
+            if runner.sharded
+            else f"megastep/t{chunk}_k{mega_k}"
         )
         targets.append(
-            (
-                f"learner_from_ring/k{plan.fused_k}",
-                learner_fn(
-                    lambda: trainer.warm_steps_from(
-                        dev_buffer, plan.fused_k, plan.lbatch
-                    )
-                ),
-            )
+            (name, learner_fn(lambda: runner.warm_megastep(chunk, mega_k)))
         )
-        if plan.overlap_k != plan.fused_k:
+    elif getattr(buffer, "is_device", False):
+        for k in sorted({fused_k, 1}, reverse=True):
             targets.append(
                 (
-                    f"learner_from_ring/k{plan.overlap_k}",
+                    f"learner_from_ring/k{k}",
                     learner_fn(
-                        lambda: trainer.warm_steps_from(
-                            dev_buffer, plan.overlap_k, plan.lbatch
-                        )
+                        lambda k=k: trainer.warm_steps_from(buffer, k, lbatch)
                     ),
                 )
             )
-    # Fused megastep (rl/megastep.py): the whole iteration as one
-    # program. Contains learner steps, so it is CPU-bypassed like the
-    # learner family (row reports skipped-cpu there); the runner/ring
-    # are only constructed when the warm will actually run.
-    mega_fn = None
-    if trainer.aot_enabled:
-        from .rl.device_buffer import DeviceReplayBuffer
-        from .rl.megastep import MegastepRunner
-
-        mega_buffer = DeviceReplayBuffer(
-            plan.train,
-            grid_shape=(
-                plan.model.GRID_INPUT_CHANNELS,
-                plan.env.ROWS,
-                plan.env.COLS,
-            ),
-            other_dim=extractor.other_dim,
-            action_dim=plan.env.action_dim,
-        )
-        runner = MegastepRunner(engine, trainer, mega_buffer, plan.train)
-        mega_fn = lambda: runner.warm_megastep(plan.chunk, plan.fused_k)
-    targets.append(
-        (f"megastep/t{plan.chunk}_k{plan.fused_k}", mega_fn)
-    )
-    # dp-sharded megastep family (megastep/dp<D>_t<T>_k<K>): when this
-    # process has a multi-device mesh and the plan's geometry divides
-    # (same gate as training/setup.py), warm the program a sharded run
-    # will actually dispatch — mesh-built engine/trainer/ring, because
-    # the cache signature covers the shardings.
-    from .telemetry.memory import sharded_megastep_dp
-
-    mega_dp = sharded_megastep_dp(plan.train)
-    if mega_dp > 1:
-        mega_dp_fn = None
-        if trainer.aot_enabled:
-            from .config.mesh_config import MeshConfig
-            from .rl.megastep import MegastepRunner
-            from .rl.sharded_device_buffer import ShardedDeviceReplayBuffer
-
-            mesh = MeshConfig(DP_SIZE=mega_dp).build_mesh()
-            dp_engine = SelfPlayEngine(
-                env, extractor, net, plan.mcts, plan.train, seed=0,
-                mesh=mesh,
-            )
-            dp_trainer = Trainer(net, plan.train, mesh=mesh)
-            dp_ring = ShardedDeviceReplayBuffer(
-                plan.train,
-                grid_shape=(
-                    plan.model.GRID_INPUT_CHANNELS,
-                    plan.env.ROWS,
-                    plan.env.COLS,
-                ),
-                other_dim=extractor.other_dim,
-                action_dim=plan.env.action_dim,
-                mesh=mesh,
-            )
-            dp_runner = MegastepRunner(
-                dp_engine, dp_trainer, dp_ring, plan.train
-            )
-            mega_dp_fn = lambda: dp_runner.warm_megastep(
-                plan.chunk, plan.fused_k
+    else:
+        if fused_k > 1:
+            targets.append(
+                (
+                    f"learner_fused/k{fused_k}",
+                    learner_fn(lambda: trainer.warm_steps(fused_k, lbatch)),
+                )
             )
         targets.append(
             (
-                f"megastep/dp{mega_dp}_t{plan.chunk}_k{plan.fused_k}",
-                mega_dp_fn,
+                f"learner_step/b{lbatch}",
+                learner_fn(lambda: trainer.warm_step(lbatch)),
             )
         )
     # Policy-service search shape (serving/service.py): warming
     # `serve/b<B>` is what turns `cli serve` startup from a flagship
     # search compile into a ~0.5s deserialize. The search program has
     # no donated buffers, so (unlike the learner family) its AOT
-    # artifacts are safe on every backend. The service's search kind
-    # follows the plan's root-selection recipe: Gumbel recipes serve
-    # exploit-mode Gumbel (the deterministic arm `cli eval --gumbel`
-    # and `cli serve --gumbel` dispatch), PUCT recipes serve PUCT.
-    if plan.serve_batch > 0:
-        from .serving import PolicyService
+    # artifacts are safe on every backend. One rung at the self-play
+    # lane count (the same MXU-batch family as the rollout's search),
+    # or the ladder a tuned artifact's winner was scored with. The
+    # service's search kind follows the bundle's root-selection recipe:
+    # Gumbel recipes serve exploit-mode Gumbel (the deterministic arm
+    # `cli eval --gumbel` and `cli serve --gumbel` dispatch), PUCT
+    # recipes serve PUCT.
+    from .mcts import BatchedMCTS, GumbelMCTS
+    from .serving import PolicyService
 
-        serve_gumbel = (
-            getattr(plan.mcts, "root_selection", "puct") == "gumbel"
+    env, extractor, net = built.env, built.extractor, built.net
+    serve_gumbel = mcts_config.root_selection == "gumbel"
+    if serve_gumbel:
+        serve_mcts = GumbelMCTS(
+            env, extractor, net.model, mcts_config, net.support, exploit=True
         )
-        if serve_gumbel:
-            from .mcts import GumbelMCTS
-
-            serve_mcts = GumbelMCTS(
-                env, extractor, net.model, plan.mcts, net.support,
-                exploit=True,
-            )
-        else:
-            from .mcts import BatchedMCTS
-
-            serve_mcts = BatchedMCTS(
-                env, extractor, net.model, plan.mcts, net.support
-            )
-        serve_service = PolicyService(
-            env,
-            extractor,
-            net,
-            serve_mcts,
-            slots=plan.serve_batch,
-            use_gumbel=serve_gumbel,
-            ladder=plan.serve_buckets,
+    else:
+        serve_mcts = BatchedMCTS(
+            env, extractor, net.model, mcts_config, net.support
         )
-        # One row per ladder rung (serving/buckets.py): the
-        # micro-batcher promises zero-recompile rung switches, which
-        # only holds if EVERY rung's program is warmed up front — for
-        # the active inference precision (the precision digest keys the
-        # cache entries apart).
-        for rung in serve_service.ladder.rungs:
-            targets.append(
-                (
-                    f"serve/b{rung}",
-                    lambda r=rung: serve_service.warm_rung(r),
-                )
-            )
+    serve_service = PolicyService(
+        env,
+        extractor,
+        net,
+        serve_mcts,
+        slots=train.SELF_PLAY_BATCH_SIZE,
+        use_gumbel=serve_gumbel,
+        ladder=serve_ladder(bundle),
+    )
+    # One row per ladder rung (serving/buckets.py): the micro-batcher
+    # promises zero-recompile rung switches, which only holds if EVERY
+    # rung's program is warmed up front — for the active inference
+    # precision (the precision digest keys the cache entries apart).
+    for rung in serve_service.ladder.rungs:
+        targets.append(
+            (f"serve/b{rung}", lambda r=rung: serve_service.warm_rung(r))
+        )
     if programs:
         targets = [
             (name, fn)
@@ -292,3 +218,4 @@ def warm_bench_programs(
         f"{stats['misses']} miss(es) now serialized for the next process"
     )
     return {"programs": rows, "stats": stats, "seconds": round(total, 1)}
+

@@ -12,8 +12,9 @@ accelerator, that the metrics-ledger pipeline end to end still works:
    `mem_bytes_in_use` on the utilization records;
 3. `cli perf <run>` summarizes it — exit 2 there means the ledger
    schema broke;
-4. `cli fit cpu` composes the CPU-scale static memory budget against
-   the host byte limit and must exit 0 (the OOM pre-flight gate);
+4. `cli fit 1` composes preset 1's static memory budget (the CPU
+   configuration of BASELINE.md) against the host byte limit and must
+   exit 0 (the OOM pre-flight gate);
 5. `cli compare <run> benchmarks/perf_reference_cpu_smoke.json`
    gates against the checked-in reference summary. The threshold is
    deliberately generous (default 0.9: fail only on a >90% collapse)
@@ -351,10 +352,10 @@ def main() -> int:
         print(f"perf-smoke: cli perf failed (rc={rc})", file=sys.stderr)
         return rc
 
-    print("perf-smoke: cli fit cpu (OOM pre-flight gate)...", flush=True)
-    rc = cli_main(["fit", "cpu"])
+    print("perf-smoke: cli fit 1 (OOM pre-flight gate)...", flush=True)
+    rc = cli_main(["fit", "1"])
     if rc != 0:
-        print(f"perf-smoke: cli fit cpu failed (rc={rc})", file=sys.stderr)
+        print(f"perf-smoke: cli fit 1 failed (rc={rc})", file=sys.stderr)
         return rc
 
     print("perf-smoke: fused-megastep mode gate...", flush=True)

@@ -1,4 +1,4 @@
-"""CI serve-smoke gate: `cli serve --smoke` -> `cli perf`/`cli compare`.
+"""CI serve-smoke gate: `cli serve --smoke` -> `cli perf`.
 
 `make serve-smoke` runs this. It proves, on any machine with no
 accelerator, that the policy-serving front end (docs/SERVING.md) works
@@ -22,18 +22,10 @@ end to end:
    buckets must show the walk (max above the base rung, final below
    the max);
 3. `cli perf <serve_run> --json` must summarize them, serve_bucket /
-   serve_fill included (exit 2 = the ledger schema broke);
-4. `cli compare <serve_run> benchmarks/perf_reference_cpu_smoke.json
-   --metrics serve_move_latency_ms_p95,serve_requests_per_sec` gates
-   the serve SLO rows against the checked-in reference. The threshold
-   is deliberately generous (default 3.0: fail only on a 4x latency
-   blowup) because CI hosts vary wildly in speed — the hard signal is
-   schema alignment plus "not catastrophically slower".
+   serve_fill included (exit 2 = the ledger schema broke).
 
 Exit 0 when every stage passes; the first failing stage's code
-otherwise. `--write-reference` merges this run's `serve_*` summary
-fields into perf_reference_cpu_smoke.json (preserving the training
-smoke's fields — the two smokes share one reference file).
+otherwise.
 """
 
 import argparse
@@ -47,7 +39,6 @@ import tempfile
 from pathlib import Path
 
 REPO = Path(__file__).resolve().parent.parent
-REFERENCE = Path(__file__).resolve().parent / "perf_reference_cpu_smoke.json"
 RUN_NAME = "serve_smoke"
 
 if str(REPO) not in sys.path:
@@ -58,7 +49,6 @@ if str(REPO) not in sys.path:
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 os.environ.setdefault("ALPHATRIANGLE_PEAK_TFLOPS", "1.0")
 
-SERVE_METRICS = "serve_move_latency_ms_p95,serve_requests_per_sec"
 BASE_RUNG = 16  # starting serve shape — the burst must outgrow it
 BUCKETS = "16,32,64"  # the ladder the storm walks (serving/buckets.py)
 SLOTS = 64  # top rung: >= 64 concurrent sessions (the acceptance bar)
@@ -68,21 +58,9 @@ SESSIONS = 96  # > SLOTS forces admit/retire churn mid-run
 def main() -> int:
     parser = argparse.ArgumentParser(description=__doc__)
     parser.add_argument(
-        "--threshold",
-        type=float,
-        default=3.0,
-        help="compare tolerance vs the checked-in serve reference "
-        "(generous by design: CI hosts vary in speed).",
-    )
-    parser.add_argument(
         "--root-dir",
         default=None,
         help="Runs root for the smoke (default: a temp dir).",
-    )
-    parser.add_argument(
-        "--write-reference",
-        action="store_true",
-        help=f"Merge this run's serve_* summary into {REFERENCE.name}.",
     )
     args = parser.parse_args()
 
@@ -283,40 +261,6 @@ def main() -> int:
         f"{summary['serve_requests_per_sec']:.0f} req/s"
     )
 
-    if args.write_reference:
-        reference = (
-            json.loads(REFERENCE.read_text()) if REFERENCE.exists() else {}
-        )
-        reference.update(
-            {
-                k: v
-                for k, v in summary.items()
-                if k.startswith("serve_")
-            }
-        )
-        reference.setdefault("schema", summary["schema"])
-        REFERENCE.write_text(json.dumps(reference, indent=2) + "\n")
-        print(f"serve-smoke: serve rows merged into {REFERENCE}")
-        return 0
-
-    print(
-        f"serve-smoke: cli compare vs {REFERENCE.name} "
-        f"(serve SLO rows, threshold {args.threshold:.0%})...",
-        flush=True,
-    )
-    rc = cli_main(
-        [
-            "compare",
-            serve_run,
-            str(REFERENCE),
-            "--root-dir", root,
-            "--threshold", str(args.threshold),
-            "--metrics", SERVE_METRICS,
-        ]
-    )
-    if rc != 0:
-        print(f"serve-smoke: cli compare failed (rc={rc})", file=sys.stderr)
-        return rc
     if args.root_dir is None:
         shutil.rmtree(root, ignore_errors=True)
     print("serve-smoke: OK")

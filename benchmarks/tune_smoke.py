@@ -4,9 +4,10 @@
 accelerator, the full fit-driven autotune loop (docs/AUTOTUNE.md) end
 to end:
 
-1. `cli tune cpu --smoke --limit-gb <host cap>` searches the smoke
-   lattice with the REAL `estimate_fit` oracle (a couple of AOT
-   compiles, nothing executed) and must exit 0 with a
+1. the search under `cli tune` (`autotune.run_search`, the REAL
+   `estimate_fit` oracle: a couple of AOT compiles, nothing executed)
+   over a tiny lattice around this script's own tiny configs, under
+   `--limit-gb <host cap>`, must find a winner and write a
    `tuned_preset.json` artifact;
 2. `cli fit <artifact>` re-runs the OOM pre-flight against the emitted
    preset with the same limit and must exit 0 — the tuner's feasibility
@@ -74,40 +75,97 @@ def main() -> int:
     jax.config.update("jax_platforms", "cpu")
     jax.config.update("jax_cpu_enable_async_dispatch", False)
 
+    from alphatriangle_tpu.autotune import (
+        SearchSpace,
+        build_tuned_preset,
+        calibration_from_targets,
+        run_search,
+        write_tuned_preset,
+    )
+    from alphatriangle_tpu.autotune.search import (
+        candidate_mcts,
+        materialize_candidate,
+    )
     from alphatriangle_tpu.cli import main as cli_main
+    from alphatriangle_tpu.config import (
+        AlphaTriangleMCTSConfig,
+        EnvConfig,
+        ModelConfig,
+        TrainConfig,
+        expected_other_features_dim,
+    )
 
     root = args.root_dir or tempfile.mkdtemp(prefix="at_tune_smoke_")
     artifact = Path(root) / "tuned_preset.json"
 
+    # The default board and net at a CPU-sized search and lane count.
+    env_cfg = EnvConfig()
+    model_cfg = ModelConfig(
+        OTHER_NN_INPUT_FEATURES_DIM=expected_other_features_dim(env_cfg),
+        COMPUTE_DTYPE="float32",
+    )
+    mcts_cfg = AlphaTriangleMCTSConfig(max_simulations=8, max_depth=4)
+    train_cfg = TrainConfig(
+        SELF_PLAY_BATCH_SIZE=16,
+        ROLLOUT_CHUNK_MOVES=4,
+        BATCH_SIZE=32,
+        BUFFER_CAPACITY=10_000,
+        MIN_BUFFER_SIZE_TO_TRAIN=1_000,
+        MAX_TRAINING_STEPS=1_000,
+        FUSED_LEARNER_STEPS=4,
+        RUN_NAME=RUN_NAME,
+    )
+    space = SearchSpace(
+        batches=[8, 16], capacities=[10_000], chunks=[4], fused_ks=[4]
+    )
+    limit = args.limit_gb * 2**30
+    calibration = calibration_from_targets([])
+
     print(
-        f"tune-smoke: cli tune cpu --smoke (limit {args.limit_gb} GiB) "
-        f"-> {artifact} ...",
+        f"tune-smoke: run_search over {space.size()} candidates (limit "
+        f"{args.limit_gb} GiB) -> {artifact} ...",
         flush=True,
     )
-    rc = cli_main(
-        [
-            "tune",
-            "cpu",
-            "--smoke",
-            "--limit-gb",
-            str(args.limit_gb),
-            "--out",
-            str(artifact),
-            "--root-dir",
-            root,
-            "--run-name",
-            RUN_NAME,
-        ]
+    result = run_search(
+        space,
+        env_cfg,
+        model_cfg,
+        mcts_cfg,
+        train_cfg,
+        limit,
+        calibration=calibration,
+        peak_tflops=1.0,
+        mode="sync",
+        device_replay=False,
+        progress=lambda msg: print(msg, file=sys.stderr, flush=True),
     )
-    if rc != 0:
-        print(f"tune-smoke: cli tune failed (rc={rc})", file=sys.stderr)
-        return rc
-    if not artifact.is_file():
+    if result.best is None:
         print(
-            f"tune-smoke: tune exited 0 but {artifact} was not written",
+            f"tune-smoke: no feasible candidate under {args.limit_gb} GiB",
             file=sys.stderr,
         )
-        return 2
+        return 1
+    best_env, best_model, best_train = materialize_candidate(
+        result.best, env_cfg, model_cfg, train_cfg, "sync"
+    )
+    write_tuned_preset(
+        build_tuned_preset(
+            result,
+            best_env,
+            best_model,
+            candidate_mcts(mcts_cfg, result.best),
+            best_train,
+            scale="tune_smoke",
+            mode="sync",
+            backend="cpu",
+            device_kind="cpu",
+            limit_bytes=limit,
+            limit_source="flag",
+            calibration=calibration,
+            run_name=RUN_NAME,
+        ),
+        artifact,
+    )
     payload = json.loads(artifact.read_text())
 
     print("tune-smoke: winner-beats-feasible invariant...", flush=True)

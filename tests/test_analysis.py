@@ -649,3 +649,20 @@ class TestCliLint:
         verdict = json.loads(proc.stdout.strip().splitlines()[-1])
         assert verdict["schema"] == LINT_SCHEMA
         assert verdict["exit_code"] == 0
+
+
+def test_package_reads_no_bench_knob():
+    """The benchmark is `chipbench/` and a run's shapes are its
+    preset's: no module of the package names a `BENCH_*` variable, the
+    retired measuring script's steering (27 of them once), in code,
+    comment or docstring."""
+    import re
+
+    knob = re.compile(r"\bBENCH_[A-Z]")
+    hits = [
+        f"{path.relative_to(REPO)}:{n}"
+        for path in sorted((REPO / "alphatriangle_tpu").rglob("*.py"))
+        for n, line in enumerate(path.read_text().splitlines(), 1)
+        if knob.search(line)
+    ]
+    assert hits == []

@@ -536,12 +536,39 @@ class TestTunedPresetArtifact:
         assert ledger_tune_outcome(empty, payload) is None
 
 
+@pytest.fixture()
+def tiny_tune_target(
+    monkeypatch,
+    tiny_env_config,
+    tiny_model_config,
+    tiny_mcts_config,
+    tiny_train_config,
+):
+    """`cli tune <target>` resolving to the tiny world, with a tiny
+    lattice: what the retired `tune cpu --smoke` stood for."""
+    from alphatriangle_tpu import cli as cli_mod
+
+    bundle = {
+        "env": tiny_env_config,
+        "model": tiny_model_config,
+        "mcts": tiny_mcts_config,
+        "train": tiny_train_config,
+        "description": "tiny",
+    }
+    monkeypatch.setattr(cli_mod, "resolve_preset", lambda target: bundle)
+    return [
+        "tune", "1", "--device", "cpu",
+        "--batches", "2,4", "--capacities", "100",
+        "--chunks", "4", "--fused-k", "1",
+    ]  # fmt: skip
+
+
 class TestCliTune:
     """cmd_tune end to end with the oracle faked out (the real oracle
     compiles programs; benchmarks/tune_smoke.py covers it)."""
 
     def test_happy_path_emits_consumable_preset(
-        self, monkeypatch, tmp_path
+        self, monkeypatch, tmp_path, tiny_tune_target
     ):
         from alphatriangle_tpu import cli as cli_mod
         from alphatriangle_tpu.autotune import search as search_mod
@@ -557,10 +584,8 @@ class TestCliTune:
         )
         out = tmp_path / "tuned_preset.json"
         rc = cli_mod.main(
-            [
-                "tune",
-                "cpu",
-                "--smoke",
+            tiny_tune_target
+            + [
                 "--limit-gb",
                 "8",
                 "--out",
@@ -584,17 +609,15 @@ class TestCliTune:
             if row["status"] in ("fit", "dominated") and row["predicted"]:
                 assert best >= row["predicted"]["games_per_hour"] - 1e-9
 
-    def test_infeasible_space_exits_1(self, tmp_path):
+    def test_infeasible_space_exits_1(self, tmp_path, tiny_tune_target):
         """A byte limit below the replay ring's own size: every
         candidate dies in the free ring prune (no compiles) and the
         command exits FIT_OVER."""
         from alphatriangle_tpu import cli as cli_mod
 
         rc = cli_mod.main(
-            [
-                "tune",
-                "cpu",
-                "--smoke",
+            tiny_tune_target
+            + [
                 "--limit-gb",
                 "0.000001",
                 "--root-dir",
@@ -603,7 +626,9 @@ class TestCliTune:
         )
         assert rc == 1
 
-    def test_unknown_limit_exits_2(self, monkeypatch, tmp_path):
+    def test_unknown_limit_exits_2(
+        self, monkeypatch, tmp_path, tiny_tune_target
+    ):
         from alphatriangle_tpu import cli as cli_mod
         from alphatriangle_tpu.telemetry import health as health_mod
         from alphatriangle_tpu.telemetry import memory as memory_mod
@@ -615,7 +640,7 @@ class TestCliTune:
             health_mod, "device_memory_stats", lambda: []
         )
         rc = cli_mod.main(
-            ["tune", "cpu", "--smoke", "--root-dir", str(tmp_path)]
+            tiny_tune_target + ["--root-dir", str(tmp_path)]
         )
         assert rc == 2
 

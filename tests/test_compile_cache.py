@@ -383,52 +383,64 @@ class TestEngineAndTrainerReuse:
             reset_compile_cache()
 
 
-class TestBenchPlan:
-    def test_plan_matches_bench_scales(self):
-        from alphatriangle_tpu.bench_config import resolve_bench_plan
+def _tiny_bundle(env_cfg, model_cfg, mcts_cfg, train_cfg, **train_updates):
+    """What `cli.resolve_preset` returns, at test size."""
+    return {
+        "env": env_cfg,
+        "model": model_cfg,
+        "mcts": mcts_cfg,
+        "train": train_cfg.model_copy(update=train_updates),
+        "description": "tiny",
+    }
 
-        smoke = resolve_bench_plan(True, "cpu", environ={})
-        assert (smoke.scale, smoke.sims, smoke.sp_batch) == ("smoke", 8, 16)
-        assert smoke.fused_k == smoke.overlap_k == 4
-        assert smoke.device_replay is False
 
-        cpu = resolve_bench_plan(False, "cpu", environ={})
-        assert (cpu.scale, cpu.sp_batch, cpu.chunk) == ("cpu", 64, 4)
+class TestPresetTargets:
+    """`cli warm` / `fit` / `tune` take their shapes from the bundle
+    `cli train --preset` runs: one resolver, no second statement."""
 
-        tpu = resolve_bench_plan(False, "tpu", environ={})
-        assert (tpu.scale, tpu.sp_batch, tpu.lbatch) == ("flagship", 512, 256)
-        assert tpu.mcts.root_selection == "gumbel"
-        assert (tpu.fused_k, tpu.overlap_k, tpu.device_replay) == (16, 64, True)
+    @pytest.mark.parametrize("n", [1, 2, 3, 4, 5])
+    def test_target_is_the_training_preset(self, n):
+        from alphatriangle_tpu.cli import resolve_preset
+        from alphatriangle_tpu.config import baseline_preset
 
-    def test_plan_honors_ab_knobs(self):
-        from alphatriangle_tpu.bench_config import resolve_bench_plan
+        got, want = resolve_preset(str(n)), baseline_preset(n)
+        assert set(got) == set(want)
+        for key in ("env", "model", "mcts", "train", "mesh"):
+            assert got[key] == want[key], key
+        train, mcts, model = got["train"], got["mcts"], got["model"]
+        lanes = {1: 16, 2: 128, 3: 512, 4: 512, 5: 1024}[n]
+        assert train.SELF_PLAY_BATCH_SIZE == lanes
+        assert train.FUSED_LEARNER_STEPS == (1 if n == 1 else 16)
+        # The run's own ring and horizon, not a measurement's.
+        defaults = type(train)()
+        assert train.BUFFER_CAPACITY == defaults.BUFFER_CAPACITY
+        assert train.BATCH_SIZE == defaults.BATCH_SIZE
+        assert train.ROLLOUT_CHUNK_MOVES == defaults.ROLLOUT_CHUNK_MOVES
+        assert train.MAX_TRAINING_STEPS == defaults.MAX_TRAINING_STEPS
+        assert mcts.root_selection == ("gumbel" if n == 3 else "puct")
+        assert mcts.fast_simulations == (16 if n == 3 else None)
+        assert model.COMPUTE_DTYPE == ("float32" if n == 1 else "bfloat16")
 
-        plan = resolve_bench_plan(
-            False,
-            "tpu",
-            environ={"BENCH_RECIPE": "puct", "BENCH_BATCH": "256"},
-        )
-        assert plan.mcts.root_selection == "puct"
-        assert plan.sp_batch == 256
+    @pytest.mark.parametrize("command", ["warm", "fit", "tune"])
+    @pytest.mark.parametrize("target", ["auto", "smoke", "cpu"])
+    def test_retired_targets_are_refused(self, command, target):
+        from alphatriangle_tpu import cli
 
-        with pytest.raises(SystemExit):
-            resolve_bench_plan(
-                False, "tpu", environ={"BENCH_RECIPE": "bogus"}
-            )
+        with pytest.raises(SystemExit) as exc:
+            cli.main([command, target])
+        assert "1..5" in str(exc.value) and "BASELINE" in str(exc.value)
 
-    def test_preset_plan_builds(self):
-        from alphatriangle_tpu.bench_config import resolve_bench_plan
+    def test_tuned_artifact_carries_its_serve_ladder(self):
+        from alphatriangle_tpu.autotune.artifact import serve_ladder
 
-        plan = resolve_bench_plan(
-            False, "cpu", environ={"BENCH_CONFIG": "1"}
-        )
-        assert plan.scale == "baseline_config_1"
-        assert plan.sp_batch <= 64  # cpu lane clamp
-        assert plan.train.ROLLOUT_CHUNK_MOVES == 4
+        assert serve_ladder({"train": None}) is None
+        tuned = {"kernels": {"serve_buckets": "16,32"}}
+        assert serve_ladder({"tuned": tuned}) == "16,32"
+        assert serve_ladder({"tuned": {"kernels": {"serve_buckets": ""}}}) is None
 
 
 class TestWarmCLI:
-    def test_cli_warm_smoke(
+    def test_cli_warm_tiny_bundle(
         self,
         tmp_path,
         monkeypatch,
@@ -438,61 +450,46 @@ class TestWarmCLI:
         tiny_mcts_config,
         tiny_train_config,
     ):
-        """`cli warm` end to end on a tiny plan: compiles the rollout
-        chunk + learner programs, serializes them, prints a JSON report,
-        and a second invocation is all hits."""
+        """`cli warm` end to end on a tiny bundle: compiles the rollout
+        chunk, the serve rung and the learner programs, serializes
+        them, prints a JSON report, and a second invocation is all
+        hits."""
         from alphatriangle_tpu import cli
-        from alphatriangle_tpu.bench_config import BenchPlan
 
-        def tiny_plan(smoke, backend, environ=None):
-            return BenchPlan(
-                env=tiny_env_config,
-                model=tiny_model_config,
-                mcts=tiny_mcts_config,
-                train=tiny_train_config,
-                scale="tiny",
-                sims=tiny_mcts_config.max_simulations,
-                sp_batch=tiny_train_config.SELF_PLAY_BATCH_SIZE,
-                chunk=tiny_train_config.ROLLOUT_CHUNK_MOVES,
-                lbatch=tiny_train_config.BATCH_SIZE,
-                fused_k=2,
-                overlap_k=2,
-                device_replay=False,
-            )
-
-        monkeypatch.setattr(
-            "alphatriangle_tpu.bench_config.resolve_bench_plan", tiny_plan
+        bundle = _tiny_bundle(
+            tiny_env_config,
+            tiny_model_config,
+            tiny_mcts_config,
+            tiny_train_config,
+            FUSED_LEARNER_STEPS=2,
         )
+        monkeypatch.setattr(cli, "resolve_preset", lambda target: bundle)
         try:
             reset_compile_cache(cache_dir=str(tmp_path / "aot"))
-            rc = cli.main(["warm", "smoke", "--jobs", "2"])
+            rc = cli.main(["warm", "1", "--jobs", "2"])
             out = capsys.readouterr().out
             report = json.loads(out.strip().splitlines()[-1])
             assert rc == 0
-            # CPU backend: the rollout chunk AOT-warms; the learner
-            # programs are deliberately skipped (cpu_aot bypass —
-            # reloaded learner executables corrupt donated state).
+            # CPU backend: the rollout chunk and the serve rung
+            # AOT-warm; the learner programs are deliberately skipped
+            # (cpu_aot bypass — reloaded learner executables corrupt
+            # donated state).
             statuses = {r["program"]: r["status"] for r in report["programs"]}
-            assert len(statuses) >= 3
-            aot = [p for p, s in statuses.items() if s == "aot"]
-            skipped = [p for p, s in statuses.items() if s == "skipped-cpu"]
-            assert aot and all(p.startswith("self_play") for p in aot)
-            # The learner family AND the megastep (which embeds learner
-            # steps) are CPU-bypassed.
-            assert skipped and all(
-                p.startswith(("learner", "megastep")) for p in skipped
-            )
-            assert any(p.startswith("megastep") for p in skipped)
-            assert set(statuses.values()) == {"aot", "skipped-cpu"}
-            assert report["stats"]["misses"] == len(aot)
+            assert statuses == {
+                "self_play_chunk/t4": "aot",
+                "learner_fused/k2": "skipped-cpu",
+                "learner_step/b4": "skipped-cpu",
+                "serve/b4": "aot",
+            }
+            assert report["stats"]["misses"] == 2
 
             reset_compile_cache(cache_dir=str(tmp_path / "aot"))
-            rc2 = cli.main(["warm", "smoke", "--jobs", "2"])
+            rc2 = cli.main(["warm", "1", "--jobs", "2"])
             report2 = json.loads(
                 capsys.readouterr().out.strip().splitlines()[-1]
             )
             assert rc2 == 0
-            assert report2["stats"]["hits"] == len(aot)
+            assert report2["stats"]["hits"] == 2
             assert report2["stats"]["misses"] == 0
         finally:
             reset_compile_cache()
@@ -508,28 +505,18 @@ class TestWarmCLI:
         tiny_train_config,
     ):
         from alphatriangle_tpu import cli
-        from alphatriangle_tpu.bench_config import BenchPlan
 
-        monkeypatch.setattr(
-            "alphatriangle_tpu.bench_config.resolve_bench_plan",
-            lambda smoke, backend, environ=None: BenchPlan(
-                env=tiny_env_config,
-                model=tiny_model_config,
-                mcts=tiny_mcts_config,
-                train=tiny_train_config,
-                scale="tiny",
-                sims=8,
-                sp_batch=4,
-                chunk=4,
-                lbatch=4,
-                fused_k=2,
-                overlap_k=2,
-            ),
+        bundle = _tiny_bundle(
+            tiny_env_config,
+            tiny_model_config,
+            tiny_mcts_config,
+            tiny_train_config,
         )
+        monkeypatch.setattr(cli, "resolve_preset", lambda target: bundle)
         try:
             reset_compile_cache(cache_dir=str(tmp_path / "aot"))
             rc = cli.main(
-                ["warm", "smoke", "--programs", "self_play", "--jobs", "1"]
+                ["warm", "1", "--programs", "self_play", "--jobs", "1"]
             )
             report = json.loads(
                 capsys.readouterr().out.strip().splitlines()[-1]
@@ -543,7 +530,7 @@ class TestWarmCLI:
             # nothing warmable: reported, and exit 1 ("nothing warm").
             reset_compile_cache(cache_dir=str(tmp_path / "aot"))
             rc2 = cli.main(
-                ["warm", "smoke", "--programs", "learner_step", "--jobs", "1"]
+                ["warm", "1", "--programs", "learner_step", "--jobs", "1"]
             )
             report2 = json.loads(
                 capsys.readouterr().out.strip().splitlines()[-1]
@@ -552,6 +539,68 @@ class TestWarmCLI:
             assert [r["status"] for r in report2["programs"]] == [
                 "skipped-cpu"
             ]
+        finally:
+            reset_compile_cache()
+
+    def test_warm_then_setup_hits(
+        self,
+        tmp_path,
+        tiny_env_config,
+        tiny_model_config,
+        tiny_mcts_config,
+        tiny_train_config,
+    ):
+        """The proof that `cli warm` is aimed at the run: warm a
+        bundle, then build the training components from the same
+        configs over the same cache directory in a "new process" (a
+        fresh CompileCache holds no executables in memory). The run's
+        first rollout dispatch reloads what the warm serialized: one
+        hit, no miss."""
+        from alphatriangle_tpu.config import PersistenceConfig
+        from alphatriangle_tpu.training import setup_training_components
+        from alphatriangle_tpu.warm import warm_programs
+
+        bundle = _tiny_bundle(
+            tiny_env_config,
+            tiny_model_config,
+            tiny_mcts_config,
+            tiny_train_config,
+            RUN_NAME="warmed",
+        )
+        cache_dir = str(tmp_path / "aot")
+        try:
+            reset_compile_cache(cache_dir=cache_dir)
+            report = warm_programs(bundle, jobs=1, programs={"self_play"})
+            assert [
+                (r["program"], r["status"]) for r in report["programs"]
+            ] == [("self_play_chunk/t4", "aot")]
+
+            run = reset_compile_cache(cache_dir=cache_dir)
+            c = setup_training_components(
+                train_config=bundle["train"].model_copy(
+                    update={"RUN_NAME": "the_run"}
+                ),
+                env_config=bundle["env"],
+                model_config=bundle["model"],
+                mcts_config=bundle["mcts"],
+                persistence_config=PersistenceConfig(
+                    ROOT_DATA_DIR=str(tmp_path), RUN_NAME="the_run"
+                ),
+                use_tensorboard=False,
+            )
+            try:
+                c.self_play.play_chunk()
+                chunk_events = [
+                    e["event"]
+                    for e in run.events
+                    if e["program"].startswith("self_play_chunk")
+                ]
+                assert chunk_events == ["hit"]
+                assert run.misses == 0
+            finally:
+                c.telemetry.close()
+                c.stats.close()
+                c.checkpoints.close()
         finally:
             reset_compile_cache()
 

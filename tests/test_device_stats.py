@@ -19,7 +19,6 @@ from alphatriangle_tpu.telemetry.device_stats import (
     beacons_armed,
     describe_beacon,
     device_stats_enabled,
-    device_stats_json,
     device_stats_record,
     device_stats_signature,
     disarm_beacons,
@@ -212,14 +211,6 @@ class TestFolds:
     def test_record_all_empty_is_none(self):
         assert device_stats_record(3) is None
         assert device_stats_record(3, search=None, per={}) is None
-
-    def test_device_stats_json_carries_last_record(self):
-        rec = device_stats_record(9, search={"root_entropy": 0.8}, now=5.0)
-        block = device_stats_json([rec])
-        assert block["ds_records"] == 1
-        assert block["last_record"]["step"] == 9
-        block["last_record"]["step"] = 0  # deep copy: caller may mutate
-        assert rec["step"] == 9
 
 
 class TestRunTelemetryWiring:

@@ -244,25 +244,23 @@ class TestCompare:
         rows, reg = compare_summaries(slower, sa, threshold=0.1)
         assert "games_per_hour" in reg
 
-    def test_load_comparable_bench_json(self, tmp_path):
-        bench = {
-            "metric": "self_play_games_per_hour",
-            "value": 12000.0,
-            "unit": "games/hour",
-            "extra": {
-                "moves_per_sec": 900.0,
-                "learner_steps_per_sec": 4.0,
-                "learner_steps_per_sec_fused": 9.5,
-                "device_kind": "TPU v5 lite",
-                "flops": {"self_play_mfu": 0.11},
-            },
-        }
-        path = tmp_path / "BENCH_x.json"
-        path.write_text(json.dumps(bench))
+    def test_load_comparable_json_must_be_a_perf_summary(self, tmp_path):
+        """A snapshot is a `cli perf --json` summary; any other JSON
+        object (a retired bench line among them) is refused with a
+        reason, never half-read."""
+        path = tmp_path / "other.json"
+        path.write_text(
+            json.dumps(
+                {"metric": "self_play_games_per_hour", "value": 12000.0}
+            )
+        )
+        s, reason = load_comparable(str(path))
+        assert s is None and "not a perf summary" in reason
+        path.write_text(
+            json.dumps({"schema": SUMMARY_SCHEMA, "games_per_hour": 7.0})
+        )
         s, label = load_comparable(str(path))
-        assert s["games_per_hour"] == 12000.0
-        assert s["learner_steps_per_sec"] == 9.5  # fused preferred
-        assert s["mfu"] == 0.11
+        assert s["games_per_hour"] == 7.0 and s["source"] == str(path)
 
     def test_load_comparable_missing(self, tmp_path):
         s, reason = load_comparable(str(tmp_path / "ghost"))

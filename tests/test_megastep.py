@@ -460,12 +460,11 @@ class TestMegastepCompileCache:
         implementations are stubbed here (their real compile/record
         path is covered by the sidecar test above) — this test pins the
         WIRING, inside the tier-1 compile budget."""
-        from alphatriangle_tpu.bench_config import BenchPlan
         from alphatriangle_tpu.rl.megastep import MegastepRunner
         from alphatriangle_tpu.rl.self_play import SelfPlayEngine
         from alphatriangle_tpu.rl.trainer import Trainer
         from alphatriangle_tpu.telemetry.memory import estimate_fit
-        from alphatriangle_tpu.warm import warm_bench_programs
+        from alphatriangle_tpu.warm import warm_programs
 
         def stub_record(program):
             return {
@@ -502,24 +501,18 @@ class TestMegastepCompileCache:
 
         env_cfg, model_cfg, mcts_cfg = tiny_world_configs
         train_cfg = make_cfg("warm_fit_probe", MAX_TRAINING_STEPS=2)
-        plan = BenchPlan(
-            env=env_cfg,
-            model=model_cfg,
-            mcts=mcts_cfg,
-            train=train_cfg,
-            scale="tiny",
-            sims=mcts_cfg.max_simulations,
-            sp_batch=train_cfg.SELF_PLAY_BATCH_SIZE,
-            chunk=train_cfg.ROLLOUT_CHUNK_MOVES,
-            lbatch=train_cfg.BATCH_SIZE,
-            fused_k=2,
-            overlap_k=2,
-            device_replay=False,
-        )
+        # A FUSED_MEGASTEP bundle: the megastep is the learner's side.
+        bundle = {
+            "env": env_cfg,
+            "model": model_cfg,
+            "mcts": mcts_cfg,
+            "train": train_cfg,
+            "mesh": MeshConfig(DP_SIZE=1),
+        }
         try:
             reset_compile_cache(cache_dir=str(tmp_path / "aot"))
-            report = warm_bench_programs(
-                plan, jobs=1, programs={"megastep"}
+            report = warm_programs(
+                bundle, jobs=1, programs={"megastep", "learner"}
             )
             rows = {r["program"]: r["status"] for r in report["programs"]}
             assert rows == {"megastep/t4_k2": "skipped-cpu"}

@@ -235,19 +235,18 @@ class TestWarmFitWiring:
     def test_warm_and_fit_cover_sharded_family(
         self, tmp_path, tiny_world_configs, monkeypatch
     ):
-        """`cli warm` lists the dp-sharded megastep program beside the
-        single-device one (skipped-cpu on this backend, like every
-        learner-embedding program) and `estimate_fit(megastep=True)`
+        """`cli warm` lists the dp-sharded megastep program, the one a
+        run on this mesh dispatches (skipped-cpu on this backend, like
+        every learner-embedding program) and `estimate_fit(megastep=True)`
         analyzes the sharded family with a per-device ring budget
         (cap_local, not the global capacity). Analyze implementations
         are stubbed — this pins the WIRING inside the tier-1 budget."""
-        from alphatriangle_tpu.bench_config import BenchPlan
         from alphatriangle_tpu.compile_cache import reset_compile_cache
         from alphatriangle_tpu.rl.megastep import MegastepRunner
         from alphatriangle_tpu.rl.self_play import SelfPlayEngine
         from alphatriangle_tpu.rl.trainer import Trainer
         from alphatriangle_tpu.telemetry.memory import estimate_fit
-        from alphatriangle_tpu.warm import warm_bench_programs
+        from alphatriangle_tpu.warm import warm_programs
 
         def stub_record(program):
             return {
@@ -293,30 +292,20 @@ class TestWarmFitWiring:
         train_cfg = make_cfg(
             "warm_fit_dp", SELF_PLAY_BATCH_SIZE=ndev, MAX_TRAINING_STEPS=2
         )
-        plan = BenchPlan(
-            env=env_cfg,
-            model=model_cfg,
-            mcts=mcts_cfg,
-            train=train_cfg,
-            scale="tiny",
-            sims=mcts_cfg.max_simulations,
-            sp_batch=train_cfg.SELF_PLAY_BATCH_SIZE,
-            chunk=train_cfg.ROLLOUT_CHUNK_MOVES,
-            lbatch=train_cfg.BATCH_SIZE,
-            fused_k=2,
-            overlap_k=2,
-            device_replay=False,
-        )
+        # No "mesh": the default spans every device, as `cli train`'s.
+        bundle = {
+            "env": env_cfg,
+            "model": model_cfg,
+            "mcts": mcts_cfg,
+            "train": train_cfg,
+        }
         try:
             reset_compile_cache(cache_dir=str(tmp_path / "aot"))
-            report = warm_bench_programs(
-                plan, jobs=1, programs={"megastep"}
+            report = warm_programs(
+                bundle, jobs=1, programs={"megastep", "learner"}
             )
             rows = {r["program"]: r["status"] for r in report["programs"]}
-            assert rows == {
-                "megastep/t2_k2": "skipped-cpu",
-                f"megastep/dp{ndev}_t2_k2": "skipped-cpu",
-            }
+            assert rows == {f"megastep/dp{ndev}_t2_k2": "skipped-cpu"}
 
             fit = estimate_fit(
                 env_cfg,

@@ -438,13 +438,14 @@ class TestFitCLI:
         tiny_train_config,
     ):
         from alphatriangle_tpu import cli
-        from alphatriangle_tpu.bench_config import BenchPlan
+        from alphatriangle_tpu.config import MeshConfig
         from alphatriangle_tpu.rl.megastep import MegastepRunner
 
-        # `cli fit` also analyzes the fused-megastep program; stub it
-        # here (its real compile/record path is pinned in
-        # tests/test_megastep.py) so this test stays inside the tier-1
-        # compile budget while still proving the wiring reaches it.
+        # A FUSED_MEGASTEP bundle: `cli fit` then also analyzes the
+        # megastep program. Stubbed here (its real compile/record path
+        # is pinned in tests/test_megastep.py) so this test stays
+        # inside the tier-1 compile budget while still proving the
+        # wiring reaches it.
         monkeypatch.setattr(
             MegastepRunner,
             "analyze_megastep",
@@ -459,39 +460,40 @@ class TestFitCLI:
                 "transient": 16,
             },
         )
-        monkeypatch.setattr(
-            "alphatriangle_tpu.bench_config.resolve_bench_plan",
-            lambda smoke, backend, environ=None: BenchPlan(
-                env=tiny_env_config,
-                model=tiny_model_config,
-                mcts=tiny_mcts_config,
-                train=tiny_train_config,
-                scale="tiny",
-                sims=tiny_mcts_config.max_simulations,
-                sp_batch=tiny_train_config.SELF_PLAY_BATCH_SIZE,
-                chunk=tiny_train_config.ROLLOUT_CHUNK_MOVES,
-                lbatch=tiny_train_config.BATCH_SIZE,
-                fused_k=2,
-                overlap_k=2,
-                device_replay=False,
+        bundle = {
+            "env": tiny_env_config,
+            "model": tiny_model_config,
+            "mcts": tiny_mcts_config,
+            "train": tiny_train_config.model_copy(
+                update={"FUSED_MEGASTEP": True, "FUSED_LEARNER_STEPS": 2}
             ),
-        )
+            "mesh": MeshConfig(DP_SIZE=1),
+            "description": "tiny",
+        }
+        monkeypatch.setattr(cli, "resolve_preset", lambda target: bundle)
         try:
             reset_compile_cache(cache_dir=str(tmp_path / "aot"))
-            rc = cli.main(["fit", "cpu", "--json"])
+            rc = cli.main(["fit", "1", "--json"])
             report = json.loads(capsys.readouterr().out.strip())
             # A tiny world against host RAM must fit.
             assert rc == 0
             assert report["exit"] == 0
             assert report["budget"]["total_bytes"] > 0
             assert report["budget"]["programs"] >= 3
+            assert "megastep/t4_k2" in {
+                r.get("program") for r in report["records"]
+            }
+            # The run's own ring (100 rows here), on the device: the
+            # megastep's home.
+            [ring] = [r for r in report["records"] if r["category"] == "ring"]
+            assert ring["capacity"] == 100 and ring["location"] == "device"
             assert report["bytes_limit"] > report["budget"]["total_bytes"]
             categories = {r["category"] for r in report["records"]}
             assert categories == {"state", "ring", "program"}
 
             # An asserted tiny limit flips the verdict to over-budget.
             reset_compile_cache(cache_dir=str(tmp_path / "aot"))
-            rc = cli.main(["fit", "cpu", "--limit-gb", "0.0000001"])
+            rc = cli.main(["fit", "1", "--limit-gb", "0.0000001"])
             assert rc == 1
         finally:
             reset_compile_cache()

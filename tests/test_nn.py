@@ -206,6 +206,20 @@ def flagship():
     return module, variables, grid, other
 
 
+@pytest.fixture(autouse=True)
+def own_default_tracer():
+    """The instants below are read from the process's default tracer. A
+    test that ran earlier in this worker may have left a `RunTelemetry`'s
+    there, a disabled one among them: give these tests their own and
+    put the other back."""
+    from alphatriangle_tpu.telemetry import tracer
+
+    before = tracer._default_tracer
+    tracer.set_default_tracer(tracer.SpanTracer())
+    yield
+    tracer._default_tracer = before
+
+
 def _attention_instant() -> dict:
     from alphatriangle_tpu.telemetry.tracer import default_tracer
 
