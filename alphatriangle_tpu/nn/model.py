@@ -31,11 +31,7 @@ from flax import linen as nn
 from jax import Array
 
 from ..config.model_config import ModelConfig
-from ..ops.encoder_attention import (
-    attention_path,
-    encoder_attention,
-    partitioned,
-)
+from ..ops.encoder_layer import encoder_layer, layer_path, partitioned
 from ..telemetry.tracer import default_tracer
 from .trunk import DecoderTrunk
 
@@ -155,8 +151,12 @@ class TransformerEncoderLayer(nn.Module):
     sequence-parallel one (`parallel/ring_attention.make_sp_attention`);
     attention-weight dropout is disabled in that case (blockwise
     kernels don't support it) — the residual dropouts still apply.
-
     Either runs inside the phase `net/encoder/attention`.
+
+    `fused`: the whole layer as `ops/encoder_layer.py`'s kernel, on the
+    variables the modules below declare (so `init` runs the modules and
+    the tree is one on both paths). `AlphaTriangleNet` sets it where
+    `layer_path` says so; it is an inference path, `train` is not read.
     """
 
     dim: int
@@ -167,9 +167,14 @@ class TransformerEncoderLayer(nn.Module):
     dropout_rate: float = 0.1
     attention_fn: Callable | None = None
     param_dtype: jnp.dtype = jnp.float32
+    fused: bool = False
 
     @nn.compact
     def __call__(self, x: Array, train: bool) -> Array:
+        if self.fused:
+            return encoder_layer(
+                x, self.variables["params"], heads=self.heads, act=self.act
+            )
         kw = {"dtype": self.dtype, "param_dtype": self.param_dtype}
         y = nn.LayerNorm(**kw)(x)
         y = nn.MultiHeadDotProductAttention(
@@ -300,11 +305,12 @@ class AlphaTriangleNet(nn.Module):
                 layer = TransformerEncoderLayer
                 if cfg.REMAT:
                     layer = nn.remat(TransformerEncoderLayer, static_argnums=(2,))
-                # With none handed in, the attention of an inference
-                # call on a TPU is the fused kernel (scores kept in
-                # VMEM), everywhere else Flax's function: one choice for
-                # all layers, from what this trace can observe.
-                fused = "fused" == attention_path(
+                # An inference call on a TPU runs each layer as one
+                # kernel (its values kept in VMEM), every other call
+                # Flax's modules, which also declare the variables: one
+                # choice for all layers, from what this trace can observe.
+                fused = "fused" == layer_path(
+                    initializing=self.is_initializing(),
                     train=train,
                     handed_in=self.attention_fn is not None,
                     masked=False,  # the layer attends to every cell
@@ -314,6 +320,7 @@ class AlphaTriangleNet(nn.Module):
                     seq=h * w,
                     heads=cfg.TRANSFORMER_HEADS,
                     head_dim=d // cfg.TRANSFORMER_HEADS,
+                    mlp_dim=cfg.TRANSFORMER_FC_DIM,
                 )
                 for _ in range(cfg.TRANSFORMER_LAYERS):
                     tokens = layer(
@@ -322,15 +329,14 @@ class AlphaTriangleNet(nn.Module):
                         cfg.TRANSFORMER_FC_DIM,
                         act,
                         dtype,
-                        attention_fn=(
-                            encoder_attention if fused else self.attention_fn
-                        ),
+                        attention_fn=self.attention_fn,
                         param_dtype=pdtype,
+                        fused=fused,
                     )(tokens, train)
                 # Once each time the net is traced into a program (a
                 # reloaded executable is not traced again).
                 default_tracer().instant(
-                    "net.attention",
+                    "net.encoder",
                     fused_layers=cfg.TRANSFORMER_LAYERS if fused else 0,
                     flax_layers=0 if fused else cfg.TRANSFORMER_LAYERS,
                     batch=b,

@@ -3,8 +3,9 @@
 The four ops exported here follow one pattern (docs/KERNELS.md): a
 Pallas TPU lowering plus interchangeable XLA lowerings, numerically
 pinned against each other by parity tests, with a config knob selecting
-the backend. `encoder_attention.py` has no knob: `nn/model.py` takes it
-or Flax's attention by what the call can observe.
+the backend. `encoder_layer.py` has no knob: `nn/model.py` runs an
+encoder layer as that kernel or as Flax's modules by what the call can
+observe.
 """
 
 from .gather_rows import gather_rows

@@ -25,8 +25,9 @@ PHASES = (
     "net/conv",
     "net/residual",
     "net/encoder",
-    # the attention of an encoder layer, from q, k, v to the heads'
-    # output: ops/encoder_attention.py's kernel or Flax's function
+    # the attention of an encoder layer on the Flax path, from q, k, v
+    # to the heads' output (Flax's function or one handed in); a layer
+    # fused by ops/encoder_layer.py is one call under net/encoder
     "net/encoder/attention",
     "net/heads",
     # a decoder stack as the trunk (nn/trunk.py), in net/encoder's

@@ -26,7 +26,7 @@ from alphatriangle_tpu.config.presets import baseline_preset  # noqa: E402
 
 KERNELS = (
     "gather_rows", "backup_update", "per_sample", "subtree_promote",
-    "encoder_attention",
+    "encoder_layer",
 )
 
 
@@ -77,44 +77,64 @@ def cases():
     }
 
 
+def _compiled_text(case: dict, one_v5e_chip) -> str:
+    """`case`'s Pallas lowering compiled for the described chip."""
+    operands = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_v5e_chip),
+        jax.eval_shape(case["operands"], jax.random.PRNGKey(0)),
+    )
+    return (
+        jax.jit(functools.partial(case["run"], "pallas"))
+        .lower(*operands)
+        .compile()
+        .as_text()
+    )
+
+
+def _layer_case(shapes: dict) -> dict:
+    case = chip_smoke.kernel_cases(shapes)[-1]
+    assert case["name"] == "encoder_layer"
+    return case
+
+
 @pytest.mark.parametrize("preset", [3, 4])
 @pytest.mark.parametrize("kernel", KERNELS)
 def test_kernel_compiles_for_v5e(
     kernel, preset, cases, one_v5e_chip, monkeypatch
 ):
-    case = cases[preset][kernel]
     # The dispatchers interpret the kernel unless the backend is a TPU;
     # the backend here is the CPU and the target is not.
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    operands = jax.tree_util.tree_map(
-        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_v5e_chip),
-        jax.eval_shape(case["operands"], jax.random.PRNGKey(0)),
-    )
-    compiled = (
-        jax.jit(functools.partial(case["run"], "pallas"))
-        .lower(*operands)
-        .compile()
-    )
-    assert "tpu_custom_call" in compiled.as_text()
+    text = _compiled_text(cases[preset][kernel], one_v5e_chip)
+    assert "tpu_custom_call" in text
 
 
-def test_encoder_attention_compiles_at_252_tokens(one_v5e_chip, monkeypatch):
-    """Preset 5's board: 252 tokens, a wave of 1,024 lanes x 32 leaves."""
+def test_encoder_layer_compiles_at_252_tokens(one_v5e_chip, monkeypatch):
+    """Preset 5's board: 252 tokens, a wave of 1,024 lanes x 32 leaves,
+    8 boards a grid step."""
     shapes = chip_smoke.kernel_shapes(baseline_preset(5))
     assert (shapes["leaves"], shapes["tokens"]) == (32768, 252)
-    case = chip_smoke.kernel_cases(shapes)[-1]
-    assert case["name"] == "encoder_attention"
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    operands = jax.tree_util.tree_map(
-        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_v5e_chip),
-        jax.eval_shape(case["operands"], jax.random.PRNGKey(0)),
-    )
-    compiled = (
-        jax.jit(functools.partial(case["run"], "pallas"))
-        .lower(*operands)
-        .compile()
-    )
-    assert "tpu_custom_call" in compiled.as_text()
+    assert "tpu_custom_call" in _compiled_text(_layer_case(shapes), one_v5e_chip)
+
+
+@pytest.mark.parametrize("activation", ["GELU", "SiLU", "Tanh", "Sigmoid"])
+def test_encoder_layer_compiles_with_every_activation(
+    one_v5e_chip, monkeypatch, activation
+):
+    """`layer_path` does not look at the activation: each one the
+    config allows lowers inside the kernel (ReLU is the flagship's,
+    compiled above), at a root batch of the flagship."""
+    from alphatriangle_tpu.nn.model import _ACTIVATIONS
+
+    assert set(_ACTIVATIONS) == {"ReLU", "GELU", "SiLU", "Tanh", "Sigmoid"}
+    shapes = {
+        **chip_smoke.kernel_shapes(baseline_preset(3)),
+        "leaves": 512,
+        "activation": activation,
+    }
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert "tpu_custom_call" in _compiled_text(_layer_case(shapes), one_v5e_chip)
 
 
 @pytest.mark.parametrize("chips,fused", [(4, False), (1, True)])
@@ -125,7 +145,8 @@ def test_lane_sharded_chunk_compiles_for_v5e(
     over dp on the described 2x2 (`SelfPlayEngine(mesh=)`, the
     megastep's rollout half; a small tree, one move). The compiler
     refuses to lower a Mosaic call it would have to partition, so the
-    net keeps Flax's attention there and the kernel on one chip."""
+    net keeps Flax's layers there; on one chip every layer is the
+    kernel, and the attention kernel it replaced is in neither."""
     import jax.numpy as jnp
     import numpy as np
     from jax.sharding import Mesh, NamedSharding, PartitionSpec
@@ -176,4 +197,7 @@ def test_lane_sharded_chunk_compiles_for_v5e(
         )
         .compile()
     )
-    assert ("encoder_attention" in compiled.as_text()) == fused
+    text = compiled.as_text()
+    assert ("encoder_layer" in text) == fused
+    assert ("tpu_custom_call" in text) == fused
+    assert "encoder_attention" not in text

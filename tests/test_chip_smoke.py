@@ -106,12 +106,12 @@ def test_kernels_phase_matches_xla(tiny_cfgs):
     }
     out = chip_smoke.phase_kernels(small)
     assert out["compiled"] is False  # interpreted here, and it says so
-    attention = out["parity"].pop("encoder_attention")
+    layer = out["parity"].pop("encoder_layer")
     assert {k["parity"] for k in out["parity"].values()} == {"exact"}
-    # Not exact: a float32 softmax against Flax's; timed beside it (a
-    # CPU trace has no device operations to list).
-    assert attention["vs"] == "flax" and "rounding" in attention["parity"]
-    assert attention["device_ms_a_call"] == {"pallas": {}, "flax": {}}
+    # Not exact: the kernel's own order of sums against Flax's layer;
+    # timed beside it (a CPU trace has no device operations to list).
+    assert layer["vs"] == "flax" and "rounding" in layer["parity"]
+    assert layer["device_ms_a_call"] == {"pallas": {}, "flax": {}}
 
 
 def test_native_engine_phase(tiny_cfgs):
@@ -183,8 +183,8 @@ def test_flagship_shapes_are_preset_three():
         "actions": 360, "depth": 8, "capacity": 250_000,
         "learner_steps": 16, "batch_size": 256,
         # The leaf wave of a fast search: 512 lanes x 16 simulations.
-        "leaves": 8192, "tokens": 120, "heads": 4, "head_dim": 32,
-        "compute_dtype": "bfloat16",
+        "leaves": 8192, "tokens": 120, "dim": 128, "heads": 4,
+        "mlp_dim": 256, "activation": "ReLU", "compute_dtype": "bfloat16",
     }
 
 

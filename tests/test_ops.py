@@ -16,10 +16,10 @@ from alphatriangle_tpu.ops import (
     per_sample,
     subtree_promote,
 )
-from alphatriangle_tpu.ops.encoder_attention import (
-    attention_path,
+from alphatriangle_tpu.ops.encoder_layer import (
     block_boards,
-    encoder_attention,
+    encoder_layer,
+    layer_path,
     partitioned,
 )
 
@@ -60,42 +60,97 @@ def _max_gap(a, b) -> float:
     )
 
 
-class TestEncoderAttention:
-    """The fused kernel (interpreted) against Flax's function, and the
-    choice between the two."""
+def _encoder_layer(dtype, activation: str = "ReLU", seed: int = 0):
+    """A flagship-width layer (D 128, 4 heads, FC 256) as Flax makes it,
+    and its variables moved off their initial values: biases start at 0
+    and scales at 1, where a kernel that dropped them would still agree."""
+    from alphatriangle_tpu.nn.model import _ACTIVATIONS, TransformerEncoderLayer
+
+    act = _ACTIVATIONS[activation]
+    layer = TransformerEncoderLayer(128, 4, 256, act, dtype)
+    variables = layer.init(
+        jax.random.PRNGKey(seed), jnp.zeros((1, 8, 128), dtype), False
+    )
+    leaves, tree = jax.tree_util.tree_flatten(variables)
+    keys = jax.random.split(jax.random.PRNGKey(seed + 1), len(leaves))
+    moved = [
+        leaf + 0.05 * jax.random.normal(key, leaf.shape)
+        for leaf, key in zip(leaves, keys)
+    ]
+    return layer, jax.tree_util.tree_unflatten(tree, moved), act
+
+
+class TestEncoderLayer:
+    """The fused layer (interpreted) against `TransformerEncoderLayer`'s
+    Flax modules on the same variables, and the choice between the two."""
+
+    def _three_answers(self, shape, dtype, activation="ReLU"):
+        """(kernel, Flax in `dtype`, Flax in float32) on one input."""
+        layer, variables, act = _encoder_layer(dtype, activation)
+        x = jax.random.normal(jax.random.PRNGKey(7), (*shape, 128), dtype)
+        got = encoder_layer(
+            x, variables["params"], heads=4, act=act, interpret=True
+        )
+        assert got.shape == x.shape and got.dtype == dtype
+        exact = layer.clone(dtype=jnp.float32).apply(
+            variables, x.astype(jnp.float32), False
+        )
+        return got, layer.apply(variables, x, False), exact
 
     # The flagship's boards, preset 5's 252 tokens, and a batch the
     # block of 32 boards does not divide (a padded last step).
-    @pytest.mark.parametrize(
-        "shape", [(2, 120, 4, 32), (2, 252, 4, 32), (35, 24, 4, 32)]
-    )
+    @pytest.mark.parametrize("shape", [(2, 120), (2, 252), (35, 24)])
     @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
     def test_kernel_matches_flax(self, shape, dtype):
-        from flax import linen as nn
-
-        assert block_boards(35, 24, 128, 2) == 32  # 35 = 32 + 3
-        q, k, v = (
-            jax.random.normal(key, shape, dtype)
-            for key in jax.random.split(jax.random.PRNGKey(0), 3)
-        )
-        got = encoder_attention(q, k, v, interpret=True)
-        assert got.shape == shape and got.dtype == dtype
-        flax = nn.dot_product_attention(q, k, v)
-        exact = nn.dot_product_attention(
-            *(x.astype(jnp.float32) for x in (q, k, v))
-        )
+        assert block_boards(35, 24, 128, 256, 2) == 32  # 35 = 32 + 3
+        got, flax, exact = self._three_answers(shape, dtype)
         if dtype == jnp.float32:
             assert _max_gap(got, flax) < 1e-5
             return
-        # Unit normal inputs: Flax's bfloat16 path lies up to 0.017 from
-        # the float32 answer here; the kernel's float32 softmax must
-        # not lie further, and the two lie within their rounding.
-        assert _max_gap(got, exact) <= max(0.008, _max_gap(flax, exact))
-        assert _max_gap(got, flax) < 0.03
+        # The kernel rounds where Flax rounds, but for the residual
+        # stream, float32 inside the layer: it must not lie further from
+        # the float32 answer than Flax's bfloat16 path, and the two lie
+        # within their rounding (outputs reach 4-8: 2^-6 a step).
+        assert _max_gap(got, exact) <= max(0.02, _max_gap(flax, exact))
+        assert _max_gap(got, flax) < 0.15
+
+    @pytest.mark.parametrize(
+        "activation", ["ReLU", "GELU", "SiLU", "Tanh", "Sigmoid"]
+    )
+    def test_every_activation_of_the_config(self, activation):
+        from alphatriangle_tpu.nn.model import _ACTIVATIONS
+
+        assert set(_ACTIVATIONS) == {"ReLU", "GELU", "SiLU", "Tanh", "Sigmoid"}
+        got, flax, _ = self._three_answers((3, 24), jnp.float32, activation)
+        assert _max_gap(got, flax) < 1e-5
+        got, flax, exact = self._three_answers((3, 24), jnp.bfloat16, activation)
+        assert _max_gap(got, exact) <= max(0.02, _max_gap(flax, exact))
+
+    def test_the_layer_takes_its_own_variables(self, monkeypatch):
+        """Through the module: `fused=True` hands the kernel the
+        variables the Flax path declared."""
+        import functools
+
+        from alphatriangle_tpu.nn import model as nn_model
+
+        layer, variables, _ = _encoder_layer(jnp.float32)
+        x = jax.random.normal(jax.random.PRNGKey(3), (2, 24, 128))
+        assert variables["params"].keys() == {
+            "LayerNorm_0", "MultiHeadDotProductAttention_0", "LayerNorm_1",
+            "Dense_0", "Dense_1",
+        }
+        monkeypatch.setattr(
+            nn_model,
+            "encoder_layer",
+            functools.partial(nn_model.encoder_layer, interpret=True),
+        )
+        got = layer.clone(fused=True).apply(variables, x, False)
+        assert _max_gap(got, layer.apply(variables, x, False)) < 1e-5
 
     FUSED = dict(
-        train=False, handed_in=False, masked=False, partitioned=False,
-        backend="tpu", dtype=jnp.bfloat16, seq=120, heads=4, head_dim=32,
+        initializing=False, train=False, handed_in=False, masked=False,
+        partitioned=False, backend="tpu", dtype=jnp.bfloat16, seq=120,
+        heads=4, head_dim=32, mlp_dim=256,
     )
 
     @pytest.mark.parametrize(
@@ -104,19 +159,36 @@ class TestEncoderAttention:
             ({}, "fused"),
             ({"dtype": jnp.float32}, "fused"),
             ({"seq": 252}, "fused"),
+            ({"mlp_dim": 1024}, "fused"),
             ({"backend": "cpu"}, "flax"),
             ({"backend": "gpu"}, "flax"),
+            ({"initializing": True}, "flax"),
             ({"train": True}, "flax"),
             ({"masked": True}, "flax"),
             ({"handed_in": True}, "flax"),
             ({"partitioned": True}, "flax"),
             ({"dtype": jnp.float16}, "flax"),
             ({"heads": 2, "head_dim": 16}, "flax"),  # 32 lanes of 128
+            ({"mlp_dim": 192}, "flax"),  # a hidden of one and a half rows
             ({"seq": 8192}, "flax"),  # one board's scores: 268 MB
         ],
     )
     def test_path_is_chosen_by_what_the_call_observes(self, change, path):
-        assert attention_path(**{**self.FUSED, **change}) == path
+        assert layer_path(**{**self.FUSED, **change}) == path
+
+    @pytest.mark.parametrize(
+        "seq,itemsize,boards",
+        [
+            (120, 2, 32), (120, 4, 32), (252, 2, 8), (256, 2, 19), (256, 4, 14),
+            (2000, 2, 0),
+        ],
+    )
+    def test_block_plan_follows_the_board(self, seq, itemsize, boards):
+        """Boards a grid step: 32 at the flagship's 120 tokens, as many
+        as the plan holds at 256, 8 at preset 5's 252 (not whole sublane
+        tiles), none where one board's values pass the plan."""
+        assert block_boards(8192, seq, 128, 256, itemsize) == boards
+        assert block_boards(5, seq, 128, 256, itemsize) == min(boards, 5)
 
     @pytest.mark.parametrize(
         "placed,manual,want",
@@ -155,10 +227,14 @@ class TestEncoderAttention:
         assert seen == [want]
 
     def test_a_board_that_does_not_fit_is_refused(self):
-        x = jax.ShapeDtypeStruct((1, 8192, 4, 32), jnp.bfloat16)
-        assert block_boards(1, 8192, 128, 2) == 0
+        _, variables, act = _encoder_layer(jnp.bfloat16)
+        x = jax.ShapeDtypeStruct((1, 8192, 128), jnp.bfloat16)
+        assert block_boards(1, 8192, 128, 256, 2) == 0
         with pytest.raises(ValueError, match="does not fit"):
-            jax.eval_shape(encoder_attention, x, x, x)
+            jax.eval_shape(
+                lambda x, p: encoder_layer(x, p, heads=4, act=act),
+                x, variables["params"],
+            )
 
 
 class TestPerSample:

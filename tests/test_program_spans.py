@@ -276,12 +276,12 @@ class TestProgramSpans:
         for name in ("learner.dispatch", "learner.wait", "learner.results"):
             assert args[name] == {"k": 2}
         # The group's program is traced inside its first dispatch, and
-        # the net says there which attention its layers took: a
-        # learner's keep Flax's function, on any backend.
+        # the net says there which path its encoder layers took: a
+        # learner's keep Flax's modules, on any backend.
         layers = world["net"].model_config.TRANSFORMER_LAYERS
         assert [(r[1], r[8], r[6]) for r in instants] == [
             (
-                "net.attention",
+                "net.encoder",
                 records[2][7],
                 {"fused_layers": 0, "flax_layers": layers, "batch": 4, "seq": 12},
             )
@@ -335,13 +335,18 @@ class TestPhaseNamesInPrograms:
         wanted -= set(_phases("net/trunk"))
         assert {p for p in wanted if p not in text} == set()
         assert "net/trunk" not in text
-        # The attention's phase lies inside the encoder's and wins.
+        # The attention's phase lies inside the encoder's and wins (the
+        # Flax path's; a fused layer is one call under `net/encoder`).
         assert "net/encoder/attention" in wanted
         assert profiling.phase_of(
             "jit(chunk)/search/evaluate/net/encoder/TransformerEncoderLayer_0/"
             "MultiHeadDotProductAttention_0/net/encoder/attention/"
-            "jit(encoder_attention)/encoder_attention/pallas_call"
+            "dot_general"
         ) == "net/encoder/attention"
+        assert profiling.phase_of(
+            "jit(chunk)/search/evaluate/net/encoder/TransformerEncoderLayer_0/"
+            "jit(encoder_layer)/encoder_layer/pallas_call"
+        ) == "net/encoder"
 
     def test_trunk_phases_in_the_chunk_of_a_decoder_stack(
         self, world, tiny_mcts_config
