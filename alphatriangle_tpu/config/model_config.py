@@ -16,17 +16,35 @@ from pydantic import BaseModel, Field, model_validator
 
 class TrunkConfig(BaseModel):
     """A decoder stack in the encoder's place (nn/trunk.py): RMSNorm,
-    rotary positions, grouped-query attention under a causal window or
-    a causal full mask by layer, SwiGLU, and routed experts with a
-    shared expert. The keys are a published `config.json`'s, under its
-    names; layer l is `layer_types[l]` with `mlp_layer_types[l]`.
+    SwiGLU, routed experts with a shared expert, and by layer one of
+    four mixers: grouped-query softmax attention under a causal window
+    (`sliding_attention`) or a causal full mask (`full_attention`), the
+    gated delta rule with a decay for every channel (`linear_attention`,
+    KDA: short causal convolutions, a recurrent state of head_dim x
+    head_dim a head, no score matrix) and latent attention
+    (`latent_attention`, MLA: keys and values expanded from a latent of
+    `kv_lora_rank`, a rotary part all heads share). The keys are a
+    published `config.json`'s, under its names; layer l is
+    `layer_types[l]` with `mlp_layer_types[l]`.
 
     `experts_held` = (first, count): the router scores all
     `num_experts`; this process computes the experts it holds for the
     tokens routed to them and adds nothing for the others (one chip's
     share under expert parallelism; (0, num_experts) is the whole
-    layer). The last four keys are what such a file leaves to the
-    family's convention."""
+    layer). `n_group` > 1 makes the router's choice a grouped one: the
+    experts stand in `n_group` groups, a group's score is the sum of its
+    two highest, the `topk_group` best groups stay in the choice.
+
+    `norm_position`, `qk_norm` and `rope_layers` are what such a file
+    leaves to the family's convention, each a choice of two:
+    "post" is x + norm(f(x)), "pre" x + f(norm(x)); `qk_norm` True an
+    RMSNorm on q and k per head of the softmax layers, "l2" the L2 norm
+    on q and k per head of the linear layers (and none on a latent
+    layer beyond its latent's); `rope_layers` "sliding" the whole head
+    turned, halves paired, on the sliding layers only, "latent" the
+    `qk_rope_head_dim` part of a latent layer turned, neighbours paired
+    (interleaved), and no positions anywhere else. A layer kind whose
+    choice is not the one it was written for is refused."""
 
     hidden_size: int = Field(gt=0)
     num_attention_heads: int = Field(gt=0)
@@ -38,19 +56,33 @@ class TrunkConfig(BaseModel):
     num_experts_per_tok: int = Field(gt=0)
     num_shared_experts: int = Field(default=1, ge=0)
     routed_scaling_factor: float = Field(default=1.0)
-    sliding_window: int = Field(gt=0)
-    layer_types: list[Literal["sliding_attention", "full_attention"]]
+    n_group: int = Field(default=1, gt=0)
+    topk_group: int = Field(default=1, gt=0)
+    sliding_window: int | None = Field(default=None, gt=0)
+    layer_types: list[
+        Literal[
+            "sliding_attention", "full_attention",
+            "linear_attention", "latent_attention",
+        ]
+    ]
     mlp_layer_types: list[Literal["dense", "sparse"]]
     rope_theta: float = Field(default=1e6, gt=0)
     rms_norm_eps: float = Field(default=1e-5, gt=0)
     experts_held: tuple[int, int]
+    # A linear layer (KDA): the causal depthwise convolution's taps and
+    # the floor of a step's log decay (g in (kda_lower_bound, 0)).
+    short_conv_kernel_size: int = Field(default=4, gt=0)
+    kda_lower_bound: float = Field(default=-5.0, lt=0)
+    # A latent layer (MLA): the latent's width, a head's query/key part
+    # without and with positions, a head's value width.
+    kv_lora_rank: int | None = Field(default=None, gt=0)
+    qk_nope_head_dim: int | None = Field(default=None, gt=0)
+    qk_rope_head_dim: int | None = Field(default=None, gt=0)
+    v_head_dim: int | None = Field(default=None, gt=0)
 
-    # The one placement implemented: x + norm(f(x)) for attention and
-    # MLP alike, an RMSNorm on q and k per head, rotary positions on the
-    # sliding layers only. The keys say so; another value is refused.
-    norm_position: Literal["post"] = Field(default="post")
-    qk_norm: Literal[True] = Field(default=True)
-    rope_layers: Literal["sliding"] = Field(default="sliding")
+    norm_position: Literal["post", "pre"] = Field(default="post")
+    qk_norm: Literal[True, "l2"] = Field(default=True)
+    rope_layers: Literal["sliding", "latent"] = Field(default="sliding")
     # A per-expert float32 parameter added to the scores for the choice
     # alone (the weights stay the raw scores'): how a router balanced by
     # bias selects. Noughts as initialised; a checkpoint brings its own.
@@ -58,6 +90,11 @@ class TrunkConfig(BaseModel):
     # Boards the net takes at a time where a search evaluates a leaf
     # batch (cut into such blocks inside the program); None = all at once.
     block_boards: int | None = Field(default=None, gt=0)
+    # Tokens a linear layer's recurrence takes at a time (its chunked
+    # form, nn/linear_attention.py): a multiple of that module's
+    # sub-block, whose rows x |kda_lower_bound| must stay under what
+    # float32's exp holds.
+    linear_chunk: int = Field(default=64, gt=0)
 
     @model_validator(mode="after")
     def _check(self) -> "TrunkConfig":
@@ -80,6 +117,46 @@ class TrunkConfig(BaseModel):
                 f"experts_held {self.experts_held} lies outside the "
                 f"{self.num_experts} experts."
             )
+        if self.num_experts % self.n_group or self.topk_group > self.n_group:
+            raise ValueError(
+                f"{self.num_experts} experts do not stand in {self.n_group} "
+                f"groups of which {self.topk_group} stay."
+            )
+        if self.n_group > 1 and (
+            self.num_experts // self.n_group < 2
+            or self.topk_group * (self.num_experts // self.n_group)
+            < self.num_experts_per_tok
+        ):
+            raise ValueError(
+                "a group holds fewer than 2 experts, or the groups that stay "
+                "fewer than num_experts_per_tok."
+            )
+        kinds = set(self.layer_types)
+        softmax = kinds & {"sliding_attention", "full_attention"}
+        if softmax and self.qk_norm is not True:
+            raise ValueError(f"{sorted(softmax)} layers are written for qk_norm True.")
+        if "sliding_attention" in kinds and (
+            self.sliding_window is None or self.rope_layers != "sliding"
+        ):
+            raise ValueError(
+                "sliding_attention layers need sliding_window and "
+                'rope_layers "sliding".'
+            )
+        if "linear_attention" in kinds and self.qk_norm != "l2":
+            raise ValueError('linear_attention layers are written for qk_norm "l2".')
+        if "latent_attention" in kinds:
+            widths = (
+                self.kv_lora_rank, self.qk_nope_head_dim,
+                self.qk_rope_head_dim, self.v_head_dim,
+            )
+            if None in widths or self.rope_layers != "latent":
+                raise ValueError(
+                    "latent_attention layers need kv_lora_rank, "
+                    "qk_nope_head_dim, qk_rope_head_dim, v_head_dim and "
+                    'rope_layers "latent".'
+                )
+            if self.qk_rope_head_dim % 2:
+                raise ValueError("qk_rope_head_dim must be even (rotary pairs).")
         return self
 
 

@@ -290,6 +290,16 @@ class AlphaTriangleNet(nn.Module):
                     x.reshape(b, h * w, d)
                 )
                 flat = tokens.reshape(b, -1)
+                # Once each time the net is traced into a program.
+                kinds = cfg.TRUNK.layer_types
+                default_tracer().instant(
+                    "net.trunk",
+                    **{kind: kinds.count(kind) for kind in sorted(set(kinds))},
+                    linear_chunk=cfg.TRUNK.linear_chunk,
+                    block_boards=cfg.TRUNK.block_boards,
+                    batch=b,
+                    seq=h * w,
+                )
         elif cfg.USE_TRANSFORMER and cfg.TRANSFORMER_LAYERS > 0:
             with jax.named_scope("net/encoder"):
                 if x.shape[-1] != cfg.TRANSFORMER_DIM:

@@ -2,20 +2,42 @@
 
 The layers of a routed-expert language model stand where the encoder
 layers stand: tokens are the board's cells in row-major order, the
-cell's index is its position. Per layer (l = 0..):
+cell's index is its position. A layer is a mixer and an MLP. Per layer
+(l = 0..), the mixer `layer_types[l]` names:
 
-- attention: q, k, v without biases, an RMSNorm on q and k per head,
+- softmax attention (`sliding_attention`, `full_attention`): q, k, v
+  without biases, an RMSNorm on q and k per head,
   rotary positions over the whole head on the sliding layers, every
   `num_attention_heads / num_key_value_heads` query heads sharing one
   key/value head, scores in float32 over sqrt(head_dim), masked to
   j <= i and on a sliding layer to i - j < `sliding_window`;
+- `linear_attention` (KDA): q, k, v to heads x head_dim each, every
+  channel through a causal depthwise convolution of
+  `short_conv_kernel_size` taps and SiLU, q and k L2-normalised per
+  head, q scaled by head_dim^-0.5; a log decay per head and channel
+  g = `kda_lower_bound` x sigmoid(exp(A_log) x (x Wf + dt_bias)), a
+  beta = sigmoid(x Wb) per head; the gated delta rule over the tokens
+  (nn/linear_attention.py, its chunked form); an RMSNorm over each
+  head's output, times a gate sigmoid(x Wg) a head, then Wo;
+- `latent_attention` (MLA): q to heads x (`qk_nope_head_dim` +
+  `qk_rope_head_dim`); x Wa to a latent of `kv_lora_rank` and one
+  rotary key of `qk_rope_head_dim` all heads share; the latent under
+  an RMSNorm, expanded to each head's keys (`qk_nope_head_dim`) and
+  values (`v_head_dim`); rotary positions, neighbouring pairs, on the
+  rotary parts; scores over sqrt of the query's whole width, causal
+  softmax, the context times a gate sigmoid(x Wg) a head, then Wo;
+
+and the MLP `mlp_layer_types[l]` names:
+
 - a SwiGLU of `intermediate_size` on a dense layer; on a sparse one a
   sigmoid router over all `num_experts`, the `num_experts_per_tok` of
-  highest score, weights `routed_scaling_factor` x score / (sum of the
-  chosen scores), every expert and the shared expert a SwiGLU of
-  `moe_intermediate_size`;
-- x + norm(f(x)) for attention and MLP alike; a final RMSNorm after
-  the last layer.
+  highest score (with `n_group` > 1 among the `topk_group` groups
+  whose two best scores sum highest), weights `routed_scaling_factor`
+  x score / (sum of the chosen scores), every expert and the shared
+  expert a SwiGLU of `moe_intermediate_size`;
+
+with x + norm(f(x)) (`norm_position` "post") or x + f(norm(x)) ("pre")
+for mixer and MLP alike, and a final RMSNorm after the last layer.
 
 One process holds the experts `experts_held` = (first, count). It
 routes over all of them, sorts the token-expert assignments that fall
@@ -43,6 +65,7 @@ from flax import linen as nn
 from jax import Array
 
 from ..config.model_config import TrunkConfig
+from . import linear_attention as delta_rule
 
 # A share sees num_experts_per_tok x count / num_experts of a block's
 # assignments if routing is even. The expert layer's buffers hold this
@@ -56,23 +79,49 @@ def sparse_layers(cfg: TrunkConfig) -> list[int]:
 
 def param_shapes(cfg: TrunkConfig) -> dict[str, tuple[tuple[int, ...], int]]:
     """name -> (shape, fan_in); fan_in 0 marks a norm's weight (ones),
-    -1 the router's selection bias (noughts)."""
+    -1 the router's selection bias (noughts). Every other parameter is
+    N(0, 1 / fan_in): for a linear layer's `A_log` (fan_in 4) and
+    `dt_bias` (1) that puts the argument of the decay's sigmoid at
+    order 1."""
     d, hd = cfg.hidden_size, cfg.head_dim
-    q_out = cfg.num_attention_heads * hd
+    heads = cfg.num_attention_heads
+    q_out = heads * hd
     kv_out = cfg.num_key_value_heads * hd
     held = cfg.experts_held[1]
     im = cfg.moe_intermediate_size
     shapes: dict[str, tuple[tuple[int, ...], int]] = {}
-    for i, kind in enumerate(cfg.mlp_layer_types):
+    for i, (mixer, kind) in enumerate(zip(cfg.layer_types, cfg.mlp_layer_types)):
         p = f"l{i}_"
         shapes[p + "attn_norm"] = ((d,), 0)
         shapes[p + "mlp_norm"] = ((d,), 0)
-        shapes[p + "wq"] = ((d, q_out), d)
-        shapes[p + "wk"] = ((d, kv_out), d)
-        shapes[p + "wv"] = ((d, kv_out), d)
-        shapes[p + "wo"] = ((q_out, d), q_out)
-        shapes[p + "q_norm"] = ((hd,), 0)
-        shapes[p + "k_norm"] = ((hd,), 0)
+        if mixer == "linear_attention":
+            taps = cfg.short_conv_kernel_size
+            for name in ("wq", "wk", "wv", "wf"):
+                shapes[p + name] = ((d, q_out), d)
+            for name in ("conv_q", "conv_k", "conv_v"):
+                shapes[p + name] = ((taps, q_out), taps)
+            shapes[p + "wb"] = ((d, heads), d)
+            shapes[p + "wg"] = ((d, heads), d)
+            shapes[p + "A_log"] = ((heads,), 4)
+            shapes[p + "dt_bias"] = ((q_out,), 1)
+            shapes[p + "o_norm"] = ((hd,), 0)
+            shapes[p + "wo"] = ((q_out, d), q_out)
+        elif mixer == "latent_attention":
+            rank, rope = cfg.kv_lora_rank, cfg.qk_rope_head_dim
+            wide = cfg.qk_nope_head_dim + cfg.v_head_dim
+            shapes[p + "wq"] = ((d, heads * (cfg.qk_nope_head_dim + rope)), d)
+            shapes[p + "wkv_a"] = ((d, rank + rope), d)
+            shapes[p + "kv_norm"] = ((rank,), 0)
+            shapes[p + "wkv_b"] = ((rank, heads * wide), rank)
+            shapes[p + "wg"] = ((d, heads), d)
+            shapes[p + "wo"] = ((heads * cfg.v_head_dim, d), heads * cfg.v_head_dim)
+        else:
+            shapes[p + "wq"] = ((d, q_out), d)
+            shapes[p + "wk"] = ((d, kv_out), d)
+            shapes[p + "wv"] = ((d, kv_out), d)
+            shapes[p + "wo"] = ((q_out, d), q_out)
+            shapes[p + "q_norm"] = ((hd,), 0)
+            shapes[p + "k_norm"] = ((hd,), 0)
         if kind == "dense":
             wide = cfg.intermediate_size
             shapes[p + "w_gate"] = ((d, wide), d)
@@ -97,19 +146,40 @@ def param_shapes(cfg: TrunkConfig) -> dict[str, tuple[tuple[int, ...], int]]:
 def forward_flops(cfg: TrunkConfig, seq: int) -> int:
     """Matmul FLOP (1 MAC = 2) of the stack on one board of `seq` tokens
     as this share computes it if routing is even: every layer's
-    projections, the score products over the pairs the mask keeps, the
-    dense layer, the router, the shared expert, and
+    projections, the score products over the pairs the mask keeps (a
+    linear layer's convolutions, and its recurrence by its recurrent
+    form: the state read by the key, written, and read by the query,
+    3 x 2 x head_dim^2 a token and head, whatever the chunked form
+    multiplies), the dense layer, the router, the shared expert, and
     `num_experts_per_tok` x held / `num_experts` experts a token."""
     d = cfg.hidden_size
-    q_out = cfg.num_attention_heads * cfg.head_dim
+    heads = cfg.num_attention_heads
+    q_out = heads * cfg.head_dim
     kv_out = cfg.num_key_value_heads * cfg.head_dim
     expert = 2 * 3 * d * cfg.moe_intermediate_size
     here = cfg.num_experts_per_tok * cfg.experts_held[1] / cfg.num_experts
     total = 0.0
     for kind, mlp in zip(cfg.layer_types, cfg.mlp_layer_types):
-        window = cfg.sliding_window if kind == "sliding_attention" else None
-        total += seq * 2 * (d * (q_out + 2 * kv_out) + q_out * d)
-        total += 2 * 2 * q_out * int(causal_mask(seq, window).sum())
+        if kind == "linear_attention":
+            total += seq * 2 * (d * (4 * q_out + 2 * heads) + q_out * d)
+            total += seq * 2 * 3 * cfg.short_conv_kernel_size * q_out
+            total += seq * 3 * 2 * q_out * cfg.head_dim
+        elif kind == "latent_attention":
+            rank, rope = cfg.kv_lora_rank, cfg.qk_rope_head_dim
+            q_wide = cfg.qk_nope_head_dim + rope
+            kv_wide = cfg.qk_nope_head_dim + cfg.v_head_dim
+            total += seq * 2 * (
+                d * (heads * q_wide + rank + rope + heads)
+                + rank * heads * kv_wide
+                + heads * cfg.v_head_dim * d
+            )
+            total += 2 * heads * (q_wide + cfg.v_head_dim) * int(
+                causal_mask(seq, None).sum()
+            )
+        else:
+            window = cfg.sliding_window if kind == "sliding_attention" else None
+            total += seq * 2 * (d * (q_out + 2 * kv_out) + q_out * d)
+            total += 2 * 2 * q_out * int(causal_mask(seq, window).sum())
         if mlp == "dense":
             total += seq * 2 * 3 * d * cfg.intermediate_size
         else:
@@ -188,21 +258,127 @@ def attention(p: dict, x: Array, cfg: TrunkConfig, sliding: bool, dtype) -> Arra
     return _dot(ctx, p["wo"], dtype).astype(dtype)
 
 
+def short_conv(x: Array, taps: Array) -> Array:
+    """A causal depthwise convolution along axis 1 of x (b, s, c), then
+    SiLU: y_t = sum_j taps[j] x_{t - (K - 1) + j}, the last tap on the
+    token itself; float32 inside, x's type out."""
+    count = taps.shape[0]
+    xf = jnp.pad(x.astype(jnp.float32), ((0, 0), (count - 1, 0), (0, 0)))
+    s = x.shape[1]
+    y = sum(
+        xf[:, j : j + s] * taps[j].astype(jnp.float32) for j in range(count)
+    )
+    return jax.nn.silu(y).astype(x.dtype)
+
+
+def _l2(x: Array) -> Array:
+    """x over its norm along the last axis, float32."""
+    xf = x.astype(jnp.float32)
+    return xf * jax.lax.rsqrt(jnp.sum(xf * xf, axis=-1, keepdims=True) + 1e-6)
+
+
+def log_decay(p: dict, x: Array, cfg: TrunkConfig, dtype) -> Array:
+    """g (b, s, heads, head_dim) float32, every entry in
+    (`kda_lower_bound`, 0): the lower-bounded gate."""
+    b, s, _ = x.shape
+    heads, hd = cfg.num_attention_heads, cfg.head_dim
+    f = _dot(x, p["wf"], dtype) + p["dt_bias"].astype(jnp.float32)
+    rate = jnp.exp(p["A_log"].astype(jnp.float32))[:, None]
+    return cfg.kda_lower_bound * jax.nn.sigmoid(rate * f.reshape(b, s, heads, hd))
+
+
+def linear_attention(p: dict, x: Array, cfg: TrunkConfig, dtype) -> Array:
+    b, s, _ = x.shape
+    heads, hd = cfg.num_attention_heads, cfg.head_dim
+    q, k, v = (
+        short_conv(_dot(x, p[w], dtype).astype(dtype), p[c]).reshape(b, s, heads, hd)
+        for w, c in (("wq", "conv_q"), ("wk", "conv_k"), ("wv", "conv_v"))
+    )
+    q, k = _l2(q) * hd**-0.5, _l2(k)
+    g = log_decay(p, x, cfg, dtype)
+    beta = jax.nn.sigmoid(_dot(x, p["wb"], dtype))  # (b, s, heads)
+    gate = jax.nn.sigmoid(_dot(x, p["wg"], dtype))
+
+    def heads_first(y):  # (b, s, heads, ...) -> (b x heads, s, ...)
+        y = jnp.moveaxis(y, 2, 1)
+        return y.reshape(b * heads, s, *y.shape[3:])
+
+    with jax.named_scope("net/trunk/linear_attn/scan"):
+        o = delta_rule.chunked(
+            *(heads_first(y) for y in (q, k, v, g, beta)),
+            cfg.linear_chunk, cfg.kda_lower_bound, dtype,
+        )
+    o = jnp.moveaxis(o.reshape(b, heads, s, hd), 1, 2)  # float32
+    o = rms_norm(o, p["o_norm"], cfg.rms_norm_eps) * gate[..., None]
+    return _dot(o.reshape(b, s, heads * hd), p["wo"], dtype).astype(dtype)
+
+
+def rotate_pairs(x: Array, theta: float) -> Array:
+    """x (b, s, ..., width) turned by its position along axis 1,
+    neighbouring entries paired (the interleaved rotary)."""
+    s, width = x.shape[1], x.shape[-1]
+    inv = theta ** (-np.arange(0, width, 2, dtype=np.float64) / width)
+    angle = np.arange(s, dtype=np.float64)[:, None] * inv[None, :]
+    shape = (1, s) + (1,) * (x.ndim - 3) + (width // 2,)
+    cos = np.cos(angle).astype(np.float32).reshape(shape)
+    sin = np.sin(angle).astype(np.float32).reshape(shape)
+    xf = x.astype(jnp.float32)
+    even, odd = xf[..., 0::2], xf[..., 1::2]
+    turned = jnp.stack([even * cos - odd * sin, odd * cos + even * sin], axis=-1)
+    return turned.reshape(x.shape).astype(x.dtype)
+
+
+def latent_attention(p: dict, x: Array, cfg: TrunkConfig, dtype) -> Array:
+    b, s, _ = x.shape
+    heads, rank = cfg.num_attention_heads, cfg.kv_lora_rank
+    nope, rope, vd = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    q = _dot(x, p["wq"], dtype).astype(dtype).reshape(b, s, heads, nope + rope)
+    q_n, q_r = q[..., :nope], rotate_pairs(q[..., nope:], cfg.rope_theta)
+    latent = _dot(x, p["wkv_a"], dtype).astype(dtype)
+    k_r = rotate_pairs(latent[..., rank:], cfg.rope_theta)  # (b, s, rope)
+    c = rms_norm(latent[..., :rank], p["kv_norm"], cfg.rms_norm_eps)
+    kv = _dot(c, p["wkv_b"], dtype).astype(dtype).reshape(b, s, heads, nope + vd)
+    k_n, v = kv[..., :nope], kv[..., nope:]
+    scores = (
+        jnp.einsum("bqhd,bshd->bhqs", q_n, k_n, preferred_element_type=jnp.float32)
+        + jnp.einsum("bqhd,bsd->bhqs", q_r, k_r, preferred_element_type=jnp.float32)
+    ) / math.sqrt(nope + rope)
+    scores = jnp.where(causal_mask(s, None)[None, None], scores, -jnp.inf)
+    weights = jax.nn.softmax(scores, axis=-1).astype(dtype)
+    ctx = jnp.einsum("bhqs,bshd->bqhd", weights, v, preferred_element_type=jnp.float32)
+    gate = jax.nn.sigmoid(_dot(x, p["wg"], dtype))
+    ctx = (ctx * gate[..., None]).astype(dtype).reshape(b, s, heads * vd)
+    return _dot(ctx, p["wo"], dtype).astype(dtype)
+
+
 def swiglu(x: Array, gate: Array, up: Array, down: Array, dtype) -> Array:
     hidden = jax.nn.silu(_dot(x, gate, dtype)) * _dot(x, up, dtype)
     return _dot(hidden.astype(dtype), down, dtype)
 
 
+def among_groups(biased: Array, groups: int, stay: int) -> Array:
+    """`biased` (T, E) with -inf on every expert outside the `stay`
+    groups (of `groups`, E / groups experts each, in order) whose two
+    highest entries sum highest; of groups that tie the first stays."""
+    t, e = biased.shape
+    best_two, _ = jax.lax.top_k(biased.reshape(t, groups, e // groups), 2)
+    _, kept = jax.lax.top_k(best_two.sum(axis=-1), stay)  # (T, stay)
+    stays = jnp.zeros((t, groups), bool).at[jnp.arange(t)[:, None], kept].set(True)
+    return jnp.where(jnp.repeat(stays, e // groups, axis=1), biased, -jnp.inf)
+
+
 def route(p: dict, x: Array, cfg: TrunkConfig, dtype):
     """x (T, d) -> chosen experts (T, k) int32 and their weights (T, k).
-    A selection bias (`router_bias`) moves the choice, not the weights."""
+    A selection bias (`router_bias`) moves the choice, not the weights;
+    with `n_group` > 1 the choice is among the groups that stay."""
     scores = jax.nn.sigmoid(_dot(x, p["w_router"], dtype))
+    biased = scores
     if cfg.router_bias:
         biased = scores + p["router_bias"].astype(jnp.float32)
-        _, chosen = jax.lax.top_k(biased, cfg.num_experts_per_tok)
-        top = jnp.take_along_axis(scores, chosen, axis=-1)
-    else:
-        top, chosen = jax.lax.top_k(scores, cfg.num_experts_per_tok)
+    if cfg.n_group > 1:
+        biased = among_groups(biased, cfg.n_group, cfg.topk_group)
+    _, chosen = jax.lax.top_k(biased, cfg.num_experts_per_tok)
+    top = jnp.take_along_axis(scores, chosen, axis=-1)
     weight = cfg.routed_scaling_factor * top / top.sum(axis=-1, keepdims=True)
     return chosen.astype(jnp.int32), weight
 
@@ -289,17 +465,31 @@ def sparse_mlp(p: dict, x: Array, cfg: TrunkConfig, dtype):
 
 
 def _residual(f, norm: Array, x: Array, cfg: TrunkConfig) -> Array:
+    if cfg.norm_position == "pre":
+        return x + f(rms_norm(x, norm, cfg.rms_norm_eps))
     return x + rms_norm(f(x), norm, cfg.rms_norm_eps)
 
 
+MIXER_SCOPES = {
+    "sliding_attention": "net/trunk/attn_window",
+    "full_attention": "net/trunk/attn_full",
+    "linear_attention": "net/trunk/linear_attn",
+    "latent_attention": "net/trunk/latent_attn",
+}
+
+
 def attention_block(p: dict, x: Array, cfg: TrunkConfig, i: int, dtype) -> Array:
-    sliding = cfg.layer_types[i] == "sliding_attention"
-    with jax.named_scope(
-        "net/trunk/attn_window" if sliding else "net/trunk/attn_full"
-    ):
-        return _residual(
-            lambda y: attention(p, y, cfg, sliding, dtype), p["attn_norm"], x, cfg
-        )
+    """The layer's first half, its mixer, on x (b, s, d)."""
+    kind = cfg.layer_types[i]
+    if kind == "linear_attention":
+        mixer = lambda y: linear_attention(p, y, cfg, dtype)  # noqa: E731
+    elif kind == "latent_attention":
+        mixer = lambda y: latent_attention(p, y, cfg, dtype)  # noqa: E731
+    else:
+        sliding = kind == "sliding_attention"
+        mixer = lambda y: attention(p, y, cfg, sliding, dtype)  # noqa: E731
+    with jax.named_scope(MIXER_SCOPES[kind]):
+        return _residual(mixer, p["attn_norm"], x, cfg)
 
 
 def mlp_block(p: dict, x: Array, cfg: TrunkConfig, i: int, dtype):
@@ -406,9 +596,14 @@ class DecoderTrunk(nn.Module):
             routed = tokens.shape[0] * tokens.shape[1] * (
                 cfg.num_experts_per_tok * len(sparse_layers(cfg))
             )
-            for name, value in (
-                ("expert_tokens", counts), ("routed", jnp.int32(routed))
-            ):
+            sown = [("expert_tokens", counts), ("routed", jnp.int32(routed))]
+            linear = cfg.layer_types.count("linear_attention")
+            if linear:  # tokens x linear layers the recurrence took
+                sown.append(
+                    ("linear_tokens",
+                     jnp.int32(tokens.shape[0] * tokens.shape[1] * linear))
+                )
+            for name, value in sown:
                 self.sow(
                     "counters", name, value,
                     reduce_fn=lambda _, new: new, init_fn=lambda: None,
@@ -417,7 +612,8 @@ class DecoderTrunk(nn.Module):
 
 
 def counters_of(state: dict) -> dict:
-    """{"expert_tokens", "routed"} out of what `apply(...,
-    mutable=["counters"])` returned beside the net's outputs."""
+    """{"expert_tokens", "routed"}, and "linear_tokens" where the stack
+    has linear layers, out of what `apply(..., mutable=["counters"])`
+    returned beside the net's outputs."""
     (sown,) = state["counters"].values()
     return dict(sown)

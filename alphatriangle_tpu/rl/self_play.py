@@ -310,9 +310,11 @@ class SelfPlayEngine:
         self._total_reused_visits = 0
         # A routed trunk's counters (nn/trunk.py), summed like the
         # simulations: assignments each held expert computed, (sparse
-        # layers, held), and the assignments routed to any expert.
+        # layers, held), the assignments routed to any expert, and the
+        # tokens the linear layers' recurrence took.
         self._expert_tokens: np.ndarray | None = None
         self._routed_assignments = 0
+        self._linear_tokens = 0
         # Cumulative host-blocking harvest-fetch seconds (the chunk's
         # device_get — includes any wait for the chunk to finish, i.e.
         # the host-visible round-trip cost telemetry/perf.py reports).
@@ -655,7 +657,8 @@ class SelfPlayEngine:
         if out.net_counters is not None:
             # A routed trunk's counters for this move's search
             # (nn/trunk.py): the assignments each held expert computed,
-            # (sparse layers, held) int32, and all the router made.
+            # (sparse layers, held) int32, all the router made, and
+            # with linear layers the tokens their recurrence took.
             outputs["trace"].update(out.net_counters)
         return new_carry, outputs
 
@@ -739,6 +742,10 @@ class SelfPlayEngine:
                 )
                 self._routed_assignments += int(
                     host["trace"]["routed"].sum(dtype=np.int64)
+                )
+            if "linear_tokens" in host["trace"]:
+                self._linear_tokens += int(
+                    host["trace"]["linear_tokens"].sum(dtype=np.int64)
                 )
 
             self.last_trace = host["trace"]
@@ -890,6 +897,7 @@ class SelfPlayEngine:
             total_reused_visits=self._total_reused_visits,
             expert_tokens=self._expert_tokens,
             routed_assignments=self._routed_assignments,
+            linear_tokens=self._linear_tokens,
             trainer_step_at_episode_start=(
                 self._min_weights_version
                 if self._min_weights_version is not None
@@ -906,5 +914,6 @@ class SelfPlayEngine:
         self._total_reused_visits = 0
         self._expert_tokens = None
         self._routed_assignments = 0
+        self._linear_tokens = 0
         self._min_weights_version = None
         return result

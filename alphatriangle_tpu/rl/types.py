@@ -48,9 +48,11 @@ class SelfPlayResult(BaseModel):
     # A routed trunk's counters over the harvest (nn/trunk.py): the
     # token-expert assignments each held expert computed, (sparse
     # layers, held) int64, and the assignments routed to any expert.
-    # None / 0 for a net that routes nothing.
+    # None / 0 for a net that routes nothing. `linear_tokens`: tokens x
+    # linear-attention layers the recurrence took (0 without such layers).
     expert_tokens: np.ndarray | None = None
     routed_assignments: int = 0
+    linear_tokens: int = 0
     # Weight version the producing rollout ran with (staleness tag,
     # reference `rl/types.py:22` / `worker.py:136-139`).
     trainer_step_at_episode_start: int = 0

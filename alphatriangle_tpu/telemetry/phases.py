@@ -36,6 +36,11 @@ PHASES = (
     "net/trunk",
     "net/trunk/attn_window",
     "net/trunk/attn_full",
+    # a linear-attention layer's mixer (projections, convolutions,
+    # norms, gate), and inside it the recurrence alone
+    "net/trunk/linear_attn",
+    "net/trunk/linear_attn/scan",
+    "net/trunk/latent_attn",
     "net/trunk/dense_mlp",
     "net/trunk/router",
     "net/trunk/experts",

@@ -348,20 +348,45 @@ class TestPhaseNamesInPrograms:
             "jit(encoder_layer)/encoder_layer/pallas_call"
         ) == "net/encoder"
 
+    @pytest.mark.parametrize(
+        "stack,absent",
+        [
+            (
+                dict(
+                    num_key_value_heads=1, sliding_window=4,
+                    layer_types=["sliding_attention", "full_attention"],
+                ),
+                {"net/trunk/linear_attn", "net/trunk/linear_attn/scan",
+                 "net/trunk/latent_attn"},
+            ),
+            (
+                dict(
+                    num_key_value_heads=2, norm_position="pre", qk_norm="l2",
+                    rope_layers="latent", kv_lora_rank=8, qk_nope_head_dim=16,
+                    qk_rope_head_dim=8, v_head_dim=16, linear_chunk=16,
+                    n_group=2, topk_group=1,
+                    layer_types=["linear_attention", "latent_attention"],
+                ),
+                {"net/trunk/attn_window", "net/trunk/attn_full"},
+            ),
+        ],
+        ids=["softmax", "hybrid"],
+    )
     def test_trunk_phases_in_the_chunk_of_a_decoder_stack(
-        self, world, tiny_mcts_config
+        self, world, tiny_mcts_config, stack, absent
     ):
+        """Each stack's chunk carries the phases of the layers it has,
+        and the two stacks together every `net/trunk` phase."""
         from alphatriangle_tpu.config import TrunkConfig
         from alphatriangle_tpu.features.core import get_feature_extractor
         from alphatriangle_tpu.nn.network import NeuralNetwork
         from alphatriangle_tpu.rl.self_play import SelfPlayEngine
 
         trunk = TrunkConfig(
-            hidden_size=32, num_attention_heads=2, num_key_value_heads=1,
+            hidden_size=32, num_attention_heads=2,
             head_dim=16, intermediate_size=48, moe_intermediate_size=16,
-            num_experts=4, num_experts_per_tok=2, sliding_window=4,
-            layer_types=["sliding_attention", "full_attention"],
-            mlp_layer_types=["dense", "sparse"], experts_held=(0, 2),
+            num_experts=4, num_experts_per_tok=2,
+            mlp_layer_types=["dense", "sparse"], experts_held=(0, 2), **stack,
         )
         env = world["env"]
         model = world["net"].model_config.model_copy(update={"TRUNK": trunk})
@@ -373,8 +398,12 @@ class TestPhaseNamesInPrograms:
         text = _lowered_text(
             engine._chunk_fn(2)._jit_fn, net.variables, engine._carry, jnp.int32(0)
         )
-        assert {p for p in _phases("net/trunk") if p not in text} == set()
-        assert len(_phases("net/trunk")) == 7 and "net/encoder" not in text
+        assert {p for p in _phases("net/trunk") if p not in text} == absent
+        assert len(_phases("net/trunk")) == 10 and "net/encoder" not in text
+        assert profiling.phase_of(
+            "jit(chunk)/search/evaluate/net/trunk/net/trunk/linear_attn/"
+            "net/trunk/linear_attn/scan/while/body/dot_general"
+        ) == "net/trunk/linear_attn/scan"
         assert profiling.phase_of(
             "jit(chunk)/search/evaluate/net/trunk/net/trunk/experts/ragged_dot"
         ) == "net/trunk/experts"

@@ -201,3 +201,312 @@ def test_the_flagship_is_untouched():
     assert hashlib.sha256(text.encode()).hexdigest() == (
         "38e83a843ed4b28038158af0da96781ef86f017e95ce3ce5ee1c2f347727ef47"
     )
+
+
+# --- the hybrid stack: linear and latent layers, a router with groups ------
+
+HYBRID = dict(
+    hidden_size=64, num_attention_heads=4, num_key_value_heads=4, head_dim=16,
+    intermediate_size=96, moe_intermediate_size=32, num_experts=8,
+    num_experts_per_tok=2, num_shared_experts=1, routed_scaling_factor=2.5,
+    n_group=2, topk_group=1, rms_norm_eps=1e-6, rope_theta=6e6,
+    kv_lora_rank=16, qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+    layer_types=["linear_attention", "linear_attention", "latent_attention"],
+    mlp_layer_types=["dense", "sparse", "sparse"], experts_held=(2, 2),
+    norm_position="pre", qk_norm="l2", rope_layers="latent", linear_chunk=16,
+    router_bias=True,
+)
+# The published widths of the benchmark's ling-flash-ep4 (one chip's share).
+HYBRID_PUBLISHED = dict(
+    HYBRID, hidden_size=2560, num_attention_heads=32, num_key_value_heads=32,
+    head_dim=128, intermediate_size=6144, moe_intermediate_size=768,
+    num_experts=512, num_experts_per_tok=8, n_group=8, topk_group=4,
+    kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+    layer_types=["linear_attention"] * 5 + ["latent_attention", "linear_attention"],
+    mlp_layer_types=["dense"] + ["sparse"] * 6, experts_held=(0, 128),
+    linear_chunk=64,
+)
+
+
+def _delta_rule_inputs(seq, dk=32, dv=16, n=6, seed=0):
+    keys = jax.random.split(jax.random.PRNGKey(seed), 5)
+    unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)  # noqa: E731
+    q = unit(jax.random.normal(keys[0], (n, seq, dk)))
+    k = unit(jax.random.normal(keys[1], (n, seq, dk)))
+    v = jax.random.normal(keys[2], (n, seq, dv))
+    g = -5.0 * jax.nn.sigmoid(jax.random.normal(keys[3], (n, seq, dk)))
+    beta = jax.nn.sigmoid(jax.random.normal(keys[4], (n, seq)))
+    return q, k, v, g, beta
+
+
+@pytest.mark.parametrize("seq", [12, 77, 252])
+@pytest.mark.parametrize("chunk", [16, 64])
+@pytest.mark.parametrize("decay", ["drawn", "lower_bound", "none"])
+def test_the_chunked_recurrence_is_the_token_by_token_one(seq, chunk, decay):
+    """At sequences that are no multiple of the chunk, with decays
+    drawn over (-5, 0), with g = -5 on every step of every chunk (16
+    rows gather exp 80: no inf, no nan, the same answer) and with next
+    to no decay (the state keeps everything)."""
+    from alphatriangle_tpu.nn import linear_attention as delta_rule
+
+    q, k, v, g, beta = _delta_rule_inputs(seq)
+    if decay == "lower_bound":
+        g = jnp.full_like(g, -5.0)
+    elif decay == "none":
+        g = jnp.full_like(g, -1e-4)
+    want = delta_rule.recurrent(q, k, v, g, beta)
+    got = delta_rule.chunked(q, k, v, g, beta, chunk, -5.0, jnp.float32)
+    assert got.shape == want.shape == (6, seq, 16)
+    assert bool(jnp.isfinite(got).all())
+    assert float(jnp.abs(got - want).max()) < 2e-5 * max(1.0, float(jnp.abs(want).max()))
+
+
+def test_the_chunked_recurrence_refuses_what_float32_cannot_hold():
+    from alphatriangle_tpu.nn import linear_attention as delta_rule
+
+    q, k, v, g, beta = _delta_rule_inputs(12)
+    with pytest.raises(ValueError, match="sub-blocks"):
+        delta_rule.chunked(q, k, v, g, beta, 24, -5.0, jnp.float32)
+    with pytest.raises(ValueError, match="float32"):
+        delta_rule.chunked(q, k, v, g, beta, 16, -6.0, jnp.float32)
+
+
+def test_the_recurrence_is_causal_and_its_state_decays():
+    """Change token 5: outputs before it stay, outputs from it on move;
+    at the lower bound what token 5 wrote is gone sixteen tokens on."""
+    from alphatriangle_tpu.nn import linear_attention as delta_rule
+
+    q, k, v, g, beta = _delta_rule_inputs(40)
+    g = jnp.full_like(g, -5.0)
+    a = delta_rule.chunked(q, k, v, g, beta, 16, -5.0, jnp.float32)
+    b = delta_rule.chunked(q, k, v.at[:, 5].add(1.0), g, beta, 16, -5.0, jnp.float32)
+    moved = np.asarray(jnp.abs(a - b).max(axis=(0, 2)))
+    assert (moved[:5] == 0).all() and moved[5] > 1e-3
+    assert moved[21:].max() < 1e-30
+
+
+def test_the_short_convolution_is_causal_and_ends_on_the_token():
+    x = jax.random.normal(jax.random.PRNGKey(0), (2, 9, 3))
+    taps = jnp.asarray([[0.0] * 3, [0.0] * 3, [0.0] * 3, [1.0] * 3])
+    assert np.allclose(trunk.short_conv(x, taps), jax.nn.silu(x), atol=1e-6)
+    taps = jax.random.normal(jax.random.PRNGKey(1), (4, 3))
+    a = trunk.short_conv(x, taps)
+    b = trunk.short_conv(x.at[:, 4].add(1.0), taps)
+    differs = np.asarray(jnp.abs(a - b).max(axis=(0, 2)) > 0)
+    assert differs.tolist() == [False] * 4 + [True] * 4 + [False]
+
+
+def test_interleaved_rotary_positions_are_relative():
+    q = jax.random.normal(jax.random.PRNGKey(2), (1, 1, 8))
+    k = jax.random.normal(jax.random.PRNGKey(3), (1, 1, 8))
+    qs = trunk.rotate_pairs(jnp.repeat(q, 12, axis=1), 6e6)
+    ks = trunk.rotate_pairs(jnp.repeat(k, 12, axis=1), 6e6)
+    scores = np.asarray(qs[0] @ ks[0].T)
+    assert scores[5, 2] == pytest.approx(scores[9, 6], rel=1e-4)
+    assert scores[5, 2] != pytest.approx(scores[5, 3], rel=1e-4)
+    # Neighbours are a pair: entries 0 and 1 turn by the position itself.
+    one = trunk.rotate_pairs(jnp.zeros((1, 3, 8)).at[:, :, 0].set(1.0), 6e6)
+    assert np.allclose(one[0, 2, :2], [np.cos(2.0), np.sin(2.0)], atol=1e-6)
+    assert np.allclose(one[0, 2, 2:], 0.0)
+
+
+def test_the_grouped_choice_against_a_plain_sort_ties_included():
+    """Scores rounded to halves tie in every row: of groups that tie
+    the first stays, of experts that tie the first is chosen."""
+    x = np.asarray(
+        jnp.round(jax.random.normal(jax.random.PRNGKey(0), (512, 16)) * 2) / 2 + 0.0
+    )
+    among = np.asarray(trunk.among_groups(jnp.asarray(x), 4, 2))
+    chosen = np.asarray(jax.lax.top_k(jnp.asarray(among), 3)[1])
+    ties = 0
+    for row, picked in zip(x, chosen):
+        worth = np.sort(row.reshape(4, 4), axis=1)[:, -2:].sum(axis=1)
+        kept = sorted(range(4), key=lambda j: (-worth[j], j))[:2]
+        allowed = [e for e in range(16) if e // 4 in kept]
+        want = sorted(allowed, key=lambda e: (-row[e], e))[:3]
+        assert picked.tolist() == want
+        ties += len(set(row[allowed])) < len(allowed)
+    assert ties > 100
+    assert np.isneginf(among).sum(axis=1).tolist() == [8] * 512
+
+
+@pytest.mark.parametrize(
+    "change,message",
+    [
+        (dict(qk_norm=True), 'qk_norm "l2"'),
+        (dict(rope_layers="sliding"), 'rope_layers "latent"'),
+        (dict(kv_lora_rank=None), "kv_lora_rank"),
+        (dict(qk_rope_head_dim=7), "even"),
+        (dict(n_group=3), "groups"),
+        (dict(topk_group=3), "groups"),
+        (dict(n_group=8), "fewer than 2"),
+        (dict(num_experts_per_tok=6), "fewer than 2 experts, or"),
+        (dict(layer_types=["sliding_attention"] * 3), "qk_norm True"),
+        (dict(layer_types=["linear_attention"] * 3, norm_position="mid"), "norm_position"),
+    ],
+)
+def test_a_stack_the_layers_were_not_written_for_is_refused(change, message):
+    with pytest.raises(ValueError, match=message):
+        TrunkConfig(**{**HYBRID, **change})
+    TrunkConfig(**HYBRID)
+    TrunkConfig(**{**TINY, "norm_position": "pre"})  # either placement, any mixer
+
+
+def test_the_hybrid_stack_at_its_published_widths_counts_its_bytes_and_flops(
+    tiny_model_config, tiny_env_config, tiny_train_config, monkeypatch
+):
+    """4.97B parameters in the stack (issue 32's arithmetic: a KDA mixer
+    52.6M, the MLA mixer 32.0M, a sparse layer's share 128 x 5.90M +
+    7.2M, the dense MLP 47.2M); a trainer refuses it by its bytes."""
+    from alphatriangle_tpu.rl.trainer import Trainer
+    from alphatriangle_tpu.telemetry.memory import BYTES_LIMIT_ENV
+
+    cfg = TrunkConfig(**HYBRID_PUBLISHED)
+    shapes = trunk.param_shapes(cfg)
+    size = lambda i: sum(  # noqa: E731
+        int(np.prod(shape)) for name, (shape, _) in shapes.items()
+        if name.startswith(f"l{i}_")
+    )
+    mixer = lambda i, names: sum(  # noqa: E731
+        int(np.prod(shapes[f"l{i}_{name}"][0])) for name in names
+    )
+    kda = mixer(0, ["wq", "wk", "wv", "wf", "wo", "conv_q", "conv_k", "conv_v",
+                    "wb", "wg", "A_log", "dt_bias", "o_norm"])
+    mla = mixer(5, ["wq", "wkv_a", "kv_norm", "wkv_b", "wg", "wo"])
+    assert kda == 5 * 2560 * 4096 + 3 * 4 * 4096 + 2 * 2560 * 32 + 4096 + 32 + 128
+    assert kda == pytest.approx(52.6e6, rel=2e-3)
+    assert mla == 2560 * 6144 + 2560 * 576 + 512 + 512 * 8192 + 2560 * 32 + 4096 * 2560
+    assert mla == pytest.approx(32.0e6, rel=2e-3)
+    assert size(0) == kda + 2 * 2560 + 3 * 2560 * 6144
+    expert = 3 * 2560 * 768
+    assert size(1) == kda + 2 * 2560 + 2560 * 512 + 512 + 129 * expert
+    count = sum(int(np.prod(shape)) for shape, _ in shapes.values())
+    assert count == pytest.approx(4.968e9, rel=1e-3)
+    # The recurrence by its recurrent form, the routed experts if even.
+    per_token = trunk.forward_flops(cfg, 252) / 252
+    assert per_token == pytest.approx(1.040e9, rel=2e-3)
+    assert 6 * 3 * 2 * 4096 * 128 / per_token == pytest.approx(0.018, abs=1e-3)
+
+    model = tiny_model_config.model_copy(
+        update={"TRUNK": cfg, "PARAM_DTYPE": "bfloat16"}
+    )
+    module = AlphaTriangleNet(model, tiny_env_config.action_dim)
+    net_shapes = jax.eval_shape(
+        lambda k: module.init(
+            k, jnp.zeros((1, 1, 3, 4)),
+            jnp.zeros((1, model.OTHER_NN_INPUT_FEATURES_DIM)), train=False,
+        ),
+        jax.random.PRNGKey(0),
+    )
+    whole = sum(int(np.prod(x.shape)) for x in jax.tree_util.tree_leaves(net_shapes))
+    net = NeuralNetwork(model, tiny_env_config, variables=net_shapes)
+    monkeypatch.setenv(BYTES_LIMIT_ENV, str(16 * 2**30))  # one v5e chip
+    with pytest.raises(ValueError, match="of training state") as refusal:
+        Trainer(net, tiny_train_config)
+    # Four copies in the parameters' own type and the biases' float32:
+    # 39.8 GB for a 16 GiB chip, and named to the byte.
+    assert 8 * whole < int(
+        str(refusal.value).split(" need ")[1].split(" B of")[0].replace(",", "")
+    ) < 8 * whole + 4 * 4 * 6 * 512 * 2
+    assert 8 * whole == pytest.approx(39.8e9, rel=5e-3)
+
+
+def test_the_search_counts_the_tokens_the_recurrence_took(
+    tiny_model_config, tiny_env_config, tiny_mcts_config, tiny_train_config
+):
+    """One chunk of self-play through the hybrid stack: the harvest
+    carries `linear_tokens` beside the experts' two counters, the
+    engine sums it, and a stack without linear layers sows none."""
+    from alphatriangle_tpu.env.engine import TriangleEnv
+    from alphatriangle_tpu.features.core import get_feature_extractor
+    from alphatriangle_tpu.rl.self_play import SelfPlayEngine
+    from alphatriangle_tpu.telemetry.tracer import default_tracer
+
+    model = tiny_model_config.model_copy(
+        update={"TRUNK": TrunkConfig(**{**HYBRID, "block_boards": 8})}
+    )
+    env = TriangleEnv(tiny_env_config)
+    net = NeuralNetwork(model, tiny_env_config, seed=0)
+    seen = []
+    tracer = default_tracer()
+    monkey = pytest.MonkeyPatch()
+    monkey.setattr(
+        tracer, "instant", lambda name, **fields: seen.append((name, fields))
+    )
+    try:
+        engine = SelfPlayEngine(
+            env, get_feature_extractor(env, model), net, tiny_mcts_config,
+            tiny_train_config, seed=0,
+        )
+        result = engine.play_moves(2)
+    finally:
+        monkey.undo()
+    lanes, sims = tiny_train_config.SELF_PLAY_BATCH_SIZE, tiny_mcts_config.max_simulations
+    evaluations = 2 * lanes * (sims + 1)
+    assert result.linear_tokens == evaluations * 12 * 2  # two linear layers
+    assert result.routed_assignments == evaluations * 12 * 2 * 2
+    assert result.expert_tokens.shape == (2, 2)
+    assert engine.last_trace["linear_tokens"].shape == (2,)
+    assert engine.harvest().linear_tokens == 0  # summed anew each harvest
+    instants = [fields for name, fields in seen if name == "net.trunk"]
+    assert instants and instants[0] == {
+        "linear_attention": 2, "latent_attention": 1, "linear_chunk": 16,
+        "block_boards": 8, "batch": instants[0]["batch"], "seq": 12,
+    }
+
+    softmax = tiny_model_config.model_copy(update={"TRUNK": TrunkConfig(**TINY)})
+    other = NeuralNetwork(softmax, tiny_env_config, seed=0)
+    _, state = other.model.apply(
+        other.variables, jnp.zeros((2, 1, 3, 4)),
+        jnp.zeros((2, softmax.OTHER_NN_INPUT_FEATURES_DIM)),
+        train=False, mutable=["counters"],
+    )
+    assert set(trunk.counters_of(state)) == {"expert_tokens", "routed"}
+
+
+def test_k_exaone_is_untouched():
+    """With the hybrid layers beside them the softmax layers, the
+    ungrouped router and the post-norm placement trace as they did: the
+    parameter tree and the lowered forward of `k-exaone-ep8` at its
+    published widths, with and without the counters, are the parent
+    commit's (565ec99), where the three digests were taken with this
+    test's code."""
+    from chipbench import manifest
+    from chipbench import reference_exaone_moe as plain
+
+    cfg = manifest.load_json(manifest.HERE / "configs" / "k-exaone-ep8.json")
+    configs = manifest.program_configs(cfg)
+    model = configs["model"].model_copy(
+        update={"TRUNK": TrunkConfig(**plain.trunk_settings(cfg))}
+    )
+    env = configs["env"]
+    module = AlphaTriangleNet(model, env.action_dim)
+    grid = jax.ShapeDtypeStruct(
+        (2, model.GRID_INPUT_CHANNELS, env.ROWS, env.COLS), jnp.float32
+    )
+    other = jax.ShapeDtypeStruct((2, model.OTHER_NN_INPUT_FEATURES_DIM), jnp.float32)
+    shapes = jax.eval_shape(
+        lambda k: module.init(
+            k, jnp.zeros(grid.shape), jnp.zeros(other.shape), train=False
+        ),
+        jax.random.PRNGKey(0),
+    )
+    tree = [
+        (jax.tree_util.keystr(k), tuple(v.shape), str(v.dtype))
+        for k, v in jax.tree_util.tree_leaves_with_path(shapes)
+    ]
+    assert len(tree) == 122
+    assert hashlib.sha256(json.dumps(tree).encode()).hexdigest() == (
+        "c5a84736dac4a96309f2175d3e72e69c34736629c97e2d7f1b1446f7843d292b"
+    )
+    digests = [
+        hashlib.sha256(
+            jax.jit(lambda v, g, o: module.apply(v, g, o, train=False, **more))
+            .lower(shapes, grid, other).as_text().encode()
+        ).hexdigest()
+        for more in ({}, {"mutable": ["counters"]})
+    ]
+    assert digests == [
+        "64faad0ab2b6cda7fe8847fcdc8fcc8dc1464e02177810b5ac1244abe9966de3",
+        "49981403830c91696af6d0e849e3ee7414f4f3c70dc7b0834089fca436bd3154",
+    ]
