@@ -40,13 +40,29 @@ Norms, decays, states and A, P are float32; the operands of the matrix
 products are `dtype`, but for the triangular inverse, which is kept in
 float32 at the highest precision (a wrong digit there is multiplied by
 every later token of the chunk).
+
+Three forms of one sum, and where each runs. `recurrent` is the
+definition and the tests' oracle: nothing calls it in a program.
+`chunked` is plain `jax.numpy` and runs wherever a linear-attention
+layer is traced for anything but one TPU chip: the CPU, heads that are
+not whole 128-lane blocks (the tests' dk = 32, dv = 16), a program the
+compiler partitions over a mesh. On one TPU chip with heads of 128 the
+layer runs `ops/delta_rule.py`'s kernel, which is `chunked`'s
+mathematics, the same sub-blocks and bound, with a chunk's
+intermediates kept in VMEM and the triangle inverted by blocks of
+`SUB`; `nn/trunk.py` picks between the two by what the trace can
+observe (`ops/delta_rule.linear_path`), no option names either. The
+doublings of a whole chunk below lose every digit where the keys of a
+chunk repeat each other and nothing decays (powers of a 64 x 64
+triangle with entries near 1 pass 1e10 before they cancel:
+tests/test_ops_delta_rule.py holds the kernel there, not this form).
 """
 
 import jax
 import jax.numpy as jnp
 from jax import Array
 
-SUB = 16  # rows of a sub-block: SUB x |lower_bound| < 88, float32's exp
+from ..ops.delta_rule import SUB  # rows of a sub-block, the kernel's too
 
 
 def recurrent(q: Array, k: Array, v: Array, g: Array, beta: Array) -> Array:

@@ -33,7 +33,7 @@ from jax import Array
 from ..config.model_config import ModelConfig
 from ..ops.encoder_layer import encoder_layer, layer_path, partitioned
 from ..telemetry.tracer import default_tracer
-from .trunk import DecoderTrunk
+from .trunk import DecoderTrunk, recurrence_path
 
 _ACTIVATIONS: dict[str, Callable[[Array], Array]] = {
     "ReLU": nn.relu,
@@ -290,11 +290,18 @@ class AlphaTriangleNet(nn.Module):
                     x.reshape(b, h * w, d)
                 )
                 flat = tokens.reshape(b, -1)
-                # Once each time the net is traced into a program.
+                # Once each time the net is traced into a program. The
+                # linear layers are alike, so they took one path: the one
+                # `nn/trunk.py` read from the same tokens.
                 kinds = cfg.TRUNK.layer_types
+                taken = {"kernel": 0, "chunked": 0}
+                taken[recurrence_path(cfg.TRUNK, tokens, dtype)] = kinds.count(
+                    "linear_attention"
+                )
                 default_tracer().instant(
                     "net.trunk",
                     **{kind: kinds.count(kind) for kind in sorted(set(kinds))},
+                    linear_path=taken,
                     linear_chunk=cfg.TRUNK.linear_chunk,
                     block_boards=cfg.TRUNK.block_boards,
                     batch=b,

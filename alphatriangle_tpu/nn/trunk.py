@@ -65,6 +65,8 @@ from flax import linen as nn
 from jax import Array
 
 from ..config.model_config import TrunkConfig
+from ..ops.delta_rule import gated_delta_rule, linear_path
+from ..ops.encoder_layer import partitioned
 from . import linear_attention as delta_rule
 
 # A share sees num_experts_per_tok x count / num_experts of a block's
@@ -287,6 +289,21 @@ def log_decay(p: dict, x: Array, cfg: TrunkConfig, dtype) -> Array:
     return cfg.kda_lower_bound * jax.nn.sigmoid(rate * f.reshape(b, s, heads, hd))
 
 
+def recurrence_path(cfg: TrunkConfig, x: Array, dtype) -> str:
+    """"kernel" (ops/delta_rule.py) or "chunked" (nn/linear_attention.py)
+    for the recurrence of a linear-attention layer on x (b, s, d), from
+    what this trace can observe: no field of the config chooses."""
+    return linear_path(
+        partitioned=partitioned(x),
+        backend=jax.default_backend(),
+        seq=x.shape[1],
+        head_dim=cfg.head_dim,
+        chunk=cfg.linear_chunk,
+        lower_bound=cfg.kda_lower_bound,
+        dtype=dtype,
+    )
+
+
 def linear_attention(p: dict, x: Array, cfg: TrunkConfig, dtype) -> Array:
     b, s, _ = x.shape
     heads, hd = cfg.num_attention_heads, cfg.head_dim
@@ -303,12 +320,25 @@ def linear_attention(p: dict, x: Array, cfg: TrunkConfig, dtype) -> Array:
         y = jnp.moveaxis(y, 2, 1)
         return y.reshape(b * heads, s, *y.shape[3:])
 
+    # A head is a 128-lane block of (b, s, heads x hd): the kernel reads
+    # it there, and nothing is moved heads-first or back.
+    kernel = recurrence_path(cfg, x, dtype) == "kernel"
     with jax.named_scope("net/trunk/linear_attn/scan"):
-        o = delta_rule.chunked(
-            *(heads_first(y) for y in (q, k, v, g, beta)),
-            cfg.linear_chunk, cfg.kda_lower_bound, dtype,
-        )
-    o = jnp.moveaxis(o.reshape(b, heads, s, hd), 1, 2)  # float32
+        if kernel:
+            o = gated_delta_rule(
+                *(y.reshape(b, s, heads * hd) for y in (q, k, v, g)), beta,
+                heads=heads, chunk=cfg.linear_chunk,
+                lower_bound=cfg.kda_lower_bound, dtype=jnp.dtype(dtype),
+            )
+        else:
+            o = delta_rule.chunked(
+                *(heads_first(y) for y in (q, k, v, g, beta)),
+                cfg.linear_chunk, cfg.kda_lower_bound, dtype,
+            )
+    if kernel:
+        o = o.reshape(b, s, heads, hd)
+    else:
+        o = jnp.moveaxis(o.reshape(b, heads, s, hd), 1, 2)  # float32
     o = rms_norm(o, p["o_norm"], cfg.rms_norm_eps) * gate[..., None]
     return _dot(o.reshape(b, s, heads * hd), p["wo"], dtype).astype(dtype)
 

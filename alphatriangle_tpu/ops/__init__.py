@@ -3,9 +3,10 @@
 The four ops exported here follow one pattern (docs/KERNELS.md): a
 Pallas TPU lowering plus interchangeable XLA lowerings, numerically
 pinned against each other by parity tests, with a config knob selecting
-the backend. `encoder_layer.py` has no knob: `nn/model.py` runs an
-encoder layer as that kernel or as Flax's modules by what the call can
-observe.
+the backend. `encoder_layer.py` and `delta_rule.py` have no knob:
+`nn/model.py` runs an encoder layer as that kernel or as Flax's modules,
+and `nn/trunk.py` a linear-attention layer's recurrence as that kernel
+or as `nn/linear_attention.chunked`, by what the call can observe.
 """
 
 from .gather_rows import gather_rows

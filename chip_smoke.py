@@ -108,12 +108,19 @@ def _train_config(base, **overrides):
     )
 
 
-def kernel_shapes(cfgs: dict) -> dict:
-    """The operand shapes the five kernels see under `cfgs`: B games, a
+# The recurrence of a linear-attention mixer as the benchmark's
+# `ling-flash-ep4` runs it (no preset has such a layer): a block of 64
+# boards, 32 heads of 128, the 252 cells of its board, chunks of 64.
+RECURRENCE = {"boards": 64, "heads": 32, "tokens": 252, "head_dim": 128, "chunk": 64}
+
+
+def kernel_shapes(cfgs: dict, recurrence: "dict | None" = None) -> dict:
+    """The operand shapes the six kernels see under `cfgs`: B games, a
     tree of N nodes x A actions searched W leaves at a time to depth D,
-    a ring of `capacity` rows sampled k x b at a time, and an encoder
+    a ring of `capacity` rows sampled k x b at a time, an encoder
     layer over the leaf wave of a fast search (of a full one where the
-    playout cap is off): `leaves` boards of `tokens` cells, `dim` wide."""
+    playout cap is off): `leaves` boards of `tokens` cells, `dim` wide;
+    and `recurrence`, which no preset sizes (`RECURRENCE`)."""
     from alphatriangle_tpu.mcts.search import tree_geometry
 
     mcts, train, model = cfgs["mcts"], cfgs["train"], cfgs["model"]
@@ -145,6 +152,7 @@ def kernel_shapes(cfgs: dict) -> dict:
         "mlp_dim": model.TRANSFORMER_FC_DIM,
         "activation": model.ACTIVATION_FUNCTION,
         "compute_dtype": model.COMPUTE_DTYPE,
+        "recurrence": dict(recurrence or RECURRENCE),
     }
 
 
@@ -154,12 +162,15 @@ def kernel_cases(shapes: dict) -> list[dict]:
     node/action indices, a real forest for the promotion), `xla` names
     the reference lowering. docs/KERNELS.md: the first four are exact;
     `encoder_layer` rounds where Flax's layer rounds (`tolerance`) and
-    is `timed` beside it."""
+    is `timed` beside it; `delta_rule` is held to the token-by-token
+    recurrence, and the chunked form it replaces on a TPU (`beside`) is
+    held to it and timed too."""
     # gather_rows is held to "take", a pure copy. Whether the default
     # one-hot einsum is exact on the MXU too is reported, not required.
     import jax
     import jax.numpy as jnp
 
+    from alphatriangle_tpu.nn import linear_attention
     from alphatriangle_tpu.nn.model import _ACTIVATIONS, TransformerEncoderLayer
     from alphatriangle_tpu.ops import (
         backup_update,
@@ -167,6 +178,7 @@ def kernel_cases(shapes: dict) -> list[dict]:
         per_sample,
         subtree_promote,
     )
+    from alphatriangle_tpu.ops.delta_rule import gated_delta_rule
     from alphatriangle_tpu.ops.encoder_layer import encoder_layer
 
     b, n, w = shapes["batch"], shapes["nodes"], shapes["wave"]
@@ -278,6 +290,49 @@ def kernel_cases(shapes: dict) -> list[dict]:
             interpret=jax.default_backend() != "tpu",
         )
 
+    rec = shapes["recurrence"]
+    rec_dtype = jnp.dtype(shapes["compute_dtype"])
+
+    def recurrence_operands(key):
+        """q, k, v, g, beta as a mixer hands them over: unit keys,
+        queries over sqrt(head_dim), v in the compute type, g over
+        (-5, 0), beta over (0, 1)."""
+        ks = jax.random.split(key, 5)
+        shape = (rec["boards"], rec["tokens"], rec["heads"], rec["head_dim"])
+        unit = lambda x: x * jax.lax.rsqrt(  # noqa: E731
+            jnp.sum(x * x, axis=-1, keepdims=True)
+        )
+        return (
+            unit(jax.random.normal(ks[0], shape)) * rec["head_dim"] ** -0.5,
+            unit(jax.random.normal(ks[1], shape)),
+            jax.random.normal(ks[2], shape).astype(rec_dtype),
+            -5.0 * jax.nn.sigmoid(jax.random.normal(ks[3], shape)),
+            jax.nn.sigmoid(jax.random.normal(ks[4], shape[:3])),
+        )
+
+    def run_recurrence(mode, q, k, v, g, beta):
+        """o (boards, tokens, heads x head_dim) float32, by the kernel,
+        the chunked form or the recurrence itself."""
+        boards, tokens, heads, hd = q.shape
+        if mode == "pallas":
+            return gated_delta_rule(
+                *(y.reshape(boards, tokens, heads * hd) for y in (q, k, v, g)),
+                beta, heads=heads, chunk=rec["chunk"], lower_bound=-5.0,
+                dtype=rec_dtype, interpret=jax.default_backend() != "tpu",
+            )
+
+        def heads_first(y):
+            y = jnp.moveaxis(y, 2, 1)
+            return y.reshape(boards * heads, tokens, *y.shape[3:])
+
+        operands = [heads_first(y) for y in (q, k, v, g, beta)]
+        if mode == "chunked":
+            o = linear_attention.chunked(*operands, rec["chunk"], -5.0, rec_dtype)
+        else:
+            o = linear_attention.recurrent(*operands)
+        o = jnp.moveaxis(o.reshape(boards, heads, tokens, hd), 1, 2)
+        return o.reshape(boards, tokens, heads * hd)
+
     def run_per(mode, priorities, key):
         return per_sample(priorities, cap, k, bs, key, mode=mode)
 
@@ -326,6 +381,17 @@ def kernel_cases(shapes: dict) -> list[dict]:
             # steps of 2^-5 there); Flax rounds it at both adds, every
             # product's output and its softmax, the kernel once.
             "tolerance": 0.13,
+            "timed": True,
+        },
+        {
+            "name": "delta_rule",
+            "xla": "recurrent",
+            "run": run_recurrence,
+            "operands": recurrence_operands,
+            # Outputs up to 0.1; bfloat16 operands move one by up to
+            # 0.002 on either path (PERF.md, PR 33).
+            "tolerance": 0.01,
+            "beside": "chunked",
             "timed": True,
         },
     ]
@@ -729,8 +795,9 @@ def _device_ops_ms(fn, operands, calls: int = 5, top: int = 6) -> dict:
     return {name: round(ns / calls / 1e6, 3) for name, ns in dearest}
 
 
-def phase_kernels(cfgs: dict) -> dict:
-    """Each Pallas kernel at the shapes of `cfgs`, compiled for this
+def phase_kernels(cfgs: dict, recurrence: "dict | None" = None) -> dict:
+    """Each Pallas kernel at the shapes of `cfgs` (the recurrence's at
+    `recurrence`, else `RECURRENCE`), compiled for this
     backend and compared with its XLA lowering on the same operands.
     On a TPU the compiled text must hold the kernel: the dispatchers
     interpret it on any other backend, which proves nothing here."""
@@ -738,7 +805,7 @@ def phase_kernels(cfgs: dict) -> dict:
     import numpy as np
 
     on_tpu = jax.default_backend() == "tpu"
-    shapes = kernel_shapes(cfgs)
+    shapes = kernel_shapes(cfgs, recurrence)
     parity: dict = {}
     wrong = []
     for i, case in enumerate(kernel_cases(shapes)):
@@ -777,10 +844,23 @@ def phase_kernels(cfgs: dict) -> dict:
             "parity": verdict,
             "seconds": round(time.monotonic() - t0, 1),
         }
+        # What the kernel is timed beside: its reference, or (`beside`)
+        # the lowering it replaces where the reference is only an
+        # oracle; that one is held to the reference as well.
+        beside, timed = case["xla"], reference
+        if "beside" in case:
+            beside = case["beside"]
+            timed = jax.jit(functools.partial(case["run"], beside))
+            gap = float(
+                np.max(np.abs(np.asarray(timed(*operands), np.float64) - want[0]))
+            )
+            if gap > case["tolerance"]:
+                wrong.append(f"{case['name']}: {beside} differs (max |diff| {gap:.3g})")
+            parity[case["name"]][beside] = f"max |diff| {gap:.3g}"
         if case.get("timed"):
             parity[case["name"]]["device_ms_a_call"] = {
                 "pallas": _device_ops_ms(compiled, operands),
-                case["xla"]: _device_ops_ms(reference, operands, top=16),
+                beside: _device_ops_ms(timed, operands, top=16),
             }
     _check(not wrong, f"kernels: {wrong}; parity so far: {parity}")
     from alphatriangle_tpu.ops import gather_rows

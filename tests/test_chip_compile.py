@@ -26,7 +26,7 @@ from alphatriangle_tpu.config.presets import baseline_preset  # noqa: E402
 
 KERNELS = (
     "gather_rows", "backup_update", "per_sample", "subtree_promote",
-    "encoder_layer",
+    "encoder_layer", "delta_rule",
 )
 
 
@@ -92,7 +92,7 @@ def _compiled_text(case: dict, one_v5e_chip) -> str:
 
 
 def _layer_case(shapes: dict) -> dict:
-    case = chip_smoke.kernel_cases(shapes)[-1]
+    case = chip_smoke.kernel_cases(shapes)[-2]
     assert case["name"] == "encoder_layer"
     return case
 
@@ -116,6 +116,25 @@ def test_encoder_layer_compiles_at_252_tokens(one_v5e_chip, monkeypatch):
     assert (shapes["leaves"], shapes["tokens"]) == (32768, 252)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     assert "tpu_custom_call" in _compiled_text(_layer_case(shapes), one_v5e_chip)
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_delta_rule_compiles_at_the_cells_shape(one_v5e_chip, monkeypatch, dtype):
+    """`ling-flash-rollout`'s recurrence: a block of 64 boards x 32
+    heads of 128 over 252 tokens (the last chunk four tokens short),
+    bfloat16 operands as the configuration states them, and float32
+    ones (every product then at the highest precision)."""
+    shapes = {
+        **chip_smoke.kernel_shapes(baseline_preset(3)), "compute_dtype": dtype,
+    }
+    assert shapes["recurrence"] == {
+        "boards": 64, "heads": 32, "tokens": 252, "head_dim": 128, "chunk": 64,
+    }
+    case = chip_smoke.kernel_cases(shapes)[-1]
+    assert case["name"] == "delta_rule"
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    text = _compiled_text(case, one_v5e_chip)
+    assert "tpu_custom_call" in text and "delta_rule" in text
 
 
 @pytest.mark.parametrize("activation", ["GELU", "SiLU", "Tanh", "Sigmoid"])

@@ -104,10 +104,19 @@ def test_kernels_phase_matches_xla(tiny_cfgs):
             update={"SELF_PLAY_BATCH_SIZE": 2}
         ),
     }
-    out = chip_smoke.phase_kernels(small)
+    out = chip_smoke.phase_kernels(
+        small,
+        recurrence={"boards": 2, "heads": 2, "tokens": 20, "head_dim": 128, "chunk": 16},
+    )
     assert out["compiled"] is False  # interpreted here, and it says so
     layer = out["parity"].pop("encoder_layer")
+    rule = out["parity"].pop("delta_rule")
     assert {k["parity"] for k in out["parity"].values()} == {"exact"}
+    # The sixth is held to the token-by-token recurrence, and so is the
+    # chunked form beside it: both gaps are in the step's line.
+    assert rule["vs"] == "recurrent" and "rounding" in rule["parity"]
+    assert rule["chunked"].startswith("max |diff| ")
+    assert rule["device_ms_a_call"] == {"pallas": {}, "chunked": {}}
     # Not exact: the kernel's own order of sums against Flax's layer;
     # timed beside it (a CPU trace has no device operations to list).
     assert layer["vs"] == "flax" and "rounding" in layer["parity"]
@@ -185,6 +194,10 @@ def test_flagship_shapes_are_preset_three():
         # The leaf wave of a fast search: 512 lanes x 16 simulations.
         "leaves": 8192, "tokens": 120, "dim": 128, "heads": 4,
         "mlp_dim": 256, "activation": "ReLU", "compute_dtype": "bfloat16",
+        # No preset's: ling-flash-rollout's mixer, a block of 64 boards.
+        "recurrence": {
+            "boards": 64, "heads": 32, "tokens": 252, "head_dim": 128, "chunk": 64,
+        },
     }
 
 

@@ -451,6 +451,7 @@ def test_the_search_counts_the_tokens_the_recurrence_took(
     instants = [fields for name, fields in seen if name == "net.trunk"]
     assert instants and instants[0] == {
         "linear_attention": 2, "latent_attention": 1, "linear_chunk": 16,
+        "linear_path": {"kernel": 0, "chunked": 2},  # the CPU, heads of 16
         "block_boards": 8, "batch": instants[0]["batch"], "seq": 12,
     }
 
@@ -462,6 +463,106 @@ def test_the_search_counts_the_tokens_the_recurrence_took(
         train=False, mutable=["counters"],
     )
     assert set(trunk.counters_of(state)) == {"expert_tokens", "routed"}
+
+
+def _trunk_instants(monkeypatch):
+    from alphatriangle_tpu.telemetry.tracer import default_tracer
+
+    seen = []
+    monkeypatch.setattr(
+        default_tracer(), "instant",
+        lambda name, **fields: seen.append(fields) if name == "net.trunk" else None,
+    )
+    return seen
+
+
+@pytest.mark.parametrize("compute", ["float32", "bfloat16"])
+def test_the_hybrid_net_through_the_kernel(
+    tiny_model_config, tiny_env_config, monkeypatch, compute
+):
+    """Heads of 128 on a backend said to be a TPU: every linear layer's
+    recurrence runs as ops/delta_rule.py's kernel (interpreted here),
+    the instant says so, and the net's answer is the chunked path's:
+    to float32 rounding with float32 operands, within what bfloat16
+    moves a logit of this net with bfloat16 ones."""
+    import functools
+
+    model = tiny_model_config.model_copy(
+        update={
+            "COMPUTE_DTYPE": compute,
+            "TRUNK": TrunkConfig(
+                **{**HYBRID, "head_dim": 128, "num_attention_heads": 2,
+                   "num_key_value_heads": 2}
+            ),
+        }
+    )
+    net = NeuralNetwork(model, tiny_env_config, seed=0)
+    grid = jax.random.normal(jax.random.PRNGKey(1), (3, 1, 3, 4))
+    other = jax.random.normal(
+        jax.random.PRNGKey(2), (3, model.OTHER_NN_INPUT_FEATURES_DIM)
+    )
+    seen = _trunk_instants(monkeypatch)
+    chunked = net.model.apply(net.variables, grid, other, train=False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(
+        trunk, "gated_delta_rule",
+        functools.partial(trunk.gated_delta_rule, interpret=True),
+    )
+    kernel = net.model.apply(net.variables, grid, other, train=False)
+    assert [fields["linear_path"] for fields in seen] == [
+        {"kernel": 0, "chunked": 2}, {"kernel": 2, "chunked": 0},
+    ]
+    limit = 1e-4 if compute == "float32" else 0.05
+    for got, want in zip(kernel, chunked):
+        assert got.shape == want.shape and bool(jnp.isfinite(got).all())
+        assert float(jnp.abs(got - want).max()) < limit
+
+
+def test_ling_flash_on_the_cpu_is_untouched():
+    """Where the kernel does not engage (here: the CPU) the hybrid
+    stack's program is the one it was: the parameter tree and the
+    lowered forward of `ling-flash-ep4` at its published widths, with
+    and without the counters, are the parent commit's (39f919d), where
+    the three digests were taken with this test's code."""
+    from chipbench import manifest
+    from chipbench import reference_ling_hybrid as plain
+
+    cfg = manifest.load_json(manifest.HERE / "configs" / "ling-flash-ep4.json")
+    configs = manifest.program_configs(cfg)
+    model = configs["model"].model_copy(
+        update={"TRUNK": TrunkConfig(**plain.trunk_settings(cfg))}
+    )
+    env = configs["env"]
+    module = AlphaTriangleNet(model, env.action_dim)
+    grid = jax.ShapeDtypeStruct(
+        (2, model.GRID_INPUT_CHANNELS, env.ROWS, env.COLS), jnp.float32
+    )
+    other = jax.ShapeDtypeStruct((2, model.OTHER_NN_INPUT_FEATURES_DIM), jnp.float32)
+    shapes = jax.eval_shape(
+        lambda k: module.init(
+            k, jnp.zeros(grid.shape), jnp.zeros(other.shape), train=False
+        ),
+        jax.random.PRNGKey(0),
+    )
+    tree = [
+        (jax.tree_util.keystr(k), tuple(v.shape), str(v.dtype))
+        for k, v in jax.tree_util.tree_leaves_with_path(shapes)
+    ]
+    assert len(tree) == 196
+    assert hashlib.sha256(json.dumps(tree).encode()).hexdigest() == (
+        "09cbe9a0414e0acda3b839ac8b50ee7a4692b2a1d1484f921ca861a18d02232d"
+    )
+    digests = [
+        hashlib.sha256(
+            jax.jit(lambda v, g, o: module.apply(v, g, o, train=False, **more))
+            .lower(shapes, grid, other).as_text().encode()
+        ).hexdigest()
+        for more in ({}, {"mutable": ["counters"]})
+    ]
+    assert digests == [
+        "a83088e49001785d71ca1ff2facda2c6a1abe9eff65bffde98b985de1b95290b",
+        "66a80929039be090eb0c1616ea3d9014d0d507f1917f182e816b6e8abaff087b",
+    ]
 
 
 def test_k_exaone_is_untouched():
