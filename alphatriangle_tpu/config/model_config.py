@@ -23,9 +23,13 @@ class TrunkConfig(BaseModel):
     KDA: short causal convolutions, a recurrent state of head_dim x
     head_dim a head, no score matrix) and latent attention
     (`latent_attention`, MLA: keys and values expanded from a latent of
-    `kv_lora_rank`, a rotary part all heads share). The keys are a
+    `kv_lora_rank`, a rotary part all heads share; with `q_lora_rank`
+    the query too comes from a latent, under an RMSNorm; `latent_gate`
+    False leaves the head-wise output gate out). The keys are a
     published `config.json`'s, under its names; layer l is
-    `layer_types[l]` with `mlp_layer_types[l]`.
+    `layer_types[l]` with `mlp_layer_types[l]`. `head_dim` is the
+    softmax and linear layers' width of a head: a stack whose mixers
+    are all latent has none and reads none.
 
     `experts_held` = (first, count): the router scores all
     `num_experts`; this process computes the experts it holds for the
@@ -49,7 +53,7 @@ class TrunkConfig(BaseModel):
     hidden_size: int = Field(gt=0)
     num_attention_heads: int = Field(gt=0)
     num_key_value_heads: int = Field(gt=0)
-    head_dim: int = Field(gt=0)
+    head_dim: int | None = Field(default=None, gt=0)
     intermediate_size: int = Field(gt=0)
     moe_intermediate_size: int = Field(gt=0)
     num_experts: int = Field(gt=0)
@@ -79,6 +83,10 @@ class TrunkConfig(BaseModel):
     qk_nope_head_dim: int | None = Field(default=None, gt=0)
     qk_rope_head_dim: int | None = Field(default=None, gt=0)
     v_head_dim: int | None = Field(default=None, gt=0)
+    # The query's latent (q = RMSNorm(x Wq_a) Wq_b); None = x Wq. And
+    # whether the context is gated a head by sigmoid(x Wg) before Wo.
+    q_lora_rank: int | None = Field(default=None, gt=0)
+    latent_gate: bool = Field(default=True)
 
     norm_position: Literal["post", "pre"] = Field(default="post")
     qk_norm: Literal[True, "l2"] = Field(default=True)
@@ -90,6 +98,15 @@ class TrunkConfig(BaseModel):
     # Boards the net takes at a time where a search evaluates a leaf
     # batch (cut into such blocks inside the program); None = all at once.
     block_boards: int | None = Field(default=None, gt=0)
+    # Boards a learner step takes at a time (rl/trainer.py: forward and
+    # backward a block, the gradients added in float32, one optimizer
+    # update a step); None = the whole batch at once.
+    learner_block_boards: int | None = Field(default=None, gt=0)
+    # What a training step moves each selection bias by, against the
+    # sign of its expert's load error (the bias is reached by no
+    # gradient: rl/trainer.py); read only with `router_bias`. Nought:
+    # the biases stay what the checkpoint brought.
+    router_bias_rate: float = Field(default=0.0, ge=0)
     # Tokens a linear layer's recurrence takes at a time (its chunked
     # form, nn/linear_attention.py): a multiple of that module's
     # sub-block, whose rows x |kda_lower_bound| must stay under what
@@ -107,7 +124,13 @@ class TrunkConfig(BaseModel):
                 f"num_attention_heads ({self.num_attention_heads}) must be a "
                 f"multiple of num_key_value_heads ({self.num_key_value_heads})."
             )
-        if self.head_dim % 2:
+        if self.head_dim is None:
+            if set(self.layer_types) - {"latent_attention"}:
+                raise ValueError(
+                    "head_dim may be left out only where every mixer is "
+                    "latent_attention."
+                )
+        elif self.head_dim % 2:
             raise ValueError("head_dim must be even (rotary pairs).")
         if self.num_experts_per_tok > self.num_experts:
             raise ValueError("num_experts_per_tok exceeds num_experts.")
@@ -209,7 +232,8 @@ class ModelConfig(BaseModel):
     # "bfloat16": parameters are made and held in bfloat16 (a trunk
     # published in it, too large to keep a float32 original beside).
     PARAM_DTYPE: Literal["float32", "bfloat16"] = Field(default="float32")
-    # jax.checkpoint the residual + transformer blocks to trade FLOPs for HBM.
+    # jax.checkpoint the residual + transformer blocks, and in a training
+    # forward each layer of a TRUNK, to trade FLOPs for HBM.
     REMAT: bool = Field(default=False)
     # Param dtype the INFERENCE family (rollout chunk, serve dispatch,
     # arena/eval) reads the network at; the learner family always

@@ -271,9 +271,12 @@ class AlphaTriangleNet(nn.Module):
                 block = ResidualBlock
                 if cfg.REMAT:
                     block = nn.remat(ResidualBlock, static_argnums=(2,))
-                for _ in range(cfg.NUM_RESIDUAL_BLOCKS):
+                for i in range(cfg.NUM_RESIDUAL_BLOCKS):
+                    # Recomputed or not, a block's variables keep its name.
+                    named = {"name": f"ResidualBlock_{i}"} if cfg.REMAT else {}
                     x = block(
-                        cfg.RESIDUAL_BLOCK_FILTERS, cfg.NORM_TYPE, act, dtype, pdtype
+                        cfg.RESIDUAL_BLOCK_FILTERS, cfg.NORM_TYPE, act, dtype, pdtype,
+                        **named,
                     )(x, train)
 
         if cfg.TRUNK is not None:
@@ -286,8 +289,8 @@ class AlphaTriangleNet(nn.Module):
                 if x.shape[-1] != d:
                     x = nn.Conv(d, (1, 1), dtype=dtype, param_dtype=pdtype)(x)
                 b, h, w, _ = x.shape
-                tokens = DecoderTrunk(cfg.TRUNK, dtype, pdtype)(
-                    x.reshape(b, h * w, d)
+                tokens = DecoderTrunk(cfg.TRUNK, dtype, pdtype, remat=cfg.REMAT)(
+                    x.reshape(b, h * w, d), train
                 )
                 flat = tokens.reshape(b, -1)
                 # Once each time the net is traced into a program. The
@@ -295,15 +298,24 @@ class AlphaTriangleNet(nn.Module):
                 # `nn/trunk.py` read from the same tokens.
                 kinds = cfg.TRUNK.layer_types
                 taken = {"kernel": 0, "chunked": 0}
-                taken[recurrence_path(cfg.TRUNK, tokens, dtype)] = kinds.count(
-                    "linear_attention"
-                )
+                if "linear_attention" in kinds:
+                    taken[recurrence_path(cfg.TRUNK, tokens, dtype)] = kinds.count(
+                        "linear_attention"
+                    )
                 default_tracer().instant(
                     "net.trunk",
                     **{kind: kinds.count(kind) for kind in sorted(set(kinds))},
                     linear_path=taken,
                     linear_chunk=cfg.TRUNK.linear_chunk,
                     block_boards=cfg.TRUNK.block_boards,
+                    # latent layers whose query comes from its own latent
+                    latent_q_compressed=(
+                        kinds.count("latent_attention")
+                        if cfg.TRUNK.q_lora_rank
+                        else 0
+                    ),
+                    learner_block_boards=cfg.TRUNK.learner_block_boards,
+                    remat_layers=len(kinds) if cfg.REMAT and train else 0,
                     batch=b,
                     seq=h * w,
                 )
@@ -339,7 +351,10 @@ class AlphaTriangleNet(nn.Module):
                     head_dim=d // cfg.TRANSFORMER_HEADS,
                     mlp_dim=cfg.TRANSFORMER_FC_DIM,
                 )
-                for _ in range(cfg.TRANSFORMER_LAYERS):
+                for i in range(cfg.TRANSFORMER_LAYERS):
+                    named = (
+                        {"name": f"TransformerEncoderLayer_{i}"} if cfg.REMAT else {}
+                    )
                     tokens = layer(
                         cfg.TRANSFORMER_DIM,
                         cfg.TRANSFORMER_HEADS,
@@ -349,6 +364,7 @@ class AlphaTriangleNet(nn.Module):
                         attention_fn=self.attention_fn,
                         param_dtype=pdtype,
                         fused=fused,
+                        **named,
                     )(tokens, train)
                 # Once each time the net is traced into a program (a
                 # reloaded executable is not traced again).

@@ -20,12 +20,14 @@ cell's index is its position. A layer is a mixer and an MLP. Per layer
   (nn/linear_attention.py, its chunked form); an RMSNorm over each
   head's output, times a gate sigmoid(x Wg) a head, then Wo;
 - `latent_attention` (MLA): q to heads x (`qk_nope_head_dim` +
-  `qk_rope_head_dim`); x Wa to a latent of `kv_lora_rank` and one
+  `qk_rope_head_dim`), with `q_lora_rank` through a latent of its own
+  (q = RMSNorm(x Wq_a) Wq_b); x Wa to a latent of `kv_lora_rank` and one
   rotary key of `qk_rope_head_dim` all heads share; the latent under
   an RMSNorm, expanded to each head's keys (`qk_nope_head_dim`) and
   values (`v_head_dim`); rotary positions, neighbouring pairs, on the
   rotary parts; scores over sqrt of the query's whole width, causal
-  softmax, the context times a gate sigmoid(x Wg) a head, then Wo;
+  softmax, the context times a gate sigmoid(x Wg) a head (unless
+  `latent_gate` is off), then Wo;
 
 and the MLP `mlp_layer_types[l]` names:
 
@@ -48,6 +50,16 @@ for the other holders or their exchange. The assignments it computed
 are counted per expert (`counters` collection, sown only where the
 caller asks for it).
 
+A training forward (`DecoderTrunk(...)(tokens, train=True)`) also counts
+the assignments the router made to every one of `num_experts`, held
+here or not (`expert_loads`: what the rule that moves the selection
+biases reads, `moved_router_biases`), and with `remat` runs each layer
+under `jax.checkpoint`, so that a backward pass holds one layer's
+activations at a time. Every function here is differentiated by the
+learner (rl/trainer.py), the expert layer's sort, grouped products and
+gather among them; the selection bias moves the choice alone and no
+gradient reaches it.
+
 The layers are plain functions of a parameter dict; `DecoderTrunk`
 declares the parameters, in `param_dtype`, straight from the key. A leaf
 batch too large for the device is cut into blocks of `block_boards`
@@ -56,6 +68,7 @@ net runs on a block at a time, so no activation of the full batch at
 this width ever exists.
 """
 
+import functools
 import math
 
 import jax
@@ -85,7 +98,7 @@ def param_shapes(cfg: TrunkConfig) -> dict[str, tuple[tuple[int, ...], int]]:
     N(0, 1 / fan_in): for a linear layer's `A_log` (fan_in 4) and
     `dt_bias` (1) that puts the argument of the decay's sigmoid at
     order 1."""
-    d, hd = cfg.hidden_size, cfg.head_dim
+    d, hd = cfg.hidden_size, cfg.head_dim or 0  # no head_dim: all latent
     heads = cfg.num_attention_heads
     q_out = heads * hd
     kv_out = cfg.num_key_value_heads * hd
@@ -111,11 +124,18 @@ def param_shapes(cfg: TrunkConfig) -> dict[str, tuple[tuple[int, ...], int]]:
         elif mixer == "latent_attention":
             rank, rope = cfg.kv_lora_rank, cfg.qk_rope_head_dim
             wide = cfg.qk_nope_head_dim + cfg.v_head_dim
-            shapes[p + "wq"] = ((d, heads * (cfg.qk_nope_head_dim + rope)), d)
+            q_wide, q_rank = heads * (cfg.qk_nope_head_dim + rope), cfg.q_lora_rank
+            if q_rank is None:
+                shapes[p + "wq"] = ((d, q_wide), d)
+            else:
+                shapes[p + "wq_a"] = ((d, q_rank), d)
+                shapes[p + "q_a_norm"] = ((q_rank,), 0)
+                shapes[p + "wq_b"] = ((q_rank, q_wide), q_rank)
             shapes[p + "wkv_a"] = ((d, rank + rope), d)
             shapes[p + "kv_norm"] = ((rank,), 0)
             shapes[p + "wkv_b"] = ((rank, heads * wide), rank)
-            shapes[p + "wg"] = ((d, heads), d)
+            if cfg.latent_gate:
+                shapes[p + "wg"] = ((d, heads), d)
             shapes[p + "wo"] = ((heads * cfg.v_head_dim, d), heads * cfg.v_head_dim)
         else:
             shapes[p + "wq"] = ((d, q_out), d)
@@ -156,8 +176,8 @@ def forward_flops(cfg: TrunkConfig, seq: int) -> int:
     `num_experts_per_tok` x held / `num_experts` experts a token."""
     d = cfg.hidden_size
     heads = cfg.num_attention_heads
-    q_out = heads * cfg.head_dim
-    kv_out = cfg.num_key_value_heads * cfg.head_dim
+    q_out = heads * (cfg.head_dim or 0)
+    kv_out = cfg.num_key_value_heads * (cfg.head_dim or 0)
     expert = 2 * 3 * d * cfg.moe_intermediate_size
     here = cfg.num_experts_per_tok * cfg.experts_held[1] / cfg.num_experts
     total = 0.0
@@ -170,8 +190,15 @@ def forward_flops(cfg: TrunkConfig, seq: int) -> int:
             rank, rope = cfg.kv_lora_rank, cfg.qk_rope_head_dim
             q_wide = cfg.qk_nope_head_dim + rope
             kv_wide = cfg.qk_nope_head_dim + cfg.v_head_dim
+            q_rank = cfg.q_lora_rank
+            query = (
+                d * heads * q_wide
+                if q_rank is None
+                else q_rank * (d + heads * q_wide)
+            )
             total += seq * 2 * (
-                d * (heads * q_wide + rank + rope + heads)
+                query
+                + d * (rank + rope + heads * cfg.latent_gate)
                 + rank * heads * kv_wide
                 + heads * cfg.v_head_dim * d
             )
@@ -362,7 +389,14 @@ def latent_attention(p: dict, x: Array, cfg: TrunkConfig, dtype) -> Array:
     b, s, _ = x.shape
     heads, rank = cfg.num_attention_heads, cfg.kv_lora_rank
     nope, rope, vd = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
-    q = _dot(x, p["wq"], dtype).astype(dtype).reshape(b, s, heads, nope + rope)
+    if cfg.q_lora_rank is None:
+        q = _dot(x, p["wq"], dtype)
+    else:
+        c_q = rms_norm(
+            _dot(x, p["wq_a"], dtype).astype(dtype), p["q_a_norm"], cfg.rms_norm_eps
+        )
+        q = _dot(c_q, p["wq_b"], dtype)
+    q = q.astype(dtype).reshape(b, s, heads, nope + rope)
     q_n, q_r = q[..., :nope], rotate_pairs(q[..., nope:], cfg.rope_theta)
     latent = _dot(x, p["wkv_a"], dtype).astype(dtype)
     k_r = rotate_pairs(latent[..., rank:], cfg.rope_theta)  # (b, s, rope)
@@ -376,8 +410,9 @@ def latent_attention(p: dict, x: Array, cfg: TrunkConfig, dtype) -> Array:
     scores = jnp.where(causal_mask(s, None)[None, None], scores, -jnp.inf)
     weights = jax.nn.softmax(scores, axis=-1).astype(dtype)
     ctx = jnp.einsum("bhqs,bshd->bqhd", weights, v, preferred_element_type=jnp.float32)
-    gate = jax.nn.sigmoid(_dot(x, p["wg"], dtype))
-    ctx = (ctx * gate[..., None]).astype(dtype).reshape(b, s, heads * vd)
+    if cfg.latent_gate:
+        ctx = ctx * jax.nn.sigmoid(_dot(x, p["wg"], dtype))[..., None]
+    ctx = ctx.astype(dtype).reshape(b, s, heads * vd)
     return _dot(ctx, p["wo"], dtype).astype(dtype)
 
 
@@ -421,7 +456,8 @@ def _ragged(x: Array, w: Array, sizes: Array, dtype) -> Array:
 
 
 def routed_experts(p: dict, x: Array, chosen: Array, weight: Array,
-                   cfg: TrunkConfig, dtype):
+                   cfg: TrunkConfig, dtype, train: bool = False,
+                   remat: bool = False):
     """The held experts' part of the routed sum for tokens x (T, d),
     float32, and how many tokens each held expert computed (count,).
 
@@ -429,7 +465,16 @@ def routed_experts(p: dict, x: Array, chosen: Array, weight: Array,
     taken `rows` at a time through the grouped products, `rows` being
     `USUAL_ROOM` times what even routing would bring here: one round as
     a rule, as many as the block's routing needs otherwise, each with
-    the same buffers. Nothing is dropped."""
+    the same buffers. Nothing is dropped.
+
+    `train` says the call will be differentiated: the rows of a round
+    past its assignments are then noughts going in as they are coming
+    out, because the grouped products' transposes leave in such rows
+    whatever they find (on the chip: stray values, which the gather's
+    transpose would add into the tokens' gradients). With `remat` (under
+    recomputation) a backward pass computes a round again and keeps no
+    round's products for it: every round's would stand side by side
+    otherwise, taken or not."""
     t, d = x.shape
     k = cfg.num_experts_per_tok
     first, held = cfg.experts_held
@@ -454,6 +499,8 @@ def routed_experts(p: dict, x: Array, chosen: Array, weight: Array,
         )
         taken = jax.lax.dynamic_slice_in_dim(order, low, rows)
         xs = x[taken // k]
+        if train:
+            xs = jnp.where(jnp.arange(rows)[:, None] < inside.sum(), xs, 0)
         hidden = jax.nn.silu(_ragged(xs, p["e_gate"], inside, dtype)) * _ragged(
             xs, p["e_up"], inside, dtype
         )
@@ -478,20 +525,37 @@ def routed_experts(p: dict, x: Array, chosen: Array, weight: Array,
     y = jnp.zeros((t, d), jnp.float32)
     if rounds == 1:
         return one_round(0, y), sizes
+    if remat:
+        maybe = jax.checkpoint(maybe)
     return jax.lax.fori_loop(0, rounds, maybe, y), sizes
 
 
 def sparse_mlp(p: dict, x: Array, cfg: TrunkConfig, dtype):
+    """-> (the layer's output, the assignments each held expert
+    computed (count,))."""
+    return sparse_mlp_counted(p, x, cfg, dtype)[:2]
+
+
+def sparse_mlp_counted(p: dict, x: Array, cfg: TrunkConfig, dtype,
+                       train=False, remat=False):
+    """`sparse_mlp`, and third in a training forward (`train`) the
+    assignments the router made to each of `num_experts` (E,) int32,
+    else None."""
     b, s, d = x.shape
     flat = x.reshape(b * s, d)
+    loads = None
     with jax.named_scope("net/trunk/router"):
         chosen, weight = route(p, flat, cfg, dtype)
+        if train:
+            loads = jnp.zeros((cfg.num_experts,), jnp.int32).at[
+                chosen.reshape(-1)
+            ].add(1)
     with jax.named_scope("net/trunk/experts"):
-        y, sizes = routed_experts(p, flat, chosen, weight, cfg, dtype)
+        y, sizes = routed_experts(p, flat, chosen, weight, cfg, dtype, train, remat)
     if cfg.num_shared_experts:
         with jax.named_scope("net/trunk/shared_expert"):
             y = y + swiglu(flat, p["s_gate"], p["s_up"], p["s_down"], dtype)
-    return y.astype(dtype).reshape(b, s, d), sizes
+    return y.astype(dtype).reshape(b, s, d), sizes, loads
 
 
 def _residual(f, norm: Array, x: Array, cfg: TrunkConfig) -> Array:
@@ -522,9 +586,11 @@ def attention_block(p: dict, x: Array, cfg: TrunkConfig, i: int, dtype) -> Array
         return _residual(mixer, p["attn_norm"], x, cfg)
 
 
-def mlp_block(p: dict, x: Array, cfg: TrunkConfig, i: int, dtype):
+def mlp_block(p: dict, x: Array, cfg: TrunkConfig, i: int, dtype,
+              train=False, remat=False):
     """The layer's second half on x (b, s, d); the second value is a
-    sparse layer's per-expert count, None on a dense layer."""
+    sparse layer's per-expert count, None on a dense layer, the third
+    `sparse_mlp_counted`'s loads."""
     if cfg.mlp_layer_types[i] == "dense":
         with jax.named_scope("net/trunk/dense_mlp"):
             return _residual(
@@ -534,19 +600,22 @@ def mlp_block(p: dict, x: Array, cfg: TrunkConfig, i: int, dtype):
                 p["mlp_norm"],
                 x,
                 cfg,
-            ), None
-    sizes = None
+            ), None, None
+    sizes = loads = None
 
     def mlp(y):
-        nonlocal sizes
-        out, sizes = sparse_mlp(p, y, cfg, dtype)
+        nonlocal sizes, loads
+        out, sizes, loads = sparse_mlp_counted(p, y, cfg, dtype, train, remat)
         return out
 
-    return _residual(mlp, p["mlp_norm"], x, cfg), sizes
+    return _residual(mlp, p["mlp_norm"], x, cfg), sizes, loads
 
 
-def decoder_layer(p: dict, x: Array, cfg: TrunkConfig, i: int, dtype):
-    return mlp_block(p, attention_block(p, x, cfg, i, dtype), cfg, i, dtype)
+def decoder_layer(p: dict, x: Array, cfg: TrunkConfig, i: int, dtype,
+                  train=False, remat=False):
+    return mlp_block(
+        p, attention_block(p, x, cfg, i, dtype), cfg, i, dtype, train, remat
+    )
 
 
 def layer_params(params: dict, i: int) -> dict:
@@ -568,19 +637,52 @@ def block_size(batch: int, block_boards: int | None) -> int:
     return size
 
 
-def apply(params: dict, tokens: Array, cfg: TrunkConfig, dtype):
+def apply(params: dict, tokens: Array, cfg: TrunkConfig, dtype,
+          train: bool = False, remat: bool = False):
     """tokens (B, S, d) through every layer and the final norm; also the
     assignments each held expert computed, (sparse layers, count) int32
-    (a (0, count) array where no layer is sparse)."""
-    x, counted = tokens, []
+    (a (0, count) array where no layer is sparse), and in a training
+    forward (`train`: one that will be differentiated) those the
+    routers made to every expert, (sparse layers, `num_experts`) int32,
+    else None. `remat` puts each layer under `jax.checkpoint`: a
+    backward pass then keeps a layer's input and computes the layer
+    again."""
+    x, counted, loaded = tokens, [], []
     for i in range(len(cfg.layer_types)):
-        x, sizes = decoder_layer(layer_params(params, i), x, cfg, i, dtype)
+        layer = functools.partial(
+            decoder_layer, cfg=cfg, i=i, dtype=dtype, train=train, remat=remat,
+        )
+        if remat:
+            layer = jax.checkpoint(layer)
+        x, sizes, loads = layer(layer_params(params, i), x)
         if sizes is not None:
             counted.append(sizes)
+            loaded.append(loads)
     x = rms_norm(x, params["norm"], cfg.rms_norm_eps)
+    loads = None
+    if train:
+        loads = jnp.stack(loaded) if loaded else jnp.zeros((0, cfg.num_experts), jnp.int32)
     if counted:
-        return x, jnp.stack(counted)
-    return x, jnp.zeros((0, cfg.experts_held[1]), jnp.int32)
+        return x, jnp.stack(counted), loads
+    return x, jnp.zeros((0, cfg.experts_held[1]), jnp.int32), loads
+
+
+def moved_router_biases(params: dict, loads: Array, cfg: TrunkConfig) -> dict:
+    """`params` (the trunk's) with every sparse layer's selection bias
+    one step on: bias_e + `router_bias_rate` x sign(mean(load) - load_e),
+    `loads` (sparse layers, `num_experts`) being the assignments the
+    layer's router made to each expert over the step's batch. The bias
+    of an expert chosen less than its share rises, of one chosen more
+    falls, by the same step whatever the gap; no gradient, no moment
+    and no decay has a part in it."""
+    out = dict(params)
+    for row, i in enumerate(sparse_layers(cfg)):
+        load = loads[row].astype(jnp.float32)
+        name = f"l{i}_router_bias"
+        out[name] = params[name] + jnp.float32(cfg.router_bias_rate) * jnp.sign(
+            load.mean() - load
+        )
+    return out
 
 
 # --- the module: parameters, then the functions above ----------------------
@@ -603,9 +705,10 @@ class DecoderTrunk(nn.Module):
     config: TrunkConfig
     dtype: jnp.dtype
     param_dtype: jnp.dtype
+    remat: bool = False  # in a training forward, each layer recomputed
 
     @nn.compact
-    def __call__(self, tokens: Array) -> Array:
+    def __call__(self, tokens: Array, train: bool = False) -> Array:
         cfg = self.config
         params = {
             name: self.param(
@@ -618,15 +721,21 @@ class DecoderTrunk(nn.Module):
             )
             for name, (shape, fan_in) in param_shapes(cfg).items()
         }
-        out, counts = apply(params, tokens.astype(self.dtype), cfg, self.dtype)
-        # Read by the search, which makes the collection mutable; no-ops
-        # otherwise: the assignments computed here, (sparse layers,
-        # held), and all the assignments the router made.
+        out, counts, loads = apply(
+            params, tokens.astype(self.dtype), cfg, self.dtype,
+            train=train, remat=self.remat and train,
+        )
+        # Read by the search and the learner, which make the collection
+        # mutable; no-ops otherwise: the assignments computed here,
+        # (sparse layers, held), all the assignments the router made,
+        # and in a training forward those by expert (`expert_loads`).
         if not self.is_initializing():
             routed = tokens.shape[0] * tokens.shape[1] * (
                 cfg.num_experts_per_tok * len(sparse_layers(cfg))
             )
             sown = [("expert_tokens", counts), ("routed", jnp.int32(routed))]
+            if loads is not None:
+                sown.append(("expert_loads", loads))
             linear = cfg.layer_types.count("linear_attention")
             if linear:  # tokens x linear layers the recurrence took
                 sown.append(
@@ -642,8 +751,8 @@ class DecoderTrunk(nn.Module):
 
 
 def counters_of(state: dict) -> dict:
-    """{"expert_tokens", "routed"}, and "linear_tokens" where the stack
-    has linear layers, out of what `apply(..., mutable=["counters"])`
+    """{"expert_tokens", "routed"}, "linear_tokens" where the stack has
+    linear layers and "expert_loads" after a training forward, out of what `apply(..., mutable=["counters"])`
     returned beside the net's outputs."""
     (sown,) = state["counters"].values()
     return dict(sown)

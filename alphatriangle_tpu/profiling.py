@@ -295,6 +295,15 @@ def phase_of(op_name: str) -> str:
     of the forward pass is `learner/backward`."""
     if "transpose(" in op_name and "learner/forward_loss" in op_name:
         return "learner/backward"
+    return forward_phase_of(op_name)
+
+
+@functools.lru_cache(maxsize=None)
+def forward_phase_of(op_name: str) -> str:
+    """The innermost phase name in an `op_name`, whichever pass the
+    operation belongs to: for one of the backward pass, the phase of
+    the forward pass it transposes (or, under recomputation, runs
+    again)."""
     at, phase = -1, OTHER
     for name in PHASES:
         found = op_name.rfind(name)
@@ -333,6 +342,20 @@ def phase_seconds(events, op_names: dict[str, str]) -> dict[str, float]:
     out = {p: total[p] for p in PHASES if p in total}
     out[OTHER] = total.get(OTHER, 0.0)
     return out
+
+
+def backward_seconds(events, op_names: dict[str, str]) -> dict[str, float]:
+    """`learner/backward`'s device seconds by the phase of the forward
+    pass each operation transposes or recomputes (`forward_phase_of`);
+    empty for a program without a backward pass."""
+    total: dict[str, float] = defaultdict(float)
+    for name, _, duration in events:
+        if _is_container(name):
+            continue
+        op_name = op_names.get(_instruction(name), name)
+        if phase_of(op_name) == "learner/backward":
+            total[forward_phase_of(op_name)] += duration / 1e9
+    return {p: total[p] for p in (*PHASES, OTHER) if p in total}
 
 
 def _by_program(modules, ops) -> dict[str, list]:
@@ -416,6 +439,14 @@ def summarize_xplane_trace(
                     f"    {phase:<24} {sec:>10.4f} "
                     f"{100.0 * sec / max(busy, 1e-12):>6.1f}%"
                 )
+            backward = backward_seconds(events, op_names.get(program, {}))
+            if backward:
+                print("    learner/backward, by the forward phase it transposes:")
+                for phase, sec in backward.items():
+                    print(
+                        f"      {phase:<22} {sec:>10.4f} "
+                        f"{100.0 * sec / max(busy, 1e-12):>6.1f}%"
+                    )
     if not devices:
         print("  (no device plane with an XLA Ops line: a CPU trace)")
     if host:

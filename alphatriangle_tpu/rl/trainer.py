@@ -20,6 +20,18 @@ TPU-native redesign:
   read from mutable optimizer state.
 - The C51 projection of a *scalar* return is a two-hot scatter
   (`trainer.py:159-202` does the same dance with torch index math).
+- A net whose trunk is a decoder stack (`ModelConfig.TRUNK`) is trained
+  through the same programs with two things more. Where the trunk
+  names `learner_block_boards` a step takes its batch a block of boards
+  at a time: forward and backward of one block (each trunk layer
+  recomputed under `ModelConfig.REMAT`), the gradients added in float32,
+  then one clip, one optimizer update. And a router's selection bias is
+  no parameter of the optimizer's: no gradient reaches it, it is left
+  out of the clipped norm, the moments and the weight decay, and each
+  step moves it by the sign of its expert's load error over the step's
+  whole batch (`nn/trunk.py` `moved_router_biases`). The loads ride the
+  group's one fetch (`Trainer.last_counters`). The blocked step is
+  written for one device: it slices the batch it is handed.
 """
 
 import logging
@@ -38,6 +50,7 @@ from ..compile_cache import config_digest, get_compile_cache
 from ..config.mesh_config import MeshConfig
 from ..config.train_config import TrainConfig
 from ..nn.network import NeuralNetwork
+from ..nn.trunk import block_size, counters_of, moved_router_biases, sparse_layers
 from ..telemetry.device_stats import emit_beacon
 from ..telemetry.flight import flight_span
 from ..telemetry.tracer import default_tracer
@@ -132,6 +145,49 @@ def make_optimizer(cfg: TrainConfig) -> optax.GradientTransformation:
             optax.clip_by_global_norm(cfg.GRADIENT_CLIP_VALUE), opt
         )
     return opt
+
+
+def _is_router_bias(path) -> bool:
+    return str(getattr(path[-1], "key", "")).endswith("router_bias")
+
+
+def without_router_biases(
+    optimizer: optax.GradientTransformation, params
+) -> optax.GradientTransformation:
+    """`optimizer` over every leaf of `params` but the routers'
+    selection biases: those get no moment, no decay and no part in the
+    clipped norm, and their update is whatever gradient comes (nought:
+    a bias moves the choice alone). The optimizer itself where the
+    tree has no such leaf."""
+    trained = jax.tree_util.tree_map_with_path(
+        lambda path, _: not _is_router_bias(path), params
+    )
+    if all(jax.tree_util.tree_leaves(trained)):
+        return optimizer
+    return optax.masked(optimizer, trained)
+
+
+def refuse_untrainable(params, devices: int = 1) -> None:
+    """A net whose training state cannot lie on the `devices` that share
+    it is refused by its bytes, before anything is copied: `params` may
+    be shapes alone. The state is the parameters, their gradients and
+    two Adam moments, four times the parameters' bytes."""
+    from ..nn.model import count_parameters
+    from ..telemetry.memory import resolve_bytes_limit
+
+    count = count_parameters(params)
+    state_bytes = 4 * sum(
+        int(np.prod(p.shape)) * jnp.dtype(p.dtype).itemsize
+        for p in jax.tree_util.tree_leaves(params)
+    )
+    limit, _ = resolve_bytes_limit(None)
+    if limit is not None and state_bytes > limit * devices:
+        raise ValueError(
+            f"Trainer: {count:,} parameters need {state_bytes:,} B of "
+            "training state (parameters, gradients and two Adam moments) "
+            f"and {devices} device(s) of {int(limit):,} B hold it: "
+            "this net can be served (INFERENCE_PRECISION), not trained here."
+        )
 
 
 # --- C51 projection -------------------------------------------------------
@@ -232,27 +288,18 @@ class Trainer:
         self.v_min, self.v_max = mc.VALUE_MIN, mc.VALUE_MAX
         self.schedule = make_lr_schedule(train_config)
         self.host_schedule = make_host_lr_schedule(train_config)
-        self.optimizer = make_optimizer(train_config)
-
-        # A net whose training state cannot lie on the devices that share
-        # it is refused here, by its bytes, before anything is copied.
-        from ..telemetry.memory import resolve_bytes_limit
-
-        from ..nn.model import count_parameters
-
-        count = count_parameters(nn.variables["params"])
-        state_bytes = 4 * sum(
-            int(np.prod(p.shape)) * jnp.dtype(p.dtype).itemsize
-            for p in jax.tree_util.tree_leaves(nn.variables["params"])
+        # What of a decoder stack the step reads: whether routers load
+        # experts (their counters come back, their biases are moved),
+        # and how many boards a step takes at a time.
+        trunk = mc.TRUNK
+        self._routed = trunk is not None and bool(sparse_layers(trunk))
+        self._block_boards = trunk.learner_block_boards if trunk else None
+        self.last_counters: dict | None = None
+        self.optimizer = without_router_biases(
+            make_optimizer(train_config), nn.variables["params"]
         )
-        limit, _ = resolve_bytes_limit(None)
-        if limit is not None and state_bytes > limit * self.tp_size:
-            raise ValueError(
-                f"Trainer: {count:,} parameters need {state_bytes:,} B of "
-                "training state (parameters, gradients and two Adam moments) "
-                f"and {self.tp_size} device(s) of {int(limit):,} B hold it: "
-                "this net can be served (INFERENCE_PRECISION), not trained here."
-            )
+
+        refuse_untrainable(nn.variables["params"], self.tp_size)
         # Deep-copy the wrapper's variables: the jitted step donates its
         # input state, and a donated buffer aliased by `nn.variables`
         # would leave the eval wrapper holding deleted arrays.
@@ -376,6 +423,8 @@ class Trainer:
         if batch_stats:
             variables["batch_stats"] = batch_stats
             mutable = ["batch_stats"]
+        if self._routed:
+            mutable = [*(mutable or []), "counters"]
         out = self.model.apply(
             variables,
             batch["grid"],
@@ -417,9 +466,8 @@ class Trainer:
         # (interpretable as nats/decision regardless of masking).
         entropy_rows = -(probs * log_policy).sum(axis=-1)  # (B,)
         entropy_term = (pw * entropy_rows).mean()
-        entropy_metric = (pw * entropy_rows).sum() / jnp.maximum(
-            pw.sum(), 1.0
-        )
+        entropy_sum, policy_rows = (pw * entropy_rows).sum(), pw.sum()
+        entropy_metric = entropy_sum / jnp.maximum(policy_rows, 1.0)
 
         w = batch["weights"]
         per_sample = (
@@ -439,14 +487,98 @@ class Trainer:
             "td_errors": value_ce,
             "batch_stats": new_batch_stats,
         }
+        if self._routed:
+            counted = counters_of(updates)
+            aux["expert_loads"] = counted["expert_loads"]
+            aux["expert_tokens"] = counted["expert_tokens"]
+        if self._block_boards is not None:
+            # What the blocks of one step add up to the step's entropy.
+            aux["entropy_sum"], aux["policy_rows"] = entropy_sum, policy_rows
         return total, aux
+
+    def _blocked_grads(self, state: TrainState, step_rng, batch, size: int):
+        """`jax.grad` of `_loss_fn` over `batch` taken `size` rows at a
+        time, in equal blocks: one block's forward and backward, then
+        the next, the gradients summed in float32 and divided by the
+        number of blocks (the loss is a mean over rows, so the mean of
+        the blocks' gradients is the batch's). `aux` as `_loss_fn`
+        gives it for the whole batch: the losses the blocks' means, the
+        TD errors row by row in order, the experts' counters summed."""
+        means = ("total_loss", "policy_loss", "value_loss")
+        sums = ("entropy_sum", "policy_rows", "expert_loads", "expert_tokens")
+        blocks = batch["value_target"].shape[0] // size
+
+        def block_at(j):
+            return jax.tree_util.tree_map(
+                lambda x: jax.lax.dynamic_slice_in_dim(x, j * size, size), batch
+            )
+
+        def one(carry, j):
+            grads_sum, totals, batch_stats = carry
+            with jax.named_scope("learner/block"):
+                grads, aux = jax.grad(
+                    lambda p: self._loss_fn(
+                        p, batch_stats, jax.random.fold_in(step_rng, j), block_at(j)
+                    ),
+                    has_aux=True,
+                )(state.params)
+            with jax.named_scope("learner/accumulate"):
+                grads_sum = jax.tree_util.tree_map(
+                    lambda total, g: total + g.astype(jnp.float32), grads_sum, grads
+                )
+                totals = {
+                    name: totals[name] + aux[name] for name in totals
+                }
+            return (grads_sum, totals, aux["batch_stats"]), aux["td_errors"]
+
+        shapes = jax.eval_shape(
+            lambda: self._loss_fn(
+                state.params, state.batch_stats, step_rng, block_at(0)
+            )[1]
+        )
+        start = (
+            jax.tree_util.tree_map(
+                lambda p: jnp.zeros(p.shape, jnp.float32), state.params
+            ),
+            {
+                name: jnp.zeros(shapes[name].shape, shapes[name].dtype)
+                for name in means + sums
+                if name in shapes
+            },
+            state.batch_stats,
+        )
+        (grads_sum, totals, batch_stats), td = jax.lax.scan(
+            one,
+            start,
+            jnp.arange(blocks),
+            unroll=True if jax.default_backend() == "cpu" else 1,
+        )
+        with jax.named_scope("learner/accumulate"):
+            grads = jax.tree_util.tree_map(
+                lambda total, p: (total / blocks).astype(p.dtype),
+                grads_sum,
+                state.params,
+            )
+        aux = {
+            **{name: totals[name] / blocks for name in means},
+            **{name: totals[name] for name in sums if name in totals},
+            "td_errors": td.reshape(-1),
+            "batch_stats": batch_stats,
+        }
+        aux["entropy"] = aux["entropy_sum"] / jnp.maximum(aux["policy_rows"], 1.0)
+        return grads, aux
 
     def _train_step_impl(self, state: TrainState, batch: DenseBatch):
         rng, step_rng = jax.random.split(state.rng)
-        grads, aux = jax.grad(
-            lambda p: self._loss_fn(p, state.batch_stats, step_rng, batch),
-            has_aux=True,
-        )(state.params)
+        rows = batch["value_target"].shape[0]
+        size = block_size(rows, self._block_boards)
+        if size < rows:
+            grads, aux = self._blocked_grads(state, step_rng, batch, size)
+        else:
+            grads, aux = jax.grad(
+                lambda p: self._loss_fn(p, state.batch_stats, step_rng, batch),
+                has_aux=True,
+            )(state.params)
         with jax.named_scope("learner/optimizer"):
             updates, opt_state = self.optimizer.update(
                 grads, state.opt_state, state.params
@@ -454,6 +586,16 @@ class Trainer:
             params = optax.apply_updates(state.params, updates)
             grad_norm = optax.global_norm(grads)
             update_norm = optax.global_norm(updates)
+        if self._routed:
+            with jax.named_scope("learner/router_bias"):
+                params = {
+                    **params,
+                    "DecoderTrunk_0": moved_router_biases(
+                        params["DecoderTrunk_0"],
+                        aux["expert_loads"],
+                        self.nn.model_config.TRUNK,
+                    ),
+                }
         new_state = TrainState(
             params=params,
             batch_stats=aux["batch_stats"],
@@ -473,6 +615,11 @@ class Trainer:
             # "adaptive-moment blowup" per fused step.
             "update_norm": update_norm,
         }
+        if self._routed:
+            # Counters, not metrics: `group_results` takes them off the
+            # group's fetch into `last_counters`.
+            metrics["expert_loads"] = aux["expert_loads"]
+            metrics["expert_tokens"] = aux["expert_tokens"]
         return new_state, metrics, aux["td_errors"]
 
     def _train_steps_impl(self, state: TrainState, stacked: DenseBatch):
@@ -587,6 +734,33 @@ class Trainer:
 
     # --- host API ---------------------------------------------------------
 
+    def _take_counters(self, host_metrics: dict, rows: int) -> dict:
+        """`host_metrics` without the routed trunk's counters, which go
+        to `last_counters`: of the group just fetched, per step, the
+        assignments the routers made to each expert (`expert_loads`:
+        steps x sparse layers x `num_experts`) and those the held
+        experts computed (`expert_tokens`: steps x sparse layers x
+        held), and over the group all the assignments routed anywhere
+        (`routed`) and the tokens x layers the trunk took
+        (`trunk_tokens`). A net without routers has none."""
+        if not self._routed:
+            return host_metrics
+        host_metrics = dict(host_metrics)
+        loads = np.asarray(host_metrics.pop("expert_loads"))
+        tokens = np.asarray(host_metrics.pop("expert_tokens"))
+        if loads.ndim == 2:  # one step, not stacked
+            loads, tokens = loads[None], tokens[None]
+        trunk = self.nn.model_config.TRUNK
+        # Every router chooses `num_experts_per_tok` for each token.
+        seen = int(loads[0, 0].sum()) // trunk.num_experts_per_tok // rows
+        self.last_counters = {
+            "expert_loads": loads,
+            "expert_tokens": tokens,
+            "routed": int(loads.sum()),
+            "trunk_tokens": len(loads) * rows * seen * len(trunk.layer_types),
+        }
+        return host_metrics
+
     @staticmethod
     def _with_policy_weight(batch: dict, n: int) -> dict:
         """Default the PCR policy-loss mask to ones when absent, so
@@ -636,6 +810,7 @@ class Trainer:
         if td_host is None:
             td_host = local_rows(td)
         self._host_step += 1
+        host_metrics = self._take_counters(host_metrics, n)
         host_metrics = {k: float(v) for k, v in host_metrics.items()}
         host_metrics["learning_rate"] = self.get_current_lr()
         # PER bookkeeping is host-local: return only this host's rows.
@@ -846,6 +1021,7 @@ class Trainer:
         axis. Host only: nothing here may dispatch to the device (the
         device idles until the next group is sampled and dispatched)."""
         k = len(td_k)
+        metrics_k = self._take_counters(metrics_k, np.shape(td_k)[-1])
         lrs = self.host_schedule(start_step + 1 + np.arange(k)).tolist()
         results = []
         for i in range(k):

@@ -45,12 +45,19 @@ PHASES = (
     "net/trunk/router",
     "net/trunk/experts",
     "net/trunk/shared_expert",
-    # fused learner (rl/trainer.py)
+    # fused learner (rl/trainer.py); a step that takes its batch in
+    # blocks runs gather, forward_loss and backward inside learner/block
+    # (which keeps only what is left over), adds each block's gradients
+    # under learner/accumulate, and moves the routers' selection biases
+    # under learner/router_bias
+    "learner/block",
     "learner/gather",
     "learner/forward_loss",
     "learner/td",
     "learner/backward",
+    "learner/accumulate",
     "learner/optimizer",
+    "learner/router_bias",
     # ring ingest (rl/device_buffer.py)
     "replay/ingest_scatter",
 )

@@ -415,14 +415,15 @@ def setup_training_components(
     import jax
 
     from ..telemetry.perf import UtilizationMeter
-    from ..utils.flops import forward_flops, train_step_flops
+    from ..utils.flops import forward_flops, model_step_flops
 
     device = jax.devices()[0]
     perf_meter = UtilizationMeter(
         forward_flops=forward_flops(
             model_config, env_config, env_config.action_dim
         ),
-        train_step_flops=train_step_flops(
+        # An MFU credits no recomputed forward (REMAT).
+        train_step_flops=model_step_flops(
             model_config,
             env_config,
             env_config.action_dim,

@@ -114,6 +114,16 @@ def train_step_flops(
     return mult * batch * forward_flops(model, env, action_dim)
 
 
+def model_step_flops(
+    model: ModelConfig, env: EnvConfig, action_dim: int, batch: int
+) -> int:
+    """What a utilization credits a step with: forward + backward,
+    3 x the forward, whatever REMAT makes the chip compute again. The
+    recomputed forward is the price of fitting, not work of the model's
+    (`train_step_flops` counts it: the work the chip really does)."""
+    return 3 * batch * forward_flops(model, env, action_dim)
+
+
 def gather_einsum_flops(batch: int, wave: int, nodes: int, width: int) -> int:
     """FLOPs of ONE einsum descent row-gather (`ops/gather_rows.py`):
     (B, W, N) one-hot x (B, N, K). The take/pallas lowerings do the

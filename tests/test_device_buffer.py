@@ -424,3 +424,67 @@ class TestLoopIntegration:
         assert loop.experiences_added > 0
         ckpts = list(tmp_path.rglob("step_*"))
         assert ckpts, "no checkpoint written"
+
+    def test_training_loop_trains_a_routed_trunk_in_blocks(
+        self,
+        tmp_path,
+        tiny_env_config,
+        tiny_model_config,
+        tiny_train_config,
+        tiny_mcts_config,
+    ):
+        """The loop's device-replay learner with a routed latent stack
+        as the trunk: the same entry points, ring and sampler; a step in
+        blocks of 4 boards under recomputation, the routers' counters
+        off the group's fetch, the biases moved by the rule."""
+        from alphatriangle_tpu.config import (
+            MeshConfig, PersistenceConfig, TrunkConfig,
+        )
+        from alphatriangle_tpu.training.loop import LoopStatus, TrainingLoop
+        from alphatriangle_tpu.training.setup import setup_training_components
+
+        trunk = TrunkConfig(
+            hidden_size=32, num_attention_heads=2, num_key_value_heads=2,
+            intermediate_size=48, moe_intermediate_size=16, num_experts=4,
+            num_experts_per_tok=2, layer_types=["latent_attention"] * 2,
+            mlp_layer_types=["dense", "sparse"], experts_held=(0, 2),
+            kv_lora_rank=8, q_lora_rank=8, qk_nope_head_dim=8, qk_rope_head_dim=4,
+            v_head_dim=8, norm_position="pre", rope_layers="latent",
+            router_bias=True, latent_gate=False, learner_block_boards=4,
+            router_bias_rate=0.001,
+        )
+        model = tiny_model_config.model_copy(update={"TRUNK": trunk, "REMAT": True})
+        cfg = _cfg(
+            tiny_train_config,
+            DEVICE_REPLAY="on",
+            FUSED_LEARNER_STEPS=2,
+            BATCH_SIZE=8,
+            MAX_TRAINING_STEPS=4,
+            MIN_BUFFER_SIZE_TO_TRAIN=8,
+            BUFFER_CAPACITY=256,
+            CHECKPOINT_SAVE_FREQ_STEPS=4,
+            RUN_NAME="pytest_devreplay_trunk",
+        )
+        comps = setup_training_components(
+            train_config=cfg,
+            env_config=tiny_env_config,
+            model_config=model,
+            mcts_config=tiny_mcts_config,
+            mesh_config=MeshConfig(DP_SIZE=1),
+            persistence_config=PersistenceConfig(
+                ROOT_DATA_DIR=str(tmp_path), RUN_NAME=cfg.RUN_NAME
+            ),
+            use_tensorboard=False,
+        )
+        before = np.asarray(comps.net.variables["params"]["DecoderTrunk_0"]["l1_router_bias"])
+        loop = TrainingLoop(comps)
+        assert loop.run() == LoopStatus.COMPLETED and loop.global_step == 4
+        counted = comps.trainer.last_counters
+        assert counted["expert_loads"].shape == (2, 1, 4)
+        tokens = 8 * tiny_env_config.ROWS * tiny_env_config.COLS
+        assert counted["routed"] == 2 * tokens * 2
+        after = np.asarray(
+            comps.trainer.state.params["DecoderTrunk_0"]["l1_router_bias"]
+        )
+        moved = np.abs(after - before)
+        assert moved.max() <= 4 * 0.001 + 1e-7 and moved.max() > 0

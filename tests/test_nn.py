@@ -439,3 +439,29 @@ def test_train_lowering_is_the_parents():
     assert hashlib.sha256(text.encode()).hexdigest() == (
         "5d5f7fe70fb0eb4cfd36a49bdee70f0cfcfba10e875199b59cc73abc3ba90580"
     )
+
+
+def test_recomputation_leaves_the_parameter_tree_what_it_is(tiny_env_config):
+    """`REMAT` wraps the residual and encoder blocks in `nn.remat`; their
+    variables keep the names and the values they have without it, so a
+    checkpoint of one loads into the other."""
+    model = ModelConfig(
+        CONV_FILTERS=[8], CONV_KERNEL_SIZES=[3], CONV_STRIDES=[1],
+        RESIDUAL_BLOCK_FILTERS=8, NUM_RESIDUAL_BLOCKS=2, TRANSFORMER_DIM=8,
+        TRANSFORMER_HEADS=2, TRANSFORMER_LAYERS=2, TRANSFORMER_FC_DIM=16,
+    )
+    grid = jnp.zeros((2, 1, tiny_env_config.ROWS, tiny_env_config.COLS))
+    other = jnp.zeros((2, model.OTHER_NN_INPUT_FEATURES_DIM))
+    trees = [
+        AlphaTriangleNet(
+            model.model_copy(update={"REMAT": remat}), tiny_env_config.action_dim
+        ).init(jax.random.PRNGKey(2), grid, other, train=False)
+        for remat in (False, True)
+    ]
+    assert "ResidualBlock_1" in trees[1]["params"]
+    assert "TransformerEncoderLayer_1" in trees[1]["params"]
+    assert jax.tree_util.tree_structure(trees[0]) == jax.tree_util.tree_structure(
+        trees[1]
+    )
+    for a, b in zip(*map(jax.tree_util.tree_leaves, trees)):
+        np.testing.assert_array_equal(a, b)

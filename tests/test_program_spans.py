@@ -442,10 +442,71 @@ class TestPhaseNamesInPrograms:
         wanted = set(_phases("learner/", "net/")) - set(_phases("net/trunk"))
         wanted.remove("learner/backward")  # drawn by autodiff:
         assert "transpose(jvp(learner/forward_loss))" in text
-        assert {p for p in wanted if p not in text} == set()
+        # A step taken whole, of a net without routers, draws neither.
+        blocked = {"learner/block", "learner/accumulate", "learner/router_bias"}
+        assert {p for p in wanted if p not in text} == blocked
         assert profiling.phase_of(
             "jit(f)/transpose(jvp(learner/forward_loss))/net/encoder/dot_general"
         ) == "learner/backward"
+
+    def test_learner_program_of_a_routed_trunk_taken_in_blocks(self, world):
+        """The phases a blocked step of a routed decoder stack adds, in
+        the program `Trainer.train_steps_from` dispatches; and where the
+        reader puts the operations of its backward pass."""
+        from alphatriangle_tpu.config import TrunkConfig
+        from alphatriangle_tpu.nn.network import NeuralNetwork
+        from alphatriangle_tpu.rl.trainer import Trainer
+
+        trunk = TrunkConfig(
+            hidden_size=32, num_attention_heads=2, num_key_value_heads=2,
+            intermediate_size=48,
+            moe_intermediate_size=16, num_experts=4, num_experts_per_tok=2,
+            layer_types=["latent_attention"] * 2,
+            mlp_layer_types=["dense", "sparse"], experts_held=(0, 2),
+            kv_lora_rank=8, q_lora_rank=8, qk_nope_head_dim=8, qk_rope_head_dim=4,
+            v_head_dim=8, norm_position="pre", rope_layers="latent",
+            router_bias=True, latent_gate=False, learner_block_boards=2,
+        )
+        env = world["env"]
+        model = world["net"].model_config.model_copy(
+            update={"TRUNK": trunk, "REMAT": True}
+        )
+        trainer = Trainer(NeuralNetwork(model, env.cfg, seed=0), world["train"])
+        text = _lowered_text(
+            trainer._from_fn._jit_fn,
+            trainer.state,
+            world["buffer"].storage,
+            np.zeros((1, 4), np.int32),
+            np.ones((1, 4), np.float32),
+        )
+        wanted = set(_phases("learner/")) | {
+            "net/trunk", "net/trunk/latent_attn", "net/trunk/dense_mlp",
+            "net/trunk/router", "net/trunk/experts", "net/trunk/shared_expert",
+        }
+        wanted.remove("learner/backward")
+        assert {p for p in wanted if p not in text} == set()
+        backward = (
+            "jit(f)/while/body/learner/block/transpose(jvp(learner/forward_loss))/"
+            "net/trunk/checkpoint/rematted_computation/net/trunk/experts/ragged_dot"
+        )
+        assert profiling.phase_of(backward) == "learner/backward"
+        assert profiling.forward_phase_of(backward) == "net/trunk/experts"
+        assert profiling.phase_of(
+            "jit(f)/while/body/learner/block/jvp(learner/forward_loss)/net/trunk/"
+            "net/trunk/latent_attn/dot_general"
+        ) == "net/trunk/latent_attn"
+        assert profiling.phase_of("jit(f)/while/body/learner/block/dynamic_slice") == (
+            "learner/block"
+        )
+        events = [
+            ("%a = f32[] fusion()", 0, 3_000_000_000),
+            ("%b = f32[] fusion()", 0, 1_000_000_000),
+        ]
+        names = {"%a": backward, "%b": "jit(f)/learner/router_bias/add"}
+        assert profiling.phase_seconds(events, names) == {
+            "learner/backward": 3.0, "learner/router_bias": 1.0, "other": 0.0
+        }
+        assert profiling.backward_seconds(events, names) == {"net/trunk/experts": 3.0}
 
     def test_ingest_program(self, world):
         buffer = world["buffer"]

@@ -591,3 +591,199 @@ class TestMultiDevice:
         trainer = Trainer(net, tiny_train_config, mesh=mesh)
         with pytest.raises(ValueError, match="not divisible"):
             trainer.train_step(make_batch(6))
+
+
+class TestRoutedTrunk:
+    """A decoder stack with routers under the learner: what the trainer
+    holds by itself. The comparison with the plain reference lives in
+    tests/chipbench/test_chipbench_glm.py."""
+
+    @staticmethod
+    def _shapes(name, settings_of):
+        from chipbench import manifest
+
+        from alphatriangle_tpu.config import TrunkConfig
+        from alphatriangle_tpu.nn.model import AlphaTriangleNet
+
+        cfg = manifest.load_json(manifest.HERE / "configs" / f"{name}.json")
+        configs = manifest.program_configs(cfg)
+        model = configs["model"].model_copy(
+            update={"TRUNK": TrunkConfig(**settings_of(cfg))}
+        )
+        env = configs["env"]
+        module = AlphaTriangleNet(model, env.action_dim)
+        return jax.eval_shape(
+            lambda k: module.init(
+                k, jnp.zeros((1, model.GRID_INPUT_CHANNELS, env.ROWS, env.COLS)),
+                jnp.zeros((1, model.OTHER_NN_INPUT_FEATURES_DIM)), train=False,
+            ),
+            jax.random.PRNGKey(0),
+        )["params"]
+
+    def test_refused_or_built_by_the_bytes_of_the_training_state(self, monkeypatch):
+        """Under one v5e chip's 16 GB the two rollout trunks are still
+        refused and `glm-flash-ep8` (579M parameters in float32, 9.27 GB
+        of state) passes; under 8 GB it is refused too."""
+        from chipbench import reference_exaone_moe, reference_glm_moe
+        from chipbench import reference_ling_hybrid
+
+        from alphatriangle_tpu.rl.trainer import refuse_untrainable
+        from alphatriangle_tpu.telemetry.memory import BYTES_LIMIT_ENV
+
+        monkeypatch.setenv(BYTES_LIMIT_ENV, str(16 * 2**30))
+        for name, module in (
+            ("k-exaone-ep8", reference_exaone_moe),
+            ("ling-flash-ep4", reference_ling_hybrid),
+        ):
+            with pytest.raises(ValueError, match="B of training state"):
+                refuse_untrainable(self._shapes(name, module.trunk_settings))
+        glm = self._shapes("glm-flash-ep8", reference_glm_moe.trunk_settings)
+        count = sum(int(np.prod(x.shape)) for x in jax.tree_util.tree_leaves(glm))
+        assert count == 579_147_239
+        refuse_untrainable(glm)
+        monkeypatch.setenv(BYTES_LIMIT_ENV, str(8 * 2**30))
+        with pytest.raises(ValueError, match=f"{16 * count:,} B of training state"):
+            refuse_untrainable(glm)
+
+    @pytest.fixture()
+    def routed(self, tiny_model_config, tiny_env_config, tiny_train_config):
+        from alphatriangle_tpu.config import TrunkConfig
+
+        trunk = TrunkConfig(
+            hidden_size=32, num_attention_heads=2, num_key_value_heads=2,
+            intermediate_size=48, moe_intermediate_size=16, num_experts=4,
+            num_experts_per_tok=2, layer_types=["latent_attention"] * 2,
+            mlp_layer_types=["dense", "sparse"], experts_held=(0, 2),
+            kv_lora_rank=8, q_lora_rank=8, qk_nope_head_dim=8, qk_rope_head_dim=4,
+            v_head_dim=8, norm_position="pre", rope_layers="latent",
+            router_bias=True, latent_gate=False, learner_block_boards=2,
+            router_bias_rate=0.01,
+        )
+        # Heads of 16: at the fixture's 8 a GroupNorm group is one
+        # channel, the head's ReLU is dead and no gradient reaches the trunk.
+        model = tiny_model_config.model_copy(
+            update={
+                "TRUNK": trunk, "REMAT": True, "FC_DIMS_SHARED": [16],
+                "POLICY_HEAD_DIMS": [16], "VALUE_HEAD_DIMS": [16],
+            }
+        )
+        train = tiny_train_config.model_copy(update={"WEIGHT_DECAY": 0.1})
+        return Trainer(NeuralNetwork(model, tiny_env_config, seed=1), train)
+
+    def test_a_selection_bias_is_no_parameter_of_the_optimizers(self, routed):
+        trainer = routed
+        params = trainer.state.params
+        biases = [
+            path
+            for path, _ in jax.tree_util.tree_leaves_with_path(params)
+            if "router_bias" in jax.tree_util.keystr(path)
+        ]
+        assert len(biases) == 1
+        # No moment: the optimizer's state has a leaf less, per moment.
+        leaves = len(jax.tree_util.tree_leaves(params))
+        adam = [
+            s for s in jax.tree_util.tree_leaves(
+                trainer.state.opt_state, is_leaf=lambda x: hasattr(x, "mu")
+            ) if hasattr(s, "mu")
+        ][0]
+        assert len(jax.tree_util.tree_leaves(adam.mu)) == leaves - 1
+        assert len(jax.tree_util.tree_leaves(adam.nu)) == leaves - 1
+        # Not in the clipped norm, no decay: a gradient of any size on
+        # the bias changes no other leaf's update, and passes through.
+        ones = jax.tree_util.tree_map(jnp.ones_like, params)
+        huge = jax.tree_util.tree_map_with_path(
+            lambda path, x: x * (1e6 if "router_bias" in jax.tree_util.keystr(path) else 1),
+            ones,
+        )
+        plain_updates, _ = trainer.optimizer.update(ones, trainer.state.opt_state, params)
+        huge_updates, _ = trainer.optimizer.update(huge, trainer.state.opt_state, params)
+        for (path, a), b in zip(
+            jax.tree_util.tree_leaves_with_path(plain_updates),
+            jax.tree_util.tree_leaves(huge_updates),
+        ):
+            if "router_bias" in jax.tree_util.keystr(path):
+                assert float(b[0]) == 1e6
+            else:
+                np.testing.assert_array_equal(a, b)
+
+    def test_a_step_moves_the_bias_by_the_rule_and_reports_the_loads(
+        self, routed, tiny_env_config
+    ):
+        trainer = routed
+        n = 8
+        rng = np.random.default_rng(0)
+        batch = trainer._zero_batch(n)
+        batch["grid"] = rng.integers(-1, 2, batch["grid"].shape).astype(np.float32)
+        batch["other_features"] = rng.random(batch["other_features"].shape).astype(
+            np.float32
+        )
+        before = np.asarray(trainer.state.params["DecoderTrunk_0"]["l1_router_bias"])
+        grads = jax.grad(
+            lambda p: trainer._loss_fn(p, {}, jax.random.PRNGKey(0), batch)[0]
+        )(trainer.state.params)
+        assert not np.asarray(grads["DecoderTrunk_0"]["l1_router_bias"]).any()
+        assert np.asarray(grads["DecoderTrunk_0"]["l1_w_router"]).any()
+        metrics, td = trainer.train_step(dict(batch))
+        assert set(metrics) == {
+            "total_loss", "policy_loss", "value_loss", "entropy", "grad_norm",
+            "update_norm", "learning_rate",
+        }
+        assert td.shape == (n,) and np.isfinite(td).all()
+        counted = trainer.last_counters
+        loads = counted["expert_loads"]
+        assert loads.shape == (1, 1, 4) and counted["expert_tokens"].shape == (1, 1, 2)
+        tokens = n * tiny_env_config.ROWS * tiny_env_config.COLS
+        assert counted["routed"] == int(loads.sum()) == tokens * 2
+        assert counted["trunk_tokens"] == tokens * 2
+        np.testing.assert_array_equal(counted["expert_tokens"][0, 0], loads[0, 0, :2])
+        after = np.asarray(trainer.state.params["DecoderTrunk_0"]["l1_router_bias"])
+        load = loads[0, 0].astype(np.float32)
+        np.testing.assert_array_equal(
+            after, before + np.float32(0.01) * np.sign(load.mean() - load)
+        )
+        # The fused group from a list of batches reports per step.
+        trainer.train_steps([dict(batch), dict(batch)])
+        assert trainer.last_counters["expert_loads"].shape == (2, 1, 4)
+        assert trainer.last_counters["trunk_tokens"] == 2 * tokens * 2
+
+
+def test_the_flagships_learner_programs_are_the_parents_text():
+    """A net without a decoder stack takes no block, counts no load and
+    moves no bias: its per-step and its fused-from-ring programs lower
+    to the text they had on the parent commit (7fee68d, where both
+    digests were taken with this test's code), at `flagship-p3`'s
+    widths, batch 8, a ring of 512 rows."""
+    import hashlib
+
+    from chipbench import manifest
+
+    from alphatriangle_tpu.rl.device_buffer import DeviceReplayBuffer
+
+    cfg = manifest.load_json(manifest.HERE / "configs" / "flagship-p3.json")
+    configs = manifest.program_configs(cfg)
+    env, model = configs["env"], configs["model"]
+    train = configs["train"].model_copy(
+        update={
+            "BUFFER_CAPACITY": 512, "MIN_BUFFER_SIZE_TO_TRAIN": 512, "BATCH_SIZE": 8
+        }
+    )
+    trainer = Trainer(NeuralNetwork(model, env, seed=1), train)
+    buffer = DeviceReplayBuffer(
+        train, (model.GRID_INPUT_CHANNELS, env.ROWS, env.COLS),
+        model.OTHER_NN_INPUT_FEATURES_DIM, env.action_dim, seed=0,
+    )
+    texts = [
+        jax.jit(trainer._train_steps_from_impl)
+        .lower(
+            trainer.state, buffer.storage,
+            np.zeros((2, 8), np.int32), np.ones((2, 8), np.float32),
+        )
+        .as_text(),
+        jax.jit(trainer._train_step_impl)
+        .lower(trainer.state, trainer._zero_batch(8))
+        .as_text(),
+    ]
+    assert [hashlib.sha256(t.encode()).hexdigest() for t in texts] == [
+        "2cff0f97b4f77481535d8c8bc012071663883d8596fbc8a1ba81f60d250f20ff",
+        "64c7e94ee9a7bffcad4947b646a58f9270b88b83c770c754190a50584d0744b1",
+    ]

@@ -453,6 +453,9 @@ def test_the_search_counts_the_tokens_the_recurrence_took(
         "linear_attention": 2, "latent_attention": 1, "linear_chunk": 16,
         "linear_path": {"kernel": 0, "chunked": 2},  # the CPU, heads of 16
         "block_boards": 8, "batch": instants[0]["batch"], "seq": 12,
+        # its latent layer's query has no latent of its own; the search
+        # trains nothing
+        "latent_q_compressed": 0, "learner_block_boards": None, "remat_layers": 0,
     }
 
     softmax = tiny_model_config.model_copy(update={"TRUNK": TrunkConfig(**TINY)})
@@ -611,3 +614,118 @@ def test_k_exaone_is_untouched():
         "64faad0ab2b6cda7fe8847fcdc8fcc8dc1464e02177810b5ac1244abe9966de3",
         "49981403830c91696af6d0e849e3ee7414f4f3c70dc7b0834089fca436bd3154",
     ]
+
+
+# --- a latent stack that is trained: compressed query, no gate ---------------
+
+LATENT = dict(
+    hidden_size=64, num_attention_heads=4, num_key_value_heads=4,
+    intermediate_size=96, moe_intermediate_size=32, num_experts=8,
+    num_experts_per_tok=2, num_shared_experts=1, routed_scaling_factor=1.8,
+    kv_lora_rank=16, q_lora_rank=24, qk_nope_head_dim=16, qk_rope_head_dim=8,
+    v_head_dim=16, layer_types=["latent_attention"] * 3,
+    mlp_layer_types=["dense", "sparse", "sparse"], experts_held=(4, 4),
+    norm_position="pre", rope_layers="latent", router_bias=True,
+    latent_gate=False, learner_block_boards=4,
+)
+
+
+def test_latent_attention_without_a_query_latent_and_gated_is_what_it_was():
+    """`q_lora_rank` None and the gate on (the defaults: `ling-flash-ep4`'s
+    latent layer) declare the parameters they declared and lower to the
+    parent commit's text (7fee68d, where both digests were taken with
+    this test's code), in float32 and in bfloat16."""
+    cfg = TrunkConfig(**HYBRID)
+    assert cfg.q_lora_rank is None and cfg.latent_gate is True
+    shapes = {
+        name[3:]: jax.ShapeDtypeStruct(shape, jnp.float32)
+        for name, (shape, _) in trunk.param_shapes(cfg).items()
+        if name.startswith("l2_")
+    }
+    assert {"wq", "wg"} <= set(shapes) and "wq_a" not in shapes
+    x = jax.ShapeDtypeStruct((3, 12, 64), jnp.float32)
+    digests = [
+        hashlib.sha256(
+            jax.jit(lambda p, x: trunk.latent_attention(p, x, cfg, dtype))
+            .lower(shapes, x).as_text().encode()
+        ).hexdigest()
+        for dtype in (jnp.float32, jnp.bfloat16)
+    ]
+    assert digests == [
+        "c3277b35c0d0b82af11db5d1b8171266661afd98c446484aeb60ad16a0b0ef20",
+        "4061d4106d6945be0b85d5df22cdd65eb4b6271eda8fe6642d760c93688f52fb",
+    ]
+
+
+def test_a_latent_stack_needs_no_head_dim_and_declares_its_query_latent():
+    cfg = TrunkConfig(**LATENT)
+    assert cfg.head_dim is None
+    shapes = trunk.param_shapes(cfg)
+    assert shapes["l0_wq_a"] == ((64, 24), 64)
+    assert shapes["l0_q_a_norm"] == ((24,), 0)
+    assert shapes["l0_wq_b"] == ((24, 4 * 24), 24)
+    assert "l0_wq" not in shapes and "l0_wg" not in shapes
+    assert shapes["l1_router_bias"] == ((8,), -1)
+    # The compressed query and the missing gate in the count of FLOP.
+    gated = TrunkConfig(**{**LATENT, "q_lora_rank": None, "latent_gate": True})
+    seq = 12
+    assert trunk.forward_flops(gated, seq) - trunk.forward_flops(cfg, seq) == (
+        3 * seq * 2 * (64 * 96 + 64 * 4 - 24 * (64 + 96))
+    )
+    with pytest.raises(ValueError, match="head_dim may be left out only"):
+        TrunkConfig(**{
+            **LATENT, "layer_types": ["latent_attention", "full_attention",
+                                      "latent_attention"],
+        })
+
+
+def test_the_bias_rule_alone():
+    """Loads in, biases out, exact: + rate where an expert was chosen
+    less than the mean, - rate where more, nothing where it met it."""
+    cfg = TrunkConfig(**{**LATENT, "router_bias_rate": 0.01})
+    params = {
+        "l1_router_bias": jnp.linspace(-0.1, 0.1, 8, dtype=jnp.float32),
+        "l2_router_bias": jnp.zeros((8,), jnp.float32),
+        "l1_w_router": jnp.ones((64, 8)),
+    }
+    loads = jnp.asarray(
+        [[10, 2, 6, 6, 0, 12, 6, 6], [6, 6, 6, 6, 6, 6, 6, 6]], jnp.int32
+    )
+    moved = trunk.moved_router_biases(params, loads, cfg)
+    step = np.float32(0.01) * np.asarray([-1, 1, 0, 0, 1, -1, 0, 0], np.float32)
+    np.testing.assert_array_equal(
+        moved["l1_router_bias"], np.asarray(params["l1_router_bias"]) + step
+    )
+    np.testing.assert_array_equal(moved["l2_router_bias"], np.zeros(8, np.float32))
+    assert moved["l1_w_router"] is params["l1_w_router"]
+    assert moved["l1_router_bias"].dtype == jnp.float32
+
+
+def test_a_training_forward_counts_every_experts_load_and_recomputes_by_layer(
+    tiny_model_config, tiny_env_config
+):
+    model = tiny_model_config.model_copy(
+        update={"TRUNK": TrunkConfig(**LATENT), "REMAT": True}
+    )
+    module = AlphaTriangleNet(model, tiny_env_config.action_dim)
+    grid = jnp.zeros((4, 1, 3, 4))
+    other = jnp.zeros((4, model.OTHER_NN_INPUT_FEATURES_DIM))
+    variables = module.init(jax.random.PRNGKey(0), grid, other, train=False)
+    recomputed = {
+        train: str(
+            jax.make_jaxpr(
+                lambda v, g, o: module.apply(v, g, o, train=train, mutable=["counters"])
+            )(variables, grid, other)
+        ).count("remat2[")
+        for train in (False, True)
+    }
+    # The trunk's three layers, in a training forward alone (the tiny
+    # stem has no residual block to recompute).
+    assert recomputed == {False: 0, True: 3}
+    _, state = module.apply(variables, grid, other, train=True, mutable=["counters"])
+    counted = trunk.counters_of(state)
+    assert counted["expert_loads"].shape == (2, 8)
+    assert counted["expert_loads"].sum(axis=1).tolist() == [4 * 12 * 2] * 2
+    np.testing.assert_array_equal(
+        counted["expert_tokens"], counted["expert_loads"][:, 4:]
+    )

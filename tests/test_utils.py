@@ -156,6 +156,11 @@ class TestFlops:
         assert train_step_flops(remat, env, env.action_dim, 8) == (
             4 * 8 * f_small
         )
+        # A utilization credits no recomputed forward.
+        from alphatriangle_tpu.utils.flops import model_step_flops
+
+        for model in (small, remat):
+            assert model_step_flops(model, env, env.action_dim, 8) == 3 * 8 * f_small
 
     def test_peak_table_and_mfu(self, monkeypatch):
         from alphatriangle_tpu.utils.flops import mfu, peak_bf16_tflops_info
