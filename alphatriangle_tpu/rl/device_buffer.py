@@ -10,13 +10,22 @@ stages ~8.5 MB of batches.
 
 `DeviceReplayBuffer` keeps the ring in device HBM instead:
 
-- **Ingest** is one jitted scatter: the rollout chunk's dense masked
+- **Ingest** is one jitted program: the rollout chunk's dense masked
   experience outputs (still device arrays — `SelfPlayEngine.
-  play_moves_device` never fetches them) are flattened, validated
+  play_moves_device` never fetches them) are flattened and validated
   (finiteness + policy-distribution checks, absorbing the role of
-  `SelfPlayResult`'s validator) and ring-written at positions derived
-  from a running cursor via a prefix-sum over the validity mask.
-  Invalid rows land in a trash slot at index `capacity`. Only the
+  `SelfPlayResult`'s validator); the rows that pass are brought
+  together, in candidate order, by a gather whose source indices come
+  from a prefix sum over the validity mask, and written over the
+  consecutive slots from the running cursor on as windows of the ring:
+  read `W` rows, lay the new rows over them, write them back in place
+  (`write_windows`; `W` is the first block's rows, at least a tile of
+  the chip's lanes: `ingest_window_rows`). The device's work
+  follows the rows that exist, not the candidates, and no whole-ring
+  array is re-laid-out for it: the chip keeps the ring's rows along
+  its lanes, where a window is contiguous and a row scatter is not.
+  Invalid rows go nowhere (the row at index `capacity` is kept for the
+  priority arrays that pin it to 0, and nothing writes it). Only the
   *count* of rows written returns to the host (one scalar), which is
   all the host-side PER SumTree needs: rows occupy slots
   `[cursor, cursor+count) % capacity` in order, and new rows get
@@ -71,9 +80,98 @@ from .buffer import ExperienceBuffer
 
 logger = logging.getLogger(__name__)
 
-# Canonical field order for experience row blocks (the key names the
-# rollout program emits for its `mat`/`flush` outputs).
-_BLOCK_FIELDS = ("grid", "other", "policy", "ret", "pw")
+# Each ring array and the field of an experience row block that fills
+# it (the key names the rollout program emits for its `mat`/`flush`
+# outputs), in canonical order.
+_RING_FIELDS = {
+    "grid": "grid",
+    "other_features": "other",
+    "policy_target": "policy",
+    "value_target": "ret",
+    "policy_weight": "pw",
+}
+
+
+# The chip lays a ring array's rows along its lanes, in tiles of up to
+# 1,024 rows. For a window narrower than a lane tile XLA re-lays-out
+# the whole ring around the write, as it did for the scatter (8 ms an
+# ingest at a 250,000-row ring: PERF.md section 5, PR 35).
+_WINDOW_ROWS_AT_LEAST = 1024
+
+
+def ingest_window_rows(blocks: tuple[dict[str, Any], ...], cap: int) -> int:
+    """Rows of one window of the ingest's ring write: the first block's
+    candidates (a chunk's `mat` block: the most rows that mature in its
+    T moves), at least a tile of lanes and at most the ring. A function
+    of shapes alone."""
+    return min(max(blocks[0]["mask"].size, _WINDOW_ROWS_AT_LEAST), cap)
+
+
+def ingest_windows(count: int, cursor: int, cap: int, width: int) -> int:
+    """How many windows `write_windows` writes for an ingest of `count`
+    valid rows at `cursor`, on the host: its loop on plain integers.
+    One where the rows fit a window; more where they outnumber it or
+    wrap the ring's end."""
+    kept = min(count, cap)
+    first = (cursor + count - kept) % cap
+    done = windows = 0
+    while done < kept:
+        done += min(width, cap - (first + done) % cap, kept - done)
+        windows += 1
+    return windows
+
+
+def write_windows(
+    storage: dict[str, jax.Array],
+    rows: dict[str, jax.Array],
+    keep: jax.Array,
+    first: jax.Array,
+    cap: int,
+    width: int,
+):
+    """Write the kept candidate rows, in candidate order, over the
+    ring's consecutive slots from `first` on (mod `cap`), a window of
+    `width` rows at a time. Returns (new_storage, windows written).
+
+    A window holds rows `[done, done + m)` of the kept ones at slots
+    `[s, s + m)`: it never wraps (`m <= cap - s`) and starts at `s`, or
+    earlier where `s` lies within `width` of the ring's end, so that it
+    lies inside `[0, cap)`. The kept row for each slot of the window
+    comes by a gather (its candidate index from the prefix sum of
+    `keep`); slots outside `[s, s + m)` keep what they held."""
+    n = keep.shape[0]
+    rank = jnp.cumsum(keep.astype(jnp.int32))  # kept rows up to and with i
+    kept = rank[-1]
+    slot = jnp.arange(width, dtype=jnp.int32)
+
+    def write(carry):
+        storage, done, windows = carry
+        s = (first + done) % cap
+        m = jnp.minimum(jnp.minimum(width, cap - s), kept - done)
+        start = jnp.minimum(s, cap - width)
+        j = slot - (s - start)  # the window's j-th new row lands here
+        fresh = (j >= 0) & (j < m)
+        source = jnp.minimum(
+            jnp.searchsorted(rank, done + j + 1, side="left"), n - 1
+        )
+        new_storage = {}
+        for name, ring in storage.items():
+            held = jax.lax.dynamic_slice_in_dim(ring, start, width)
+            new = rows[_RING_FIELDS[name]][source].astype(ring.dtype)
+            window = jnp.where(
+                fresh.reshape(-1, *[1] * (ring.ndim - 1)), new, held
+            )
+            new_storage[name] = jax.lax.dynamic_update_slice_in_dim(
+                ring, window, start, 0
+            )
+        return new_storage, done + m, windows + 1
+
+    storage, _, windows = jax.lax.while_loop(
+        lambda carry: carry[1] < kept,
+        write,
+        (storage, jnp.int32(0), jnp.int32(0)),
+    )
+    return storage, windows
 
 
 @jax.named_scope("replay/ingest_scatter")
@@ -84,13 +182,13 @@ def ring_scatter(
     cap: int,
     with_positions: bool = False,
 ):
-    """Flatten + validate + ring-scatter experience blocks (pure).
+    """Flatten + validate + ring-write experience blocks (pure).
 
     The single source of the ingest math for BOTH device rings AND the
     fused megastep program (rl/megastep.py): the single-device buffer
     calls it whole-ring, the dp-sharded buffer calls it per shard
-    inside `shard_map` — the validation predicate and keep/trash-slot
-    rules must never diverge between them.
+    inside `shard_map` — the validation predicate and the keep and
+    slot rules must never diverge between them.
 
     Each block holds arrays with arbitrary leading dims (the chunk
     program's (T,B) matured and (T,B,n) flushed outputs) plus a boolean
@@ -98,9 +196,10 @@ def ring_scatter(
     leading-dims-major — the same order the host path produces via
     boolean indexing, so the paths fill identical slots with identical
     rows. Returns (new_storage, new_cursor, rows_written); with
-    `with_positions` it additionally returns the per-row scatter slots
-    and keep mask, which the megastep needs to max-priority-init the
-    fresh rows in its device-resident PER array."""
+    `with_positions` it additionally returns each candidate row's slot
+    (`cap` for a row that is not written) and keep mask, which the
+    megastep needs to max-priority-init the fresh rows in its
+    device-resident PER array."""
 
     def flat(block: dict[str, jax.Array], f: str) -> jax.Array:
         lead = block["mask"].shape
@@ -109,7 +208,7 @@ def ring_scatter(
 
     rows = {
         f: jnp.concatenate([flat(b, f) for b in blocks])
-        for f in _BLOCK_FIELDS
+        for f in _RING_FIELDS.values()
     }
     mask = jnp.concatenate([b["mask"].reshape(-1) for b in blocks])
     # Validation absorbed from SelfPlayResult's validator + the host
@@ -126,29 +225,20 @@ def ring_scatter(
     count = valid.sum(dtype=jnp.int32)
     # A single ingest larger than the ring keeps only the newest `cap`
     # rows — the older ones would be overwritten by the wrap anyway,
-    # and dropping them guarantees distinct scatter slots, making
-    # last-write-wins deterministic (`.at[pos].set` with duplicate
-    # indices has an unspecified winner). The cursor still advances by
-    # the full count, matching the host ring.
+    # and dropping them keeps the written slots distinct. The cursor
+    # still advances by the full count, matching the host ring.
     keep = valid & (offsets >= count - cap)
-    pos = jnp.where(keep, (cursor + offsets) % cap, cap)
-    new_storage = {
-        "grid": storage["grid"].at[pos].set(rows["grid"].astype(jnp.int8)),
-        "other_features": storage["other_features"]
-        .at[pos]
-        .set(rows["other"].astype(jnp.float32)),
-        "policy_target": storage["policy_target"]
-        .at[pos]
-        .set(rows["policy"].astype(jnp.float32)),
-        "value_target": storage["value_target"]
-        .at[pos]
-        .set(rows["ret"].astype(jnp.float32)),
-        "policy_weight": storage["policy_weight"]
-        .at[pos]
-        .set(rows["pw"].astype(jnp.float32)),
-    }
+    new_storage, _ = write_windows(
+        storage,
+        rows,
+        keep,
+        (cursor + jnp.maximum(count - cap, 0)) % cap,
+        cap,
+        ingest_window_rows(blocks, cap),
+    )
     new_cursor = (cursor + count) % cap
     if with_positions:
+        pos = jnp.where(keep, (cursor + offsets) % cap, cap)
         return new_storage, new_cursor, count, pos, keep
     return new_storage, new_cursor, count
 
@@ -173,7 +263,8 @@ class DeviceReplayBuffer(ExperienceBuffer):
     ):
         super().__init__(config, seed=seed, action_dim=action_dim)
         cap = self.capacity
-        # One trash row at index `cap` absorbs invalid-row scatters.
+        # One row past the ring at index `cap`: the slot `ring_scatter`
+        # reports for a row it does not write (nothing writes it).
         self.storage: dict[str, jax.Array] = {
             "grid": jnp.zeros((cap + 1, *grid_shape), jnp.int8),
             "other_features": jnp.zeros((cap + 1, other_dim), jnp.float32),
@@ -196,7 +287,7 @@ class DeviceReplayBuffer(ExperienceBuffer):
         cursor: jax.Array,
         blocks: tuple[dict[str, jax.Array], ...],
     ):
-        """Flatten + validate + ring-scatter experience blocks.
+        """Flatten + validate + ring-write experience blocks.
 
         The math lives in the module-level `ring_scatter` (shared with
         the dp-sharded ring's per-shard ingest).
@@ -216,6 +307,12 @@ class DeviceReplayBuffer(ExperienceBuffer):
         with tracer.span("replay.ingest_wait") as args:
             count = int(count_dev)  # the one blocking scalar fetch
             args["rows"] = count
+            args["windows"] = ingest_windows(
+                count,
+                self._pos,
+                self.capacity,
+                ingest_window_rows(blocks, self.capacity),
+            )
         with tracer.span("replay.tree_update", rows=count):
             slots = (self._pos + np.arange(count)) % self.capacity
             if self.tree is not None and count:

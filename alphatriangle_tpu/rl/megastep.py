@@ -17,7 +17,8 @@ into that program:
   ZERO-staleness — there is no `sync_to_network` copy on the hot path,
   and every move of every megastep searches with the newest weights.
 - `ring_scatter` (rl/device_buffer.py): the chunk's masked experience
-  outputs scatter straight into the device-resident replay ring —
+  outputs go straight into the device-resident replay ring (the rows
+  that pass validation, written as windows of consecutive slots) —
   nothing is fetched, nothing is re-uploaded.
 - `Trainer._train_steps_from_impl` (rl/trainer.py): K training batches
   are sampled ON DEVICE from the ring (stratified proportional PER over

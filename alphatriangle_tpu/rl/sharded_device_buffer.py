@@ -23,9 +23,10 @@ over the dp axis, and the whole experience path becomes device-local —
 
 Indices are globally encoded as `shard * (cap_local + 1) + slot` — the
 actual row index in the sharded storage array — so priority updates
-route by arithmetic and the trash row (one per shard, at local index
-`cap_local`) absorbs invalid scatters exactly like the single-device
-ring.
+route by arithmetic; the row past each shard's ring (local index
+`cap_local`) is the slot `ring_scatter` reports for a row it does not
+write, exactly like the single-device ring (priority arrays pin it to
+0; the rings' own arrays are not written there).
 
 Scope (gated in training/setup.py): single-process, dp-only meshes
 (mdl == sp == 1) — with a wider sp the sp-replicas of the learner batch
@@ -133,9 +134,10 @@ class ShardedDeviceReplayBuffer(ExperienceBuffer):
         cursor_local: jax.Array,
         blocks_local: tuple[dict[str, jax.Array], ...],
     ):
-        """One shard's ring-scatter: the SAME `ring_scatter` math as the
-        single-device ring, over the LOCAL lanes and the LOCAL ring
-        shard (cap = cap_local). Runs under shard_map with no
+        """One shard's ring write: the SAME `ring_scatter` math as the
+        single-device ring (validation, the gather of the rows that
+        pass, the window write), over the LOCAL lanes and the LOCAL
+        ring shard (cap = cap_local). Runs under shard_map with no
         collectives — the partitioning IS the distribution."""
         new_storage, _, count = ring_scatter(
             storage_local, cursor_local[0], blocks_local, self.cap_local

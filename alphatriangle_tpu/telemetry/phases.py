@@ -58,6 +58,8 @@ PHASES = (
     "learner/accumulate",
     "learner/optimizer",
     "learner/router_bias",
-    # ring ingest (rl/device_buffer.py)
+    # ring ingest (rl/device_buffer.py `ring_scatter`: validation, the
+    # gather of the rows that pass, the window write; its host span
+    # `replay.ingest_wait` carries `rows` and `windows`)
     "replay/ingest_scatter",
 )

@@ -220,3 +220,78 @@ def test_lane_sharded_chunk_compiles_for_v5e(
     assert ("encoder_layer" in text) == fused
     assert ("tpu_custom_call" in text) == fused
     assert "encoder_attention" not in text
+
+
+# ring rows, (grid, other features, actions), the blocks' leading dims
+INGEST_SHAPES = {
+    # flagship-rollout: a chunk of 16 moves x 512 lanes, 49,152 candidates
+    "flagship-chunk": (3_000_000, ((1, 8, 15), 30, 360), ((16, 512), (16, 512, 5))),
+    # k-exaone-rollout, ling-flash-rollout: one move of 16 lanes, 96 candidates
+    "trunk-cells-chunk": (250_000, ((1, 12, 21), 30, 756), ((1, 16), (1, 16, 5))),
+    # flagship-learner's ring fill: 125,000 rows, all valid
+    "fill-block": (3_000_000, ((1, 8, 15), 30, 360), ((62_500,), (62_500,))),
+    # `add_dense` of one row
+    "one-row": (3_000_000, ((1, 8, 15), 30, 360), ((1,),)),
+}
+
+
+@pytest.mark.parametrize("name", sorted(INGEST_SHAPES))
+def test_the_ingest_writes_the_ring_where_it_lies(one_v5e_chip, name):
+    """The chip lays the ring's rows along its lanes. The scatter the
+    ingest was made XLA re-lay-out `policy_target` and `grid` whole,
+    there and back, on every ingest (43 of its 60 ms at the flagship's
+    ring, all 8 ms at the trunk cells'), and a window narrower than a
+    lane tile does the same. Held here: the compiled ingest copies no
+    whole-ring array, scatters nothing into one, and returns the ring
+    in the buffers it was given."""
+    import re
+
+    import jax.numpy as jnp
+
+    from alphatriangle_tpu.rl.device_buffer import ring_scatter
+
+    cap, (grid, other, actions), leads = INGEST_SHAPES[name]
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_v5e_chip)
+
+    storage = {
+        "grid": shape((cap + 1, *grid), jnp.int8),
+        "other_features": shape((cap + 1, other), jnp.float32),
+        "policy_target": shape((cap + 1, actions), jnp.float32),
+        "value_target": shape((cap + 1,), jnp.float32),
+        "policy_weight": shape((cap + 1,), jnp.float32),
+    }
+    blocks = tuple(
+        {
+            "grid": shape((*lead, *grid), jnp.float32),
+            "other": shape((*lead, other), jnp.float32),
+            "policy": shape((*lead, actions), jnp.float32),
+            "ret": shape(lead, jnp.float32),
+            "pw": shape(lead, jnp.float32),
+            "mask": shape(lead, jnp.bool_),
+        }
+        for lead in leads
+    )
+    compiled = (
+        jax.jit(
+            lambda ring, cursor, blocks: ring_scatter(ring, cursor, blocks, cap),
+            donate_argnums=(0,),
+        )
+        .lower(storage, shape((), jnp.int32), blocks)
+        .compile()
+    )
+    whole_ring = re.compile(rf"^\s*(?:ROOT )?%\S+ = \w+\[{cap + 1}[,\]]\S* (\w[\w-]*)\(")
+    made = [
+        m.group(1)
+        for m in map(whole_ring.match, compiled.as_text().splitlines())
+        if m
+    ]
+    assert "copy" not in made and "scatter" not in made, made
+    assert "dynamic-update-slice" in made or "fusion" in made
+    ring_bytes = (cap + 1) * (
+        grid[0] * grid[1] * grid[2] + 4 * (other + actions + 2)
+    )
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= ring_bytes
+    assert memory.temp_size_in_bytes < ring_bytes // 8

@@ -147,6 +147,476 @@ class TestIngestParity:
         assert hits > 25
 
 
+def _block(lead, rng, share=1.0, bad=()):
+    """One experience block with leading dims `lead`: `share` of its
+    rows masked in; the flat rows listed in `bad` spoiled in turn by a
+    NaN cell, a policy that sums to 3, an infinite return, a NaN in the
+    other features, an infinite policy entry."""
+    n = int(np.prod(lead))
+    grid, other, policy, val, pw = _rows(n, rng)
+    for k, i in enumerate(bad):
+        if k % 5 == 0:
+            grid[i, 0, 0, 0] = np.nan
+        elif k % 5 == 1:
+            policy[i] *= 3.0
+        elif k % 5 == 2:
+            val[i] = np.inf
+        elif k % 5 == 3:
+            other[i, -1] = np.nan
+        else:
+            policy[i, 0] = np.inf
+    return {
+        "grid": grid.reshape(*lead, *GRID_SHAPE),
+        "other": other.reshape(*lead, OTHER_DIM),
+        "policy": policy.reshape(*lead, ACTION_DIM),
+        "ret": val.reshape(lead),
+        "pw": pw.reshape(lead),
+        "mask": (rng.random(lead) < share),
+    }
+
+
+def _random_ring(cap, rng):
+    """A ring that already holds something in every slot, the row past
+    the ring included."""
+    grid, other, policy, val, pw = _rows(cap + 1, rng)
+    return {
+        "grid": grid.astype(np.int8),
+        "other_features": other,
+        "policy_target": policy,
+        "value_target": val,
+        "policy_weight": pw,
+    }
+
+
+def _scatter_oracle(storage, cursor, blocks, cap):
+    """The ingest as it was before the window write, written plainly:
+    every candidate row scattered by `.at[pos].set`, the rows that are
+    not kept at the slot past the ring. Returns (ring, cursor, count,
+    pos, keep, the flat rows)."""
+    import jax.numpy as jnp
+
+    def flat(block, f):
+        lead = block["mask"].shape
+        return block[f].reshape(-1, *block[f].shape[len(lead):])
+
+    rows = {
+        f: np.concatenate([flat(b, f) for b in blocks])
+        for f in ("grid", "other", "policy", "ret", "pw")
+    }
+    mask = np.concatenate([b["mask"].reshape(-1) for b in blocks])
+    with np.errstate(invalid="ignore"):
+        valid = (
+            mask
+            & np.isfinite(rows["grid"]).all(axis=(1, 2, 3))
+            & np.isfinite(rows["other"]).all(axis=1)
+            & np.isfinite(rows["policy"]).all(axis=1)
+            & np.isfinite(rows["ret"])
+            & (np.abs(rows["policy"].sum(axis=1) - 1.0) < 1e-3)
+        )
+    offsets = np.cumsum(valid.astype(np.int32)) - 1
+    count = int(valid.sum())
+    keep = valid & (offsets >= count - cap)
+    pos = np.where(keep, (cursor + offsets) % cap, cap)
+    fields = {
+        "grid": "grid", "other_features": "other", "policy_target": "policy",
+        "value_target": "ret", "policy_weight": "pw",
+    }
+    with np.errstate(invalid="ignore"):
+        ring = {
+            name: np.asarray(
+                jnp.asarray(storage[name])
+                .at[pos]
+                .set(jnp.asarray(rows[f].astype(storage[name].dtype)))
+            )
+            for name, f in fields.items()
+        }
+    return ring, (cursor + count) % cap, count, pos, keep, rows, valid
+
+
+# (capacity, cursor, [(leading dims, share masked in, spoiled rows)...],
+#  least rows of a window or None for the module's own)
+WINDOW_CASES = {
+    "no_valid_row": (16, 5, [((2, 3), 0.0, ()), ((2, 3, 2), 0.0, ())], 4),
+    "all_valid": (64, 7, [((2, 4), 1.0, ()), ((2, 4, 3), 1.0, ())], 4),
+    "valid_between_invalid": (
+        64, 60, [((3, 4), 0.7, (0, 3, 4, 7, 10)),
+                 ((3, 4, 2), 0.5, (1, 2, 5, 9, 20, 23))], 4,
+    ),
+    "wraps_the_rings_end": (32, 27, [((2, 4), 1.0, ()), ((2, 4, 2), 0.5, (3,))], 4),
+    "ends_at_the_rings_end": (32, 24, [((8,), 1.0, ())], 4),
+    "more_kept_rows_than_a_window": (
+        64, 11, [((4,), 1.0, ()), ((29,), 1.0, (5, 6))], 4,
+    ),
+    "larger_than_the_ring": (8, 3, [((4,), 1.0, ()), ((25,), 0.9, (7,))], 4),
+    "larger_than_the_ring_by_one": (8, 7, [((9,), 1.0, ())], 4),
+    "ring_smaller_than_a_window": (6, 4, [((4, 4), 0.3, (2,))], 4),
+    "one_row": (16, 15, [((1,), 1.0, ())], 4),
+    "the_modules_own_width_one_window": (
+        1500, 1400, [((2, 8), 0.5, (3,)), ((2, 8, 3), 0.2, ())], None,
+    ),
+    "the_modules_own_width_three_windows": (
+        3000, 2900, [((8,), 1.0, ()), ((2600,), 1.0, (17, 1200))], None,
+    ),
+}
+
+
+class TestWindowWrite:
+    """The ingest's ring write (`ring_scatter` -> `write_windows`): the
+    kept rows brought together by a gather and written as windows of
+    the ring, against the scatter of every candidate row it replaced
+    and against the host ring."""
+
+    @pytest.fixture
+    def case(self, request, monkeypatch):
+        from alphatriangle_tpu.rl import device_buffer
+
+        cap, cursor, spec, at_least = WINDOW_CASES[request.param]
+        if at_least is not None:
+            monkeypatch.setattr(
+                device_buffer, "_WINDOW_ROWS_AT_LEAST", at_least
+            )
+        rng = np.random.default_rng(sorted(WINDOW_CASES).index(request.param))
+        blocks = tuple(_block(*b[:1], rng, *b[1:]) for b in spec)
+        return cap, cursor, blocks, rng
+
+    @pytest.mark.parametrize("case", sorted(WINDOW_CASES), indirect=True)
+    def test_ring_is_the_scatters_bit_for_bit(self, case):
+        import jax
+        import jax.numpy as jnp
+
+        from alphatriangle_tpu.rl.device_buffer import (
+            ingest_window_rows,
+            ingest_windows,
+            ring_scatter,
+            write_windows,
+        )
+
+        cap, cursor, blocks, rng = case
+        storage = _random_ring(cap, rng)
+        want, want_cursor, want_count, pos, keep, rows, _ = _scatter_oracle(
+            storage, cursor, blocks, cap
+        )
+        ingest = jax.jit(ring_scatter, static_argnums=(3, 4))
+        got, got_cursor, got_count, got_pos, got_keep = ingest(
+            storage, jnp.int32(cursor), blocks, cap, True
+        )
+        assert int(got_count) == want_count
+        assert int(got_cursor) == want_cursor
+        for name in want:
+            np.testing.assert_array_equal(
+                np.asarray(got[name])[:cap], want[name][:cap], err_msg=name
+            )
+            # nothing writes the row past the ring any more
+            np.testing.assert_array_equal(
+                np.asarray(got[name])[cap], storage[name][cap], err_msg=name
+            )
+            assert got[name].dtype == storage[name].dtype
+            assert got[name].shape == storage[name].shape
+        # `with_positions`: today's slots and keep mask per candidate row
+        np.testing.assert_array_equal(np.asarray(got_pos), pos)
+        np.testing.assert_array_equal(np.asarray(got_keep), keep)
+        # without positions: the same ring, cursor and count
+        plain = ingest(storage, jnp.int32(cursor), blocks, cap, False)
+        assert len(plain) == 3 and int(plain[2]) == want_count
+        for name in want:
+            np.testing.assert_array_equal(
+                np.asarray(plain[0][name]), np.asarray(got[name])
+            )
+        # the windows the device's loop wrote are the host's count
+        width = ingest_window_rows(blocks, cap)
+        kept = min(want_count, cap)
+        _, windows = jax.jit(write_windows, static_argnums=(4, 5))(
+            storage,
+            {f: np.nan_to_num(v) for f, v in rows.items()},
+            keep,
+            jnp.int32((cursor + want_count - kept) % cap),
+            cap,
+            width,
+        )
+        assert int(windows) == ingest_windows(want_count, cursor, cap, width)
+        assert int(windows) >= -(-kept // width)
+
+    @pytest.mark.parametrize("case", sorted(WINDOW_CASES), indirect=True)
+    def test_ring_is_the_host_rings(self, case, tiny_train_config):
+        """The same blocks through `DeviceReplayBuffer` and, the rows
+        that pass, through the host `ExperienceBuffer`, both brought to
+        the case's cursor first: same slots, same rows, same cursor."""
+        cap, cursor, blocks, rng = case
+        cfg = _cfg(tiny_train_config, BUFFER_CAPACITY=cap, USE_PER=True,
+                   PER_BETA_ANNEAL_STEPS=100)
+        host = ExperienceBuffer(cfg, action_dim=ACTION_DIM)
+        dev = _dev_buffer(cfg)
+        first = _rows(cursor, rng)
+        if cursor:
+            host.add_dense(*first[:4], policy_weight=first[4])
+            dev.add_dense(*first[:4], policy_weight=first[4])
+        *_, rows, valid = _scatter_oracle(
+            _random_ring(cap, rng), cursor, blocks, cap
+        )
+        count, slots = dev._ingest_blocks(blocks)
+        host_slots = host.add_dense(
+            rows["grid"][valid], rows["other"][valid], rows["policy"][valid],
+            rows["ret"][valid], policy_weight=rows["pw"][valid],
+        )
+        assert count == int(valid.sum())
+        np.testing.assert_array_equal(slots, host_slots)
+        assert dev._pos == host._pos and len(dev) == len(host)
+        hs, ds = host.get_state(), dev.get_state()
+        if len(host):
+            for k in hs["storage"]:
+                np.testing.assert_array_equal(
+                    hs["storage"][k], ds["storage"][k], err_msg=k
+                )
+            np.testing.assert_allclose(hs["priorities"], ds["priorities"])
+
+    def test_the_megasteps_call(self):
+        """As `rl/megastep.py` calls it: inside a jitted program, the
+        chunk's (T, B) and (T, B, n) blocks, `with_positions`, and the
+        priorities of the fresh rows set from `pos` and `keep`."""
+        import jax
+        import jax.numpy as jnp
+
+        from alphatriangle_tpu.rl.device_buffer import ring_scatter
+
+        cap, cursor = 40, 33
+        rng = np.random.default_rng(11)
+        blocks = (
+            _block((4, 4), rng, 0.8, (1, 6)),
+            _block((4, 4, 3), rng, 0.3, (0, 9, 30)),
+        )
+        storage = _random_ring(cap, rng)
+        priorities = rng.random(cap + 1).astype(np.float32)
+
+        @jax.jit
+        def step(storage, priorities, cursor, mat, flush):
+            new_storage, new_cursor, count, pos, keep = ring_scatter(
+                storage, cursor, (mat, flush), cap, with_positions=True
+            )
+            priorities = priorities.at[pos].set(jnp.where(keep, 7.0, 0.0))
+            return new_storage, priorities.at[cap].set(0.0), new_cursor, count
+
+        got, got_priorities, got_cursor, got_count = step(
+            storage, priorities, jnp.int32(cursor), *blocks
+        )
+        want, want_cursor, want_count, pos, keep, *_ = _scatter_oracle(
+            storage, cursor, blocks, cap
+        )
+        want_priorities = priorities.copy()
+        want_priorities[pos[keep]] = 7.0
+        want_priorities[cap] = 0.0
+        assert (int(got_cursor), int(got_count)) == (want_cursor, want_count)
+        np.testing.assert_array_equal(np.asarray(got_priorities), want_priorities)
+        for name in want:
+            np.testing.assert_array_equal(
+                np.asarray(got[name])[:cap], want[name][:cap], err_msg=name
+            )
+
+    def test_the_dp_sharded_ingest(self, tiny_train_config):
+        """`ShardedDeviceReplayBuffer`'s per-shard ingest inside
+        `shard_map`: each shard's lanes through the same write into the
+        shard's own ring, twice, so that every shard wraps."""
+        import jax
+
+        from alphatriangle_tpu.config import MeshConfig
+        from alphatriangle_tpu.rl.sharded_device_buffer import (
+            ShardedDeviceReplayBuffer,
+        )
+
+        dp, cap_local = 4, 16
+        mesh = MeshConfig(DP_SIZE=dp).build_mesh(jax.devices()[:dp])
+        cfg = _cfg(tiny_train_config, BUFFER_CAPACITY=dp * cap_local,
+                   USE_PER=False, SELF_PLAY_BATCH_SIZE=2 * dp, BATCH_SIZE=dp)
+        buf = ShardedDeviceReplayBuffer(
+            cfg, grid_shape=GRID_SHAPE, other_dim=OTHER_DIM,
+            action_dim=ACTION_DIM, mesh=mesh, dp_axis="dp",
+        )
+        rng = np.random.default_rng(12)
+        want = {
+            k: np.asarray(v).reshape(dp, cap_local + 1, *v.shape[1:]).copy()
+            for k, v in jax.device_get(buf.storage).items()
+        }
+        cursors = np.zeros(dp, np.int64)
+        lanes = 2  # a shard's lanes
+        for bad in ((3, 17), (0, 40)):
+            blocks = (
+                _block((3, lanes * dp), rng, 0.9, bad[:1]),
+                _block((3, lanes * dp, 2), rng, 0.4, bad[1:]),
+            )
+            total, _ = buf._ingest_blocks(blocks)
+            counted = 0
+            for k in range(dp):
+                local = tuple(
+                    {f: v[:, k * lanes:(k + 1) * lanes] for f, v in b.items()}
+                    for b in blocks
+                )
+                ring, cursor, count, *_ = _scatter_oracle(
+                    {f: v[k] for f, v in want.items()},
+                    int(cursors[k]), local, cap_local,
+                )
+                for f in want:
+                    want[f][k] = ring[f]
+                cursors[k] = cursor
+                counted += count
+            assert total == counted
+            np.testing.assert_array_equal(buf._cursors, cursors)
+        assert (buf._sizes == cap_local).all()  # every shard wrapped
+        got = jax.device_get(buf.storage)
+        for f, v in want.items():
+            np.testing.assert_array_equal(
+                np.asarray(got[f]).reshape(v.shape)[:, :cap_local],
+                v[:, :cap_local], err_msg=f,
+            )
+
+
+def _flagship_ingest_shapes():
+    """`flagship-rollout`'s ring and one chunk's payload, as shapes."""
+    import jax
+    import jax.numpy as jnp
+
+    from chipbench import manifest
+
+    cell = manifest.cell("flagship-rollout")
+    configs = manifest.program_configs(cell["config_file"])
+    env, model, train = configs["env"], configs["model"], configs["train"]
+    cap = train.BUFFER_CAPACITY
+    grid = (model.GRID_INPUT_CHANNELS, env.ROWS, env.COLS)
+    other, actions = model.OTHER_NN_INPUT_FEATURES_DIM, env.action_dim
+    shape = jax.ShapeDtypeStruct
+    storage = {
+        "grid": shape((cap + 1, *grid), jnp.int8),
+        "other_features": shape((cap + 1, other), jnp.float32),
+        "policy_target": shape((cap + 1, actions), jnp.float32),
+        "value_target": shape((cap + 1,), jnp.float32),
+        "policy_weight": shape((cap + 1,), jnp.float32),
+    }
+
+    def block(lead):
+        return {
+            "grid": shape((*lead, *grid), jnp.float32),
+            "other": shape((*lead, other), jnp.float32),
+            "policy": shape((*lead, actions), jnp.float32),
+            "ret": shape(lead, jnp.float32),
+            "pw": shape(lead, jnp.float32),
+            "mask": shape(lead, jnp.bool_),
+        }
+
+    lead = (cell["traffic_file"]["chunk_moves"], train.SELF_PLAY_BATCH_SIZE)
+    blocks = (block(lead), block((*lead, train.N_STEP_RETURNS)))
+    return cap, storage, blocks
+
+
+class TestIngestProgram:
+    def test_no_scatter_of_the_candidates_and_the_ring_in_place(self):
+        """The ingest's lowered program at `flagship-rollout`'s shapes
+        (49,152 candidate rows, a 3,000,000-row ring; lowering allocates
+        nothing): no scatter takes the candidates as its updates, the
+        ring is written by `dynamic_update_slice`, and each of its five
+        arrays is donated and aliased to the output that replaces it."""
+        import re
+        import types
+
+        import jax
+
+        cap, storage, blocks = _flagship_ingest_shapes()
+        candidates = sum(int(np.prod(b["mask"].shape)) for b in blocks)
+        assert (cap, candidates) == (3_000_000, 49_152)
+        text = (
+            jax.jit(
+                DeviceReplayBuffer._ingest_impl,
+                static_argnums=0,
+                donate_argnums=1,
+            )
+            .lower(
+                types.SimpleNamespace(capacity=cap),
+                storage,
+                jax.ShapeDtypeStruct((), np.int32),
+                blocks,
+            )
+            .as_text()
+        )
+        scatters = [
+            line for line in text.splitlines() if "stablehlo.scatter" in line
+        ]
+        assert not [s for s in scatters if f"tensor<{candidates}x" in s]
+        assert text.count("stablehlo.dynamic_update_slice") == len(storage)
+        main = next(
+            line for line in text.splitlines() if "func.func public @main" in line
+        )
+        rings = re.findall(
+            rf"tensor<{cap + 1}(?:x\d+)*x\w+> {{tf.aliasing_output = (\d) : i32}}",
+            main,
+        )
+        assert rings == ["0", "1", "2", "3", "4"]
+
+    def test_a_buffers_own_program_donates_its_ring(self, tiny_train_config):
+        dev = _dev_buffer(_cfg(tiny_train_config, BUFFER_CAPACITY=16))
+        block = {
+            k: np.asarray(v)
+            for k, v in _block((4,), np.random.default_rng(0)).items()
+        }
+        text = dev._ingest_jit.lower(
+            dev.storage, np.int32(0), (block,)
+        ).as_text()
+        assert text.count("tf.aliasing_output") == len(dev.storage)
+        assert "stablehlo.scatter" not in text
+
+    def test_programs_that_take_the_ring_see_the_ring_they_saw(
+        self, tiny_env_config, tiny_model_config, tiny_train_config
+    ):
+        """The ring keeps its arrays' shapes and types through the new
+        write, the row past the ring included, so a program that takes
+        it as an argument (`learner_fused_from_ring`) lowers from a ring
+        that has been written to the text it lowers to from a fresh one.
+        (`tests/test_trainer.py::test_the_flagships_learner_programs_
+        are_the_parents_text` holds that text to the digest taken two
+        commits before this write, at `flagship-p3`'s widths.)"""
+        import jax
+
+        from alphatriangle_tpu.nn.network import NeuralNetwork
+        from alphatriangle_tpu.rl.trainer import Trainer
+
+        cfg = _cfg(tiny_train_config, BUFFER_CAPACITY=32,
+                   MIN_BUFFER_SIZE_TO_TRAIN=8, FUSED_LEARNER_STEPS=2)
+        shape = (
+            tiny_model_config.GRID_INPUT_CHANNELS,
+            tiny_env_config.ROWS,
+            tiny_env_config.COLS,
+        )
+        dev = DeviceReplayBuffer(
+            cfg, grid_shape=shape,
+            other_dim=tiny_model_config.OTHER_NN_INPUT_FEATURES_DIM,
+            action_dim=tiny_env_config.action_dim,
+        )
+        trainer = Trainer(
+            NeuralNetwork(tiny_model_config, tiny_env_config, seed=3), cfg
+        )
+
+        def text():
+            return (
+                jax.jit(trainer._train_steps_from_impl)
+                .lower(
+                    trainer.state, dev.storage,
+                    np.zeros((2, 4), np.int32), np.ones((2, 4), np.float32),
+                )
+                .as_text()
+            )
+
+        fresh = text()
+        before = {k: (v.shape, v.dtype) for k, v in dev.storage.items()}
+        rng = np.random.default_rng(6)
+        n = 40  # more than the ring holds
+        grid = rng.integers(-1, 2, size=(n, *shape)).astype(np.float32)
+        other = rng.random(
+            (n, tiny_model_config.OTHER_NN_INPUT_FEATURES_DIM), dtype=np.float32
+        )
+        policy = rng.random((n, tiny_env_config.action_dim), dtype=np.float32)
+        policy /= policy.sum(axis=1, keepdims=True)
+        dev.add_dense(grid, other, policy, rng.normal(size=n).astype(np.float32))
+        assert {k: (v.shape, v.dtype) for k, v in dev.storage.items()} == before
+        assert all(v.shape[0] == cfg.BUFFER_CAPACITY + 1 for v in dev.storage.values())
+        assert text() == fresh
+
+
 class TestTrainEquivalence:
     def test_train_steps_from_matches_host_path(
         self, tiny_env_config, tiny_model_config, tiny_train_config

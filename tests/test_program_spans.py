@@ -229,6 +229,7 @@ class TestProgramSpans:
         while rows < 16:  # enough rows for the learner's test below
             mark = len(tracer.records())
             _, payload = engine.play_moves_device(4)
+            cursor = buffer._pos
             added = buffer.ingest_payload(payload)
             rows += added
         records = tracer.records()[mark:]
@@ -243,9 +244,63 @@ class TestProgramSpans:
         args = {r[1]: r[6] for r in records}
         for name in ("rollout.dispatch", "rollout.wait", "rollout.fold"):
             assert args[name] == {"t": 4, "lanes": 4}
-        assert args["replay.ingest_wait"] == {"rows": added}
+        # A tiny ring is one window wide: one window unless the rows
+        # wrap its end, none when there are no rows.
+        windows = (added > 0) + (cursor + added > buffer.capacity)
+        assert args["replay.ingest_wait"] == {"rows": added, "windows": windows}
         assert args["replay.tree_update"] == {"rows": added}
         assert args["replay.ingest_dispatch"] is None
+
+    def test_ingest_wait_counts_the_windows_it_wrote(
+        self, tracer, tiny_train_config, monkeypatch
+    ):
+        """`windows` on `replay.ingest_wait`: how many windows of the
+        ring the ingest's device loop wrote, worked out on the host from
+        the count, the cursor and the window's rows (nothing more is
+        fetched). Here a window is 4 rows wide (the block's 4, the
+        module's least brought down to it) and the ring 10."""
+        from alphatriangle_tpu.rl import device_buffer
+
+        monkeypatch.setattr(device_buffer, "_WINDOW_ROWS_AT_LEAST", 4)
+        buffer = device_buffer.DeviceReplayBuffer(
+            tiny_train_config.model_copy(
+                update={"BUFFER_CAPACITY": 10, "USE_PER": False}
+            ),
+            grid_shape=(1, 3, 4), other_dim=5, action_dim=12,
+        )
+        rng = np.random.default_rng(0)
+
+        def block(lead, valid):
+            n = int(np.prod(lead))
+            policy = rng.random((n, 12), dtype=np.float32) + 0.01
+            policy /= policy.sum(axis=1, keepdims=True)
+            return {
+                "grid": np.zeros((*lead, 1, 3, 4), np.float32),
+                "other": np.zeros((*lead, 5), np.float32),
+                "policy": policy.reshape(*lead, 12),
+                "ret": np.zeros(lead, np.float32),
+                "pw": np.ones(lead, np.float32),
+                "mask": (np.arange(n) < valid).reshape(lead),
+            }
+
+        seen = []
+        # (rows of the second block that are valid): with the first
+        # block's 3 of 4, the ingests write 3, 9, 0, 3 and 15 rows.
+        for more in (0, 6, None, 0, 12):
+            mark = len(tracer.records())
+            first = block((4,), 0 if more is None else 3)
+            count, _ = buffer._ingest_blocks((first, block((2, 6), more or 0)))
+            waits = [
+                r[6] for r in tracer.records()[mark:]
+                if r[1] == "replay.ingest_wait"
+            ]
+            assert len(waits) == 1 and waits[0]["rows"] == count
+            seen.append((count, waits[0]["windows"]))
+        # 3 rows at 0: one window. 9 at 3: rows 3-6, 7-9 (the ring's
+        # end), then 0-1: three. None: none. 3 at 2: one. 15 into a
+        # ring of 10 keeps 10, from slot 0 on: 4, 4 and 2: three.
+        assert seen == [(3, 1), (9, 3), (0, 0), (3, 1), (15, 3)]
+        assert buffer._pos == 0 and len(buffer) == 10
 
     def test_learner_group_spans_under_the_open_phase(self, world, tracer):
         trainer, buffer = world["trainer"], world["buffer"]
