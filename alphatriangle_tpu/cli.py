@@ -577,14 +577,14 @@ def cmd_trace(args: argparse.Namespace) -> int:
         return 0
     width = max(max(len(r["name"]) for r in rows), 5)
     print(
-        f"{'span':<{width}}  {'count':>7}  {'total s':>9}  "
+        f"{'span':<{width}}  {'count':>7}  {'total s':>9}  {'self s':>9}  "
         f"{'mean ms':>9}  {'max ms':>9}  {'threads':>7}"
     )
     for r in rows:
         print(
             f"{r['name']:<{width}}  {r['count']:>7d}  "
-            f"{r['total_ms'] / 1e3:>9.2f}  {r['mean_ms']:>9.2f}  "
-            f"{r['max_ms']:>9.2f}  {r['threads']:>7d}"
+            f"{r['total_ms'] / 1e3:>9.2f}  {r['self_ms'] / 1e3:>9.2f}  "
+            f"{r['mean_ms']:>9.2f}  {r['max_ms']:>9.2f}  {r['threads']:>7d}"
         )
     print(
         f"\nfull timeline: load {path} in https://ui.perfetto.dev "

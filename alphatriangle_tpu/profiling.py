@@ -358,6 +358,48 @@ def backward_seconds(events, op_names: dict[str, str]) -> dict[str, float]:
     return {p: total[p] for p in (*PHASES, OTHER) if p in total}
 
 
+def idle_gap_seconds(modules, host_spans) -> dict[str, dict[str, float]]:
+    """For each program of an `XLA Modules` line, the seconds the device
+    idled between two of its runs, by the program's own host span that
+    covered them (`at:` spans, the prefix taken off; the innermost where
+    spans nest); what no span covered is `other`, last. Both lists are
+    (name, start_ns, duration_ns) on the trace's one clock. Another
+    program's runs inside a gap (the ingest between two chunks) leave
+    it a gap: it is this program's device that waits. The program that
+    ran longest comes first: of a small one's "gaps" most is the large
+    one running."""
+    runs: dict[str, list] = defaultdict(list)
+    for name, start, duration in sorted(modules, key=lambda e: e[1]):
+        runs[name.split("(")[0]].append((start, start + duration))
+    out: dict[str, dict[str, float]] = {}
+    by_time = sorted(runs, key=lambda p: -sum(b - a for a, b in runs[p]))
+    for program, ran in ((p, runs[p]) for p in by_time):
+        total: dict[str, float] = defaultdict(float)
+        for (_, gap_start), (gap_stop, _) in zip(ran, ran[1:]):
+            if gap_stop <= gap_start:
+                continue
+            over = [
+                (max(s, gap_start), min(s + d, gap_stop), s, name)
+                for name, s, d in host_spans
+                if min(s + d, gap_stop) > max(s, gap_start)
+            ]
+            edges = sorted(
+                {gap_start, gap_stop, *(e for o in over for e in o[:2])}
+            )
+            for left, right in zip(edges, edges[1:]):
+                inside = [o for o in over if o[0] <= left and right <= o[1]]
+                # Of nested spans the one begun last is the innermost.
+                name = max(inside, key=lambda o: o[2])[3] if inside else OTHER
+                total[name] += (right - left) / 1e9
+        if len(ran) > 1:
+            named = sorted(
+                (kv for kv in total.items() if kv[0] != OTHER),
+                key=lambda kv: -kv[1],
+            )
+            out[program] = {**dict(named), OTHER: total.get(OTHER, 0.0)}
+    return out
+
+
 def _by_program(modules, ops) -> dict[str, list]:
     """The operations under the program execution that holds them
     (`XLA Modules` names a run `jit_f(<fingerprint>)`)."""
@@ -400,7 +442,9 @@ def summarize_xplane_trace(
     path: Path, op_names: "dict | None" = None, top: int = 20
 ) -> None:
     """Per device plane and program: device seconds and share per named
-    phase, `other` last; then the program's own host spans (`at:`).
+    phase, `other` last; then the program's own host spans (`at:`), and
+    for each program the idle gaps between its runs by the span that
+    covered them (`idle_gap_seconds`).
 
     Reads the xplane with `jax.profiler.ProfileData` alone. `op_names`
     is `op_names.json` as `ProfileSession` wrote it beside the trace
@@ -416,6 +460,7 @@ def summarize_xplane_trace(
         return
     op_names = op_names or {}
     host: dict[str, list[float]] = defaultdict(lambda: [0.0, 0])
+    host_spans = []  # (name without the prefix, start_ns, duration_ns)
     for plane in planes:
         if plane.name.startswith("/device:"):
             continue
@@ -424,6 +469,13 @@ def summarize_xplane_trace(
                 if e.name.startswith(ANNOTATION_PREFIX):
                     host[e.name][0] += e.duration_ns / 1e6
                     host[e.name][1] += 1
+                    host_spans.append(
+                        (
+                            e.name[len(ANNOTATION_PREFIX):],
+                            int(e.start_ns),
+                            int(e.duration_ns),
+                        )
+                    )
     devices = device_operations(planes)
     for plane_name, ops, modules in devices:
         for program, events in sorted(_by_program(modules, ops).items()):
@@ -447,6 +499,17 @@ def summarize_xplane_trace(
                         f"      {phase:<22} {sec:>10.4f} "
                         f"{100.0 * sec / max(busy, 1e-12):>6.1f}%"
                     )
+        for program, gaps in idle_gap_seconds(modules, host_spans).items():
+            idle = sum(gaps.values())
+            print(
+                f"\n  plane {plane_name} / program {program}: idle between "
+                f"its runs {idle:.4f} s, by host span ({ANNOTATION_PREFIX}):"
+            )
+            for name, sec in gaps.items():
+                print(
+                    f"    {name:<32} {sec:>10.4f} "
+                    f"{100.0 * sec / max(idle, 1e-12):>6.1f}%"
+                )
     if not devices:
         print("  (no device plane with an XLA Ops line: a CPU trace)")
     if host:

@@ -726,12 +726,15 @@ class SelfPlayEngine:
         with self._transfer_lock:
             self.transfer_d2h_seconds += dt
             self.dispatch_count += 1
-        with tracer.span("rollout.fold", t=t, lanes=lanes):
+        with tracer.span("rollout.fold", t=t, lanes=lanes) as folded:
             # Under playout cap randomization the per-move sim count
             # varies; the trace records what actually ran.
             self._total_simulations += (
                 int(host["trace"]["sims"].sum()) * self.batch_size
             )
+            # The chunk's time follows its full searches: the count
+            # goes on the span that folds the fetch that brought it.
+            folded["full_moves"] = int(host["trace"]["is_full"].sum())
             self._total_reused_visits += int(host["trace"]["reused"].sum())
             if "expert_tokens" in host["trace"]:
                 tokens = host["trace"]["expert_tokens"].sum(axis=0, dtype=np.int64)

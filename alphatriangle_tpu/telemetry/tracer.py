@@ -149,7 +149,7 @@ class SpanTracer:
             return
         self._record(_INSTANT, name, time.perf_counter_ns(), 0, args)
 
-    # --- export / summary ---------------------------------------------
+    # --- export -------------------------------------------------------
 
     def records(self) -> list:
         """The buffered records, oldest first (the tuple's layout is at
@@ -218,27 +218,6 @@ class SpanTracer:
             )
         return len(events)
 
-    def summary(self) -> dict[str, dict[str, float]]:
-        """Per-name aggregate of the buffered spans (count/total/mean/max)."""
-        total_ns: dict[str, int] = defaultdict(int)
-        max_ns: dict[str, int] = defaultdict(int)
-        count: dict[str, int] = defaultdict(int)
-        for kind, name, _t0, dur_ns, *_ in self.records():
-            if kind != _COMPLETE:
-                continue
-            total_ns[name] += dur_ns
-            max_ns[name] = max(max_ns[name], dur_ns)
-            count[name] += 1
-        return {
-            name: {
-                "count": count[name],
-                "total_ms": total_ns[name] / 1e6,
-                "mean_ms": total_ns[name] / 1e6 / max(count[name], 1),
-                "max_ms": max_ns[name] / 1e6,
-            }
-            for name in sorted(total_ns)
-        }
-
 
 # --- process-wide default ---------------------------------------------------
 
@@ -268,21 +247,36 @@ def set_default_tracer(tracer: SpanTracer) -> SpanTracer:
 
 def summarize_trace_file(path: Path, top: int = 20) -> list[dict]:
     """Aggregate a `trace.json` (this tracer's or any Chrome trace) into
-    per-name rows, busiest first. Accepts both the object form
-    ({"traceEvents": [...]}) and the bare-array form. Raises OSError /
-    ValueError on unreadable input — the CLI maps that to exit 1."""
+    per-name rows, busiest first. `self_ms` is a name's time less what
+    its spans' children cover (an event's `parent` names its parent's
+    `id`, as `export` writes them; events without them are their own
+    time). Accepts both the object form ({"traceEvents": [...]}) and
+    the bare-array form. Raises OSError / ValueError on unreadable
+    input — the CLI maps that to exit 1."""
     data = json.loads(Path(path).read_text())
     events = data.get("traceEvents", []) if isinstance(data, dict) else data
     total_us: dict[str, float] = defaultdict(float)
+    self_us: dict[str, float] = defaultdict(float)
     max_us: dict[str, float] = defaultdict(float)
     count: dict[str, int] = defaultdict(int)
     threads: dict[str, set] = defaultdict(set)
-    for ev in events:
-        if not isinstance(ev, dict) or ev.get("ph") != _COMPLETE:
-            continue
+    spans = [
+        ev for ev in events
+        if isinstance(ev, dict) and ev.get("ph") == _COMPLETE
+    ]
+    name_of = {  # ids are one process's own
+        (ev.get("pid"), ev["id"]): ev.get("name", "?")
+        for ev in spans
+        if "id" in ev
+    }
+    for ev in spans:
         name = ev.get("name", "?")
         dur = float(ev.get("dur", 0))
         total_us[name] += dur
+        self_us[name] += dur
+        parent = (ev.get("pid"), ev.get("parent"))
+        if parent in name_of:  # the parent loses its child's time
+            self_us[name_of[parent]] -= dur
         max_us[name] = max(max_us[name], dur)
         count[name] += 1
         threads[name].add(ev.get("tid"))
@@ -291,6 +285,7 @@ def summarize_trace_file(path: Path, top: int = 20) -> list[dict]:
             "name": name,
             "count": count[name],
             "total_ms": total_us[name] / 1e3,
+            "self_ms": self_us[name] / 1e3,
             "mean_ms": total_us[name] / 1e3 / max(count[name], 1),
             "max_ms": max_us[name] / 1e3,
             "threads": len(threads[name]),

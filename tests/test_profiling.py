@@ -124,7 +124,7 @@ class TestProfileSession:
         # Both surfaces see the phase: whole-run mean AND per-occurrence
         # spans (disabled device profiling doesn't gate the tracer).
         assert s.timers.summary()["rollout"]["count"] == 2
-        assert tracer.summary()["rollout"]["count"] == 2
+        assert [r[1] for r in tracer.records()] == ["rollout", "rollout"]
 
 
 class TestXplaneSummary:

@@ -242,8 +242,15 @@ class TestProgramSpans:
             "replay.tree_update",
         ]
         args = {r[1]: r[6] for r in records}
-        for name in ("rollout.dispatch", "rollout.wait", "rollout.fold"):
+        for name in ("rollout.dispatch", "rollout.wait"):
             assert args[name] == {"t": 4, "lanes": 4}
+        # The fold carries the chunk's full searches, which its time
+        # follows: the moves that ran the full simulation count.
+        is_full = engine.last_trace["is_full"]
+        assert args["rollout.fold"] == {
+            "t": 4, "lanes": 4, "full_moves": int(is_full.sum()),
+        }
+        assert is_full.shape == (4,)
         # A tiny ring is one window wide: one window unless the rows
         # wrap its end, none when there are no rows.
         windows = (added > 0) + (cursor + added > buffer.capacity)
@@ -575,6 +582,51 @@ class TestPhaseNamesInPrograms:
         assert _phases("replay/") == ["replay/ingest_scatter"]
         assert "replay/ingest_scatter" in text
 
+    def test_the_three_programs_are_the_parents(self, world, monkeypatch):
+        """The count on `rollout.fold` is a host sum of an array the
+        fetch already brought: the chunk, the fused group and the ingest
+        lower to the text they had on the parent commit (5be2e37, where
+        these digests were taken with this test's code), so the compile
+        caches hit. The chunk is a fresh engine's with the stat-pack
+        off, whatever an earlier test of this process left switched on
+        (`set_device_stats` is the process's, and it adds outputs)."""
+        import hashlib
+
+        from alphatriangle_tpu.rl.self_play import SelfPlayEngine
+        from alphatriangle_tpu.telemetry.device_stats import DEVICE_STATS_ENV
+
+        monkeypatch.setenv(DEVICE_STATS_ENV, "0")
+        engine = SelfPlayEngine(
+            world["env"], world["extractor"], world["net"], world["mcts"],
+            world["train"], seed=7,
+        )
+        assert not engine.device_stats
+        trainer, buffer = world["trainer"], world["buffer"]
+        _, payload = world["engine"].play_moves_device(4)
+        texts = {
+            "chunk": engine._chunk_fn(2)._jit_fn.lower(
+                engine.net.variables, engine._carry, jnp.int32(0)
+            ),
+            "group": trainer._from_fn._jit_fn.lower(
+                trainer.state,
+                buffer.storage,
+                np.zeros((2, 4), np.int32),
+                np.ones((2, 4), np.float32),
+            ),
+            "ingest": buffer._ingest_jit.lower(
+                buffer.storage, jnp.int32(0), (payload["mat"], payload["flush"])
+            ),
+        }
+        digests = {
+            name: hashlib.sha256(lowered.as_text().encode()).hexdigest()[:16]
+            for name, lowered in texts.items()
+        }
+        assert digests == {
+            "chunk": "1ea86bcbac2ad792",
+            "group": "b87c1a12373f0a77",
+            "ingest": "3bff02d6c77e22d5",
+        }
+
     def test_every_phase_is_held_by_some_test_above(self):
         covered = _phases(
             "rollout/", "search/", "gumbel/", "net/", "learner/", "replay/"
@@ -659,6 +711,66 @@ class TestPhaseSeconds:
         assert names["%copy.1"] == names["%copy.4"] == names["%fusion.3"]
         assert names.get("%x") != "x"
         assert profiling.phase_of(names["%copy.1"]) == "learner/gather"
+
+
+class TestIdleGapSeconds:
+    """`profiling.idle_gap_seconds`: a program's idle gaps on `XLA
+    Modules` put down to the `at:` span that covered them."""
+
+    MS = 1_000_000
+
+    def test_gaps_go_to_the_innermost_covering_span(self):
+        ms = self.MS
+        modules = [
+            ("jit_chunk(1)", 0, 100 * ms),
+            ("jit_ingest(2)", 104 * ms, 3 * ms),  # inside the chunk's gap
+            ("jit_chunk(1)", 114 * ms, 100 * ms),
+            ("jit_chunk(1)", 220 * ms, 100 * ms),
+        ]
+        host = [
+            ("rollout", 90 * ms, 13 * ms),  # a phase around the three below
+            ("rollout.wait", 91 * ms, 10 * ms),  # 1 ms of it in the gap
+            ("rollout.fold", 101 * ms, 2 * ms),
+            ("replay.ingest_wait", 104 * ms, 4 * ms),
+            ("rollout.dispatch", 111 * ms, 5 * ms),  # 3 ms in the gap
+            ("rollout.fold", 214 * ms, 4 * ms),
+        ]
+        gaps = profiling.idle_gap_seconds(modules, host)
+        assert list(gaps) == ["jit_chunk"]  # one run of the ingest: no gap
+        assert gaps["jit_chunk"] == pytest.approx(
+            {
+                "rollout.fold": 0.006,
+                "replay.ingest_wait": 0.004,
+                "rollout.dispatch": 0.003,
+                "rollout.wait": 0.001,
+                # 103-104 and 108-111 of the first gap, 2 of the second.
+                "other": 0.006,
+            }
+        )
+        # Busiest first, `other` last; the gaps are all accounted for.
+        assert list(gaps["jit_chunk"]) == [
+            "rollout.fold", "replay.ingest_wait", "rollout.dispatch",
+            "rollout.wait", "other",
+        ]
+        assert sum(gaps["jit_chunk"].values()) == pytest.approx(0.020)
+
+    def test_no_span_no_gap(self):
+        ms = self.MS
+        two = [("jit_f(1)", 0, ms), ("jit_f(1)", 3 * ms, ms)]
+        assert profiling.idle_gap_seconds(two, []) == {
+            "jit_f": {"other": 0.002}
+        }
+        # The program that ran longest comes first.
+        small = [("jit_g(2)", 1 * ms, 100), ("jit_g(2)", 2 * ms, 100)]
+        assert list(profiling.idle_gap_seconds(small + two, [])) == [
+            "jit_f", "jit_g"
+        ]
+        assert profiling.idle_gap_seconds(two[:1], []) == {}
+        # Runs that touch or overlap leave nothing to put down.
+        touching = [("jit_f(1)", 0, 2 * ms), ("jit_f(1)", 2 * ms, ms)]
+        assert profiling.idle_gap_seconds(touching, []) == {
+            "jit_f": {"other": 0.0}
+        }
 
 
 class TestProgramOpNames:

@@ -103,16 +103,27 @@ class TestSpanTracer:
         assert tr.recorded == 0
         assert tr.export(tmp_path / "t.json") == 0
 
-    def test_summary_and_file_summary_agree(self, tmp_path):
+    def test_file_summary_counts_and_takes_children_off_self_time(
+        self, tmp_path
+    ):
         tr = SpanTracer()
         for _ in range(3):
             with tr.span("rollout"):
-                pass
-        s = tr.summary()
-        assert s["rollout"]["count"] == 3
+                with tr.span("rollout.wait"):
+                    time.sleep(0.002)
         tr.export(tmp_path / "t.json")
-        rows = summarize_trace_file(tmp_path / "t.json")
-        assert rows[0]["name"] == "rollout" and rows[0]["count"] == 3
+        rows = {r["name"]: r for r in summarize_trace_file(tmp_path / "t.json")}
+        assert rows["rollout"]["count"] == rows["rollout.wait"]["count"] == 3
+        wait = rows["rollout.wait"]
+        assert wait["self_ms"] == wait["total_ms"] >= 6.0
+        assert rows["rollout"]["self_ms"] == pytest.approx(
+            rows["rollout"]["total_ms"] - wait["total_ms"]
+        )
+        # A trace without ids (another tool's) is all self time.
+        bare = [{"name": "a", "ph": "X", "ts": 0, "dur": 1500, "tid": 1}]
+        (tmp_path / "bare.json").write_text(json.dumps(bare))
+        (row,) = summarize_trace_file(tmp_path / "bare.json")
+        assert row["self_ms"] == row["total_ms"] == 1.5
 
 
 class TestHealthMonitor:
