@@ -34,7 +34,19 @@ stages ~8.5 MB of batches.
   SURVEY.md §7 "PER on host vs device") but returns only slot
   *indices* and IS weights; the trainer gathers the actual rows on
   device (`Trainer.train_steps_from`), so a fused K-step group uploads
-  K*B int32 indices (~16 KB) instead of K batches (~8.5 MB).
+  K*B int32 indices (~16 KB) instead of K batches (~8.5 MB). Every
+  program that trains from a device ring gathers through `read_rows`
+  (the fused group, the dp-sharded ring's local gather, the megastep):
+  `ring[idx]` bit for bit, read from the ring as it lies. A 2-D array a
+  whole number of the chip's 8 sublanes wide (`policy_target` at 360
+  actions) is gathered through its view `(rows, width // 8, 8)`, the
+  same bytes in the chip's tiles of 8 features x 128 rows, because a
+  gather of whole rows that wide makes XLA copy the whole array to
+  row-major first (4.3 GB and 13.6 ms a group at 3,000,000 rows); every
+  other array is read by `ring[idx]`, which lowers in place for the
+  narrow ones (`grid`, `other_features`, the 1-D arrays) and still
+  copies a wide one of another width (756). `ring_read` says which
+  arrays went which way, on the dispatch spans.
 - **Priorities** update from the TD errors the trainer already fetches
   (K*B float32 — small), identical to the host path.
 - **Persistence** round-trips through the same snapshot dict as the
@@ -172,6 +184,47 @@ def write_windows(
         (storage, jnp.int32(0), jnp.int32(0)),
     )
     return storage, windows
+
+
+# The chip keeps a 2-D ring array as tiles of 8 features (the sublanes)
+# x 128 rows (the lanes): one a whole number of these wide is read
+# through the view `(rows, width // 8, 8)`, the same bytes (the module's
+# docstring, "Sampling"; PERF.md section 6, PR 37).
+_SUBLANES = 8
+
+
+def _viewed(ring) -> bool:
+    """Whether `read_rows` reads this ring array through its view of
+    sublane tiles. A function of its shape alone."""
+    return ring.ndim == 2 and ring.shape[1] % _SUBLANES == 0
+
+
+def ring_read(storage: dict[str, Any]) -> dict[str, list[str]]:
+    """How `read_rows` reads each ring array: `in_place` through the
+    view of sublane tiles, `as_is` by `ring[idx]`. What the dispatch
+    spans report (`learner.dispatch`, `megastep.dispatch`), by the rule
+    the program is built on."""
+    how: dict[str, list[str]] = {"in_place": [], "as_is": []}
+    for name, ring in storage.items():
+        how["in_place" if _viewed(ring) else "as_is"].append(name)
+    return how
+
+
+def read_rows(
+    storage: dict[str, jax.Array], idx: jax.Array
+) -> dict[str, jax.Array]:
+    """The rows `idx` (any shape) of every ring array, `ring[idx]` bit
+    for bit, read from the ring as the chip keeps it: the write's twin
+    (`write_windows`). The one gather of batch rows for every program
+    that trains from a device ring."""
+    rows = {}
+    for name, ring in storage.items():
+        if _viewed(ring):
+            tiles = ring.reshape(ring.shape[0], -1, _SUBLANES)[idx]
+            rows[name] = tiles.reshape(*idx.shape, ring.shape[1])
+        else:
+            rows[name] = ring[idx]
+    return rows
 
 
 @jax.named_scope("replay/ingest_scatter")

@@ -113,7 +113,8 @@ from ..telemetry.device_stats import (
     rollout_chunk_stats,
 )
 from ..telemetry.flight import flight_span
-from .device_buffer import DeviceReplayBuffer, ring_scatter
+from ..telemetry.tracer import default_tracer
+from .device_buffer import DeviceReplayBuffer, read_rows, ring_read, ring_scatter
 
 logger = logging.getLogger(__name__)
 
@@ -511,7 +512,7 @@ class MegastepRunner:
                 w = w / wmax
             w = w.astype(jnp.float32)
             # Local row gather: each device reads only its own shard.
-            rows = {name: v[idx_local] for name, v in new_storage.items()}
+            rows = read_rows(new_storage, idx_local)
             idx_global = (shard * buf.stride + idx_local).astype(jnp.int32)
             return (
                 new_storage,
@@ -717,13 +718,19 @@ class MegastepRunner:
             avals=f"B{self.batch_size}xT{t}xK{k}",
         ):
             note_dispatch(self._name_fn(t, k))
-            (
-                trainer.state,
-                engine._carry,
-                buf.storage,
-                self._priorities,
-                out,
-            ) = self._megastep_fn(t, k)(*args)
+            with default_tracer().span(
+                "megastep.dispatch",
+                t=t,
+                k=k,
+                ring_read=ring_read(buf.storage),
+            ):
+                (
+                    trainer.state,
+                    engine._carry,
+                    buf.storage,
+                    self._priorities,
+                    out,
+                ) = self._megastep_fn(t, k)(*args)
             self.dispatch_count += 1
             t0 = time.perf_counter()
             host = jax.device_get(out)  # graftlint: allow(host-sync-in-hot-path) the one transfer per megastep

@@ -62,6 +62,7 @@ from ..parallel.sharding import (
     state_shardings,
 )
 from ..utils.types import DenseBatch
+from .device_buffer import read_rows, ring_read
 
 logger = logging.getLogger(__name__)
 
@@ -690,7 +691,7 @@ class Trainer:
             def gather_local(storage_local, idx_local):
                 base = jax.lax.axis_index(dp_axis) * stride
                 local = idx_local - base  # global encoding -> local slot
-                return {k: v[local] for k, v in storage_local.items()}
+                return read_rows(storage_local, local)
 
             gather = jax.shard_map(
                 gather_local,
@@ -727,7 +728,7 @@ class Trainer:
         matching (K, B) IS weights. Bit-identical to `_train_steps_impl`
         on the same rows."""
         with jax.named_scope("learner/gather"):
-            rows = {name: v[idx] for name, v in storage.items()}
+            rows = read_rows(storage, idx)
         return self._train_steps_impl(
             state, self._stacked_rows_batch(rows, weights)
         )
@@ -937,7 +938,11 @@ class Trainer:
         """
         if not samples:
             return None
-        with default_tracer().span("learner.dispatch", k=len(samples)):
+        with default_tracer().span(
+            "learner.dispatch",
+            k=len(samples),
+            ring_read=ring_read(buffer.storage),
+        ):
             idx = np.stack(
                 [np.asarray(s["indices"], dtype=np.int32) for s in samples]
             )

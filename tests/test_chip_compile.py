@@ -295,3 +295,75 @@ def test_the_ingest_writes_the_ring_where_it_lies(one_v5e_chip, name):
     memory = compiled.memory_analysis()
     assert memory.alias_size_in_bytes >= ring_bytes
     assert memory.temp_size_in_bytes < ring_bytes // 8
+
+
+# ring rows, (grid, other features, actions), the indices' shape
+READ_SHAPES = {
+    # flagship-learner: a fused group of 16 steps at batch 256
+    "flagship-group": (3_000_000, ((1, 8, 15), 30, 360), (16, 256)),
+    # one flagship step (`cli train` at FUSED_LEARNER_STEPS 1)
+    "flagship-step": (3_000_000, ((1, 8, 15), 30, 360), (1, 256)),
+    # glm-flash-learner's step, and the rollout trunk cells' ring
+    "trunk-cells-step": (250_000, ((1, 12, 21), 30, 756), (1, 256)),
+    # the dp-sharded ring's local gather: a quarter of a 12,000,000-row
+    # ring and of the batch on each of four chips
+    "dp4-shard-group": (3_000_000, ((1, 8, 15), 30, 360), (16, 64)),
+}
+
+
+@pytest.mark.parametrize("name", sorted(READ_SHAPES))
+def test_the_learner_reads_the_ring_where_it_lies(one_v5e_chip, name):
+    """The chip lays the ring's rows along its lanes, and a gather of
+    whole rows 360 wide made XLA re-lay-out `policy_target` whole at
+    the head of every learner group (13.6 ms and 4.3 GB of temporaries
+    at the flagship's ring). Held here: for every array `ring_read`
+    says is read in place, the compiled `read_rows` makes no whole-ring
+    array but the parameter and its bitcast, and holds no temporaries
+    to speak of. An array the rule leaves as it is may be copied: the
+    trunk cells' `policy_target`, 756 wide, is (2.35 ms of a 2,120 ms
+    step: PERF.md section 7), and the case says so."""
+    import re
+
+    import jax.numpy as jnp
+
+    from alphatriangle_tpu.rl.device_buffer import read_rows, ring_read
+
+    cap, (grid, other, actions), lead = READ_SHAPES[name]
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_v5e_chip)
+
+    storage = {
+        "grid": shape((cap + 1, *grid), jnp.int8),
+        "other_features": shape((cap + 1, other), jnp.float32),
+        "policy_target": shape((cap + 1, actions), jnp.float32),
+        "value_target": shape((cap + 1,), jnp.float32),
+        "policy_weight": shape((cap + 1,), jnp.float32),
+    }
+    how = ring_read(storage)
+    assert sorted(how["in_place"] + how["as_is"]) == sorted(storage)
+    assert how["in_place"] == (["policy_target"] if actions % 8 == 0 else [])
+    compiled = jax.jit(read_rows).lower(storage, shape(lead, jnp.int32)).compile()
+    text = compiled.as_text()
+
+    def made(ring):
+        """The operations whose result is `ring` whole, under its own
+        shape or the view's."""
+        rows, width = ring.shape
+        whole = re.compile(
+            rf"^\s*(?:ROOT )?%\S+ = \w+\[{rows},(?:{width}|{width // 8},8)\]\S* "
+            r"(\w[\w-]*)\("
+        )
+        return {m.group(1) for m in map(whole.match, text.splitlines()) if m}
+
+    for array in how["in_place"]:
+        assert made(storage[array]) == {"parameter", "bitcast"}, array
+    ring_bytes = (cap + 1) * (
+        grid[0] * grid[1] * grid[2] + 4 * (other + actions + 2)
+    )
+    temporaries = compiled.memory_analysis().temp_size_in_bytes
+    if how["in_place"]:
+        assert temporaries < ring_bytes // 8
+    else:
+        assert "copy" in made(storage["policy_target"])
+        assert temporaries >= 4 * (cap + 1) * actions

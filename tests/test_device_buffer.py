@@ -617,6 +617,105 @@ class TestIngestProgram:
         assert text() == fresh
 
 
+# a ring array's trailing dims and dtype: the widths the cells' rings
+# have (360 and 30 at the flagship, 756 at the trunk cells), one tile of
+# sublanes, the ranks and both dtypes
+READ_ARRAYS = {
+    "f32-360": ((360,), np.float32),
+    "f32-756": ((756,), np.float32),
+    "f32-30": ((30,), np.float32),
+    "f32-8": ((8,), np.float32),
+    "s8-360": ((360,), np.int8),
+    "f32-rank1": ((), np.float32),
+    "s8-rank4": ((1, 8, 15), np.int8),
+}
+READ_IN_PLACE = {"f32-360", "f32-8", "s8-360"}
+
+
+class TestReadRows:
+    @pytest.mark.parametrize("idx_rank", (1, 2))
+    @pytest.mark.parametrize("name", sorted(READ_ARRAYS))
+    def test_rows_are_the_plain_gathers_bit_for_bit(self, name, idx_rank):
+        """`read_rows` against `ring[idx]`, with a repeated index and
+        the row past the ring (`cap`); the shape helper names the
+        arrays the function reads through the view of sublane tiles,
+        and no others."""
+        import jax
+
+        from alphatriangle_tpu.rl.device_buffer import read_rows, ring_read
+
+        cap = 37
+        rng = np.random.default_rng(sorted(READ_ARRAYS).index(name))
+        trailing, dtype = READ_ARRAYS[name]
+        ring = (rng.normal(size=(cap + 1, *trailing)) * 50).astype(dtype)
+        idx = rng.integers(0, cap + 1, size=(3, 5) if idx_rank == 2 else (15,))
+        idx.flat[:3] = cap, 4, 4
+        idx = idx.astype(np.int32)
+        storage = {name: ring}
+        got = jax.jit(read_rows)(storage, idx)[name]
+        assert got.dtype == ring.dtype and got.shape == idx.shape + trailing
+        np.testing.assert_array_equal(np.asarray(got), ring[idx])
+        in_place = name in READ_IN_PLACE
+        assert ring_read(storage) == {
+            "in_place": [name] if in_place else [],
+            "as_is": [] if in_place else [name],
+        }
+        reshapes = [
+            eqn.params["new_sizes"]
+            for eqn in jax.make_jaxpr(read_rows)(storage, idx).eqns
+            if eqn.primitive.name == "reshape"
+            and eqn.invars[0].aval.shape == ring.shape
+        ]
+        assert reshapes == ([(cap + 1, ring.shape[1] // 8, 8)] if in_place else [])
+
+    def test_every_array_of_a_ring_in_its_place(self, tiny_train_config):
+        """A buffer's own storage at the flagship's widths: one call
+        reads all five arrays, and only `policy_target` is viewed."""
+        from alphatriangle_tpu.rl.device_buffer import read_rows, ring_read
+
+        dev = DeviceReplayBuffer(
+            _cfg(tiny_train_config, BUFFER_CAPACITY=16),
+            grid_shape=(1, 8, 15), other_dim=30, action_dim=360,
+        )
+        rng = np.random.default_rng(3)
+        storage = {
+            k: rng.integers(-1, 2, size=v.shape).astype(v.dtype)
+            for k, v in dev.storage.items()
+        }
+        assert ring_read(dev.storage) == ring_read(storage) == {
+            "in_place": ["policy_target"],
+            "as_is": ["grid", "other_features", "value_target", "policy_weight"],
+        }
+        idx = np.array([[16, 0, 3], [3, 15, 16]], np.int32)
+        rows = read_rows(storage, idx)
+        assert list(rows) == list(storage)
+        for k, v in storage.items():
+            np.testing.assert_array_equal(np.asarray(rows[k]), v[idx], err_msg=k)
+
+
+    def test_a_ring_with_nothing_to_view_is_gathered_as_before(
+        self, tiny_train_config
+    ):
+        """At the trunk cells' widths (756 actions, 30 features, a
+        12 x 21 grid) the rule views no array, and `read_rows` traces to
+        the plain gathers' own program: `glm-flash-learner`'s step is
+        the one it was."""
+        import jax
+
+        from alphatriangle_tpu.rl.device_buffer import read_rows, ring_read
+
+        dev = DeviceReplayBuffer(
+            _cfg(tiny_train_config, BUFFER_CAPACITY=16),
+            grid_shape=(1, 12, 21), other_dim=30, action_dim=756,
+        )
+        assert ring_read(dev.storage)["in_place"] == []
+        idx = np.zeros((1, 4), np.int32)
+        plain = jax.make_jaxpr(
+            lambda storage, idx: {k: v[idx] for k, v in storage.items()}
+        )(dev.storage, idx)
+        assert str(jax.make_jaxpr(read_rows)(dev.storage, idx)) == str(plain)
+
+
 class TestTrainEquivalence:
     def test_train_steps_from_matches_host_path(
         self, tiny_env_config, tiny_model_config, tiny_train_config

@@ -377,6 +377,36 @@ class TestMegastepLoop:
         lrs = [m["learning_rate"] for m, _td in outs]
         assert lrs == pytest.approx(want, rel=1e-6)
 
+    def test_the_dispatch_span_says_how_the_ring_is_read(
+        self, tiny_world_configs
+    ):
+        """`megastep.dispatch` around the one jitted call: the chunk's
+        moves, the group's steps, and `ring_read`, by the rule the
+        program's own `read_rows` goes by; the ring it names is the one
+        that went in (the call donates it)."""
+        from alphatriangle_tpu.rl.device_buffer import ring_read
+        from alphatriangle_tpu.telemetry import (
+            SpanTracer,
+            default_tracer,
+            set_default_tracer,
+        )
+
+        tc = make_cfg("span_probe", ROLLOUT_CHUNK_MOVES=2)
+        runner = direct_runner(tiny_world_configs, tc)
+        fill_ring(runner.buffer, 64)
+        want = ring_read(runner.buffer.storage)
+        before = default_tracer()
+        tracer = set_default_tracer(SpanTracer())
+        try:
+            runner.run_megastep(2, 2)
+        finally:
+            set_default_tracer(before)
+        spans = [r for r in tracer.records() if r[1] == "megastep.dispatch"]
+        assert [r[6] for r in spans] == [{"t": 2, "k": 2, "ring_read": want}]
+        assert sorted(want["in_place"] + want["as_is"]) == sorted(
+            runner.buffer.storage
+        )
+
     @pytest.mark.slow
     def test_run_training_and_resume(self, tmp_path, tiny_world_configs):
         """Checkpoint + resume work in megastep mode (run, 'kill',

@@ -17,6 +17,7 @@ import numpy as np
 import pytest
 
 from alphatriangle_tpu import profiling
+from alphatriangle_tpu.rl.device_buffer import ring_read
 from alphatriangle_tpu.telemetry import (
     RunTelemetry,
     SpanTracer,
@@ -335,8 +336,11 @@ class TestProgramSpans:
         train_id = records[-1][7]
         assert {r[8] for r in records[:-1]} == {train_id}
         args = {r[1]: r[6] for r in records}
-        for name in ("learner.dispatch", "learner.wait", "learner.results"):
+        for name in ("learner.wait", "learner.results"):
             assert args[name] == {"k": 2}
+        assert args["learner.dispatch"] == {
+            "k": 2, "ring_read": ring_read(buffer.storage)
+        }
         # The group's program is traced inside its first dispatch, and
         # the net says there which path its encoder layers took: a
         # learner's keep Flax's modules, on any backend.
@@ -348,6 +352,63 @@ class TestProgramSpans:
                 {"fused_layers": 0, "flax_layers": layers, "batch": 4, "seq": 12},
             )
         ]
+
+    def test_the_dispatch_says_how_the_ring_is_read(
+        self, tracer, monkeypatch, tiny_env_config, tiny_model_config,
+        tiny_train_config,
+    ):
+        """`ring_read` on `learner.dispatch`: the arrays the group's
+        program reads through the view of sublane tiles and those it
+        reads as they are, by the shape rule `read_rows` itself goes by,
+        on the dispatch that traces the program and on the next one,
+        which only calls it. Here a ring 16 actions wide beside the tiny
+        world's 12."""
+        from alphatriangle_tpu.nn.network import NeuralNetwork
+        from alphatriangle_tpu.rl import trainer as trainer_module
+        from alphatriangle_tpu.rl.device_buffer import (
+            DeviceReplayBuffer,
+            read_rows,
+        )
+        from alphatriangle_tpu.rl.trainer import Trainer
+
+        env = tiny_env_config.model_copy(
+            update={"ROWS": 4, "PLAYABLE_RANGE_PER_ROW": [(0, 4)] * 4}
+        )
+        assert env.action_dim == 16
+        train = tiny_train_config.model_copy(
+            update={"BUFFER_CAPACITY": 32, "MIN_BUFFER_SIZE_TO_TRAIN": 8}
+        )
+        trainer = Trainer(NeuralNetwork(tiny_model_config, env, seed=2), train)
+        buffer = DeviceReplayBuffer(
+            train,
+            grid_shape=(tiny_model_config.GRID_INPUT_CHANNELS, 4, 4),
+            other_dim=tiny_model_config.OTHER_NN_INPUT_FEATURES_DIM,
+            action_dim=16,
+            seed=0,
+        )
+        rng = np.random.default_rng(1)
+        policy = rng.random((12, 16), dtype=np.float32) + 0.01
+        buffer.add_dense(
+            rng.integers(-1, 2, size=(12, *buffer.storage["grid"].shape[1:])),
+            rng.random((12, buffer.storage["other_features"].shape[1])),
+            policy / policy.sum(axis=1, keepdims=True),
+            rng.normal(size=12),
+        )
+        want = ring_read(buffer.storage)
+        assert want["in_place"] == ["policy_target"]
+        traces = []  # `read_rows` runs when the program is traced
+
+        def counted(storage, idx):
+            traces.append(ring_read(storage))
+            return read_rows(storage, idx)
+
+        monkeypatch.setattr(trainer_module, "read_rows", counted)
+        for _ in range(2):  # traced, then only called
+            trainer.train_steps_from(buffer, [buffer.sample(4, 0)])
+        assert traces == [want]
+        assert [r[6] for r in tracer.records() if r[1] == "learner.dispatch"] == [
+            {"k": 1, "ring_read": want}
+        ] * 2
 
     def test_host_batches_group_has_the_same_three(self, world, tracer):
         trainer = world["trainer"]

@@ -747,16 +747,19 @@ class TestRoutedTrunk:
         assert trainer.last_counters["trunk_tokens"] == 2 * tokens * 2
 
 
-def test_the_flagships_learner_programs_are_the_parents_text():
+def test_the_flagships_learner_programs_are_the_parents_text(monkeypatch):
     """A net without a decoder stack takes no block, counts no load and
     moves no bias: its per-step and its fused-from-ring programs lower
-    to the text they had on the parent commit (7fee68d, where both
-    digests were taken with this test's code), at `flagship-p3`'s
-    widths, batch 8, a ring of 512 rows."""
+    to the text they had on commit 7fee68d (where both digests were
+    taken with this test's code), at `flagship-p3`'s widths, batch 8, a
+    ring of 512 rows. Since PR 37 the from-ring program gathers through
+    `read_rows`; with the plain gather in its place the text is still
+    that commit's, so the read is all that changed."""
     import hashlib
 
     from chipbench import manifest
 
+    from alphatriangle_tpu.rl import trainer as trainer_module
     from alphatriangle_tpu.rl.device_buffer import DeviceReplayBuffer
 
     cfg = manifest.load_json(manifest.HERE / "configs" / "flagship-p3.json")
@@ -772,18 +775,31 @@ def test_the_flagships_learner_programs_are_the_parents_text():
         train, (model.GRID_INPUT_CHANNELS, env.ROWS, env.COLS),
         model.OTHER_NN_INPUT_FEATURES_DIM, env.action_dim, seed=0,
     )
-    texts = [
-        jax.jit(trainer._train_steps_from_impl)
-        .lower(
-            trainer.state, buffer.storage,
-            np.zeros((2, 8), np.int32), np.ones((2, 8), np.float32),
+
+    def from_ring():
+        return (
+            jax.jit(trainer._train_steps_from_impl)
+            .lower(
+                trainer.state, buffer.storage,
+                np.zeros((2, 8), np.int32), np.ones((2, 8), np.float32),
+            )
+            .as_text()
         )
-        .as_text(),
+
+    texts = [
+        from_ring(),
         jax.jit(trainer._train_step_impl)
         .lower(trainer.state, trainer._zero_batch(8))
         .as_text(),
     ]
+    monkeypatch.setattr(
+        trainer_module,
+        "read_rows",
+        lambda storage, idx: {name: v[idx] for name, v in storage.items()},
+    )
+    texts.append(from_ring())
     assert [hashlib.sha256(t.encode()).hexdigest() for t in texts] == [
-        "2cff0f97b4f77481535d8c8bc012071663883d8596fbc8a1ba81f60d250f20ff",
+        "61e1be3729f32876b32d073dca766922a48141b637f7046d905ec65ac3f417de",
         "64c7e94ee9a7bffcad4947b646a58f9270b88b83c770c754190a50584d0744b1",
+        "2cff0f97b4f77481535d8c8bc012071663883d8596fbc8a1ba81f60d250f20ff",
     ]
