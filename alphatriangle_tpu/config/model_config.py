@@ -16,20 +16,36 @@ from pydantic import BaseModel, Field, model_validator
 
 class TrunkConfig(BaseModel):
     """A decoder stack in the encoder's place (nn/trunk.py): RMSNorm,
-    SwiGLU, routed experts with a shared expert, and by layer one of
-    four mixers: grouped-query softmax attention under a causal window
+    routed experts with a shared expert, and by layer one of five
+    mixers: grouped-query softmax attention under a causal window
     (`sliding_attention`) or a causal full mask (`full_attention`), the
     gated delta rule with a decay for every channel (`linear_attention`,
     KDA: short causal convolutions, a recurrent state of head_dim x
-    head_dim a head, no score matrix) and latent attention
+    head_dim a head, no score matrix), latent attention
     (`latent_attention`, MLA: keys and values expanded from a latent of
     `kv_lora_rank`, a rotary part all heads share; with `q_lora_rank`
     the query too comes from a latent, under an RMSNorm; `latent_gate`
-    False leaves the head-wise output gate out). The keys are a
-    published `config.json`'s, under its names; layer l is
-    `layer_types[l]` with `mlp_layer_types[l]`. `head_dim` is the
-    softmax and linear layers' width of a head: a stack whose mixers
-    are all latent has none and reads none.
+    False leaves the head-wise output gate out) and a selective state
+    space (`state_space`, Mamba-2, nn/state_space.py: `mamba_num_heads`
+    heads of `mamba_head_dim` with a state of `mamba_head_dim` x
+    `ssm_state_size` each and one decay a head, B and C shared by
+    `n_groups` groups of heads, one causal convolution of `conv_kernel`
+    taps over x, B and C together, a gated RMSNorm over each group's
+    channels, the recurrence `chunk_size` tokens at a time). The keys
+    are a published `config.json`'s, under its names; layer l is
+    `layer_types[l]` with `mlp_layer_types[l]`, and "none" in either
+    list says that layer l has no such half: it is then the other half
+    alone, under one norm and one residual. `head_dim` is the softmax
+    and linear layers' width of a head: a stack that has neither has
+    none and reads none.
+
+    `mlp_hidden_act` "silu" makes every MLP a SwiGLU (gate, up, down);
+    "relu2" an ungated one, down(relu(up x)^2), in the experts and the
+    shared expert. With `moe_latent_size` the routed experts live in a
+    latent of that width: one projection down before the tokens are
+    sorted, one up after the held experts' sum. The shared expert is
+    `moe_shared_expert_intermediate_size` wide where that is given and
+    as wide as a routed expert otherwise, and reads the hidden size.
 
     `experts_held` = (first, count): the router scores all
     `num_experts`; this process computes the experts it holds for the
@@ -40,11 +56,12 @@ class TrunkConfig(BaseModel):
     two highest, the `topk_group` best groups stay in the choice.
 
     `norm_position`, `qk_norm` and `rope_layers` are what such a file
-    leaves to the family's convention, each a choice of two:
+    leaves to the family's convention:
     "post" is x + norm(f(x)), "pre" x + f(norm(x)); `qk_norm` True an
     RMSNorm on q and k per head of the softmax layers, "l2" the L2 norm
     on q and k per head of the linear layers (and none on a latent
-    layer beyond its latent's); `rope_layers` "sliding" the whole head
+    layer beyond its latent's), "none" no norm on q or k of a full
+    attention layer; `rope_layers` "sliding" the whole head
     turned, halves paired, on the sliding layers only, "latent" the
     `qk_rope_head_dim` part of a latent layer turned, neighbours paired
     (interleaved), and no positions anywhere else. A layer kind whose
@@ -66,10 +83,10 @@ class TrunkConfig(BaseModel):
     layer_types: list[
         Literal[
             "sliding_attention", "full_attention",
-            "linear_attention", "latent_attention",
+            "linear_attention", "latent_attention", "state_space", "none",
         ]
     ]
-    mlp_layer_types: list[Literal["dense", "sparse"]]
+    mlp_layer_types: list[Literal["dense", "sparse", "none"]]
     rope_theta: float = Field(default=1e6, gt=0)
     rms_norm_eps: float = Field(default=1e-5, gt=0)
     experts_held: tuple[int, int]
@@ -87,9 +104,26 @@ class TrunkConfig(BaseModel):
     # whether the context is gated a head by sigmoid(x Wg) before Wo.
     q_lora_rank: int | None = Field(default=None, gt=0)
     latent_gate: bool = Field(default=True)
+    # A state-space layer (Mamba-2): heads and their width, the state's
+    # width a head, the groups that share B and C, the convolution's
+    # taps and whether it has a bias, the tokens the recurrence takes
+    # at a time (its chunked form, nn/state_space.py).
+    mamba_num_heads: int | None = Field(default=None, gt=0)
+    mamba_head_dim: int | None = Field(default=None, gt=0)
+    ssm_state_size: int | None = Field(default=None, gt=0)
+    n_groups: int = Field(default=1, gt=0)
+    conv_kernel: int = Field(default=4, gt=0)
+    use_conv_bias: bool = Field(default=True)
+    chunk_size: int = Field(default=128, gt=0)
+    # The experts' latent (None: they read and write the hidden size),
+    # the shared expert's own width (None: a routed expert's), and the
+    # MLPs' activation.
+    moe_latent_size: int | None = Field(default=None, gt=0)
+    moe_shared_expert_intermediate_size: int | None = Field(default=None, gt=0)
+    mlp_hidden_act: Literal["silu", "relu2"] = Field(default="silu")
 
     norm_position: Literal["post", "pre"] = Field(default="post")
-    qk_norm: Literal[True, "l2"] = Field(default=True)
+    qk_norm: Literal[True, "l2", "none"] = Field(default=True)
     rope_layers: Literal["sliding", "latent"] = Field(default="sliding")
     # A per-expert float32 parameter added to the scores for the choice
     # alone (the weights stay the raw scores'): how a router balanced by
@@ -125,10 +159,10 @@ class TrunkConfig(BaseModel):
                 f"multiple of num_key_value_heads ({self.num_key_value_heads})."
             )
         if self.head_dim is None:
-            if set(self.layer_types) - {"latent_attention"}:
+            if set(self.layer_types) - {"latent_attention", "state_space", "none"}:
                 raise ValueError(
-                    "head_dim may be left out only where every mixer is "
-                    "latent_attention."
+                    "head_dim may be left out only where no mixer is softmax "
+                    "or linear attention."
                 )
         elif self.head_dim % 2:
             raise ValueError("head_dim must be even (rotary pairs).")
@@ -154,8 +188,18 @@ class TrunkConfig(BaseModel):
                 "a group holds fewer than 2 experts, or the groups that stay "
                 "fewer than num_experts_per_tok."
             )
+        halves = list(zip(self.layer_types, self.mlp_layer_types))
+        if ("none", "none") in halves:
+            raise ValueError(
+                f"layer {halves.index(('none', 'none'))} has neither a mixer "
+                "nor an MLP."
+            )
         kinds = set(self.layer_types)
         softmax = kinds & {"sliding_attention", "full_attention"}
+        if self.qk_norm == "none":
+            # Written for the full mask alone; a linear layer's "l2"
+            # below refuses the rest.
+            softmax -= {"full_attention"}
         if softmax and self.qk_norm is not True:
             raise ValueError(f"{sorted(softmax)} layers are written for qk_norm True.")
         if "sliding_attention" in kinds and (
@@ -180,6 +224,19 @@ class TrunkConfig(BaseModel):
                 )
             if self.qk_rope_head_dim % 2:
                 raise ValueError("qk_rope_head_dim must be even (rotary pairs).")
+        if "state_space" in kinds:
+            if None in (self.mamba_num_heads, self.mamba_head_dim, self.ssm_state_size):
+                raise ValueError(
+                    "state_space layers need mamba_num_heads, mamba_head_dim "
+                    "and ssm_state_size."
+                )
+            if self.mamba_num_heads % self.n_groups:
+                raise ValueError(
+                    f"{self.mamba_num_heads} state-space heads do not stand in "
+                    f"{self.n_groups} groups."
+                )
+        if self.mlp_hidden_act == "relu2" and "dense" in self.mlp_layer_types:
+            raise ValueError("the dense MLP is written for mlp_hidden_act silu.")
         return self
 
 

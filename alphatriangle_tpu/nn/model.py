@@ -304,9 +304,23 @@ class AlphaTriangleNet(nn.Module):
                     )
                 default_tracer().instant(
                     "net.trunk",
-                    **{kind: kinds.count(kind) for kind in sorted(set(kinds))},
+                    # the layers by mixer; one that has none is not one
+                    **{
+                        kind: kinds.count(kind)
+                        for kind in sorted(set(kinds) - {"none"})
+                    },
                     linear_path=taken,
                     linear_chunk=cfg.TRUNK.linear_chunk,
+                    # tokens a state-space layer's scan takes at a time
+                    # (a stack with such layers only), the experts' latent
+                    # (None: the hidden size) and which MLP they are
+                    **(
+                        {"ssm_chunk": cfg.TRUNK.chunk_size}
+                        if "state_space" in kinds
+                        else {}
+                    ),
+                    moe_latent_size=cfg.TRUNK.moe_latent_size,
+                    mlp_hidden_act=cfg.TRUNK.mlp_hidden_act,
                     block_boards=cfg.TRUNK.block_boards,
                     # latent layers whose query comes from its own latent
                     latent_q_compressed=(

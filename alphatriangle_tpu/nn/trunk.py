@@ -2,12 +2,14 @@
 
 The layers of a routed-expert language model stand where the encoder
 layers stand: tokens are the board's cells in row-major order, the
-cell's index is its position. A layer is a mixer and an MLP. Per layer
-(l = 0..), the mixer `layer_types[l]` names:
+cell's index is its position. A layer is a mixer and an MLP, or where
+either list says "none" the other half alone, under one norm and one
+residual. Per layer (l = 0..), the mixer `layer_types[l]` names:
 
 - softmax attention (`sliding_attention`, `full_attention`): q, k, v
-  without biases, an RMSNorm on q and k per head,
-  rotary positions over the whole head on the sliding layers, every
+  without biases, an RMSNorm on q and k per head (none on a full
+  layer with `qk_norm` "none"), rotary positions over the whole head
+  on the sliding layers, every
   `num_attention_heads / num_key_value_heads` query heads sharing one
   key/value head, scores in float32 over sqrt(head_dim), masked to
   j <= i and on a sliding layer to i - j < `sliding_window`;
@@ -28,6 +30,15 @@ cell's index is its position. A layer is a mixer and an MLP. Per layer
   rotary parts; scores over sqrt of the query's whole width, causal
   softmax, the context times a gate sigmoid(x Wg) a head (unless
   `latent_gate` is off), then Wo;
+- `state_space` (Mamba-2): [z | xBC | dt] = x W_in; xBC through one
+  causal depthwise convolution of `conv_kernel` taps with its bias,
+  then SiLU, and cut into the heads' x (`mamba_num_heads` x
+  `mamba_head_dim`) and the groups' B and C (`n_groups` x
+  `ssm_state_size` each); a step softplus(dt + dt_bias) and a decay
+  exp(-step exp(A_log)) a head, float32; the recurrence over the
+  tokens with its skip D (nn/state_space.py, its chunked form at
+  `chunk_size`); y x SiLU(z) under an RMSNorm over each group's
+  channels, then W_out;
 
 and the MLP `mlp_layer_types[l]` names:
 
@@ -35,8 +46,13 @@ and the MLP `mlp_layer_types[l]` names:
   sigmoid router over all `num_experts`, the `num_experts_per_tok` of
   highest score (with `n_group` > 1 among the `topk_group` groups
   whose two best scores sum highest), weights `routed_scaling_factor`
-  x score / (sum of the chosen scores), every expert and the shared
-  expert a SwiGLU of `moe_intermediate_size`;
+  x score / (sum of the chosen scores), every expert a SwiGLU of
+  `moe_intermediate_size` (with `mlp_hidden_act` "relu2" ungated:
+  down(relu(up x)^2), two matrices) on the hidden size, or with
+  `moe_latent_size` on a latent of that width (x W_dn before the sort,
+  the held experts' weighted sum times W_up after it), and the shared
+  expert the same kind of MLP on the hidden size,
+  `moe_shared_expert_intermediate_size` wide where that is given;
 
 with x + norm(f(x)) (`norm_position` "post") or x + f(norm(x)) ("pre")
 for mixer and MLP alike, and a final RMSNorm after the last layer.
@@ -81,6 +97,7 @@ from ..config.model_config import TrunkConfig
 from ..ops.delta_rule import gated_delta_rule, linear_path
 from ..ops.encoder_layer import partitioned
 from . import linear_attention as delta_rule
+from . import state_space
 
 # A share sees num_experts_per_tok x count / num_experts of a block's
 # assignments if routing is even. The expert layer's buffers hold this
@@ -92,24 +109,43 @@ def sparse_layers(cfg: TrunkConfig) -> list[int]:
     return [i for i, kind in enumerate(cfg.mlp_layer_types) if kind == "sparse"]
 
 
-def param_shapes(cfg: TrunkConfig) -> dict[str, tuple[tuple[int, ...], int]]:
+def param_shapes(cfg: TrunkConfig) -> dict[str, tuple[tuple[int, ...], int | str]]:
     """name -> (shape, fan_in); fan_in 0 marks a norm's weight (ones),
-    -1 the router's selection bias (noughts). Every other parameter is
-    N(0, 1 / fan_in): for a linear layer's `A_log` (fan_in 4) and
-    `dt_bias` (1) that puts the argument of the decay's sigmoid at
-    order 1."""
-    d, hd = cfg.hidden_size, cfg.head_dim or 0  # no head_dim: all latent
+    -1 the router's selection bias (noughts), a name a state-space
+    layer's float32 parameter drawn its own way (`_INITS`). Every other
+    parameter is N(0, 1 / fan_in): for a linear layer's `A_log` (fan_in
+    4) and `dt_bias` (1) that puts the argument of the decay's sigmoid
+    at order 1. A half a layer lacks declares nothing, its norm
+    neither."""
+    d, hd = cfg.hidden_size, cfg.head_dim or 0  # no head_dim: none reads it
     heads = cfg.num_attention_heads
     q_out = heads * hd
     kv_out = cfg.num_key_value_heads * hd
     held = cfg.experts_held[1]
     im = cfg.moe_intermediate_size
-    shapes: dict[str, tuple[tuple[int, ...], int]] = {}
+    gated = cfg.mlp_hidden_act == "silu"
+    shapes: dict[str, tuple[tuple[int, ...], int | str]] = {}
     for i, (mixer, kind) in enumerate(zip(cfg.layer_types, cfg.mlp_layer_types)):
         p = f"l{i}_"
-        shapes[p + "attn_norm"] = ((d,), 0)
-        shapes[p + "mlp_norm"] = ((d,), 0)
-        if mixer == "linear_attention":
+        if mixer != "none":
+            shapes[p + "attn_norm"] = ((d,), 0)
+        if kind != "none":
+            shapes[p + "mlp_norm"] = ((d,), 0)
+        if mixer == "none":
+            pass
+        elif mixer == "state_space":
+            inner, mixed = ssm_widths(cfg)
+            ssm_heads = cfg.mamba_num_heads
+            shapes[p + "w_in"] = ((d, inner + mixed + ssm_heads), d)
+            shapes[p + "conv"] = ((cfg.conv_kernel, mixed), cfg.conv_kernel)
+            if cfg.use_conv_bias:
+                shapes[p + "conv_bias"] = ((mixed,), cfg.conv_kernel)
+            shapes[p + "A_log"] = ((ssm_heads,), "A_log")
+            shapes[p + "D"] = ((ssm_heads,), "D")
+            shapes[p + "dt_bias"] = ((ssm_heads,), "dt_bias")
+            shapes[p + "gated_norm"] = ((inner,), 0)
+            shapes[p + "w_out"] = ((inner, d), inner)
+        elif mixer == "linear_attention":
             taps = cfg.short_conv_kernel_size
             for name in ("wq", "wk", "wv", "wf"):
                 shapes[p + name] = ((d, q_out), d)
@@ -142,8 +178,11 @@ def param_shapes(cfg: TrunkConfig) -> dict[str, tuple[tuple[int, ...], int]]:
             shapes[p + "wk"] = ((d, kv_out), d)
             shapes[p + "wv"] = ((d, kv_out), d)
             shapes[p + "wo"] = ((q_out, d), q_out)
-            shapes[p + "q_norm"] = ((hd,), 0)
-            shapes[p + "k_norm"] = ((hd,), 0)
+            if cfg.qk_norm is True:
+                shapes[p + "q_norm"] = ((hd,), 0)
+                shapes[p + "k_norm"] = ((hd,), 0)
+        if kind == "none":
+            continue
         if kind == "dense":
             wide = cfg.intermediate_size
             shapes[p + "w_gate"] = ((d, wide), d)
@@ -153,16 +192,35 @@ def param_shapes(cfg: TrunkConfig) -> dict[str, tuple[tuple[int, ...], int]]:
         shapes[p + "w_router"] = ((d, cfg.num_experts), d)
         if cfg.router_bias:
             shapes[p + "router_bias"] = ((cfg.num_experts,), -1)
-        shapes[p + "e_gate"] = ((held, d, im), d)
-        shapes[p + "e_up"] = ((held, d, im), d)
-        shapes[p + "e_down"] = ((held, im, d), im)
+        lat = cfg.moe_latent_size or d  # what an expert reads and writes
+        if cfg.moe_latent_size:
+            shapes[p + "w_latent_down"] = ((d, lat), d)
+            shapes[p + "w_latent_up"] = ((lat, d), lat)
+        if gated:
+            shapes[p + "e_gate"] = ((held, lat, im), lat)
+        shapes[p + "e_up"] = ((held, lat, im), lat)
+        shapes[p + "e_down"] = ((held, im, lat), im)
         if cfg.num_shared_experts:
-            wide = im * cfg.num_shared_experts
-            shapes[p + "s_gate"] = ((d, wide), d)
+            wide = shared_width(cfg)
+            if gated:
+                shapes[p + "s_gate"] = ((d, wide), d)
             shapes[p + "s_up"] = ((d, wide), d)
             shapes[p + "s_down"] = ((wide, d), wide)
     shapes["norm"] = ((d,), 0)
     return shapes
+
+
+def ssm_widths(cfg: TrunkConfig) -> tuple[int, int]:
+    """A state-space layer's inner width (heads x their width: z's, x's
+    and y's) and the convolution's (x, B and C side by side)."""
+    inner = cfg.mamba_num_heads * cfg.mamba_head_dim
+    return inner, inner + 2 * cfg.n_groups * cfg.ssm_state_size
+
+
+def shared_width(cfg: TrunkConfig) -> int:
+    return cfg.num_shared_experts * (
+        cfg.moe_shared_expert_intermediate_size or cfg.moe_intermediate_size
+    )
 
 
 def forward_flops(cfg: TrunkConfig, seq: int) -> int:
@@ -172,17 +230,31 @@ def forward_flops(cfg: TrunkConfig, seq: int) -> int:
     linear layer's convolutions, and its recurrence by its recurrent
     form: the state read by the key, written, and read by the query,
     3 x 2 x head_dim^2 a token and head, whatever the chunked form
-    multiplies), the dense layer, the router, the shared expert, and
-    `num_experts_per_tok` x held / `num_experts` experts a token."""
+    multiplies; a state-space layer's likewise: its two projections,
+    its convolution, the state written and read, 2 x 2 x
+    `mamba_head_dim` x `ssm_state_size` a token and head), the dense
+    layer, the router, the latent's two projections, the shared expert,
+    and `num_experts_per_tok` x held / `num_experts` experts a token (an
+    MLP's two matrices or, gated, three)."""
     d = cfg.hidden_size
     heads = cfg.num_attention_heads
     q_out = heads * (cfg.head_dim or 0)
     kv_out = cfg.num_key_value_heads * (cfg.head_dim or 0)
-    expert = 2 * 3 * d * cfg.moe_intermediate_size
+    matrices = 3 if cfg.mlp_hidden_act == "silu" else 2
+    lat = cfg.moe_latent_size or d
+    expert = 2 * matrices * lat * cfg.moe_intermediate_size
+    shared = 2 * matrices * d * shared_width(cfg)
     here = cfg.num_experts_per_tok * cfg.experts_held[1] / cfg.num_experts
     total = 0.0
     for kind, mlp in zip(cfg.layer_types, cfg.mlp_layer_types):
-        if kind == "linear_attention":
+        if kind == "none":
+            pass
+        elif kind == "state_space":
+            inner, mixed = ssm_widths(cfg)
+            total += seq * 2 * (d * (inner + mixed + cfg.mamba_num_heads) + inner * d)
+            total += seq * 2 * cfg.conv_kernel * mixed
+            total += seq * 2 * 2 * inner * cfg.ssm_state_size
+        elif kind == "linear_attention":
             total += seq * 2 * (d * (4 * q_out + 2 * heads) + q_out * d)
             total += seq * 2 * 3 * cfg.short_conv_kernel_size * q_out
             total += seq * 3 * 2 * q_out * cfg.head_dim
@@ -211,11 +283,10 @@ def forward_flops(cfg: TrunkConfig, seq: int) -> int:
             total += 2 * 2 * q_out * int(causal_mask(seq, window).sum())
         if mlp == "dense":
             total += seq * 2 * 3 * d * cfg.intermediate_size
-        else:
-            total += seq * (
-                2 * d * cfg.num_experts
-                + (cfg.num_shared_experts + here) * expert
-            )
+        elif mlp == "sparse":
+            total += seq * (2 * d * cfg.num_experts + shared + here * expert)
+            if cfg.moe_latent_size:
+                total += seq * 2 * 2 * d * lat
     return int(total)
 
 
@@ -269,8 +340,9 @@ def attention(p: dict, x: Array, cfg: TrunkConfig, sliding: bool, dtype) -> Arra
     q = _dot(x, p["wq"], dtype).astype(dtype).reshape(b, s, hkv, rep, hd)
     k = _dot(x, p["wk"], dtype).astype(dtype).reshape(b, s, hkv, hd)
     v = _dot(x, p["wv"], dtype).astype(dtype).reshape(b, s, hkv, hd)
-    q = rms_norm(q, p["q_norm"], cfg.rms_norm_eps)
-    k = rms_norm(k, p["k_norm"], cfg.rms_norm_eps)
+    if cfg.qk_norm is True:
+        q = rms_norm(q, p["q_norm"], cfg.rms_norm_eps)
+        k = rms_norm(k, p["k_norm"], cfg.rms_norm_eps)
     if sliding:
         cos, sin = rotary_table(s, hd, cfg.rope_theta)
         q, k = rotate(q, cos, sin), rotate(k, cos, sin)
@@ -287,16 +359,18 @@ def attention(p: dict, x: Array, cfg: TrunkConfig, sliding: bool, dtype) -> Arra
     return _dot(ctx, p["wo"], dtype).astype(dtype)
 
 
-def short_conv(x: Array, taps: Array) -> Array:
+def short_conv(x: Array, taps: Array, bias: Array | None = None) -> Array:
     """A causal depthwise convolution along axis 1 of x (b, s, c), then
-    SiLU: y_t = sum_j taps[j] x_{t - (K - 1) + j}, the last tap on the
-    token itself; float32 inside, x's type out."""
+    SiLU: y_t = sum_j taps[j] x_{t - (K - 1) + j} (+ bias), the last tap
+    on the token itself; float32 inside, x's type out."""
     count = taps.shape[0]
     xf = jnp.pad(x.astype(jnp.float32), ((0, 0), (count - 1, 0), (0, 0)))
     s = x.shape[1]
     y = sum(
         xf[:, j : j + s] * taps[j].astype(jnp.float32) for j in range(count)
     )
+    if bias is not None:
+        y = y + bias.astype(jnp.float32)
     return jax.nn.silu(y).astype(x.dtype)
 
 
@@ -416,9 +490,49 @@ def latent_attention(p: dict, x: Array, cfg: TrunkConfig, dtype) -> Array:
     return _dot(ctx, p["wo"], dtype).astype(dtype)
 
 
+def state_space_mixer(p: dict, x: Array, cfg: TrunkConfig, dtype) -> Array:
+    b, s, _ = x.shape
+    heads, hd = cfg.mamba_num_heads, cfg.mamba_head_dim
+    groups, n = cfg.n_groups, cfg.ssm_state_size
+    inner, mixed = ssm_widths(cfg)
+    # One matrix, [z | xBC | dt]; the step's columns apart, so that it
+    # alone stays float32: (b, s, heads).
+    zxbc = _dot(x, p["w_in"][:, : inner + mixed], dtype).astype(dtype)
+    dt = _dot(x, p["w_in"][:, inner + mixed :], dtype)
+    z = zxbc[..., :inner]
+    xbc = short_conv(zxbc[..., inner:], p["conv"], p.get("conv_bias"))
+    step = jax.nn.softplus(dt + p["dt_bias"].astype(jnp.float32))
+    log_a = -step * jnp.exp(p["A_log"].astype(jnp.float32))
+    with jax.named_scope("net/trunk/state_space/scan"):
+        y = state_space.chunked(
+            xbc[..., :inner].reshape(b, s, heads, hd), step, log_a,
+            xbc[..., inner : inner + groups * n].reshape(b, s, groups, n),
+            xbc[..., inner + groups * n :].reshape(b, s, groups, n),
+            p["D"], cfg.chunk_size, dtype,
+        )
+    # The gate, then the norm, over each group's channels; one weight
+    # as wide as the layer's inner width.
+    y = y.reshape(b, s, inner) * jax.nn.silu(z.astype(jnp.float32))
+    y = rms_norm(
+        y.reshape(b, s, groups, inner // groups),
+        p["gated_norm"].reshape(groups, inner // groups), cfg.rms_norm_eps,
+    )
+    return _dot(y.reshape(b, s, inner), p["w_out"], dtype).astype(dtype)
+
+
 def swiglu(x: Array, gate: Array, up: Array, down: Array, dtype) -> Array:
     hidden = jax.nn.silu(_dot(x, gate, dtype)) * _dot(x, up, dtype)
     return _dot(hidden.astype(dtype), down, dtype)
+
+
+def relu2(x: Array) -> Array:
+    return jnp.square(jax.nn.relu(x))
+
+
+def shared_expert(p: dict, x: Array, cfg: TrunkConfig, dtype) -> Array:
+    if cfg.mlp_hidden_act == "silu":
+        return swiglu(x, p["s_gate"], p["s_up"], p["s_down"], dtype)
+    return _dot(relu2(_dot(x, p["s_up"], dtype)).astype(dtype), p["s_down"], dtype)
 
 
 def among_groups(biased: Array, groups: int, stay: int) -> Array:
@@ -459,7 +573,9 @@ def routed_experts(p: dict, x: Array, chosen: Array, weight: Array,
                    cfg: TrunkConfig, dtype, train: bool = False,
                    remat: bool = False):
     """The held experts' part of the routed sum for tokens x (T, d),
-    float32, and how many tokens each held expert computed (count,).
+    float32, and how many tokens each held expert computed (count,); d
+    is what an expert reads and writes, the hidden size or the latent's.
+    `mlp_hidden_act` says which MLP an expert is.
 
     The assignments that fall on held experts are sorted by expert and
     taken `rows` at a time through the grouped products, `rows` being
@@ -501,9 +617,12 @@ def routed_experts(p: dict, x: Array, chosen: Array, weight: Array,
         xs = x[taken // k]
         if train:
             xs = jnp.where(jnp.arange(rows)[:, None] < inside.sum(), xs, 0)
-        hidden = jax.nn.silu(_ragged(xs, p["e_gate"], inside, dtype)) * _ragged(
-            xs, p["e_up"], inside, dtype
-        )
+        if cfg.mlp_hidden_act == "silu":
+            hidden = jax.nn.silu(_ragged(xs, p["e_gate"], inside, dtype)) * _ragged(
+                xs, p["e_up"], inside, dtype
+            )
+        else:
+            hidden = relu2(_ragged(xs, p["e_up"], inside, dtype))
         out = _ragged(hidden, p["e_down"], inside, dtype).astype(dtype)
         # Rows past the round's assignments hold whatever the product
         # left there: nought, so that nothing stray reaches the sum.
@@ -550,11 +669,18 @@ def sparse_mlp_counted(p: dict, x: Array, cfg: TrunkConfig, dtype,
             loads = jnp.zeros((cfg.num_experts,), jnp.int32).at[
                 chosen.reshape(-1)
             ].add(1)
+    read = flat
+    if cfg.moe_latent_size:
+        with jax.named_scope("net/trunk/latent_proj"):
+            read = _dot(flat, p["w_latent_down"], dtype).astype(dtype)
     with jax.named_scope("net/trunk/experts"):
-        y, sizes = routed_experts(p, flat, chosen, weight, cfg, dtype, train, remat)
+        y, sizes = routed_experts(p, read, chosen, weight, cfg, dtype, train, remat)
+    if cfg.moe_latent_size:
+        with jax.named_scope("net/trunk/latent_proj"):
+            y = _dot(y, p["w_latent_up"], dtype)
     if cfg.num_shared_experts:
         with jax.named_scope("net/trunk/shared_expert"):
-            y = y + swiglu(flat, p["s_gate"], p["s_up"], p["s_down"], dtype)
+            y = y + shared_expert(p, flat, cfg, dtype)
     return y.astype(dtype).reshape(b, s, d), sizes, loads
 
 
@@ -569,13 +695,16 @@ MIXER_SCOPES = {
     "full_attention": "net/trunk/attn_full",
     "linear_attention": "net/trunk/linear_attn",
     "latent_attention": "net/trunk/latent_attn",
+    "state_space": "net/trunk/state_space",
 }
 
 
 def attention_block(p: dict, x: Array, cfg: TrunkConfig, i: int, dtype) -> Array:
     """The layer's first half, its mixer, on x (b, s, d)."""
     kind = cfg.layer_types[i]
-    if kind == "linear_attention":
+    if kind == "state_space":
+        mixer = lambda y: state_space_mixer(p, y, cfg, dtype)  # noqa: E731
+    elif kind == "linear_attention":
         mixer = lambda y: linear_attention(p, y, cfg, dtype)  # noqa: E731
     elif kind == "latent_attention":
         mixer = lambda y: latent_attention(p, y, cfg, dtype)  # noqa: E731
@@ -613,9 +742,13 @@ def mlp_block(p: dict, x: Array, cfg: TrunkConfig, i: int, dtype,
 
 def decoder_layer(p: dict, x: Array, cfg: TrunkConfig, i: int, dtype,
                   train=False, remat=False):
-    return mlp_block(
-        p, attention_block(p, x, cfg, i, dtype), cfg, i, dtype, train, remat
-    )
+    """Layer i's halves, those it has, on x (b, s, d); the second and
+    third values are `mlp_block`'s (None without a sparse MLP)."""
+    if cfg.layer_types[i] != "none":
+        x = attention_block(p, x, cfg, i, dtype)
+    if cfg.mlp_layer_types[i] == "none":
+        return x, None, None
+    return mlp_block(p, x, cfg, i, dtype, train, remat)
 
 
 def layer_params(params: dict, i: int) -> dict:
@@ -698,7 +831,13 @@ def _normal(fan_in: int):
     return init
 
 
-_INITS = {0: nn.initializers.ones, -1: nn.initializers.zeros}
+_INITS = {
+    0: nn.initializers.ones,
+    -1: nn.initializers.zeros,
+    "A_log": state_space.init_a_log,
+    "D": state_space.init_skip,
+    "dt_bias": state_space.init_dt_bias,
+}
 
 
 class DecoderTrunk(nn.Module):
@@ -713,11 +852,15 @@ class DecoderTrunk(nn.Module):
         params = {
             name: self.param(
                 name,
-                _INITS[fan_in] if fan_in <= 0 else _normal(fan_in),
+                _INITS[fan_in] if fan_in in _INITS else _normal(fan_in),
                 shape,
                 # A selection bias settles ties between scores that
-                # differ in the fourth decimal: float32 whatever the rest.
-                jnp.float32 if fan_in == -1 else self.param_dtype,
+                # differ in the fourth decimal, and a state-space head's
+                # decay is raised to the power of a board's length:
+                # float32 whatever the rest.
+                jnp.float32
+                if fan_in == -1 or isinstance(fan_in, str)
+                else self.param_dtype,
             )
             for name, (shape, fan_in) in param_shapes(cfg).items()
         }
@@ -736,12 +879,16 @@ class DecoderTrunk(nn.Module):
             sown = [("expert_tokens", counts), ("routed", jnp.int32(routed))]
             if loads is not None:
                 sown.append(("expert_loads", loads))
-            linear = cfg.layer_types.count("linear_attention")
-            if linear:  # tokens x linear layers the recurrence took
-                sown.append(
-                    ("linear_tokens",
-                     jnp.int32(tokens.shape[0] * tokens.shape[1] * linear))
-                )
+            # tokens x layers of the kind that a recurrence took
+            for counter, kind in (
+                ("linear_tokens", "linear_attention"), ("ssm_tokens", "state_space"),
+            ):
+                layers = cfg.layer_types.count(kind)
+                if layers:
+                    sown.append(
+                        (counter,
+                         jnp.int32(tokens.shape[0] * tokens.shape[1] * layers))
+                    )
             for name, value in sown:
                 self.sow(
                     "counters", name, value,
@@ -752,7 +899,8 @@ class DecoderTrunk(nn.Module):
 
 def counters_of(state: dict) -> dict:
     """{"expert_tokens", "routed"}, "linear_tokens" where the stack has
-    linear layers and "expert_loads" after a training forward, out of what `apply(..., mutable=["counters"])`
-    returned beside the net's outputs."""
+    linear layers, "ssm_tokens" where it has state-space layers and
+    "expert_loads" after a training forward, out of what
+    `apply(..., mutable=["counters"])` returned beside the net's outputs."""
     (sown,) = state["counters"].values()
     return dict(sown)

@@ -658,7 +658,8 @@ class SelfPlayEngine:
             # A routed trunk's counters for this move's search
             # (nn/trunk.py): the assignments each held expert computed,
             # (sparse layers, held) int32, all the router made, and
-            # with linear layers the tokens their recurrence took.
+            # with linear or state-space layers the tokens their
+            # recurrence took (`linear_tokens`, `ssm_tokens`).
             outputs["trace"].update(out.net_counters)
         return new_carry, outputs
 

@@ -41,8 +41,14 @@ PHASES = (
     "net/trunk/linear_attn",
     "net/trunk/linear_attn/scan",
     "net/trunk/latent_attn",
+    # a state-space layer's mixer (projections, convolution, gated
+    # norm), and inside it the recurrence alone
+    "net/trunk/state_space",
+    "net/trunk/state_space/scan",
     "net/trunk/dense_mlp",
     "net/trunk/router",
+    # the two projections of experts that live in a latent
+    "net/trunk/latent_proj",
     "net/trunk/experts",
     "net/trunk/shared_expert",
     # fused learner (rl/trainer.py); a step that takes its batch in

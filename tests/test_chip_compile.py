@@ -222,6 +222,76 @@ def test_lane_sharded_chunk_compiles_for_v5e(
     assert "encoder_attention" not in text
 
 
+def test_nemotron_supers_chunk_compiles_at_its_published_widths(
+    one_v5e_chip, monkeypatch
+):
+    """`nemotron-super-ep4`'s one-move chunk as the cell dispatches it
+    (16 lanes, waves of 32, the net in blocks of `block_boards` 64):
+    the described chip's compiler takes the state-space scan, the
+    latent experts' grouped products and top 22 of 512 at their
+    published widths, no kernel of this repo's among them, and the
+    program fits: 9.03
+    GB of weights and under 4 GB of temporaries (3.66 at PR 38; the
+    ring's 0.85 GB stands beside them on the chip)."""
+    import jax.numpy as jnp
+
+    from alphatriangle_tpu.config import TrunkConfig
+    from alphatriangle_tpu.env.engine import TriangleEnv
+    from alphatriangle_tpu.features.core import get_feature_extractor
+    from alphatriangle_tpu.nn.model import AlphaTriangleNet
+    from alphatriangle_tpu.nn.network import NeuralNetwork
+    from alphatriangle_tpu.rl import SelfPlayEngine
+    from chipbench import manifest
+    from chipbench import reference_nemotron_h as plain
+
+    cfg = manifest.load_json(manifest.HERE / "configs" / "nemotron-super-ep4.json")
+    configs = manifest.program_configs(cfg)
+    model = configs["model"].model_copy(
+        update={"TRUNK": TrunkConfig(**plain.trunk_settings(cfg))}
+    )
+    assert model.TRUNK.block_boards == 64
+    env_cfg = configs["env"]
+    module = AlphaTriangleNet(model, env_cfg.action_dim)
+    shapes = jax.eval_shape(
+        lambda k: module.init(
+            k, jnp.zeros((1, 1, env_cfg.ROWS, env_cfg.COLS)),
+            jnp.zeros((1, model.OTHER_NN_INPUT_FEATURES_DIM)), train=False,
+        ),
+        jax.random.PRNGKey(0),
+    )
+    net = NeuralNetwork(model, env_cfg, variables=shapes)
+    env = TriangleEnv(env_cfg)
+    engine = SelfPlayEngine(
+        env, get_feature_extractor(env, model), net, configs["mcts"],
+        configs["train"], seed=0,
+    )
+
+    def described(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_v5e_chip)
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled = (
+        jax.jit(functools.partial(engine._chunk, 1))
+        .lower(
+            jax.tree_util.tree_map(
+                described, engine._inference_variables(net.variables, 0)
+            ),
+            jax.tree_util.tree_map(described, engine._carry),
+            described(jnp.int32(0)),
+        )
+        .compile()
+    )
+    memory = compiled.memory_analysis()
+    assert 9.0e9 < memory.argument_size_in_bytes < 9.1e9
+    assert memory.temp_size_in_bytes < 4.0e9
+    # The compiler's own grouped products are the only custom calls:
+    # nothing of the scan is one.
+    calls = [line for line in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert calls and all("ragged" in line for line in calls)
+    assert not any("state_space" in line for line in calls)
+
+
 # ring rows, (grid, other features, actions), the blocks' leading dims
 INGEST_SHAPES = {
     # flagship-rollout: a chunk of 16 moves x 512 lanes, 49,152 candidates

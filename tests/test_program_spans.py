@@ -442,6 +442,12 @@ def _phases(*prefixes) -> list[str]:
     return [p for p in PHASES if p.startswith(prefixes)]
 
 
+# What only a stack with state-space layers and experts in a latent has.
+SSM_PHASES = (
+    "net/trunk/state_space", "net/trunk/state_space/scan", "net/trunk/latent_proj",
+)
+
+
 class TestPhaseNamesInPrograms:
     def test_chunk_program(self, world):
         engine = world["engine"]
@@ -480,7 +486,7 @@ class TestPhaseNamesInPrograms:
                     layer_types=["sliding_attention", "full_attention"],
                 ),
                 {"net/trunk/linear_attn", "net/trunk/linear_attn/scan",
-                 "net/trunk/latent_attn"},
+                 "net/trunk/latent_attn", *SSM_PHASES},
             ),
             (
                 dict(
@@ -490,27 +496,43 @@ class TestPhaseNamesInPrograms:
                     n_group=2, topk_group=1,
                     layer_types=["linear_attention", "latent_attention"],
                 ),
-                {"net/trunk/attn_window", "net/trunk/attn_full"},
+                {"net/trunk/attn_window", "net/trunk/attn_full", *SSM_PHASES},
+            ),
+            (
+                dict(
+                    num_key_value_heads=1, norm_position="pre", qk_norm="none",
+                    mamba_num_heads=4, mamba_head_dim=8, ssm_state_size=8,
+                    n_groups=2, chunk_size=8, moe_latent_size=8,
+                    mlp_hidden_act="relu2", router_bias=True,
+                    layer_types=["state_space", "none", "full_attention"],
+                    mlp_layer_types=["none", "sparse", "none"],
+                ),
+                {"net/trunk/attn_window", "net/trunk/linear_attn",
+                 "net/trunk/linear_attn/scan", "net/trunk/latent_attn",
+                 "net/trunk/dense_mlp"},
             ),
         ],
-        ids=["softmax", "hybrid"],
+        ids=["softmax", "hybrid", "state_space"],
     )
     def test_trunk_phases_in_the_chunk_of_a_decoder_stack(
         self, world, tiny_mcts_config, stack, absent
     ):
         """Each stack's chunk carries the phases of the layers it has,
-        and the two stacks together every `net/trunk` phase."""
+        and the three stacks together every `net/trunk` phase."""
         from alphatriangle_tpu.config import TrunkConfig
         from alphatriangle_tpu.features.core import get_feature_extractor
         from alphatriangle_tpu.nn.network import NeuralNetwork
         from alphatriangle_tpu.rl.self_play import SelfPlayEngine
 
-        trunk = TrunkConfig(
-            hidden_size=32, num_attention_heads=2,
-            head_dim=16, intermediate_size=48, moe_intermediate_size=16,
-            num_experts=4, num_experts_per_tok=2,
-            mlp_layer_types=["dense", "sparse"], experts_held=(0, 2), **stack,
-        )
+        trunk = TrunkConfig(**{
+            **dict(
+                hidden_size=32, num_attention_heads=2,
+                head_dim=16, intermediate_size=48, moe_intermediate_size=16,
+                num_experts=4, num_experts_per_tok=2,
+                mlp_layer_types=["dense", "sparse"], experts_held=(0, 2),
+            ),
+            **stack,
+        })
         env = world["env"]
         model = world["net"].model_config.model_copy(update={"TRUNK": trunk})
         net = NeuralNetwork(model, env.cfg, seed=0)
@@ -522,11 +544,15 @@ class TestPhaseNamesInPrograms:
             engine._chunk_fn(2)._jit_fn, net.variables, engine._carry, jnp.int32(0)
         )
         assert {p for p in _phases("net/trunk") if p not in text} == absent
-        assert len(_phases("net/trunk")) == 10 and "net/encoder" not in text
+        assert len(_phases("net/trunk")) == 13 and "net/encoder" not in text
         assert profiling.phase_of(
             "jit(chunk)/search/evaluate/net/trunk/net/trunk/linear_attn/"
             "net/trunk/linear_attn/scan/while/body/dot_general"
         ) == "net/trunk/linear_attn/scan"
+        assert profiling.phase_of(
+            "jit(chunk)/search/evaluate/net/trunk/net/trunk/state_space/"
+            "net/trunk/state_space/scan/while/body/dot_general"
+        ) == "net/trunk/state_space/scan"
         assert profiling.phase_of(
             "jit(chunk)/search/evaluate/net/trunk/net/trunk/experts/ragged_dot"
         ) == "net/trunk/experts"

@@ -452,6 +452,9 @@ def test_the_search_counts_the_tokens_the_recurrence_took(
     assert instants and instants[0] == {
         "linear_attention": 2, "latent_attention": 1, "linear_chunk": 16,
         "linear_path": {"kernel": 0, "chunked": 2},  # the CPU, heads of 16
+        # no state-space layer, so no `ssm_chunk`; gated experts on the
+        # hidden size
+        "moe_latent_size": None, "mlp_hidden_act": "silu",
         "block_boards": 8, "batch": instants[0]["batch"], "seq": 12,
         # its latent layer's query has no latent of its own; the search
         # trains nothing
@@ -728,4 +731,287 @@ def test_a_training_forward_counts_every_experts_load_and_recomputes_by_layer(
     assert counted["expert_loads"].sum(axis=1).tolist() == [4 * 12 * 2] * 2
     np.testing.assert_array_equal(
         counted["expert_tokens"], counted["expert_loads"][:, 4:]
+    )
+
+
+# --- a state-space stack: layers of one half, experts in a latent -------------
+
+SSM = dict(
+    hidden_size=32, num_attention_heads=4, num_key_value_heads=2, head_dim=8,
+    intermediate_size=24, moe_intermediate_size=24, num_experts=8,
+    num_experts_per_tok=3, num_shared_experts=1, routed_scaling_factor=5.0,
+    mamba_num_heads=8, mamba_head_dim=8, ssm_state_size=8, n_groups=2,
+    conv_kernel=4, chunk_size=8, moe_latent_size=16,
+    moe_shared_expert_intermediate_size=48, mlp_hidden_act="relu2",
+    layer_types=["state_space", "none", "state_space", "full_attention", "none"],
+    mlp_layer_types=["none", "sparse", "none", "none", "sparse"],
+    experts_held=(2, 2), norm_position="pre", qk_norm="none", router_bias=True,
+)
+
+
+def _scan_inputs(seq, heads=6, groups=2, p=4, n=5, boards=2, seed=0):
+    from alphatriangle_tpu.nn import state_space
+
+    keys = jax.random.split(jax.random.PRNGKey(seed), 7)
+    x = jax.random.normal(keys[0], (boards, seq, heads, p))
+    step = jax.nn.softplus(
+        jax.random.normal(keys[1], (boards, seq, heads))
+        + state_space.init_dt_bias(keys[2], (heads,))
+    )
+    log_a = -step * jnp.exp(state_space.init_a_log(keys[3], (heads,)))
+    b = jax.random.normal(keys[4], (boards, seq, groups, n))
+    c = jax.random.normal(keys[5], (boards, seq, groups, n))
+    return x, step, log_a, b, c, state_space.init_skip(keys[6], (heads,))
+
+
+@pytest.mark.parametrize("seq", [12, 77, 252])
+@pytest.mark.parametrize("chunk", [16, 128])
+@pytest.mark.parametrize("decay", ["drawn", "fast", "none"])
+def test_the_chunked_scan_is_the_token_by_token_one(seq, chunk, decay):
+    """At sequences that are no multiple of the chunk, three heads a
+    group, float32: with steps and decays as Mamba-2 draws them, with a
+    decay of e^-8 a token (exp(G_t - G_i) falls to nought within a
+    chunk, and nothing is inf or nan) and with none (the state keeps
+    everything)."""
+    from alphatriangle_tpu.nn import state_space
+
+    x, step, log_a, b, c, skip = _scan_inputs(seq)
+    if decay == "fast":
+        log_a = jnp.full_like(log_a, -8.0)
+    elif decay == "none":
+        log_a = jnp.zeros_like(log_a)
+    want = state_space.recurrent(x, step, log_a, b, c, skip)
+    got = state_space.chunked(x, step, log_a, b, c, skip, chunk, jnp.float32)
+    assert got.shape == want.shape == (2, seq, 6, 4) and got.dtype == jnp.float32
+    assert bool(jnp.isfinite(got).all())
+    assert float(jnp.abs(got - want).max()) < 2e-5 * max(1.0, float(jnp.abs(want).max()))
+
+
+def test_the_scan_is_causal_reads_its_group_and_keeps_its_skip():
+    """Change token 5's input: outputs before it stay, outputs from it
+    on move. Change group 1's B: the heads of group 0 stay. With C at
+    nought what is left is D x."""
+    from alphatriangle_tpu.nn import state_space
+
+    x, step, log_a, b, c, skip = _scan_inputs(40)
+    run = lambda x, b, c: state_space.chunked(  # noqa: E731
+        x, step, log_a, b, c, skip, 16, jnp.float32
+    )
+    a = run(x, b, c)
+    moved = np.asarray(jnp.abs(a - run(x.at[:, 5].add(1.0), b, c)).max(axis=(0, 2, 3)))
+    assert (moved[:5] == 0).all() and (moved[5:] > 1e-6).all()
+    by_head = np.asarray(
+        jnp.abs(a - run(x, b.at[:, :, 1].add(1.0), c)).max(axis=(0, 1, 3))
+    )
+    assert (by_head[:3] == 0).all() and (by_head[3:] > 1e-3).all()
+    np.testing.assert_allclose(
+        run(x, b, jnp.zeros_like(c)), skip[:, None] * x, atol=1e-6
+    )
+
+
+def test_mamba_draws_its_own_parameters():
+    from alphatriangle_tpu.nn import state_space
+
+    key = jax.random.PRNGKey(0)
+    a_log = state_space.init_a_log(key, (4096,))
+    assert a_log.dtype == jnp.float32
+    assert 0.0 <= float(a_log.min()) < 0.1 and 2.7 < float(a_log.max()) <= np.log(16.0)
+    step = jax.nn.softplus(state_space.init_dt_bias(key, (4096,)))
+    assert 1e-3 * 0.999 < float(step.min()) < 1.2e-3 and 0.08 < float(step.max()) < 0.1001
+    skip = state_space.init_skip(key, (4096,))
+    assert abs(float(skip.mean()) - 1.0) < 0.05 and 0.4 < float(skip.std()) < 0.6
+
+
+@pytest.mark.parametrize(
+    "change,message",
+    [
+        (dict(layer_types=["state_space", "none", "none", "full_attention", "none"],
+              mlp_layer_types=["none", "sparse", "none", "none", "sparse"]),
+         "layer 2 has neither"),
+        (dict(mamba_num_heads=None), "mamba_num_heads"),
+        (dict(ssm_state_size=None), "ssm_state_size"),
+        (dict(n_groups=3), "groups"),
+        (dict(mlp_layer_types=["none", "dense", "none", "none", "sparse"]), "silu"),
+        (dict(layer_types=["state_space", "none", "sliding_attention",
+                           "full_attention", "none"], sliding_window=4),
+         "qk_norm True"),
+        (dict(layer_types=["linear_attention", "none", "state_space",
+                           "full_attention", "none"]), 'qk_norm "l2"'),
+        (dict(mlp_hidden_act="gelu"), "mlp_hidden_act"),
+        (dict(layer_types=["state_space"] * 4), "same layers"),
+    ],
+)
+def test_a_state_space_stack_no_layer_was_written_for_is_refused(change, message):
+    with pytest.raises(ValueError, match=message):
+        TrunkConfig(**{**SSM, **change})
+    TrunkConfig(**SSM)
+    # A stack with neither softmax nor linear layers reads no head_dim.
+    TrunkConfig(**{**SSM, "head_dim": None,
+                   "layer_types": ["state_space", "none", "state_space",
+                                   "state_space", "none"]})
+
+
+def test_a_layer_of_one_half_has_one_norm_and_the_experts_their_latent():
+    cfg = TrunkConfig(**SSM)
+    shapes = trunk.param_shapes(cfg)
+    of = lambda i: {n[3:] for n in shapes if n.startswith(f"l{i}_")}  # noqa: E731
+    assert of(0) == {"attn_norm", "w_in", "conv", "conv_bias", "A_log", "D",
+                     "dt_bias", "gated_norm", "w_out"}
+    assert of(1) == {"mlp_norm", "w_router", "router_bias", "w_latent_down",
+                     "w_latent_up", "e_up", "e_down", "s_up", "s_down"}
+    assert of(3) == {"attn_norm", "wq", "wk", "wv", "wo"}  # no q/k norm
+    assert shapes["l0_w_in"] == ((32, 64 + 64 + 2 * 2 * 8 + 8), 32)
+    assert shapes["l0_conv"] == ((4, 96), 4) and shapes["l0_conv_bias"] == ((96,), 4)
+    assert shapes["l0_A_log"] == ((8,), "A_log") and shapes["l0_D"] == ((8,), "D")
+    assert shapes["l1_e_up"] == ((2, 16, 24), 16) and shapes["l1_e_down"] == ((2, 24, 16), 24)
+    assert shapes["l1_s_up"] == ((32, 48), 32) and shapes["l1_w_latent_up"] == ((16, 32), 16)
+    assert not trunk.param_shapes(TrunkConfig(**{**SSM, "use_conv_bias": False})).get(
+        "l0_conv_bias"
+    )
+    # The count of FLOP: a board of 12 tokens, by hand.
+    mamba = 2 * (32 * 168 + 64 * 32) + 2 * 4 * 96 + 2 * 2 * 64 * 8
+    attention = 2 * (32 * (32 + 2 * 16) + 32 * 32)
+    experts = 2 * 32 * 8 + 2 * 2 * 32 * 16 + 2 * 2 * 32 * 48 + 3 * 2 / 8 * 2 * 2 * 16 * 24
+    assert trunk.forward_flops(cfg, 12) == (
+        12 * (2 * mamba + attention + 2 * experts) + 2 * 2 * 32 * 78
+    )
+
+
+def test_the_search_counts_the_tokens_the_scan_took(
+    tiny_model_config, tiny_env_config, tiny_mcts_config, tiny_train_config,
+    monkeypatch,
+):
+    """A whole chunk of self-play through the state-space stack and its
+    rows into the ring, as `cli train` runs them in sync mode: the
+    harvest carries `ssm_tokens` beside the experts' two counters (in
+    `last_trace`, where the benchmark reads it; the engine keeps no sum
+    nothing reads), the instant names the stack, parameters a decay is
+    made of are float32 whatever the rest, and a stack without such
+    layers sows no such counter."""
+    from alphatriangle_tpu.env.engine import TriangleEnv
+    from alphatriangle_tpu.features.core import get_feature_extractor
+    from alphatriangle_tpu.rl.device_buffer import DeviceReplayBuffer
+    from alphatriangle_tpu.rl.self_play import SelfPlayEngine
+
+    model = tiny_model_config.model_copy(
+        update={"TRUNK": TrunkConfig(**{**SSM, "block_boards": 8}),
+                "PARAM_DTYPE": "bfloat16", "INFERENCE_PRECISION": "bfloat16"}
+    )
+    env = TriangleEnv(tiny_env_config)
+    net = NeuralNetwork(model, tiny_env_config, seed=0)
+    stack = net.variables["params"]["DecoderTrunk_0"]
+    assert {name.split("_", 1)[1] for name, v in stack.items()
+            if v.dtype == jnp.float32} == {"A_log", "D", "dt_bias", "router_bias"}
+    seen = _trunk_instants(monkeypatch)
+    engine = SelfPlayEngine(
+        env, get_feature_extractor(env, model), net, tiny_mcts_config,
+        tiny_train_config, seed=0,
+    )
+    result, payload = engine.play_moves_device(2)
+    lanes, sims = tiny_train_config.SELF_PLAY_BATCH_SIZE, tiny_mcts_config.max_simulations
+    evaluations = 2 * lanes * (sims + 1)
+    assert result.routed_assignments == evaluations * 12 * 3 * 2
+    assert result.expert_tokens.shape == (2, 2) and result.linear_tokens == 0
+    # a move's count each; two state-space layers
+    assert engine.last_trace["ssm_tokens"].shape == (2,)
+    assert int(engine.last_trace["ssm_tokens"].sum()) == evaluations * 12 * 2
+    assert "linear_tokens" not in engine.last_trace
+    assert seen and seen[0] == {
+        "state_space": 2, "full_attention": 1, "ssm_chunk": 8,
+        "moe_latent_size": 16, "mlp_hidden_act": "relu2",
+        "linear_path": {"kernel": 0, "chunked": 0}, "linear_chunk": 64,
+        "block_boards": 8, "batch": seen[0]["batch"], "seq": 12,
+        "latent_q_compressed": 0, "learner_block_boards": None, "remat_layers": 0,
+    }
+    buffer = DeviceReplayBuffer(
+        tiny_train_config, (model.GRID_INPUT_CHANNELS, 3, 4),
+        model.OTHER_NN_INPUT_FEATURES_DIM, tiny_env_config.action_dim, seed=0,
+    )
+    added = buffer.ingest_payload(payload)
+    assert added == len(buffer) > 0
+
+    hybrid = tiny_model_config.model_copy(update={"TRUNK": TrunkConfig(**HYBRID)})
+    other = NeuralNetwork(hybrid, tiny_env_config, seed=0)
+    _, state = other.model.apply(
+        other.variables, jnp.zeros((2, 1, 3, 4)),
+        jnp.zeros((2, hybrid.OTHER_NN_INPUT_FEATURES_DIM)),
+        train=False, mutable=["counters"],
+    )
+    assert "ssm_tokens" not in trunk.counters_of(state)
+
+
+def test_a_trainer_refuses_nemotron_super_by_its_bytes(
+    tiny_model_config, tiny_env_config, tiny_train_config, monkeypatch
+):
+    """`nemotron-super-ep4` at its published widths: 4,379,728,256
+    parameters in the stack (a Mamba-2 mixer 109,640,064), 4.51 G with
+    the test's stem and heads; four copies are 36 GB and 16 B a
+    parameter would be 72 GB for a 16 GiB chip: refused, to the byte."""
+    from alphatriangle_tpu.rl.trainer import Trainer
+    from alphatriangle_tpu.telemetry.memory import BYTES_LIMIT_ENV
+    from chipbench import manifest
+    from chipbench import reference_nemotron_h as plain
+
+    cfg = manifest.load_json(manifest.HERE / "configs" / "nemotron-super-ep4.json")
+    stack = TrunkConfig(**plain.trunk_settings(cfg))
+    shapes = trunk.param_shapes(stack)
+    count = sum(int(np.prod(shape)) for shape, _ in shapes.values())
+    assert count == 4_379_728_256
+    assert sum(
+        int(np.prod(shape)) for name, (shape, _) in shapes.items()
+        if name.startswith("l0_")
+    ) == 109_640_064
+    assert 16 * count == pytest.approx(70.1e9, rel=1e-3)  # 72 GB with the heads
+    model = tiny_model_config.model_copy(
+        update={"TRUNK": stack, "PARAM_DTYPE": "bfloat16"}
+    )
+    module = AlphaTriangleNet(model, tiny_env_config.action_dim)
+    net_shapes = jax.eval_shape(
+        lambda k: module.init(
+            k, jnp.zeros((1, 1, 3, 4)),
+            jnp.zeros((1, model.OTHER_NN_INPUT_FEATURES_DIM)), train=False,
+        ),
+        jax.random.PRNGKey(0),
+    )
+    net = NeuralNetwork(model, tiny_env_config, variables=net_shapes)
+    monkeypatch.setenv(BYTES_LIMIT_ENV, str(16 * 2**30))  # one v5e chip
+    with pytest.raises(ValueError, match="of training state"):
+        Trainer(net, tiny_train_config)
+
+
+def test_glm_flash_trained_is_untouched():
+    """With the one-half layers, the latent experts and the ungated
+    activation beside it, `glm-flash-ep8`'s training forward and its
+    backward at published widths (a gated expert layer on the hidden
+    size, differentiated through its rounds) lower to the parent
+    commit's text (ec84fef, where the digest was taken with this test's
+    code)."""
+    from chipbench import manifest
+    from chipbench import reference_glm_moe as plain
+
+    cfg = manifest.load_json(manifest.HERE / "configs" / "glm-flash-ep8.json")
+    configs = manifest.program_configs(cfg)
+    model = configs["model"].model_copy(
+        update={"TRUNK": TrunkConfig(**plain.trunk_settings(cfg))}
+    )
+    env = configs["env"]
+    module = AlphaTriangleNet(model, env.action_dim)
+    grid = jax.ShapeDtypeStruct(
+        (2, model.GRID_INPUT_CHANNELS, env.ROWS, env.COLS), jnp.float32
+    )
+    other = jax.ShapeDtypeStruct((2, model.OTHER_NN_INPUT_FEATURES_DIM), jnp.float32)
+    shapes = jax.eval_shape(
+        lambda k: module.init(
+            k, jnp.zeros(grid.shape), jnp.zeros(other.shape), train=False
+        ),
+        jax.random.PRNGKey(0),
+    )
+
+    def loss(v, g, o):
+        (policy, value), _ = module.apply(v, g, o, train=True, mutable=["counters"])
+        return policy.sum() + value.sum()
+
+    text = jax.jit(jax.grad(loss)).lower(shapes, grid, other).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() == (
+        "68fc6dfdce69984d1693bfb630faabb81b239b3aab1adb1e89402092701b7c48"
     )
