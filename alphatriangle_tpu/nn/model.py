@@ -33,7 +33,7 @@ from jax import Array
 from ..config.model_config import ModelConfig
 from ..ops.encoder_layer import encoder_layer, layer_path, partitioned
 from ..telemetry.tracer import default_tracer
-from .trunk import DecoderTrunk, recurrence_path
+from .trunk import DecoderTrunk, recurrence_path, scan_path
 
 _ACTIVATIONS: dict[str, Callable[[Array], Array]] = {
     "ReLU": nn.relu,
@@ -295,7 +295,8 @@ class AlphaTriangleNet(nn.Module):
                 flat = tokens.reshape(b, -1)
                 # Once each time the net is traced into a program. The
                 # linear layers are alike, so they took one path: the one
-                # `nn/trunk.py` read from the same tokens.
+                # `nn/trunk.py` read from the same tokens; so did the
+                # state-space layers.
                 kinds = cfg.TRUNK.layer_types
                 taken = {"kernel": 0, "chunked": 0}
                 if "linear_attention" in kinds:
@@ -312,10 +313,19 @@ class AlphaTriangleNet(nn.Module):
                     linear_path=taken,
                     linear_chunk=cfg.TRUNK.linear_chunk,
                     # tokens a state-space layer's scan takes at a time
-                    # (a stack with such layers only), the experts' latent
-                    # (None: the hidden size) and which MLP they are
+                    # and the path the scans took (a stack with such
+                    # layers only), the experts' latent (None: the hidden
+                    # size) and which MLP they are
                     **(
-                        {"ssm_chunk": cfg.TRUNK.chunk_size}
+                        {
+                            "ssm_chunk": cfg.TRUNK.chunk_size,
+                            "ssm_path": {
+                                "kernel": 0, "chunked": 0,
+                                scan_path(cfg.TRUNK, tokens, dtype): kinds.count(
+                                    "state_space"
+                                ),
+                            },
+                        }
                         if "state_space" in kinds
                         else {}
                     ),

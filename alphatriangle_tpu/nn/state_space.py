@@ -31,8 +31,13 @@ products are `dtype`, their sums float32.
 
 Two forms of one sum, and where each runs. `recurrent` is the
 definition and the tests' oracle: nothing calls it in a program.
-`chunked` is plain `jax.numpy` and runs wherever a state-space layer is
-traced, on every backend: the scan has no kernel (docs/KERNELS.md).
+`chunked` is plain `jax.numpy`: it runs on the CPU, in a program
+partitioned over a mesh and at shapes the kernel refuses. On one TPU
+chip the same chunked form runs as one kernel a layer and block of
+boards, a chunk's decays and weights kept in VMEM
+(`ops/state_space_scan.py`); which of the two a layer takes is decided
+by what its site can observe (`ssm_path`), never by an option
+(docs/KERNELS.md).
 """
 
 import jax

@@ -37,8 +37,8 @@ residual. Per layer (l = 0..), the mixer `layer_types[l]` names:
   `ssm_state_size` each); a step softplus(dt + dt_bias) and a decay
   exp(-step exp(A_log)) a head, float32; the recurrence over the
   tokens with its skip D (nn/state_space.py, its chunked form at
-  `chunk_size`); y x SiLU(z) under an RMSNorm over each group's
-  channels, then W_out;
+  `chunk_size`; on one TPU chip ops/state_space_scan.py); y x SiLU(z)
+  under an RMSNorm over each group's channels, then W_out;
 
 and the MLP `mlp_layer_types[l]` names:
 
@@ -96,6 +96,7 @@ from jax import Array
 from ..config.model_config import TrunkConfig
 from ..ops.delta_rule import gated_delta_rule, linear_path
 from ..ops.encoder_layer import partitioned
+from ..ops.state_space_scan import ssm_path, state_space_scan
 from . import linear_attention as delta_rule
 from . import state_space
 
@@ -490,6 +491,24 @@ def latent_attention(p: dict, x: Array, cfg: TrunkConfig, dtype) -> Array:
     return _dot(ctx, p["wo"], dtype).astype(dtype)
 
 
+def scan_path(cfg: TrunkConfig, x: Array, dtype) -> str:
+    """"kernel" (ops/state_space_scan.py) or "chunked"
+    (nn/state_space.py) for the scan of a state-space layer on x (b, s,
+    d), from what this trace can observe: no field of the config
+    chooses."""
+    return ssm_path(
+        partitioned=partitioned(x),
+        backend=jax.default_backend(),
+        seq=x.shape[1],
+        heads=cfg.mamba_num_heads,
+        head_dim=cfg.mamba_head_dim,
+        groups=cfg.n_groups,
+        state_size=cfg.ssm_state_size,
+        chunk=cfg.chunk_size,
+        dtype=dtype,
+    )
+
+
 def state_space_mixer(p: dict, x: Array, cfg: TrunkConfig, dtype) -> Array:
     b, s, _ = x.shape
     heads, hd = cfg.mamba_num_heads, cfg.mamba_head_dim
@@ -503,13 +522,20 @@ def state_space_mixer(p: dict, x: Array, cfg: TrunkConfig, dtype) -> Array:
     xbc = short_conv(zxbc[..., inner:], p["conv"], p.get("conv_bias"))
     step = jax.nn.softplus(dt + p["dt_bias"].astype(jnp.float32))
     log_a = -step * jnp.exp(p["A_log"].astype(jnp.float32))
+    # x, B and C are 128-lane blocks of xbc: the kernel reads them there.
     with jax.named_scope("net/trunk/state_space/scan"):
-        y = state_space.chunked(
-            xbc[..., :inner].reshape(b, s, heads, hd), step, log_a,
-            xbc[..., inner : inner + groups * n].reshape(b, s, groups, n),
-            xbc[..., inner + groups * n :].reshape(b, s, groups, n),
-            p["D"], cfg.chunk_size, dtype,
-        )
+        if scan_path(cfg, x, dtype) == "kernel":
+            y = state_space_scan(
+                xbc, step, log_a, p["D"], heads=heads, head_dim=hd,
+                groups=groups, chunk=cfg.chunk_size, dtype=jnp.dtype(dtype),
+            )
+        else:
+            y = state_space.chunked(
+                xbc[..., :inner].reshape(b, s, heads, hd), step, log_a,
+                xbc[..., inner : inner + groups * n].reshape(b, s, groups, n),
+                xbc[..., inner + groups * n :].reshape(b, s, groups, n),
+                p["D"], cfg.chunk_size, dtype,
+            )
     # The gate, then the norm, over each group's channels; one weight
     # as wide as the layer's inner width.
     y = y.reshape(b, s, inner) * jax.nn.silu(z.astype(jnp.float32))

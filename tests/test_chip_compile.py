@@ -15,6 +15,7 @@ A compile that passes here is not a chip run: nothing executes.
 
 import functools
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs to /tmp
 
@@ -227,12 +228,12 @@ def test_nemotron_supers_chunk_compiles_at_its_published_widths(
 ):
     """`nemotron-super-ep4`'s one-move chunk as the cell dispatches it
     (16 lanes, waves of 32, the net in blocks of `block_boards` 64):
-    the described chip's compiler takes the state-space scan, the
-    latent experts' grouped products and top 22 of 512 at their
-    published widths, no kernel of this repo's among them, and the
-    program fits: 9.03
-    GB of weights and under 4 GB of temporaries (3.66 at PR 38; the
-    ring's 0.85 GB stands beside them on the chip)."""
+    the described chip's compiler takes the state-space scan as this
+    repo's kernel (PR 39), the latent experts' grouped products and top
+    22 of 512 at their published widths, and the program fits: 9.03 GB
+    of weights and under 4 GB of temporaries (3.66 at PR 38 with the
+    scan in `jax.numpy`, 3.44 with the kernel; the ring's 0.85 GB stands
+    beside them on the chip)."""
     import jax.numpy as jnp
 
     from alphatriangle_tpu.config import TrunkConfig
@@ -284,12 +285,18 @@ def test_nemotron_supers_chunk_compiles_at_its_published_widths(
     memory = compiled.memory_analysis()
     assert 9.0e9 < memory.argument_size_in_bytes < 9.1e9
     assert memory.temp_size_in_bytes < 4.0e9
-    # The compiler's own grouped products are the only custom calls:
-    # nothing of the scan is one.
-    calls = [line for line in compiled.as_text().splitlines()
+    text = compiled.as_text()
+    # The scan is one custom call a layer wherever a block of boards is
+    # evaluated, each the block's whole `(b, 252, 128 x 64)` y, beside
+    # the compiler's own grouped products; `chunked`'s decays and
+    # weights `f32[64,2,128,128,128]` are gone.
+    calls = [line for line in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
-    assert calls and all("ragged" in line for line in calls)
-    assert not any("state_space" in line for line in calls)
+    scans = [line for line in calls if "state_space_scan" in line]
+    assert scans and len(scans) % 5 == 0
+    assert all(re.search(r"= f32\[(16|64),252,8192\]", line) for line in scans)
+    assert all("ragged" in line for line in calls if line not in scans)
+    assert "f32[64,2,128,128,128]" not in text
 
 
 # ring rows, (grid, other features, actions), the blocks' leading dims

@@ -918,6 +918,7 @@ def test_the_search_counts_the_tokens_the_scan_took(
     assert "linear_tokens" not in engine.last_trace
     assert seen and seen[0] == {
         "state_space": 2, "full_attention": 1, "ssm_chunk": 8,
+        "ssm_path": {"kernel": 0, "chunked": 2},  # the CPU
         "moe_latent_size": 16, "mlp_hidden_act": "relu2",
         "linear_path": {"kernel": 0, "chunked": 0}, "linear_chunk": 64,
         "block_boards": 8, "batch": seen[0]["batch"], "seq": 12,
@@ -938,6 +939,70 @@ def test_the_search_counts_the_tokens_the_scan_took(
         train=False, mutable=["counters"],
     )
     assert "ssm_tokens" not in trunk.counters_of(state)
+
+
+@pytest.mark.parametrize(
+    "stack,path",
+    [(SSM, {"kernel": 0, "chunked": 2}), (HYBRID, None)],
+    ids=["state_space", "none"],
+)
+def test_the_trunk_instant_says_which_path_the_scans_took(
+    tiny_model_config, tiny_env_config, monkeypatch, stack, path
+):
+    """On the CPU every state-space layer's scan is `chunked`, and the
+    `net.trunk` instant says so for the stack's two; a stack without
+    such layers carries no `ssm_path`."""
+    model = tiny_model_config.model_copy(update={"TRUNK": TrunkConfig(**stack)})
+    net = NeuralNetwork(model, tiny_env_config, seed=0)
+    seen = _trunk_instants(monkeypatch)
+    net.model.apply(
+        net.variables, jnp.zeros((2, 1, 3, 4)),
+        jnp.zeros((2, model.OTHER_NN_INPUT_FEATURES_DIM)), train=False,
+    )
+    assert len(seen) == 1 and seen[0].get("ssm_path") == path
+
+
+@pytest.mark.parametrize("compute", ["float32", "bfloat16"])
+def test_the_state_space_net_through_the_kernel(
+    tiny_model_config, tiny_env_config, monkeypatch, compute
+):
+    """Heads of 64 and a state of 128 on a backend said to be a TPU:
+    every state-space layer's scan runs as ops/state_space_scan.py's
+    kernel (interpreted here), the instant says so, and the net's
+    answer is the chunked path's: to float32 rounding with float32
+    operands, within what bfloat16 moves a logit of this net with
+    bfloat16 ones."""
+    import functools
+
+    model = tiny_model_config.model_copy(
+        update={
+            "COMPUTE_DTYPE": compute,
+            "TRUNK": TrunkConfig(
+                **{**SSM, "mamba_head_dim": 64, "ssm_state_size": 128,
+                   "chunk_size": 128}
+            ),
+        }
+    )
+    net = NeuralNetwork(model, tiny_env_config, seed=0)
+    grid = jax.random.normal(jax.random.PRNGKey(1), (3, 1, 3, 4))
+    other = jax.random.normal(
+        jax.random.PRNGKey(2), (3, model.OTHER_NN_INPUT_FEATURES_DIM)
+    )
+    seen = _trunk_instants(monkeypatch)
+    chunked = net.model.apply(net.variables, grid, other, train=False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(
+        trunk, "state_space_scan",
+        functools.partial(trunk.state_space_scan, interpret=True),
+    )
+    kernel = net.model.apply(net.variables, grid, other, train=False)
+    assert [fields["ssm_path"] for fields in seen] == [
+        {"kernel": 0, "chunked": 2}, {"kernel": 2, "chunked": 0},
+    ]
+    limit = 1e-4 if compute == "float32" else 0.05
+    for got, want in zip(kernel, chunked):
+        assert got.shape == want.shape and bool(jnp.isfinite(got).all())
+        assert float(jnp.abs(got - want).max()) < limit
 
 
 def test_a_trainer_refuses_nemotron_super_by_its_bytes(
