@@ -66,10 +66,18 @@ stands: nothing grows, so there are no sub-blocks and no lower bound
 (g may pass -88 in one token; nn/state_space.py does the same for
 Mamba-2). Several value heads read one key head: the products of the
 keys and queries with each other and with the state are made once a
-key head, and the decays and betas, one a value head, weigh them. It
-has no kernel: it is plain `jax.numpy` on every backend, and
-`ops/delta_rule.linear_path` never hands it to `chunked`'s kernel,
-whose bound it breaks.
+key head, and the decays and betas, one a value head, weigh them. It is
+plain `jax.numpy` and runs wherever a gated-delta-net layer is traced
+for anything but one TPU chip (the CPU, heads that are not whole
+128-lane blocks, a partitioned program); on one TPU chip with heads of
+128 the layer runs `ops/gated_delta_rule.py`'s kernel, this form's
+mathematics with a chunk's decays, triangle and state kept in VMEM and
+the triangle inverted by blocks of `SUB` (`ops/gated_delta_rule.
+gdn_path` picks; `ops/delta_rule.py`'s kernel never sees such a layer,
+whose bound it breaks). Its doublings of the whole chunk lose every
+digit on keys that repeat each other with nothing decaying, as
+`chunked`'s do (tests/test_ops_gated_delta_rule.py holds the kernel
+there).
 """
 
 import jax

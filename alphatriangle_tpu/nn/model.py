@@ -33,7 +33,7 @@ from jax import Array
 from ..config.model_config import ModelConfig
 from ..ops.encoder_layer import encoder_layer, layer_path, partitioned
 from ..telemetry.tracer import default_tracer
-from .trunk import DecoderTrunk, recurrence_path, scan_path
+from .trunk import DecoderTrunk, delta_net_path, recurrence_path, scan_path
 
 _ACTIVATIONS: dict[str, Callable[[Array], Array]] = {
     "ReLU": nn.relu,
@@ -327,6 +327,20 @@ class AlphaTriangleNet(nn.Module):
                             },
                         }
                         if "state_space" in kinds
+                        else {}
+                    ),
+                    # the path the gated-delta-net recurrences took (a
+                    # stack with such layers only)
+                    **(
+                        {
+                            "gdn_path": {
+                                "kernel": 0, "chunked": 0,
+                                delta_net_path(cfg.TRUNK, tokens, dtype): kinds.count(
+                                    "gated_deltanet"
+                                ),
+                            },
+                        }
+                        if "gated_deltanet" in kinds
                         else {}
                     ),
                     moe_latent_size=cfg.TRUNK.moe_latent_size,

@@ -47,9 +47,10 @@ residual. Per layer (l = 0..), the mixer `layer_types[l]` names:
   SiLU; q and k L2-normalised per head, q scaled by its width^-0.5; a
   log decay g = -exp(A_log) softplus(x W_a + dt_bias) and a beta =
   sigmoid(x W_b), one a value head, float32; the delta rule over the
-  tokens (nn/linear_attention.py, `chunked_scalar`); each head's output
-  under an RMSNorm times s sigmoid(w_o) and the gate s sigmoid(x W_z),
-  one a channel (s = `linear_sigmoid_gate_scale`), then W_o;
+  tokens (nn/linear_attention.py, `chunked_scalar`; on one TPU chip
+  ops/gated_delta_rule.py); each head's output under an RMSNorm times
+  s sigmoid(w_o) and the gate s sigmoid(x W_z), one a channel
+  (s = `linear_sigmoid_gate_scale`), then W_o;
 
 and the MLP `mlp_layer_types[l]` names:
 
@@ -114,6 +115,7 @@ from jax import Array
 from ..config.model_config import TrunkConfig
 from ..ops.delta_rule import gated_delta_rule, linear_path
 from ..ops.encoder_layer import partitioned
+from ..ops.gated_delta_rule import gated_delta_net, gdn_path
 from ..ops.state_space_scan import ssm_path, state_space_scan
 from . import linear_attention as delta_rule
 from . import state_space
@@ -657,6 +659,23 @@ def state_space_mixer(p: dict, x: Array, cfg: TrunkConfig, dtype) -> Array:
     return _dot(y.reshape(b, s, inner), p["w_out"], dtype).astype(dtype)
 
 
+def delta_net_path(cfg: TrunkConfig, x: Array, dtype) -> str:
+    """"kernel" (ops/gated_delta_rule.py) or "chunked"
+    (nn/linear_attention.chunked_scalar) for the recurrence of a
+    gated-delta-net layer on x (b, s, d), from what this trace can
+    observe: no field of the config chooses."""
+    return gdn_path(
+        partitioned=partitioned(x),
+        backend=jax.default_backend(),
+        seq=x.shape[1],
+        dk=cfg.linear_key_head_dim,
+        dv=cfg.linear_value_head_dim,
+        group=cfg.linear_num_value_heads // cfg.linear_num_key_heads,
+        chunk=cfg.linear_chunk,
+        dtype=dtype,
+    )
+
+
 def gated_deltanet(p: dict, x: Array, cfg: TrunkConfig, dtype) -> Array:
     b, s, _ = x.shape
     key_heads, dk = cfg.linear_num_key_heads, cfg.linear_key_head_dim
@@ -674,14 +693,32 @@ def gated_deltanet(p: dict, x: Array, cfg: TrunkConfig, dtype) -> Array:
         y = jnp.moveaxis(y.reshape(b, s, key_heads, *y.shape[3:]), 2, 1)
         return y.reshape(b * key_heads, s, *y.shape[3:])
 
+    # A key head is a 128-lane block of q and k, a value head one of
+    # qkv where the convolution wrote it: the kernel reads them there,
+    # and nothing is moved heads-first or back.
+    kernel = delta_net_path(cfg, x, dtype) == "kernel"
     with jax.named_scope("net/trunk/gated_deltanet/scan"):
-        o = delta_rule.chunked_scalar(
-            heads_first(q), heads_first(k), heads_first(v),
-            heads_first(g.reshape(b, s, key_heads, group)),
-            heads_first(beta.reshape(b, s, key_heads, group)),
-            cfg.linear_chunk, dtype,
-        )
-    o = jnp.moveaxis(o.reshape(b, key_heads, s, group, dv), 1, 2)  # float32
+        if kernel:
+            # v's heads start on a whole block of qkv unless dv does not
+            # divide 2 x keys (no published width): then v is sliced out
+            whole = 2 * keys % dv == 0
+            o = gated_delta_net(
+                q.reshape(b, s, keys), k.reshape(b, s, keys),
+                qkv if whole else qkv[..., 2 * keys :], g, beta,
+                key_heads=key_heads, value_dim=dv, chunk=cfg.linear_chunk,
+                dtype=jnp.dtype(dtype), v_first=2 * keys if whole else 0,
+            )
+        else:
+            o = delta_rule.chunked_scalar(
+                heads_first(q), heads_first(k), heads_first(v),
+                heads_first(g.reshape(b, s, key_heads, group)),
+                heads_first(beta.reshape(b, s, key_heads, group)),
+                cfg.linear_chunk, dtype,
+            )
+    if kernel:
+        o = o.reshape(b, s, key_heads, group, dv)
+    else:
+        o = jnp.moveaxis(o.reshape(b, key_heads, s, group, dv), 1, 2)  # float32
     scale = cfg.linear_sigmoid_gate_scale
     gate = zero_centred(_dot(x, p["w_z"], dtype), scale)
     o = rms_norm(o, zero_centred(p["o_norm"], scale), cfg.rms_norm_eps)

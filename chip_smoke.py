@@ -112,15 +112,25 @@ def _train_config(base, **overrides):
 # `ling-flash-ep4` runs it (no preset has such a layer): a block of 64
 # boards, 32 heads of 128, the 252 cells of its board, chunks of 64.
 RECURRENCE = {"boards": 64, "heads": 32, "tokens": 252, "head_dim": 128, "chunk": 64}
+# The recurrence of a gated-delta-net mixer as `gigachat35-ep16` runs it:
+# a block of 64 boards, 32 key heads of 128 each read by 2 value heads of
+# 128, the 252 cells of its board, chunks of 64.
+GDN_RECURRENCE = {
+    "boards": 64, "key_heads": 32, "group": 2, "tokens": 252, "head_dim": 128,
+    "chunk": 64,
+}
 
 
-def kernel_shapes(cfgs: dict, recurrence: "dict | None" = None) -> dict:
-    """The operand shapes the six kernels see under `cfgs`: B games, a
+def kernel_shapes(
+    cfgs: dict, recurrence: "dict | None" = None, gdn: "dict | None" = None
+) -> dict:
+    """The operand shapes the seven kernels see under `cfgs`: B games, a
     tree of N nodes x A actions searched W leaves at a time to depth D,
     a ring of `capacity` rows sampled k x b at a time, an encoder
     layer over the leaf wave of a fast search (of a full one where the
     playout cap is off): `leaves` boards of `tokens` cells, `dim` wide;
-    and `recurrence`, which no preset sizes (`RECURRENCE`)."""
+    and `recurrence` and `gdn`, which no preset sizes (`RECURRENCE`,
+    `GDN_RECURRENCE`)."""
     from alphatriangle_tpu.mcts.search import tree_geometry
 
     mcts, train, model = cfgs["mcts"], cfgs["train"], cfgs["model"]
@@ -153,6 +163,7 @@ def kernel_shapes(cfgs: dict, recurrence: "dict | None" = None) -> dict:
         "activation": model.ACTIVATION_FUNCTION,
         "compute_dtype": model.COMPUTE_DTYPE,
         "recurrence": dict(recurrence or RECURRENCE),
+        "gdn": dict(gdn or GDN_RECURRENCE),
     }
 
 
@@ -162,9 +173,9 @@ def kernel_cases(shapes: dict) -> list[dict]:
     node/action indices, a real forest for the promotion), `xla` names
     the reference lowering. docs/KERNELS.md: the first four are exact;
     `encoder_layer` rounds where Flax's layer rounds (`tolerance`) and
-    is `timed` beside it; `delta_rule` is held to the token-by-token
-    recurrence, and the chunked form it replaces on a TPU (`beside`) is
-    held to it and timed too."""
+    is `timed` beside it; `delta_rule` and `gated_delta_net` are held
+    to the token-by-token recurrence, and the chunked form each replaces
+    on a TPU (`beside`) is held to it and timed too."""
     # gather_rows is held to "take", a pure copy. Whether the default
     # one-hot einsum is exact on the MXU too is reported, not required.
     import jax
@@ -180,6 +191,7 @@ def kernel_cases(shapes: dict) -> list[dict]:
     )
     from alphatriangle_tpu.ops.delta_rule import gated_delta_rule
     from alphatriangle_tpu.ops.encoder_layer import encoder_layer
+    from alphatriangle_tpu.ops.gated_delta_rule import gated_delta_net
 
     b, n, w = shapes["batch"], shapes["nodes"], shapes["wave"]
     a, d = shapes["actions"], shapes["depth"]
@@ -333,6 +345,78 @@ def kernel_cases(shapes: dict) -> list[dict]:
         o = jnp.moveaxis(o.reshape(boards, heads, tokens, hd), 1, 2)
         return o.reshape(boards, tokens, heads * hd)
 
+    gdn = shapes["gdn"]
+    key_heads, group, gdn_dim = gdn["key_heads"], gdn["group"], gdn["head_dim"]
+    value_heads = key_heads * group
+
+    def gdn_operands(key):
+        """q, k, [q | k | v], g, beta as the mixer hands them over: unit
+        keys, queries over sqrt(head_dim), qkv in the compute type, g =
+        -A softplus(.) with A over (1, 16), beta over (0, 1)."""
+        ks = jax.random.split(key, 6)
+        b, s = gdn["boards"], gdn["tokens"]
+        unit = lambda x: x * jax.lax.rsqrt(  # noqa: E731
+            jnp.sum(x * x, axis=-1, keepdims=True)
+        )
+        q = unit(jax.random.normal(ks[0], (b, s, key_heads, gdn_dim))) * gdn_dim**-0.5
+        k = unit(jax.random.normal(ks[1], (b, s, key_heads, gdn_dim)))
+        q, k = q.reshape(b, s, -1), k.reshape(b, s, -1)
+        v = jax.random.normal(ks[2], (b, s, value_heads * gdn_dim))
+        rate = jax.random.uniform(ks[3], (value_heads,), minval=1.0, maxval=16.0)
+        return (
+            q, k, jnp.concatenate([q, k, v], axis=-1).astype(rec_dtype),
+            -rate * jax.nn.softplus(jax.random.normal(ks[4], (b, s, value_heads))),
+            jax.nn.sigmoid(jax.random.normal(ks[5], (b, s, value_heads))),
+        )
+
+    def run_gdn(mode, q, k, qkv, g, beta):
+        """o (boards, tokens, value heads x head_dim) float32, by the
+        kernel, the chunked form or the recurrence itself."""
+        b, s, keys = q.shape
+        if mode == "pallas":
+            return gated_delta_net(
+                q, k, qkv, g, beta, key_heads=key_heads, value_dim=gdn_dim,
+                chunk=gdn["chunk"], dtype=rec_dtype, v_first=2 * keys,
+                interpret=jax.default_backend() != "tpu",
+            )
+        v = qkv[..., 2 * keys :]
+        if mode == "chunked":
+
+            def heads_first(y):
+                y = jnp.moveaxis(y.reshape(b, s, key_heads, *y.shape[3:]), 2, 1)
+                return y.reshape(b * key_heads, s, *y.shape[3:])
+
+            o = linear_attention.chunked_scalar(
+                heads_first(q.reshape(b, s, key_heads, gdn_dim)),
+                heads_first(k.reshape(b, s, key_heads, gdn_dim)),
+                heads_first(v.reshape(b, s, key_heads, group, gdn_dim)),
+                heads_first(g.reshape(b, s, key_heads, group)),
+                heads_first(beta.reshape(b, s, key_heads, group)),
+                gdn["chunk"], rec_dtype,
+            )
+            o = jnp.moveaxis(o.reshape(b, key_heads, s, group, gdn_dim), 1, 2)
+            return o.reshape(b, s, value_heads * gdn_dim)
+
+        def by_value_head(y):  # (b, s, value heads, ...) -> (b x value heads, s, ...)
+            return jnp.moveaxis(y, 2, 1).reshape(b * value_heads, s, *y.shape[3:])
+
+        def repeated(y):  # a key head's rows for each of its value heads
+            return jnp.repeat(
+                y.reshape(b, s, key_heads, gdn_dim).astype(rec_dtype), group, axis=2
+            )
+
+        o = linear_attention.recurrent(
+            by_value_head(repeated(q)), by_value_head(repeated(k)),
+            by_value_head(v.reshape(b, s, value_heads, gdn_dim)),
+            by_value_head(
+                jnp.broadcast_to(g[..., None], (b, s, value_heads, gdn_dim))
+            ),
+            by_value_head(beta),
+        )
+        return jnp.moveaxis(o.reshape(b, value_heads, s, gdn_dim), 1, 2).reshape(
+            b, s, value_heads * gdn_dim
+        )
+
     def run_per(mode, priorities, key):
         return per_sample(priorities, cap, k, bs, key, mode=mode)
 
@@ -390,6 +474,18 @@ def kernel_cases(shapes: dict) -> list[dict]:
             "operands": recurrence_operands,
             # Outputs up to 0.1; bfloat16 operands move one by up to
             # 0.002 on either path (PERF.md, PR 33).
+            "tolerance": 0.01,
+            "beside": "chunked",
+            "timed": True,
+        },
+        {
+            "name": "gated_delta_net",
+            "xla": "recurrent",
+            "run": run_gdn,
+            "operands": gdn_operands,
+            # Outputs up to 0.1; bfloat16 operands move one by under
+            # 0.002 even on keys that repeat each other (interpreted:
+            # tests/test_ops_gated_delta_rule.py).
             "tolerance": 0.01,
             "beside": "chunked",
             "timed": True,
@@ -795,9 +891,11 @@ def _device_ops_ms(fn, operands, calls: int = 5, top: int = 6) -> dict:
     return {name: round(ns / calls / 1e6, 3) for name, ns in dearest}
 
 
-def phase_kernels(cfgs: dict, recurrence: "dict | None" = None) -> dict:
-    """Each Pallas kernel at the shapes of `cfgs` (the recurrence's at
-    `recurrence`, else `RECURRENCE`), compiled for this
+def phase_kernels(
+    cfgs: dict, recurrence: "dict | None" = None, gdn: "dict | None" = None
+) -> dict:
+    """Each Pallas kernel at the shapes of `cfgs` (the recurrences' at
+    `recurrence` and `gdn`, else `RECURRENCE`, `GDN_RECURRENCE`), compiled for this
     backend and compared with its XLA lowering on the same operands.
     On a TPU the compiled text must hold the kernel: the dispatchers
     interpret it on any other backend, which proves nothing here."""
@@ -805,7 +903,7 @@ def phase_kernels(cfgs: dict, recurrence: "dict | None" = None) -> dict:
     import numpy as np
 
     on_tpu = jax.default_backend() == "tpu"
-    shapes = kernel_shapes(cfgs, recurrence)
+    shapes = kernel_shapes(cfgs, recurrence, gdn)
     parity: dict = {}
     wrong = []
     for i, case in enumerate(kernel_cases(shapes)):

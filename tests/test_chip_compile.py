@@ -27,7 +27,7 @@ from alphatriangle_tpu.config.presets import baseline_preset  # noqa: E402
 
 KERNELS = (
     "gather_rows", "backup_update", "per_sample", "subtree_promote",
-    "encoder_layer", "delta_rule",
+    "encoder_layer", "delta_rule", "gated_delta_net",
 )
 
 
@@ -92,10 +92,13 @@ def _compiled_text(case: dict, one_v5e_chip) -> str:
     )
 
 
-def _layer_case(shapes: dict) -> dict:
-    case = chip_smoke.kernel_cases(shapes)[-2]
-    assert case["name"] == "encoder_layer"
+def _case(shapes: dict, name: str) -> dict:
+    (case,) = [c for c in chip_smoke.kernel_cases(shapes) if c["name"] == name]
     return case
+
+
+def _layer_case(shapes: dict) -> dict:
+    return _case(shapes, "encoder_layer")
 
 
 @pytest.mark.parametrize("preset", [3, 4])
@@ -131,11 +134,28 @@ def test_delta_rule_compiles_at_the_cells_shape(one_v5e_chip, monkeypatch, dtype
     assert shapes["recurrence"] == {
         "boards": 64, "heads": 32, "tokens": 252, "head_dim": 128, "chunk": 64,
     }
-    case = chip_smoke.kernel_cases(shapes)[-1]
-    assert case["name"] == "delta_rule"
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    text = _compiled_text(case, one_v5e_chip)
+    text = _compiled_text(_case(shapes, "delta_rule"), one_v5e_chip)
     assert "tpu_custom_call" in text and "delta_rule" in text
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_gated_delta_net_compiles_at_the_cells_shape(one_v5e_chip, monkeypatch, dtype):
+    """`gigachat35-rollout`'s recurrence: a block of 64 boards x 32 key
+    heads of 128, each read by two value heads of 128, over 252 tokens
+    (the last chunk four tokens short), v read from `[q | k | v]`;
+    bfloat16 operands as the configuration states them, and float32
+    ones (every product then at the highest precision)."""
+    shapes = {
+        **chip_smoke.kernel_shapes(baseline_preset(3)), "compute_dtype": dtype,
+    }
+    assert shapes["gdn"] == {
+        "boards": 64, "key_heads": 32, "group": 2, "tokens": 252,
+        "head_dim": 128, "chunk": 64,
+    }
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    text = _compiled_text(_case(shapes, "gated_delta_net"), one_v5e_chip)
+    assert re.search(r"gated_delta_net[.0-9]* = f32\[64,252,8192\]", text)
 
 
 @pytest.mark.parametrize("activation", ["GELU", "SiLU", "Tanh", "Sigmoid"])
@@ -302,13 +322,13 @@ def test_nemotron_supers_chunk_compiles_at_its_published_widths(
 def test_gigachats_chunk_compiles_at_its_published_widths(one_v5e_chip, monkeypatch):
     """`gigachat35-ep16`'s one-move chunk as the cell dispatches it (16
     lanes, waves of 32, the net in blocks of `block_boards`): the
-    described chip's compiler takes the gated-delta-net recurrence in
-    its chunked `jax.numpy` form (no kernel: no custom call but the
-    experts' grouped products), YaRN, the gate a channel and the
+    described chip's compiler takes the gated-delta-net recurrence as
+    this repo's kernel (PR 41), YaRN, the gate a channel and the
     sandwich norms at their published widths, and the program fits:
-    9.47 GB of weights and under 4.2 GB of temporaries (3.90 at blocks
-    of 64, 2.25 at 32: PERF.md section 4; the ring's 0.85 GB stands
-    beside them on the chip)."""
+    9.47 GB of weights and under 4.2 GB of temporaries (4.12 at blocks
+    of 64 with the kernel; 3.90 at PR 40 with `chunked_scalar`, 2.25 at
+    blocks of 32: PERF.md section 4; the ring's 0.85 GB stands beside
+    them on the chip)."""
     import jax.numpy as jnp
 
     from alphatriangle_tpu.config import TrunkConfig
@@ -360,9 +380,18 @@ def test_gigachats_chunk_compiles_at_its_published_widths(one_v5e_chip, monkeypa
     assert model.TRUNK.block_boards == 64
     assert 9.4e9 < memory.argument_size_in_bytes < 9.5e9
     assert memory.temp_size_in_bytes < 4.2e9
-    calls = [line for line in compiled.as_text().splitlines()
+    text = compiled.as_text()
+    # The recurrence is one custom call a layer wherever a block of
+    # boards is evaluated, each the block's whole `(b, 252, 64 x 128)`
+    # o, beside the compiler's own grouped products; `chunked_scalar`'s
+    # pairwise decays and products `f32[4,2048,2,64,64]` are gone.
+    calls = [line for line in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
-    assert all("ragged" in line for line in calls)
+    rules = [line for line in calls if "gated_delta_net" in line]
+    assert rules and len(rules) % 4 == 0
+    assert all(re.search(r"= f32\[(16|64),252,8192\]", line) for line in rules)
+    assert all("ragged" in line for line in calls if line not in rules)
+    assert "f32[4,2048,2,64,64]" not in text
 
 
 # ring rows, (grid, other features, actions), the blocks' leading dims

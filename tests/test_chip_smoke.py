@@ -107,16 +107,20 @@ def test_kernels_phase_matches_xla(tiny_cfgs):
     out = chip_smoke.phase_kernels(
         small,
         recurrence={"boards": 2, "heads": 2, "tokens": 20, "head_dim": 128, "chunk": 16},
+        gdn={"boards": 2, "key_heads": 1, "group": 2, "tokens": 20, "head_dim": 128,
+             "chunk": 16},
     )
     assert out["compiled"] is False  # interpreted here, and it says so
     layer = out["parity"].pop("encoder_layer")
-    rule = out["parity"].pop("delta_rule")
+    rules = [out["parity"].pop(name) for name in ("delta_rule", "gated_delta_net")]
     assert {k["parity"] for k in out["parity"].values()} == {"exact"}
-    # The sixth is held to the token-by-token recurrence, and so is the
-    # chunked form beside it: both gaps are in the step's line.
-    assert rule["vs"] == "recurrent" and "rounding" in rule["parity"]
-    assert rule["chunked"].startswith("max |diff| ")
-    assert rule["device_ms_a_call"] == {"pallas": {}, "chunked": {}}
+    # The sixth and seventh are held to the token-by-token recurrence,
+    # and so is the chunked form beside each: both gaps are in the
+    # step's line.
+    for rule in rules:
+        assert rule["vs"] == "recurrent" and "rounding" in rule["parity"]
+        assert rule["chunked"].startswith("max |diff| ")
+        assert rule["device_ms_a_call"] == {"pallas": {}, "chunked": {}}
     # Not exact: the kernel's own order of sums against Flax's layer;
     # timed beside it (a CPU trace has no device operations to list).
     assert layer["vs"] == "flax" and "rounding" in layer["parity"]
@@ -197,6 +201,11 @@ def test_flagship_shapes_are_preset_three():
         # No preset's: ling-flash-rollout's mixer, a block of 64 boards.
         "recurrence": {
             "boards": 64, "heads": 32, "tokens": 252, "head_dim": 128, "chunk": 64,
+        },
+        # gigachat35-rollout's mixer: 32 key heads, two value heads each.
+        "gdn": {
+            "boards": 64, "key_heads": 32, "group": 2, "tokens": 252,
+            "head_dim": 128, "chunk": 64,
         },
     }
 

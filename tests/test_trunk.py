@@ -1355,6 +1355,7 @@ def test_the_search_counts_the_tokens_the_gated_deltanet_took(
     assert "ssm_tokens" not in engine.last_trace
     assert seen and seen[0]["gated_deltanet"] == 2 and seen[0]["latent_attention"] == 1
     assert seen[0]["linear_path"] == {"kernel": 0, "chunked": 0}  # no KDA layer
+    assert seen[0]["gdn_path"] == {"kernel": 0, "chunked": 2}  # the CPU
     buffer = DeviceReplayBuffer(
         tiny_train_config, (model.GRID_INPUT_CHANNELS, 3, 4),
         model.OTHER_NN_INPUT_FEATURES_DIM, tiny_env_config.action_dim, seed=0,
@@ -1370,6 +1371,50 @@ def test_the_search_counts_the_tokens_the_gated_deltanet_took(
         train=False, mutable=["counters"],
     )
     assert "gdn_tokens" not in trunk.counters_of(state)
+
+
+@pytest.mark.parametrize("compute", ["float32", "bfloat16"])
+def test_the_gated_deltanet_net_through_the_kernel(
+    tiny_model_config, tiny_env_config, monkeypatch, compute
+):
+    """Heads of 128, two value heads a key head, on a backend said to be
+    a TPU: every gated-delta-net layer's recurrence runs as
+    ops/gated_delta_rule.py's kernel (interpreted here), the instant
+    says so, and the net's answer is the chunked path's: to float32
+    rounding with float32 operands, within what bfloat16 moves a logit
+    of this net with bfloat16 ones."""
+    import functools
+
+    model = tiny_model_config.model_copy(
+        update={
+            "COMPUTE_DTYPE": compute,
+            "TRUNK": TrunkConfig(
+                **{**GDN, "linear_num_key_heads": 1, "linear_num_value_heads": 2,
+                   "linear_key_head_dim": 128, "linear_value_head_dim": 128,
+                   "linear_chunk": 64}
+            ),
+        }
+    )
+    net = NeuralNetwork(model, tiny_env_config, seed=0)
+    grid = jax.random.normal(jax.random.PRNGKey(1), (3, 1, 3, 4))
+    other = jax.random.normal(
+        jax.random.PRNGKey(2), (3, model.OTHER_NN_INPUT_FEATURES_DIM)
+    )
+    seen = _trunk_instants(monkeypatch)
+    chunked = net.model.apply(net.variables, grid, other, train=False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(
+        trunk, "gated_delta_net",
+        functools.partial(trunk.gated_delta_net, interpret=True),
+    )
+    kernel = net.model.apply(net.variables, grid, other, train=False)
+    assert [fields["gdn_path"] for fields in seen] == [
+        {"kernel": 0, "chunked": 2}, {"kernel": 2, "chunked": 0},
+    ]
+    limit = 1e-4 if compute == "float32" else 0.05
+    for got, want in zip(kernel, chunked):
+        assert got.shape == want.shape and bool(jnp.isfinite(got).all())
+        assert float(jnp.abs(got - want).max()) < limit
 
 
 def test_a_trainer_refuses_gigachat_by_its_bytes(
