@@ -33,7 +33,14 @@ from jax import Array
 from ..config.model_config import ModelConfig
 from ..ops.encoder_layer import encoder_layer, layer_path, partitioned
 from ..telemetry.tracer import default_tracer
-from .trunk import DecoderTrunk, delta_net_path, recurrence_path, scan_path
+from .trunk import (
+    DecoderTrunk,
+    delta_net_path,
+    recurrence_path,
+    routed_path,
+    scan_path,
+    sparse_layers,
+)
 
 _ACTIVATIONS: dict[str, Callable[[Array], Array]] = {
     "ReLU": nn.relu,
@@ -341,6 +348,20 @@ class AlphaTriangleNet(nn.Module):
                             },
                         }
                         if "gated_deltanet" in kinds
+                        else {}
+                    ),
+                    # the path the routed experts' rounds took (a stack
+                    # with sparse layers only): alike layers, one path
+                    **(
+                        {
+                            "expert_path": {
+                                "kernel": 0, "ragged": 0,
+                                routed_path(cfg.TRUNK, tokens, dtype, train): len(
+                                    sparse_layers(cfg.TRUNK)
+                                ),
+                            },
+                        }
+                        if sparse_layers(cfg.TRUNK)
                         else {}
                     ),
                     moe_latent_size=cfg.TRUNK.moe_latent_size,

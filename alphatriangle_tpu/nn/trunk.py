@@ -78,12 +78,13 @@ context a channel, sigmoid(x Wg) of heads x `v_head_dim`.
 
 One process holds the experts `experts_held` = (first, count). It
 routes over all of them, sorts the token-expert assignments that fall
-on its own by expert, runs them through one grouped product
-(`jax.lax.ragged_dot`) and adds nothing for the experts held
-elsewhere: no token is dropped, no capacity is set, nothing stands in
-for the other holders or their exchange. The assignments it computed
-are counted per expert (`counters` collection, sown only where the
-caller asks for it).
+on its own by expert, runs them through the experts' MLP as grouped
+products (in an inference forward on one TPU chip the two kernels of
+ops/grouped_experts.py, else `jax.lax.ragged_dot` a matrix) and adds
+nothing for the experts held elsewhere: no token is dropped, no
+capacity is set, nothing stands in for the other holders or their
+exchange. The assignments it computed are counted per expert
+(`counters` collection, sown only where the caller asks for it).
 
 A training forward (`DecoderTrunk(...)(tokens, train=True)`) also counts
 the assignments the router made to every one of `num_experts`, held
@@ -116,6 +117,7 @@ from ..config.model_config import TrunkConfig
 from ..ops.delta_rule import gated_delta_rule, linear_path
 from ..ops.encoder_layer import partitioned
 from ..ops.gated_delta_rule import gated_delta_net, gdn_path
+from ..ops.grouped_experts import expert_path, grouped_experts
 from ..ops.state_space_scan import ssm_path, state_space_scan
 from . import linear_attention as delta_rule
 from . import state_space
@@ -786,6 +788,51 @@ def _ragged(x: Array, w: Array, sizes: Array, dtype) -> Array:
     )
 
 
+def ragged_experts(x: Array, sizes: Array, up: Array, down: Array,
+                   gate: Array | None = None, *, limit: float | None = None,
+                   dtype) -> Array:
+    """ops/grouped_experts.py's round by `jax.lax.ragged_dot`: the held
+    experts' MLP over x (rows, d), sorted by expert with `sizes` rows
+    each, (rows, d) in `dtype`; a SwiGLU with `gate`, else relu^2."""
+    if gate is not None:
+        hidden = jax.nn.silu(
+            _limited(_ragged(x, gate, sizes, dtype), limit, False)
+        ) * _limited(_ragged(x, up, sizes, dtype), limit, True)
+    else:
+        hidden = relu2(_ragged(x, up, sizes, dtype))
+    out = _ragged(hidden, down, sizes, dtype).astype(dtype)
+    # Rows past the round's assignments hold whatever the product left
+    # there: nought, so that nothing stray reaches the sum.
+    return jnp.where(jnp.arange(x.shape[0])[:, None] < sizes.sum(), out, 0)
+
+
+def round_rows(cfg: TrunkConfig, tokens: int) -> int:
+    """Rows of a round of the expert layer over `tokens` tokens:
+    `USUAL_ROOM` times what even routing would bring here, at most
+    every assignment that can fall here."""
+    k, held = cfg.num_experts_per_tok, cfg.experts_held[1]
+    return min(
+        tokens * min(k, held), -(-USUAL_ROOM * tokens * k * held // cfg.num_experts)
+    )
+
+
+def routed_path(cfg: TrunkConfig, x: Array, dtype, train: bool) -> str:
+    """"kernel" (ops/grouped_experts.py) or "ragged"
+    (`jax.lax.ragged_dot`) for the routed experts of a sparse layer on
+    the tokens x (..., width), from what this trace can observe: no
+    field of the config chooses."""
+    return expert_path(
+        partitioned=partitioned(x),
+        backend=jax.default_backend(),
+        train=train,
+        rows=round_rows(cfg, math.prod(x.shape[:-1])),
+        d=cfg.moe_latent_size or cfg.hidden_size,
+        inter=cfg.moe_intermediate_size,
+        gated=cfg.mlp_hidden_act == "silu",
+        dtype=dtype,
+    )
+
+
 def routed_experts(p: dict, x: Array, chosen: Array, weight: Array,
                    cfg: TrunkConfig, dtype, train: bool = False,
                    remat: bool = False):
@@ -795,10 +842,16 @@ def routed_experts(p: dict, x: Array, chosen: Array, weight: Array,
     `mlp_hidden_act` says which MLP an expert is.
 
     The assignments that fall on held experts are sorted by expert and
-    taken `rows` at a time through the grouped products, `rows` being
-    `USUAL_ROOM` times what even routing would bring here: one round as
-    a rule, as many as the block's routing needs otherwise, each with
-    the same buffers. Nothing is dropped.
+    taken `rows` at a time (`round_rows`: `USUAL_ROOM` times what even
+    routing would bring here) through the experts' MLP: one round as a
+    rule, as many as the block's routing needs otherwise, each with the
+    same buffers. Nothing is dropped. A round is ops/grouped_experts.py's
+    two kernels where `routed_path` says so (an inference forward on one
+    TPU chip): the products over only the row tiles its assignments
+    fill, the activation in the first one's epilogue, hidden and output
+    written once in `dtype`; else `jax.lax.ragged_dot`, a grouped
+    product a matrix over all `rows`, with the activation and the casts
+    as passes of their own.
 
     `train` says the call will be differentiated: the rows of a round
     past its assignments are then noughts going in as they are coming
@@ -821,8 +874,10 @@ def routed_experts(p: dict, x: Array, chosen: Array, weight: Array,
     share = jnp.where(here, weight, 0.0).reshape(-1)
 
     most = t * min(k, held)
-    rows = min(most, -(-USUAL_ROOM * t * k * held // cfg.num_experts))
+    rows = round_rows(cfg, t)
     rounds = -(-most // rows)
+    kernel = routed_path(cfg, x, dtype, train) == "kernel"
+    gated = cfg.mlp_hidden_act == "silu"
     # The rounds read `rounds x rows` entries, the assignments held here
     # first. A share that holds fewer experts than a token chooses has
     # more entries than that (all past the held ones): nothing to pad.
@@ -837,17 +892,10 @@ def routed_experts(p: dict, x: Array, chosen: Array, weight: Array,
         xs = x[taken // k]
         if train:
             xs = jnp.where(jnp.arange(rows)[:, None] < inside.sum(), xs, 0)
-        if cfg.mlp_hidden_act == "silu":
-            limit = cfg.swiglu_limit
-            hidden = jax.nn.silu(
-                _limited(_ragged(xs, p["e_gate"], inside, dtype), limit, False)
-            ) * _limited(_ragged(xs, p["e_up"], inside, dtype), limit, True)
-        else:
-            hidden = relu2(_ragged(xs, p["e_up"], inside, dtype))
-        out = _ragged(hidden, p["e_down"], inside, dtype).astype(dtype)
-        # Rows past the round's assignments hold whatever the product
-        # left there: nought, so that nothing stray reaches the sum.
-        out = jnp.where(jnp.arange(rows)[:, None] < inside.sum(), out, 0)
+        out = (grouped_experts if kernel else ragged_experts)(
+            xs, inside, p["e_up"], p["e_down"], p["e_gate"] if gated else None,
+            limit=cfg.swiglu_limit, dtype=jnp.dtype(dtype),
+        )
         # Each assignment fetches its row of this round, if it has one.
         at = rank - low
         mine = (at >= 0) & (at < rows)

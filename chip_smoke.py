@@ -121,16 +121,26 @@ GDN_RECURRENCE = {
 }
 
 
+# A round of the routed experts as `ling-flash-ep4` runs it: a block of
+# 64 boards routes 16,128 tokens to 8 of 512 experts, 128 of them held;
+# the round's buffer is twice what even routing brings, 64,512 rows of
+# 2,560, and SwiGLUs of 768.
+EXPERTS = {"rows": 64512, "d": 2560, "inter": 768, "held": 128, "limit": None}
+
+
 def kernel_shapes(
-    cfgs: dict, recurrence: "dict | None" = None, gdn: "dict | None" = None
+    cfgs: dict,
+    recurrence: "dict | None" = None,
+    gdn: "dict | None" = None,
+    experts: "dict | None" = None,
 ) -> dict:
-    """The operand shapes the seven kernels see under `cfgs`: B games, a
+    """The operand shapes the eight kernels see under `cfgs`: B games, a
     tree of N nodes x A actions searched W leaves at a time to depth D,
     a ring of `capacity` rows sampled k x b at a time, an encoder
     layer over the leaf wave of a fast search (of a full one where the
     playout cap is off): `leaves` boards of `tokens` cells, `dim` wide;
-    and `recurrence` and `gdn`, which no preset sizes (`RECURRENCE`,
-    `GDN_RECURRENCE`)."""
+    and `recurrence`, `gdn` and `experts`, which no preset sizes
+    (`RECURRENCE`, `GDN_RECURRENCE`, `EXPERTS`)."""
     from alphatriangle_tpu.mcts.search import tree_geometry
 
     mcts, train, model = cfgs["mcts"], cfgs["train"], cfgs["model"]
@@ -164,6 +174,7 @@ def kernel_shapes(
         "compute_dtype": model.COMPUTE_DTYPE,
         "recurrence": dict(recurrence or RECURRENCE),
         "gdn": dict(gdn or GDN_RECURRENCE),
+        "experts": dict(experts or EXPERTS),
     }
 
 
@@ -175,7 +186,8 @@ def kernel_cases(shapes: dict) -> list[dict]:
     `encoder_layer` rounds where Flax's layer rounds (`tolerance`) and
     is `timed` beside it; `delta_rule` and `gated_delta_net` are held
     to the token-by-token recurrence, and the chunked form each replaces
-    on a TPU (`beside`) is held to it and timed too."""
+    on a TPU (`beside`) is held to it and timed too; `grouped_experts`
+    is held to and timed beside the `ragged_dot` chain it replaces."""
     # gather_rows is held to "take", a pure copy. Whether the default
     # one-hot einsum is exact on the MXU too is reported, not required.
     import jax
@@ -183,6 +195,7 @@ def kernel_cases(shapes: dict) -> list[dict]:
 
     from alphatriangle_tpu.nn import linear_attention
     from alphatriangle_tpu.nn.model import _ACTIVATIONS, TransformerEncoderLayer
+    from alphatriangle_tpu.nn.trunk import ragged_experts
     from alphatriangle_tpu.ops import (
         backup_update,
         gather_rows,
@@ -192,6 +205,7 @@ def kernel_cases(shapes: dict) -> list[dict]:
     from alphatriangle_tpu.ops.delta_rule import gated_delta_rule
     from alphatriangle_tpu.ops.encoder_layer import encoder_layer
     from alphatriangle_tpu.ops.gated_delta_rule import gated_delta_net
+    from alphatriangle_tpu.ops.grouped_experts import grouped_experts
 
     b, n, w = shapes["batch"], shapes["nodes"], shapes["wave"]
     a, d = shapes["actions"], shapes["depth"]
@@ -417,6 +431,40 @@ def kernel_cases(shapes: dict) -> list[dict]:
             b, s, value_heads * gdn_dim
         )
 
+    experts = shapes["experts"]
+
+    def experts_operands(key):
+        """A round's rows, half of them assigned, spread over the held
+        experts at random; unit-normal rows, weights N(0, 1 / fan_in),
+        all bfloat16 as the trunk cells state them."""
+        ks = jax.random.split(key, 5)
+        rows, width, inter, held = (
+            experts["rows"], experts["d"], experts["inter"], experts["held"]
+        )
+        picks = jax.random.randint(ks[0], (rows // 2,), 0, held)
+
+        def weight(k, fan_in, shape):
+            return (jax.random.normal(k, shape) * fan_in**-0.5).astype(jnp.bfloat16)
+
+        return (
+            jax.random.normal(ks[1], (rows, width)).astype(jnp.bfloat16),
+            jnp.zeros((held,), jnp.int32).at[picks].add(1),
+            weight(ks[2], width, (held, width, inter)),
+            weight(ks[3], inter, (held, inter, width)),
+            weight(ks[4], width, (held, width, inter)),
+        )
+
+    def run_experts(mode, x, sizes, up, down, gate):
+        """The round's (rows, d) out, by the kernels or the ragged chain."""
+        if mode == "pallas":
+            return grouped_experts(
+                x, sizes, up, down, gate, limit=experts["limit"], dtype=jnp.bfloat16,
+                interpret=jax.default_backend() != "tpu",
+            )
+        return ragged_experts(
+            x, sizes, up, down, gate, limit=experts["limit"], dtype=jnp.bfloat16
+        )
+
     def run_per(mode, priorities, key):
         return per_sample(priorities, cap, k, bs, key, mode=mode)
 
@@ -488,6 +536,17 @@ def kernel_cases(shapes: dict) -> list[dict]:
             # tests/test_ops_gated_delta_rule.py).
             "tolerance": 0.01,
             "beside": "chunked",
+            "timed": True,
+        },
+        {
+            "name": "grouped_experts",
+            "xla": "ragged",
+            "run": run_experts,
+            "operands": experts_operands,
+            # Outputs up to about 4, rounded to bfloat16 on both paths
+            # (steps of 2^-6 there); only the order of the float32 sums
+            # differs (exact at this shape on a v5e: PERF.md section 6).
+            "tolerance": 0.05,
             "timed": True,
         },
     ]
@@ -892,10 +951,14 @@ def _device_ops_ms(fn, operands, calls: int = 5, top: int = 6) -> dict:
 
 
 def phase_kernels(
-    cfgs: dict, recurrence: "dict | None" = None, gdn: "dict | None" = None
+    cfgs: dict,
+    recurrence: "dict | None" = None,
+    gdn: "dict | None" = None,
+    experts: "dict | None" = None,
 ) -> dict:
     """Each Pallas kernel at the shapes of `cfgs` (the recurrences' at
-    `recurrence` and `gdn`, else `RECURRENCE`, `GDN_RECURRENCE`), compiled for this
+    `recurrence` and `gdn`, the experts' round at `experts`, else
+    `RECURRENCE`, `GDN_RECURRENCE`, `EXPERTS`), compiled for this
     backend and compared with its XLA lowering on the same operands.
     On a TPU the compiled text must hold the kernel: the dispatchers
     interpret it on any other backend, which proves nothing here."""
@@ -903,7 +966,7 @@ def phase_kernels(
     import numpy as np
 
     on_tpu = jax.default_backend() == "tpu"
-    shapes = kernel_shapes(cfgs, recurrence, gdn)
+    shapes = kernel_shapes(cfgs, recurrence, gdn, experts)
     parity: dict = {}
     wrong = []
     for i, case in enumerate(kernel_cases(shapes)):

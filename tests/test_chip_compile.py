@@ -27,7 +27,7 @@ from alphatriangle_tpu.config.presets import baseline_preset  # noqa: E402
 
 KERNELS = (
     "gather_rows", "backup_update", "per_sample", "subtree_promote",
-    "encoder_layer", "delta_rule", "gated_delta_net",
+    "encoder_layer", "delta_rule", "gated_delta_net", "grouped_experts",
 )
 
 
@@ -251,9 +251,10 @@ def test_nemotron_supers_chunk_compiles_at_its_published_widths(
     the described chip's compiler takes the state-space scan as this
     repo's kernel (PR 39), the latent experts' grouped products and top
     22 of 512 at their published widths, and the program fits: 9.03 GB
-    of weights and under 4 GB of temporaries (3.66 at PR 38 with the
-    scan in `jax.numpy`, 3.44 with the kernel; the ring's 0.85 GB stands
-    beside them on the chip)."""
+    of weights and under 3 GB of temporaries (3.66 with the scan in
+    `jax.numpy`, 3.44 with its kernel and `ragged_dot`'s float32
+    products, 2.89 with the experts' kernels too; the ring's 0.85 GB
+    stands beside them on the chip)."""
     import jax.numpy as jnp
 
     from alphatriangle_tpu.config import TrunkConfig
@@ -304,19 +305,27 @@ def test_nemotron_supers_chunk_compiles_at_its_published_widths(
     )
     memory = compiled.memory_analysis()
     assert 9.0e9 < memory.argument_size_in_bytes < 9.1e9
-    assert memory.temp_size_in_bytes < 4.0e9
+    assert memory.temp_size_in_bytes < 3.0e9
     text = compiled.as_text()
     # The scan is one custom call a layer wherever a block of boards is
-    # evaluated, each the block's whole `(b, 252, 128 x 64)` y, beside
-    # the compiler's own grouped products; `chunked`'s decays and
-    # weights `f32[64,2,128,128,128]` are gone.
+    # evaluated, each the block's whole `(b, 252, 128 x 64)` y;
+    # `chunked`'s decays and weights `f32[64,2,128,128,128]` are gone.
     calls = [line for line in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
     scans = [line for line in calls if "state_space_scan" in line]
     assert scans and len(scans) % 5 == 0
     assert all(re.search(r"= f32\[(16|64),252,8192\]", line) for line in scans)
-    assert all("ragged" in line for line in calls if line not in scans)
     assert "f32[64,2,128,128,128]" not in text
+    # So are the experts' rounds, two calls a layer, each
+    # writing its round's rows in bfloat16 (177,408 at a block of 64
+    # boards, 44,352 at one of 16): no float32 grouped product is left.
+    ups = [line for line in calls if re.match(r"\s*%grouped_experts_up[.0-9]* =", line)]
+    downs = [line for line in calls if re.match(r"\s*%grouped_experts_down[.0-9]* =", line)]
+    assert ups and len(ups) % 5 == 0 and len(downs) == len(ups)
+    assert all(re.search(r"= bf16\[(44352|177408),2688\]", line) for line in ups)
+    assert all(re.search(r"= bf16\[(44352|177408),1024\]", line) for line in downs)
+    assert len(calls) == len(scans) + 2 * len(ups)
+    assert "ragged" not in text and "f32[177408,2688]" not in text
 
 
 def test_gigachats_chunk_compiles_at_its_published_widths(one_v5e_chip, monkeypatch):
@@ -325,10 +334,11 @@ def test_gigachats_chunk_compiles_at_its_published_widths(one_v5e_chip, monkeypa
     described chip's compiler takes the gated-delta-net recurrence as
     this repo's kernel (PR 41), YaRN, the gate a channel and the
     sandwich norms at their published widths, and the program fits:
-    9.47 GB of weights and under 4.2 GB of temporaries (4.12 at blocks
-    of 64 with the kernel; 3.90 at PR 40 with `chunked_scalar`, 2.25 at
-    blocks of 32: PERF.md section 4; the ring's 0.85 GB stands beside
-    them on the chip)."""
+    9.47 GB of weights and under 3.9 GB of temporaries (3.84 at blocks
+    of 64 with the recurrence's and the experts' kernels, 4.12 with
+    `ragged_dot`'s float32 products instead, 3.90 with `chunked_scalar`
+    and those, 2.25 at blocks of 32 then: PERF.md section 4; the ring's
+    0.85 GB stands beside them on the chip)."""
     import jax.numpy as jnp
 
     from alphatriangle_tpu.config import TrunkConfig
@@ -379,19 +389,32 @@ def test_gigachats_chunk_compiles_at_its_published_widths(one_v5e_chip, monkeypa
     memory = compiled.memory_analysis()
     assert model.TRUNK.block_boards == 64
     assert 9.4e9 < memory.argument_size_in_bytes < 9.5e9
-    assert memory.temp_size_in_bytes < 4.2e9
+    assert memory.temp_size_in_bytes < 3.9e9
     text = compiled.as_text()
     # The recurrence is one custom call a layer wherever a block of
     # boards is evaluated, each the block's whole `(b, 252, 64 x 128)`
-    # o, beside the compiler's own grouped products; `chunked_scalar`'s
-    # pairwise decays and products `f32[4,2048,2,64,64]` are gone.
+    # o; `chunked_scalar`'s pairwise decays and products
+    # `f32[4,2048,2,64,64]` are gone.
     calls = [line for line in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
     rules = [line for line in calls if "gated_delta_net" in line]
     assert rules and len(rules) % 4 == 0
     assert all(re.search(r"= f32\[(16|64),252,8192\]", line) for line in rules)
-    assert all("ragged" in line for line in calls if line not in rules)
     assert "f32[4,2048,2,64,64]" not in text
+    # So are the experts' rounds, two calls a layer, each
+    # writing its round's rows in bfloat16 (16,128 at a block of 64
+    # boards, 4,032 at one of 16): no float32 grouped product is left.
+    ups = [line for line in calls if re.match(r"\s*%grouped_experts_up[.0-9]* =", line)]
+    downs = [line for line in calls if re.match(r"\s*%grouped_experts_down[.0-9]* =", line)]
+    assert ups and len(ups) % 4 == 0 and len(downs) == len(ups)
+    assert all(re.search(r"= bf16\[(4032|16128),2048\]", line) for line in ups)
+    assert all(re.search(r"= bf16\[(4032|16128),7168\]", line) for line in downs)
+    assert len(calls) == len(rules) + 2 * len(ups)
+    assert "ragged" not in text
+    assert not [  # the shared expert's float32 `[16128, 2048]` stays
+        line for line in text.splitlines()
+        if re.search(r"= f32\[16128,2048\]", line) and "net/trunk/experts" in line
+    ]
 
 
 # ring rows, (grid, other features, actions), the blocks' leading dims

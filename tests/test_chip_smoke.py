@@ -109,10 +109,12 @@ def test_kernels_phase_matches_xla(tiny_cfgs):
         recurrence={"boards": 2, "heads": 2, "tokens": 20, "head_dim": 128, "chunk": 16},
         gdn={"boards": 2, "key_heads": 1, "group": 2, "tokens": 20, "head_dim": 128,
              "chunk": 16},
+        experts={"rows": 100, "d": 128, "inter": 256, "held": 4, "limit": 10.0},
     )
     assert out["compiled"] is False  # interpreted here, and it says so
     layer = out["parity"].pop("encoder_layer")
     rules = [out["parity"].pop(name) for name in ("delta_rule", "gated_delta_net")]
+    experts = out["parity"].pop("grouped_experts")
     assert {k["parity"] for k in out["parity"].values()} == {"exact"}
     # The sixth and seventh are held to the token-by-token recurrence,
     # and so is the chunked form beside each: both gaps are in the
@@ -121,6 +123,11 @@ def test_kernels_phase_matches_xla(tiny_cfgs):
         assert rule["vs"] == "recurrent" and "rounding" in rule["parity"]
         assert rule["chunked"].startswith("max |diff| ")
         assert rule["device_ms_a_call"] == {"pallas": {}, "chunked": {}}
+    # The eighth is held to the ragged_dot chain it replaces and timed
+    # beside it; both round to bfloat16 where the layer does.
+    assert experts["vs"] == "ragged"
+    assert experts["parity"] == "exact" or "rounding" in experts["parity"]
+    assert experts["device_ms_a_call"] == {"pallas": {}, "ragged": {}}
     # Not exact: the kernel's own order of sums against Flax's layer;
     # timed beside it (a CPU trace has no device operations to list).
     assert layer["vs"] == "flax" and "rounding" in layer["parity"]
@@ -207,6 +214,8 @@ def test_flagship_shapes_are_preset_three():
             "boards": 64, "key_heads": 32, "group": 2, "tokens": 252,
             "head_dim": 128, "chunk": 64,
         },
+        # ling-flash-rollout's round of routed experts, a block of 64 boards.
+        "experts": {"rows": 64512, "d": 2560, "inter": 768, "held": 128, "limit": None},
     }
 
 

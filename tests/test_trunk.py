@@ -452,6 +452,7 @@ def test_the_search_counts_the_tokens_the_recurrence_took(
     assert instants and instants[0] == {
         "linear_attention": 2, "latent_attention": 1, "linear_chunk": 16,
         "linear_path": {"kernel": 0, "chunked": 2},  # the CPU, heads of 16
+        "expert_path": {"kernel": 0, "ragged": 2},  # the CPU, widths of 32
         # no state-space layer, so no `ssm_chunk`; gated experts on the
         # hidden size
         "moe_latent_size": None, "mlp_hidden_act": "silu",
@@ -919,6 +920,7 @@ def test_the_search_counts_the_tokens_the_scan_took(
     assert seen and seen[0] == {
         "state_space": 2, "full_attention": 1, "ssm_chunk": 8,
         "ssm_path": {"kernel": 0, "chunked": 2},  # the CPU
+        "expert_path": {"kernel": 0, "ragged": 2},  # the CPU
         "moe_latent_size": 16, "mlp_hidden_act": "relu2",
         "linear_path": {"kernel": 0, "chunked": 0}, "linear_chunk": 64,
         "block_boards": 8, "batch": seen[0]["batch"], "seq": 12,
