@@ -94,7 +94,6 @@ def materialize_candidate(candidate, base_env, base_model, base_train, mode):
         BUFFER_CAPACITY=candidate.capacity,
         ROLLOUT_CHUNK_MOVES=candidate.chunk,
         FUSED_LEARNER_STEPS=candidate.fused_k,
-        PER_SAMPLE_BACKEND=candidate.per_sample,
         MIN_BUFFER_SIZE_TO_TRAIN=min(
             base_train.MIN_BUFFER_SIZE_TO_TRAIN, candidate.capacity
         ),
@@ -109,10 +108,10 @@ def materialize_candidate(candidate, base_env, base_model, base_train, mode):
 
 def candidate_mcts(base_mcts, candidate):
     """The MCTS config a candidate's programs lower with: the base
-    search config carrying the candidate's kernel axes."""
+    search config carrying the candidate's backup lowering and
+    `tree_reuse`."""
     return base_mcts.model_copy(
         update={
-            "descent_gather": candidate.descent_gather,
             "backup_update": candidate.backup_update,
             "tree_reuse": candidate.tree_reuse,
         }

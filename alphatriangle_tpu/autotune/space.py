@@ -38,13 +38,12 @@ STATUS_SKIPPED = "skipped"  # search ended before evaluation
 class Candidate:
     """One point in the autotuner's search space.
 
-    The kernel axes (docs/KERNELS.md) select interchangeable lowerings
-    of the hot kernels plus the rollout inference precision. They are
-    parity-pinned rewrites of the same math, so they never change WHAT
-    a run computes — only how fast — and all but two are memory-free:
-    `descent_gather="einsum"` adds a one-hot transient and
-    `inference_precision="bfloat16"` a cast parameter copy, which is
-    why exactly those two appear in `oracle_key()`."""
+    Beside the shape axes it carries the wave backup's lowering, the
+    rollout inference precision, the serve ladder and subtree reuse
+    (`kernels()`, the artifact's provenance block). The backup's two
+    lowerings are parity-pinned rewrites of the same math and move the
+    same buffers; `inference_precision="bfloat16"` adds a cast
+    parameter copy, which is why it appears in `oracle_key()`."""
 
     geometry: str  # named board geometry (config/presets.py)
     sp_batch: int  # SELF_PLAY_BATCH_SIZE (lockstep lanes)
@@ -52,9 +51,7 @@ class Candidate:
     chunk: int  # ROLLOUT_CHUNK_MOVES (T)
     fused_k: int  # FUSED_LEARNER_STEPS (K)
     dp: int  # data-parallel mesh width tuned for
-    descent_gather: str = "einsum"  # MCTSConfig.descent_gather
     backup_update: str = "xla"  # MCTSConfig.backup_update
-    per_sample: str = "xla"  # TrainConfig.PER_SAMPLE_BACKEND
     inference_precision: str = "float32"  # ModelConfig.INFERENCE_PRECISION
     # Serve-shape ladder spec (serving/buckets.py): CSV rung list, ""
     # meaning a single fixed rung at the lane count. A serve-
@@ -64,8 +61,7 @@ class Candidate:
     # MCTSConfig.tree_reuse: NOT memory-free — reuse widens every tree
     # plane from max_simulations+1 to ~2x that many node slots, so it
     # appears in oracle_key() alongside the other residency-changing
-    # axes. It is also the one kernel axis that changes per-move search
-    # behavior (carried visits), not just lowering speed.
+    # axes. It also changes per-move search behavior (carried visits).
     tree_reuse: bool = False
 
     def group_key(self) -> tuple:
@@ -76,19 +72,17 @@ class Candidate:
             self.chunk,
             self.fused_k,
             self.dp,
-            self.descent_gather,
             self.backup_update,
-            self.per_sample,
             self.inference_precision,
             self.serve_buckets,
             self.tree_reuse,
         )
 
     def oracle_key(self) -> tuple:
-        """Axes the feasibility oracle's answer can depend on. Kernel
-        axes that only reorder the same buffer traffic (backup_update,
-        per_sample) are deliberately absent: candidates differing only
-        there share one oracle result (a free axis for the search)."""
+        """Axes the feasibility oracle's answer can depend on. The serve
+        ladder and the backup's lowering are deliberately absent:
+        candidates differing only there share one oracle result (free
+        axes for the search)."""
         return (
             self.geometry,
             self.sp_batch,
@@ -96,7 +90,6 @@ class Candidate:
             self.chunk,
             self.fused_k,
             self.dp,
-            self.descent_gather,
             self.inference_precision,
             self.tree_reuse,
         )
@@ -104,9 +97,7 @@ class Candidate:
     def kernels(self) -> dict:
         """The kernel-axis block (tuned_preset.json provenance)."""
         return {
-            "descent_gather": self.descent_gather,
             "backup_update": self.backup_update,
-            "per_sample": self.per_sample,
             "inference_precision": self.inference_precision,
             "serve_buckets": self.serve_buckets,
             "tree_reuse": self.tree_reuse,
@@ -120,9 +111,7 @@ class Candidate:
         tags = [
             tag
             for tag, default in (
-                (f"g-{self.descent_gather}", "g-einsum"),
                 (f"b-{self.backup_update}", "b-xla"),
-                (f"s-{self.per_sample}", "s-xla"),
                 (f"p-{self.inference_precision}", "p-float32"),
                 (f"sb-{self.serve_buckets}", "sb-"),
                 (f"r-{'on' if self.tree_reuse else 'off'}", "r-off"),
@@ -144,12 +133,10 @@ class SearchSpace:
     chunks: list = field(default_factory=lambda: [8, 16])
     fused_ks: list = field(default_factory=lambda: [8, 16])
     dps: list = field(default_factory=lambda: [1])
-    # Kernel axes (docs/KERNELS.md). Single-valued by default, so the
-    # lattice only grows when a caller opts into the comparison; axes
-    # sharing an oracle_key reuse the same feasibility answer.
-    descent_gathers: list = field(default_factory=lambda: ["einsum"])
+    # Backup, precision, ladder and reuse axes. Single-valued by
+    # default, so the lattice only grows when a caller opts into the
+    # comparison; axes sharing an oracle_key reuse the same answer.
     backup_updates: list = field(default_factory=lambda: ["xla"])
-    per_samples: list = field(default_factory=lambda: ["xla"])
     precisions: list = field(default_factory=lambda: ["float32"])
     # Serve-shape ladders ("" = fixed single rung; "64,256,1024" =
     # the micro-batcher's rung set). Free axis for the oracle.
@@ -160,10 +147,8 @@ class SearchSpace:
         """Every lattice point, B descending within each group so the
         dominance walk can early-exit on the first feasible lane count."""
         kernel_points = [
-            (g, bu, ps, pr, sb, tr)
-            for g in self.descent_gathers
+            (bu, pr, sb, tr)
             for bu in self.backup_updates
-            for ps in self.per_samples
             for pr in self.precisions
             for sb in self.serve_bucket_ladders
             for tr in self.tree_reuses
@@ -174,14 +159,7 @@ class SearchSpace:
                 for chunk in sorted({int(t) for t in self.chunks}):
                     for k in sorted({int(k) for k in self.fused_ks}):
                         for dp in sorted({int(d) for d in self.dps}):
-                            for (
-                                gather,
-                                backup,
-                                sample,
-                                prec,
-                                buckets,
-                                reuse,
-                            ) in kernel_points:
+                            for backup, prec, buckets, reuse in kernel_points:
                                 for b in sorted(
                                     {int(b) for b in self.batches},
                                     reverse=True,
@@ -194,9 +172,7 @@ class SearchSpace:
                                             chunk=chunk,
                                             fused_k=k,
                                             dp=dp,
-                                            descent_gather=gather,
                                             backup_update=backup,
-                                            per_sample=sample,
                                             inference_precision=prec,
                                             serve_buckets=buckets,
                                             tree_reuse=reuse,
@@ -212,9 +188,7 @@ class SearchSpace:
             * len({int(t) for t in self.chunks})
             * len({int(k) for k in self.fused_ks})
             * len({int(d) for d in self.dps})
-            * len(self.descent_gathers)
             * len(self.backup_updates)
-            * len(self.per_samples)
             * len(self.precisions)
             * len(self.serve_bucket_ladders)
             * len(self.tree_reuses)

@@ -2827,7 +2827,6 @@ def cmd_tune(args: argparse.Namespace) -> int:
         fused_ks=fused_ks,
         dps=dps,
         backup_updates=kernel_backends,
-        per_samples=kernel_backends,
         precisions=precisions,
         serve_bucket_ladders=serve_ladders,
         tree_reuses=tree_reuses,
@@ -3814,10 +3813,10 @@ def main(argv: list[str] | None = None) -> int:
         "--kernel-backends",
         default=None,
         metavar="BACKENDS",
-        help="Kernel lowerings to search for backup_update and "
-        "PER_SAMPLE_BACKEND (comma-separated from xla,pallas — "
-        "docs/KERNELS.md). Free axes: memory-neutral variants share "
-        "oracle results. Default: xla only.",
+        help="Lowerings to search for the wave backup, MCTSConfig."
+        "backup_update (comma-separated from xla,pallas — "
+        "docs/KERNELS.md). A free axis: both variants share oracle "
+        "results. Default: xla only.",
     )
     tune.add_argument(
         "--precisions",

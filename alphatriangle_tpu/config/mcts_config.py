@@ -36,11 +36,6 @@ class AlphaTriangleMCTSConfig(BaseModel):
     # then collapse onto one leaf; the duplicate shows up in
     # `SearchOutput.wasted_slots`).
     wave_noise_scale: float = Field(default=0.25, ge=0)
-    # How descent reads tree rows: "einsum" (one-hot matmul on the
-    # MXU), "pallas" (custom VMEM row-copy kernel, ops/gather_rows.py),
-    # or "take" (XLA native gather). Numerically identical; a pure
-    # performance knob to be settled by on-hardware benchmarks.
-    descent_gather: str = Field(default="einsum", pattern="^(einsum|pallas|take)$")
     # How the wave's insertion + discounted backup writes the edge
     # planes: "xla" (the original scatter chain) or "pallas" (one fused
     # per-game VMEM kernel, ops/mcts_backup.py). Parity-pinned; a pure
@@ -60,11 +55,6 @@ class AlphaTriangleMCTSConfig(BaseModel):
     # fresh root evaluation; only edge statistics and interior priors
     # are carried.
     tree_reuse: bool = Field(default=False)
-    # How the promotion pass reorders the (B, N, A) edge planes:
-    # "xla" (take_along_axis gathers) or "pallas" (one fused per-game
-    # VMEM row-reorder kernel). Pure copies of identical values, so the
-    # two are bit-identical by construction; parity-pinned anyway.
-    tree_reuse_backend: str = Field(default="xla", pattern="^(xla|pallas)$")
     # Max nodes retained across a move (root + interior), excluding the
     # +1 root slot. None -> max_simulations (retain up to a full
     # search's worth of subtree).

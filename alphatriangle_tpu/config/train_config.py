@@ -170,12 +170,6 @@ class TrainConfig(BaseModel):
     PER_BETA_FINAL: float = Field(default=1.0, ge=0, le=1.0)
     PER_BETA_ANNEAL_STEPS: int | None = Field(default=None)
     PER_EPSILON: float = Field(default=1e-5, gt=0)
-    # How the on-device stratified PER draw locates its cumsum indices:
-    # "xla" (searchsorted) or "pallas" (tiled compare-count kernel,
-    # ops/per_sample.py). Bit-identical selections (exact float
-    # compares over a shared prefix-sum); a pure performance knob to
-    # be settled by on-hardware benchmarks.
-    PER_SAMPLE_BACKEND: str = Field(default="xla", pattern="^(xla|pallas)$")
 
     # --- Temperature schedule for action selection (move-indexed) ---
     TEMPERATURE_INITIAL: float = Field(default=1.0, ge=0)

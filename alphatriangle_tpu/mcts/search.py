@@ -352,9 +352,8 @@ class BatchedMCTS:
 
         def level(d, carry):
             node, action, stop, rec_node, rec_action, rec_reward, rec_active = carry
-            # (B, W, 6A) exact row select; lowering per config (one-hot
-            # MXU matmul / Pallas VMEM copy / XLA gather).
-            rows = gather_rows(stats, node, mode=cfg.descent_gather)
+            # (B, W, 6A) exact row select (one-hot MXU matmul).
+            rows = gather_rows(stats, node)
             visits_r = rows[..., 0 * a : 1 * a]
             value_r = rows[..., 1 * a : 2 * a]
             reward_r = rows[..., 2 * a : 3 * a]
@@ -788,10 +787,9 @@ class BatchedMCTS:
     @jax.named_scope("rollout/promote")
     def promote(self, tree: Tree, actions: jax.Array) -> CarriedTree:
         """Batched root promotion: compact each game's chosen child's
-        subtree into the leading rows (ops/subtree_reuse.py; lowering
-        per `tree_reuse_backend`). `valid` is False where the chosen
-        child was never expanded; callers additionally clear lanes on
-        episode reset / churn."""
+        subtree into the leading rows (ops/subtree_reuse.py). `valid` is
+        False where the chosen child was never expanded; callers
+        additionally clear lanes on episode reset / churn."""
         cfg = self.config
         (
             e_visits, e_value, e_reward, children, prior, valid,
@@ -807,7 +805,6 @@ class BatchedMCTS:
             actions.astype(jnp.int32),
             max_retained=self.reuse_slots,
             bfs_rounds=cfg.max_depth,
-            mode=cfg.tree_reuse_backend,
         )
         batch = actions.shape[0]
         bcol = jnp.arange(batch)[:, None]

@@ -1,12 +1,15 @@
-"""Custom TPU ops (Pallas kernels beside their XLA lowerings).
+"""Custom TPU ops: Pallas kernels beside the lowerings they replace.
 
-The four ops exported here follow one pattern (docs/KERNELS.md): a
-Pallas TPU lowering plus interchangeable XLA lowerings, numerically
-pinned against each other by parity tests, with a config knob selecting
-the backend. `encoder_layer.py` and `delta_rule.py` have no knob:
-`nn/model.py` runs an encoder layer as that kernel or as Flax's modules,
-and `nn/trunk.py` a linear-attention layer's recurrence as that kernel
-or as `nn/linear_attention.chunked`, by what the call can observe.
+Each kernel of this package (`encoder_layer`, `delta_rule`,
+`gated_delta_rule`, `state_space_scan`, `grouped_experts`; docs/KERNELS.md)
+sits beside the XLA or Flax lowering it replaces, and the call picks
+one by what it can observe: the backend, the mesh of the trace, the
+widths, `train`. The four ops exported here are the search's and the
+replay programs' own: the descent's row gather, the stratified PER draw
+and the subtree promotion are one XLA lowering each; the wave's backup
+still has a Pallas lowering beside its XLA chain that a config field
+(`MCTSConfig.backup_update`) chooses, until the search picks it by
+observation too (ROADMAP S3).
 """
 
 from .gather_rows import gather_rows

@@ -20,17 +20,12 @@ planes — no dynamic shapes, no host round trip:
    — the slot is simply re-expandable). Parent-before-child ranking
    makes the truncation frontier consistent: a kept node's parent is
    always kept.
-3. **Row reorder** (the backend split): the six f32 edge planes are
-   gathered into BFS-rank order with freed rows re-zeroed (children
-   rows to -1). Two lowerings — `"xla"` (`take_along_axis` gathers)
-   and `"pallas"` (one fused per-game kernel that streams the planes
-   through VMEM once, emitting all six in a single pass). Both are
-   pure copies of identical values, so they are bit-identical by
-   construction; parity tests pin them anyway (tests/test_ops.py).
+3. **Row reorder**: the six f32 edge planes are gathered into BFS-rank
+   order (`take_along_axis`) with freed rows re-zeroed (children rows
+   to -1).
 
-`MCTSConfig.tree_reuse_backend` selects the lowering. The caller
-(`mcts/search.py`) re-seats root statistics by construction — the
-promoted row 0 *is* the chosen child's edge row — and re-applies
+The caller (`mcts/search.py`) re-seats root statistics by construction
+— the promoted row 0 *is* the chosen child's edge row — and re-applies
 fresh root priors + Dirichlet noise on the next search's init.
 
 Shapes: planes `(B, N, A)` f32, `terminal` `(B, N)` bool, `actions`
@@ -43,15 +38,8 @@ the chosen child was never expanded — nothing to reuse) and
 insertion base).
 """
 
-import functools
-
 import jax
 import jax.numpy as jnp
-
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
-
-from ._vmem import f32_block_bytes, vmem_params
 
 
 def _promotion_plan(
@@ -128,7 +116,7 @@ def _promotion_plan(
     return order, state_index, keep_mask, new_children, promo_valid, retained
 
 
-def _reorder_planes_xla(order, keep_mask, planes, fills):
+def _reorder_planes(order, keep_mask, planes, fills):
     """out[b, r] = planes[b, order[b, r]] where keep, else fill."""
     idx = jnp.where(keep_mask, order, 0)[:, :, None]
     out = []
@@ -136,94 +124,6 @@ def _reorder_planes_xla(order, keep_mask, planes, fills):
         gathered = jnp.take_along_axis(plane, idx, axis=1)
         out.append(jnp.where(keep_mask[:, :, None], gathered, fill))
     return tuple(out)
-
-
-def _promote_kernel(
-    order_ref,
-    retained_ref,
-    v_ref,
-    q_ref,
-    r_ref,
-    c_ref,
-    p_ref,
-    m_ref,
-    ov_ref,
-    oq_ref,
-    or_ref,
-    oc_ref,
-    op_ref,
-    om_ref,
-):
-    """One grid program per game: emit all six planes in BFS-rank order
-    in a single VMEM pass; rows past `retained` are the zeroed frees
-    (children rows -1)."""
-    n = v_ref.shape[1]
-    ret = retained_ref[0, 0, 0]
-
-    def row(r, _):
-        src = order_ref[0, 0, r]
-        take = r < ret
-        ov_ref[0, pl.ds(r, 1), :] = jnp.where(
-            take, v_ref[0, pl.ds(src, 1), :], 0.0
-        )
-        oq_ref[0, pl.ds(r, 1), :] = jnp.where(
-            take, q_ref[0, pl.ds(src, 1), :], 0.0
-        )
-        or_ref[0, pl.ds(r, 1), :] = jnp.where(
-            take, r_ref[0, pl.ds(src, 1), :], 0.0
-        )
-        oc_ref[0, pl.ds(r, 1), :] = jnp.where(
-            take, c_ref[0, pl.ds(src, 1), :], -1.0
-        )
-        op_ref[0, pl.ds(r, 1), :] = jnp.where(
-            take, p_ref[0, pl.ds(src, 1), :], 0.0
-        )
-        om_ref[0, pl.ds(r, 1), :] = jnp.where(
-            take, m_ref[0, pl.ds(src, 1), :], 0.0
-        )
-        return 0
-
-    jax.lax.fori_loop(0, n, row, 0)
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _reorder_planes_pallas(
-    order, retained, e_visits, e_value, e_reward, children, prior, valid,
-    interpret: bool = False,
-):
-    """Fused per-game row reorder of the six edge planes (VMEM)."""
-    b, n, a = e_visits.shape
-    # (B, 1, N) / (B, 1, 1): the TPU lowering wants a block's last two
-    # dims to be whole array dims (or multiples of 8 x 128).
-    smem_order = pl.BlockSpec(
-        (1, 1, n), lambda i: (i, 0, 0), memory_space=pltpu.SMEM
-    )
-    smem_ret = pl.BlockSpec(
-        (1, 1, 1), lambda i: (i, 0, 0), memory_space=pltpu.SMEM
-    )
-    vmem_plane = pl.BlockSpec(
-        (1, n, a), lambda i: (i, 0, 0), memory_space=pltpu.VMEM
-    )
-    plane = jax.ShapeDtypeStruct((b, n, a), jnp.float32)
-    return pl.pallas_call(
-        _promote_kernel,
-        grid=(b,),
-        in_specs=[smem_order, smem_ret] + [vmem_plane] * 6,
-        out_specs=(vmem_plane,) * 6,
-        out_shape=(plane,) * 6,
-        compiler_params=vmem_params(12 * f32_block_bytes(n, a)),
-        interpret=interpret,
-        name="subtree_promote",
-    )(
-        order.astype(jnp.int32).reshape(b, 1, n),
-        retained.astype(jnp.int32).reshape(b, 1, 1),
-        e_visits,
-        e_value,
-        e_reward,
-        children,
-        prior,
-        valid,
-    )
 
 
 def subtree_promote(
@@ -237,14 +137,13 @@ def subtree_promote(
     actions: jax.Array,
     max_retained: int,
     bfs_rounds: int,
-    mode: str = "xla",
 ) -> tuple[
     jax.Array, jax.Array, jax.Array, jax.Array, jax.Array, jax.Array,
     jax.Array, jax.Array, jax.Array, jax.Array,
 ]:
     """Promote each game's chosen child to the root row (see module doc).
 
-    Dispatch by mode ("xla" | "pallas"). Returns
+    Returns
     `(e_visits, e_value, e_reward, children, prior, valid, terminal,
     state_index, promo_valid, retained)` — the six planes + terminal in
     BFS-rank layout with freed rows zeroed, plus the node_state gather
@@ -254,20 +153,10 @@ def subtree_promote(
         _promotion_plan(children, actions, max_retained, bfs_rounds)
     )
     planes = (e_visits, e_value, e_reward, new_children, prior, valid)
-    if mode == "xla":
-        out = _reorder_planes_xla(
-            order, keep_mask, planes, (0.0, 0.0, 0.0, -1.0, 0.0, 0.0)
-        )
-    elif mode == "pallas":
-        # Compiled on a TPU backend, interpreted everywhere else (CPU
-        # tests).
-        interpret = jax.default_backend() != "tpu"
-        out = _reorder_planes_pallas(
-            order, retained, *planes, interpret=interpret
-        )
-    else:
-        raise ValueError(f"unknown subtree_promote mode: {mode!r}")
-    # terminal is bool (and cheap): shared XLA epilogue for both modes.
+    out = _reorder_planes(
+        order, keep_mask, planes, (0.0, 0.0, 0.0, -1.0, 0.0, 0.0)
+    )
+    # terminal is bool: gathered the same way, freed rows False.
     term = jnp.take_along_axis(
         terminal, jnp.where(keep_mask, order, 0), axis=1
     )

@@ -186,7 +186,6 @@ class MegastepRunner:
         self.beta_initial = float(train_config.PER_BETA_INITIAL)
         self.beta_final = float(train_config.PER_BETA_FINAL)
         self.beta_anneal = float(train_config.PER_BETA_ANNEAL_STEPS or 1)
-        self.per_sample_backend = train_config.PER_SAMPLE_BACKEND
         # Device-resident priority array — the sampling truth inside
         # the program. Single-device: (cap + 1,) float32, the +1 the
         # trash slot pinned at priority 0 so it is never sampled.
@@ -253,9 +252,8 @@ class MegastepRunner:
         """On-device (K, B) slot sampling + IS weights.
 
         PER: stratified proportional sampling over the priority array
-        (ops/per_sample.py; `TrainConfig.PER_SAMPLE_BACKEND` picks the
-        searchsorted or Pallas compare-count lowering) — the vectorized
-        equivalent of the host SumTree's stratified descent
+        (ops/per_sample.py, a searchsorted over the cumsum) — the
+        vectorized equivalent of the host SumTree's stratified descent
         (utils/sumtree.py). Zero-priority (empty/trash) slots are never
         selected: their cumsum segments are empty. Uniform:
         floor(u * size).
@@ -264,14 +262,7 @@ class MegastepRunner:
         rng, k_sample = jax.random.split(state.rng)
         state = state.replace(rng=rng)
         if self.use_per:
-            idx, probs = per_sample(
-                priorities,
-                self.cap,
-                k,
-                b,
-                k_sample,
-                mode=self.per_sample_backend,
-            )
+            idx, probs = per_sample(priorities, self.cap, k, b, k_sample)
             # Beta annealed on the learner-step clock, exactly as the
             # host mirror's `ExperienceBuffer.beta` computes it.
             frac = jnp.clip(

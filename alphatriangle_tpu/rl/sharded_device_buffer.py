@@ -87,7 +87,6 @@ class ShardedDeviceReplayBuffer(ExperienceBuffer):
         self.dp = dp
         self.cap_local = self.capacity // dp
         self.stride = self.cap_local + 1  # + per-shard trash row
-        self.per_sample_backend = config.PER_SAMPLE_BACKEND
         self._grid_shape = grid_shape
         self._other_dim = other_dim
 
@@ -286,9 +285,8 @@ class ShardedDeviceReplayBuffer(ExperienceBuffer):
     ):
         """One shard's stratified (K, b_local) slot sampling inside an
         enclosing `shard_map` body. PER: the shared stratified draw over
-        the shard's own priority slice (ops/per_sample.py;
-        `TrainConfig.PER_SAMPLE_BACKEND` picks the searchsorted or
-        Pallas compare-count lowering) — the vectorized equivalent of
+        the shard's own priority slice (ops/per_sample.py, a
+        searchsorted over the cumsum) — the vectorized equivalent of
         this shard's SumTree descent (utils/sumtree.py); zero-priority
         (empty/trash) slots have empty cumsum segments and are never
         selected. IS weights come back UNNORMALIZED — the caller
@@ -300,12 +298,7 @@ class ShardedDeviceReplayBuffer(ExperienceBuffer):
         size_f = size.astype(jnp.float32)
         if self.use_per:
             idx, probs = per_sample(
-                priorities_local,
-                self.cap_local,
-                k,
-                b_local,
-                key,
-                mode=self.per_sample_backend,
+                priorities_local, self.cap_local, k, b_local, key
             )
             weights = (size_f * probs) ** (-beta)
         else:

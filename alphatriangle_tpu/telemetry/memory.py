@@ -518,42 +518,40 @@ def estimate_fit(
             location="device" if device_replay else "host",
         )
     )
-    if getattr(mcts_config, "descent_gather", "einsum") == "einsum":
-        # The einsum descent gather materializes a (B, W, N) f32
-        # one-hot every level (mcts/search.py `_descend_wave`,
-        # ops/gather_rows.py). XLA's memory analysis can fuse that
-        # temp out of the reported footprint entirely (CPU analyses
-        # often report temp=0), so the composed transient silently
-        # undercounted the rollout program. This analytic record
-        # floors the budget with the one-hot bytes; when the
-        # program-reported peak is larger it still wins (max over
-        # records in `compose_budget`). The "pallas"/"take" gathers
-        # never build the one-hot, so no floor applies there.
-        wave = max(
-            1,
-            min(mcts_config.mcts_batch_size, mcts_config.max_simulations),
-        )
-        while mcts_config.max_simulations % wave:
-            wave -= 1
-        onehot_bytes = (
-            4
-            * train_config.SELF_PLAY_BATCH_SIZE
-            * wave
-            * (mcts_config.max_simulations + 1)
-        )
-        records.append(
-            {
-                "kind": MEMORY_KIND,
-                "category": "program",
-                "component": "program/descent_gather_onehot",
-                "program": "descent_gather_onehot",
-                "origin": "analytic",
-                "bytes": {"temp": onehot_bytes},
-                "total": onehot_bytes,
-                "transient": onehot_bytes,
-                "time": time.time(),
-            }
-        )
+    # The einsum descent gather materializes a (B, W, N) f32
+    # one-hot every level (mcts/search.py `_descend_wave`,
+    # ops/gather_rows.py). XLA's memory analysis can fuse that
+    # temp out of the reported footprint entirely (CPU analyses
+    # often report temp=0), so the composed transient silently
+    # undercounted the rollout program. This analytic record
+    # floors the budget with the one-hot bytes; when the
+    # program-reported peak is larger it still wins (max over
+    # records in `compose_budget`).
+    wave = max(
+        1,
+        min(mcts_config.mcts_batch_size, mcts_config.max_simulations),
+    )
+    while mcts_config.max_simulations % wave:
+        wave -= 1
+    onehot_bytes = (
+        4
+        * train_config.SELF_PLAY_BATCH_SIZE
+        * wave
+        * (mcts_config.max_simulations + 1)
+    )
+    records.append(
+        {
+            "kind": MEMORY_KIND,
+            "category": "program",
+            "component": "program/descent_gather_onehot",
+            "program": "descent_gather_onehot",
+            "origin": "analytic",
+            "bytes": {"temp": onehot_bytes},
+            "total": onehot_bytes,
+            "transient": onehot_bytes,
+            "time": time.time(),
+        }
+    )
     chunk = train_config.ROLLOUT_CHUNK_MOVES
     lbatch = train_config.BATCH_SIZE
     targets = [

@@ -126,8 +126,7 @@ def model_step_flops(
 
 def gather_einsum_flops(batch: int, wave: int, nodes: int, width: int) -> int:
     """FLOPs of ONE einsum descent row-gather (`ops/gather_rows.py`):
-    (B, W, N) one-hot x (B, N, K). The take/pallas lowerings do the
-    same row select with zero matmul FLOPs."""
+    (B, W, N) one-hot x (B, N, K)."""
     return 2 * batch * wave * nodes * width
 
 

@@ -134,13 +134,12 @@ def kernel_shapes(
     gdn: "dict | None" = None,
     experts: "dict | None" = None,
 ) -> dict:
-    """The operand shapes the eight kernels see under `cfgs`: B games, a
-    tree of N nodes x A actions searched W leaves at a time to depth D,
-    a ring of `capacity` rows sampled k x b at a time, an encoder
-    layer over the leaf wave of a fast search (of a full one where the
-    playout cap is off): `leaves` boards of `tokens` cells, `dim` wide;
-    and `recurrence`, `gdn` and `experts`, which no preset sizes
-    (`RECURRENCE`, `GDN_RECURRENCE`, `EXPERTS`)."""
+    """The operand shapes the kernels see under `cfgs`: B games, a tree
+    of N nodes x A actions searched W leaves at a time to depth D, an
+    encoder layer over the leaf wave of a fast search (of a full one
+    where the playout cap is off): `leaves` boards of `tokens` cells,
+    `dim` wide; and `recurrence`, `gdn` and `experts`, which no preset
+    sizes (`RECURRENCE`, `GDN_RECURRENCE`, `EXPERTS`)."""
     from alphatriangle_tpu.mcts.search import tree_geometry
 
     mcts, train, model = cfgs["mcts"], cfgs["train"], cfgs["model"]
@@ -153,18 +152,12 @@ def kernel_shapes(
             }
         )
     )
-    # The carried tree of MCTSConfig.tree_reuse at this budget.
-    reuse_nodes, _ = tree_geometry(mcts.model_copy(update={"tree_reuse": True}))
     return {
         "batch": train.SELF_PLAY_BATCH_SIZE,
         "nodes": nodes,
-        "reuse_nodes": reuse_nodes,
         "wave": wave,
         "actions": cfgs["env"].action_dim,
         "depth": mcts.max_depth,
-        "capacity": train.BUFFER_CAPACITY,
-        "learner_steps": train.FUSED_LEARNER_STEPS,
-        "batch_size": train.BATCH_SIZE,
         "leaves": train.SELF_PLAY_BATCH_SIZE * fast_wave,
         "tokens": cfgs["env"].ROWS * cfgs["env"].COLS,
         "dim": model.TRANSFORMER_DIM,
@@ -179,29 +172,24 @@ def kernel_shapes(
 
 
 def kernel_cases(shapes: dict) -> list[dict]:
-    """One case per kernel of ops/: `run(mode, *operands)` calls its
-    dispatcher, `operands(key)` makes seeded inputs at `shapes` (valid
-    node/action indices, a real forest for the promotion), `xla` names
-    the reference lowering. docs/KERNELS.md: the first four are exact;
-    `encoder_layer` rounds where Flax's layer rounds (`tolerance`) and
-    is `timed` beside it; `delta_rule` and `gated_delta_net` are held
-    to the token-by-token recurrence, and the chunked form each replaces
-    on a TPU (`beside`) is held to it and timed too; `grouped_experts`
-    is held to and timed beside the `ragged_dot` chain it replaces."""
-    # gather_rows is held to "take", a pure copy. Whether the default
-    # one-hot einsum is exact on the MXU too is reported, not required.
+    """One case for each of five Pallas kernels of ops/: `run(mode,
+    *operands)` calls it or the lowering named by `mode`, `operands(key)`
+    makes seeded inputs at `shapes` (valid node/action indices), `xla`
+    names the reference lowering (docs/KERNELS.md). `backup_update`
+    matches its XLA chain but for the order of duplicate sums
+    (`rounding`); `encoder_layer` rounds where Flax's layer rounds
+    (`tolerance`) and is `timed` beside it; `delta_rule` and
+    `gated_delta_net` are held to the token-by-token recurrence, and
+    the chunked form each replaces on a TPU (`beside`) is held to it
+    and timed too; `grouped_experts` is held to and timed beside the
+    `ragged_dot` chain it replaces."""
     import jax
     import jax.numpy as jnp
 
     from alphatriangle_tpu.nn import linear_attention
     from alphatriangle_tpu.nn.model import _ACTIVATIONS, TransformerEncoderLayer
     from alphatriangle_tpu.nn.trunk import ragged_experts
-    from alphatriangle_tpu.ops import (
-        backup_update,
-        gather_rows,
-        per_sample,
-        subtree_promote,
-    )
+    from alphatriangle_tpu.ops import backup_update
     from alphatriangle_tpu.ops.delta_rule import gated_delta_rule
     from alphatriangle_tpu.ops.encoder_layer import encoder_layer
     from alphatriangle_tpu.ops.gated_delta_rule import gated_delta_net
@@ -209,17 +197,6 @@ def kernel_cases(shapes: dict) -> list[dict]:
 
     b, n, w = shapes["batch"], shapes["nodes"], shapes["wave"]
     a, d = shapes["actions"], shapes["depth"]
-    rn = shapes["reuse_nodes"]
-    cap, k, bs = (
-        shapes["capacity"], shapes["learner_steps"], shapes["batch_size"]
-    )
-
-    def gather_operands(key):
-        ks = jax.random.split(key, 2)
-        return (
-            jax.random.normal(ks[0], (b, n, 6 * a)),
-            jax.random.randint(ks[1], (b, w), 0, n),
-        )
 
     def backup_operands(key):
         ks = jax.random.split(key, 12)
@@ -250,41 +227,6 @@ def kernel_cases(shapes: dict) -> list[dict]:
             jax.random.randint(ks[10], (b, w, d), -1, min(a, 8)),
             jax.random.bernoulli(ks[11], 0.7, (b, w, d)),
             jax.random.normal(ks[3], (b, w, d)),
-        )
-
-    def per_operands(key):
-        ks = jax.random.split(key, 3)
-        live = jax.random.bernoulli(ks[0], 0.9, (cap,))
-        return (
-            jnp.where(live, jax.random.uniform(ks[1], (cap,)) + 1e-3, 0.0),
-            ks[2],
-        )
-
-    def promote_operands(key):
-        ks = jax.random.split(key, 9)
-        # A forest: node i hangs under a random earlier node by a random
-        # action; a slot taken twice leaves the loser an orphan.
-        ids = jnp.arange(1, rn)
-        parent = (jax.random.uniform(ks[0], (b, rn - 1)) * ids).astype(
-            jnp.int32
-        )
-        action = jax.random.randint(ks[1], (b, rn - 1), 0, a)
-        children = (
-            jnp.full((b, rn, a), -1.0)
-            .at[jnp.arange(b)[:, None], parent, action]
-            .max(ids.astype(jnp.float32))
-        )
-        planes = [jax.random.uniform(ks[2 + i], (b, rn, a)) for i in range(5)]
-        planes.insert(3, children)
-        # Half the games promote an expanded root child, half may not.
-        root_actions = jnp.where(
-            jnp.arange(b) % 2 == 0,
-            action[:, 0],
-            jax.random.randint(ks[7], (b,), 0, a),
-        )
-        return tuple(planes) + (
-            jax.random.bernoulli(ks[8], 0.2, (b, rn)),
-            root_actions,
         )
 
     act = _ACTIVATIONS[shapes["activation"]]
@@ -465,21 +407,7 @@ def kernel_cases(shapes: dict) -> list[dict]:
             x, sizes, up, down, gate, limit=experts["limit"], dtype=jnp.bfloat16
         )
 
-    def run_per(mode, priorities, key):
-        return per_sample(priorities, cap, k, bs, key, mode=mode)
-
-    def run_promote(mode, *operands):
-        return subtree_promote(
-            *operands, max_retained=rn // 2, bfs_rounds=d, mode=mode
-        )
-
     return [
-        {
-            "name": "gather_rows",
-            "xla": "take",
-            "run": lambda mode, *ops: gather_rows(*ops, mode=mode),
-            "operands": gather_operands,
-        },
         {
             "name": "backup_update",
             "xla": "xla",
@@ -490,18 +418,6 @@ def kernel_cases(shapes: dict) -> list[dict]:
             # order the TPU's scatter-add takes duplicates is XLA's
             # affair, so the sums agree to f32 rounding, not to the bit.
             "rounding": {1},
-        },
-        {
-            "name": "per_sample",
-            "xla": "xla",
-            "run": run_per,
-            "operands": per_operands,
-        },
-        {
-            "name": "subtree_promote",
-            "xla": "xla",
-            "run": run_promote,
-            "operands": promote_operands,
         },
         {
             "name": "encoder_layer",
@@ -972,8 +888,6 @@ def phase_kernels(
     for i, case in enumerate(kernel_cases(shapes)):
         t0 = time.monotonic()
         operands = jax.jit(case["operands"])(jax.random.PRNGKey(SEED + i))
-        if case["name"] == "gather_rows":
-            stats, idx = operands
         compiled = (
             jax.jit(functools.partial(case["run"], "pallas"))
             .lower(*operands)
@@ -1024,18 +938,6 @@ def phase_kernels(
                 beside: _device_ops_ms(timed, operands, top=16),
             }
     _check(not wrong, f"kernels: {wrong}; parity so far: {parity}")
-    from alphatriangle_tpu.ops import gather_rows
-
-    parity["gather_rows"]["einsum_vs_take"] = (
-        "exact"
-        if np.array_equal(
-            np.asarray(jax.jit(gather_rows)(stats, idx)),
-            np.asarray(
-                jax.jit(functools.partial(gather_rows, mode="take"))(stats, idx)
-            ),
-        )
-        else "differs"
-    )
     return {"shapes": shapes, "compiled": on_tpu, "parity": parity}
 
 

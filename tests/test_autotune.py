@@ -433,6 +433,44 @@ class TestTunedPresetArtifact:
         )
         assert bundle["tuned"]["candidate"]["sp_batch"] == 8
 
+    @pytest.mark.parametrize(
+        "gather,backend", [("einsum", "xla"), ("pallas", "pallas"), ("take", "xla")]
+    )
+    def test_a_preset_tuned_before_the_one_lowering_still_loads(
+        self,
+        tmp_path,
+        tiny_env_config,
+        tiny_model_config,
+        tiny_mcts_config,
+        gather,
+        backend,
+    ):
+        """Artifacts written while the descent's gather, the subtree
+        promotion and the PER draw had lowerings chosen by config fields
+        (`descent_gather`, `tree_reuse_backend`, `PER_SAMPLE_BACKEND`,
+        and two of them in the `kernels` block) load as the same run:
+        the keys are ignored."""
+        result, env, model, train = self._result_and_configs(
+            tiny_env_config, tiny_model_config, tiny_mcts_config
+        )
+        payload = build_tuned_preset(
+            result, env, model, tiny_mcts_config, train, scale="cpu",
+            mode="sync", backend="cpu", device_kind="cpu", limit_bytes=10**9,
+            limit_source="flag", calibration=Calibration(), run_name="old",
+        )
+        payload["configs"]["mcts"].update(
+            descent_gather=gather, tree_reuse_backend=backend
+        )
+        payload["configs"]["train"]["PER_SAMPLE_BACKEND"] = backend
+        payload["kernels"].update(descent_gather=gather, per_sample=backend)
+        bundle = load_tuned_preset(
+            write_tuned_preset(payload, tmp_path / "tuned_preset.json")
+        )
+        assert bundle["mcts"] == tiny_mcts_config
+        assert bundle["train"] == train
+        assert "descent_gather" not in bundle["mcts"].model_dump()
+        assert "PER_SAMPLE_BACKEND" not in bundle["train"].model_dump()
+
     def test_schema_mismatch_is_a_clear_error(self, tmp_path):
         path = tmp_path / "tuned_preset.json"
         path.write_text(

@@ -4,11 +4,11 @@ libtpu compiles for a chip that is described and not attached
 (`jax.experimental.topologies`). Interpret-mode tests (tests/test_ops.py)
 pin what the kernels compute; they cannot see what the compiler refuses
 — a block that is not a whole (8, 128) tile, a scalar operand laid out
-for VMEM, more VMEM than the scoped limit. The first four kernels had
-passed every interpret-mode test and none compiled. These cases hold the
+for VMEM, more VMEM than the scoped limit. These cases hold the
 compiled-for-v5e property at the widths the chip runs: preset 3 (the
 flagship, what `chip_smoke.py` executes on the chip) and preset 4's
-400-simulation tree (the largest planes).
+400-simulation tree (the largest planes); and the search's and the
+replay programs' own ops as XLA lowers them, at the same two.
 
 A compile that passes here is not a chip run: nothing executes.
 """
@@ -26,8 +26,8 @@ import chip_smoke  # noqa: E402
 from alphatriangle_tpu.config.presets import baseline_preset  # noqa: E402
 
 KERNELS = (
-    "gather_rows", "backup_update", "per_sample", "subtree_promote",
-    "encoder_layer", "delta_rule", "gated_delta_net", "grouped_experts",
+    "backup_update", "encoder_layer", "delta_rule", "gated_delta_net",
+    "grouped_experts",
 )
 
 
@@ -111,6 +111,75 @@ def test_kernel_compiles_for_v5e(
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     text = _compiled_text(cases[preset][kernel], one_v5e_chip)
     assert "tpu_custom_call" in text
+
+
+def _xla_op(name: str, preset: int, sharding):
+    """(the op at `preset`'s shapes, its operands as described avals):
+    B lanes of trees of N nodes x A actions searched W leaves at a time
+    to depth D, the carried tree of `tree_reuse` at that budget, a ring
+    of `capacity` rows sampled K x batch at a time."""
+    import jax.numpy as jnp
+
+    from alphatriangle_tpu.mcts.search import tree_geometry
+    from alphatriangle_tpu.ops import (
+        backup_update,
+        gather_rows,
+        per_sample,
+        subtree_promote,
+    )
+
+    cfgs = baseline_preset(preset)
+    mcts, train = cfgs["mcts"], cfgs["train"]
+    b, a, d = train.SELF_PLAY_BATCH_SIZE, cfgs["env"].action_dim, mcts.max_depth
+    n, w = tree_geometry(mcts)
+    reuse_n, _ = tree_geometry(mcts.model_copy(update={"tree_reuse": True}))
+
+    def aval(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    wave = (aval((b, w), jnp.int32),) * 2 + (aval((b, w)),) * 2
+    record = (aval((b, w, d), jnp.int32),) * 2 + (
+        aval((b, w, d), jnp.bool_), aval((b, w, d)),
+    )
+    cap, k, batch = train.BUFFER_CAPACITY, train.FUSED_LEARNER_STEPS, train.BATCH_SIZE
+    return {
+        "gather_rows": (gather_rows, (aval((b, n, 6 * a)), aval((b, w), jnp.int32))),
+        "backup_update": (backup_update, (aval((b, n, a)),) * 4 + wave + record),
+        "per_sample": (
+            lambda p, key: per_sample(p, cap, k, batch, key),
+            (aval((cap + 1,)), aval((2,), jnp.uint32)),
+        ),
+        "subtree_promote": (
+            functools.partial(subtree_promote, max_retained=reuse_n // 2, bfs_rounds=d),
+            (aval((b, reuse_n, a)),) * 6
+            + (aval((b, reuse_n), jnp.bool_), aval((b,), jnp.int32)),
+        ),
+    }[name]
+
+
+@pytest.mark.parametrize("preset", [3, 4])
+@pytest.mark.parametrize(
+    "op", ["gather_rows", "backup_update", "per_sample", "subtree_promote"]
+)
+def test_xla_op_compiles_for_v5e(op, preset, one_v5e_chip):
+    """Each of the search's and the replay programs' ops, as XLA lowers
+    it by default for the described chip at the preset's shapes (512
+    lanes): no Mosaic kernel, and what it reads, writes and keeps fits the chip's
+    16 GB (the carried tree's promotion at preset 4 needs 14.7 of them).
+    The descent's row gather rides the MXU: a one-hot product, no
+    gather."""
+    fn, operands = _xla_op(op, preset, one_v5e_chip)
+    compiled = jax.jit(fn).lower(*operands).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" not in text
+    memory = compiled.memory_analysis()
+    assert (
+        memory.argument_size_in_bytes
+        + memory.output_size_in_bytes
+        + memory.temp_size_in_bytes
+    ) < 16e9
+    if op == "gather_rows":
+        assert "convolution(" in text and " gather(" not in text
 
 
 def test_encoder_layer_compiles_at_252_tokens(one_v5e_chip, monkeypatch):
@@ -243,18 +312,61 @@ def test_lane_sharded_chunk_compiles_for_v5e(
     assert "encoder_attention" not in text
 
 
-def test_nemotron_supers_chunk_compiles_at_its_published_widths(
-    one_v5e_chip, monkeypatch
-):
-    """`nemotron-super-ep4`'s one-move chunk as the cell dispatches it
-    (16 lanes, waves of 32, the net in blocks of `block_boards` 64):
-    the described chip's compiler takes the state-space scan as this
-    repo's kernel (PR 39), the latent experts' grouped products and top
-    22 of 512 at their published widths, and the program fits: 9.03 GB
-    of weights and under 3 GB of temporaries (3.66 with the scan in
-    `jax.numpy`, 3.44 with its kernel and `ragged_dot`'s float32
-    products, 2.89 with the experts' kernels too; the ring's 0.85 GB
-    stands beside them on the chip)."""
+# A cell's one-move chunk as it dispatches it (16 lanes, waves of 32,
+# the net in blocks of `block_boards` 64) at its published widths: the
+# described chip's compiler takes the recurrence as this repo's kernel,
+# one custom call a layer wherever a block of boards is evaluated, each
+# the block's whole `(b, 252, 8192)` output, and the experts' rounds as
+# two calls a layer, each writing its round's rows in bfloat16 (`up` and
+# `down`: rows at blocks of 16 and 64 boards, width); and the program
+# fits (`weights`: its argument bytes; `temp_below`; the ring's 0.85 GB
+# stands beside them on the chip). `absent`: (pattern, what the line
+# must also hold) of what the kernels replaced.
+PUBLISHED_WIDTH_CHUNKS = {
+    # The state-space scan in five Mamba-2 layers; the latent
+    # experts' top 22 of 512 in five. Temporaries: 3.66 GB with the
+    # scan in `jax.numpy`, 3.44 with its kernel and `ragged_dot`'s
+    # float32 products, 2.89 with the experts' kernels too. `chunked`'s
+    # decays and weights `f32[64,2,128,128,128]` are gone, and no
+    # float32 grouped product is left.
+    "nemotron-super-ep4": {
+        "reference": "reference_nemotron_h",
+        "weights": (9.0e9, 9.1e9),
+        "temp_below": 3.0e9,
+        "kernel": "state_space_scan",
+        "layers": 5,
+        "up": ("44352|177408", 2688),
+        "down": ("44352|177408", 1024),
+        "absent": ((r"f32\[64,2,128,128,128\]", ""), (r"f32\[177408,2688\]", "")),
+    },
+    # The gated-delta-net recurrence in four layers; YaRN, the
+    # gate a channel and the sandwich norms. Temporaries: 3.84 GB with
+    # the recurrence's and the experts' kernels, 4.12 with `ragged_dot`'s
+    # float32 products instead, 3.90 with `chunked_scalar` and those,
+    # 2.25 at blocks of 32 then (PERF.md section 4). `chunked_scalar`'s
+    # pairwise decays and products `f32[4,2048,2,64,64]` are gone; the
+    # shared expert's float32 `[16128, 2048]` stays, the routed ones'
+    # does not.
+    "gigachat35-ep16": {
+        "reference": "reference_gigachat35",
+        "weights": (9.4e9, 9.5e9),
+        "temp_below": 3.9e9,
+        "kernel": "gated_delta_net",
+        "layers": 4,
+        "up": ("4032|16128", 2048),
+        "down": ("4032|16128", 7168),
+        "absent": (
+            (r"f32\[4,2048,2,64,64\]", ""),
+            (r"= f32\[16128,2048\]", "net/trunk/experts"),
+        ),
+    },
+}
+
+
+@pytest.mark.parametrize("config", sorted(PUBLISHED_WIDTH_CHUNKS))
+def test_the_chunk_compiles_at_its_published_widths(one_v5e_chip, monkeypatch, config):
+    import importlib
+
     import jax.numpy as jnp
 
     from alphatriangle_tpu.config import TrunkConfig
@@ -264,9 +376,10 @@ def test_nemotron_supers_chunk_compiles_at_its_published_widths(
     from alphatriangle_tpu.nn.network import NeuralNetwork
     from alphatriangle_tpu.rl import SelfPlayEngine
     from chipbench import manifest
-    from chipbench import reference_nemotron_h as plain
 
-    cfg = manifest.load_json(manifest.HERE / "configs" / "nemotron-super-ep4.json")
+    want = PUBLISHED_WIDTH_CHUNKS[config]
+    plain = importlib.import_module(f"chipbench.{want['reference']}")
+    cfg = manifest.load_json(manifest.HERE / "configs" / f"{config}.json")
     configs = manifest.program_configs(cfg)
     model = configs["model"].model_copy(
         update={"TRUNK": TrunkConfig(**plain.trunk_settings(cfg))}
@@ -304,117 +417,27 @@ def test_nemotron_supers_chunk_compiles_at_its_published_widths(
         .compile()
     )
     memory = compiled.memory_analysis()
-    assert 9.0e9 < memory.argument_size_in_bytes < 9.1e9
-    assert memory.temp_size_in_bytes < 3.0e9
+    low, high = want["weights"]
+    assert low < memory.argument_size_in_bytes < high
+    assert memory.temp_size_in_bytes < want["temp_below"]
     text = compiled.as_text()
-    # The scan is one custom call a layer wherever a block of boards is
-    # evaluated, each the block's whole `(b, 252, 128 x 64)` y;
-    # `chunked`'s decays and weights `f32[64,2,128,128,128]` are gone.
     calls = [line for line in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
-    scans = [line for line in calls if "state_space_scan" in line]
-    assert scans and len(scans) % 5 == 0
-    assert all(re.search(r"= f32\[(16|64),252,8192\]", line) for line in scans)
-    assert "f32[64,2,128,128,128]" not in text
-    # So are the experts' rounds, two calls a layer, each
-    # writing its round's rows in bfloat16 (177,408 at a block of 64
-    # boards, 44,352 at one of 16): no float32 grouped product is left.
-    ups = [line for line in calls if re.match(r"\s*%grouped_experts_up[.0-9]* =", line)]
-    downs = [line for line in calls if re.match(r"\s*%grouped_experts_down[.0-9]* =", line)]
-    assert ups and len(ups) % 5 == 0 and len(downs) == len(ups)
-    assert all(re.search(r"= bf16\[(44352|177408),2688\]", line) for line in ups)
-    assert all(re.search(r"= bf16\[(44352|177408),1024\]", line) for line in downs)
-    assert len(calls) == len(scans) + 2 * len(ups)
-    assert "ragged" not in text and "f32[177408,2688]" not in text
-
-
-def test_gigachats_chunk_compiles_at_its_published_widths(one_v5e_chip, monkeypatch):
-    """`gigachat35-ep16`'s one-move chunk as the cell dispatches it (16
-    lanes, waves of 32, the net in blocks of `block_boards`): the
-    described chip's compiler takes the gated-delta-net recurrence as
-    this repo's kernel (PR 41), YaRN, the gate a channel and the
-    sandwich norms at their published widths, and the program fits:
-    9.47 GB of weights and under 3.9 GB of temporaries (3.84 at blocks
-    of 64 with the recurrence's and the experts' kernels, 4.12 with
-    `ragged_dot`'s float32 products instead, 3.90 with `chunked_scalar`
-    and those, 2.25 at blocks of 32 then: PERF.md section 4; the ring's
-    0.85 GB stands beside them on the chip)."""
-    import jax.numpy as jnp
-
-    from alphatriangle_tpu.config import TrunkConfig
-    from alphatriangle_tpu.env.engine import TriangleEnv
-    from alphatriangle_tpu.features.core import get_feature_extractor
-    from alphatriangle_tpu.nn.model import AlphaTriangleNet
-    from alphatriangle_tpu.nn.network import NeuralNetwork
-    from alphatriangle_tpu.rl import SelfPlayEngine
-    from chipbench import manifest
-    from chipbench import reference_gigachat35 as plain
-
-    cfg = manifest.load_json(manifest.HERE / "configs" / "gigachat35-ep16.json")
-    configs = manifest.program_configs(cfg)
-    model = configs["model"].model_copy(
-        update={"TRUNK": TrunkConfig(**plain.trunk_settings(cfg))}
-    )
-    env_cfg = configs["env"]
-    module = AlphaTriangleNet(model, env_cfg.action_dim)
-    shapes = jax.eval_shape(
-        lambda k: module.init(
-            k, jnp.zeros((1, 1, env_cfg.ROWS, env_cfg.COLS)),
-            jnp.zeros((1, model.OTHER_NN_INPUT_FEATURES_DIM)), train=False,
-        ),
-        jax.random.PRNGKey(0),
-    )
-    net = NeuralNetwork(model, env_cfg, variables=shapes)
-    env = TriangleEnv(env_cfg)
-    engine = SelfPlayEngine(
-        env, get_feature_extractor(env, model), net, configs["mcts"],
-        configs["train"], seed=0,
-    )
-
-    def described(x):
-        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_v5e_chip)
-
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    compiled = (
-        jax.jit(functools.partial(engine._chunk, 1))
-        .lower(
-            jax.tree_util.tree_map(
-                described, engine._inference_variables(net.variables, 0)
-            ),
-            jax.tree_util.tree_map(described, engine._carry),
-            described(jnp.int32(0)),
-        )
-        .compile()
-    )
-    memory = compiled.memory_analysis()
-    assert model.TRUNK.block_boards == 64
-    assert 9.4e9 < memory.argument_size_in_bytes < 9.5e9
-    assert memory.temp_size_in_bytes < 3.9e9
-    text = compiled.as_text()
-    # The recurrence is one custom call a layer wherever a block of
-    # boards is evaluated, each the block's whole `(b, 252, 64 x 128)`
-    # o; `chunked_scalar`'s pairwise decays and products
-    # `f32[4,2048,2,64,64]` are gone.
-    calls = [line for line in text.splitlines()
-             if 'custom_call_target="tpu_custom_call"' in line]
-    rules = [line for line in calls if "gated_delta_net" in line]
-    assert rules and len(rules) % 4 == 0
+    rules = [line for line in calls if want["kernel"] in line]
+    assert rules and len(rules) % want["layers"] == 0
     assert all(re.search(r"= f32\[(16|64),252,8192\]", line) for line in rules)
-    assert "f32[4,2048,2,64,64]" not in text
-    # So are the experts' rounds, two calls a layer, each
-    # writing its round's rows in bfloat16 (16,128 at a block of 64
-    # boards, 4,032 at one of 16): no float32 grouped product is left.
     ups = [line for line in calls if re.match(r"\s*%grouped_experts_up[.0-9]* =", line)]
     downs = [line for line in calls if re.match(r"\s*%grouped_experts_down[.0-9]* =", line)]
-    assert ups and len(ups) % 4 == 0 and len(downs) == len(ups)
-    assert all(re.search(r"= bf16\[(4032|16128),2048\]", line) for line in ups)
-    assert all(re.search(r"= bf16\[(4032|16128),7168\]", line) for line in downs)
+    assert ups and len(ups) % want["layers"] == 0 and len(downs) == len(ups)
+    for lines, (rows, width) in ((ups, want["up"]), (downs, want["down"])):
+        assert all(re.search(rf"= bf16\[({rows}),{width}\]", line) for line in lines)
     assert len(calls) == len(rules) + 2 * len(ups)
     assert "ragged" not in text
-    assert not [  # the shared expert's float32 `[16128, 2048]` stays
-        line for line in text.splitlines()
-        if re.search(r"= f32\[16128,2048\]", line) and "net/trunk/experts" in line
-    ]
+    for pattern, scope in want["absent"]:
+        assert not [
+            line for line in text.splitlines()
+            if re.search(pattern, line) and scope in line
+        ]
 
 
 # ring rows, (grid, other features, actions), the blocks' leading dims

@@ -116,14 +116,14 @@ def test_kernels_phase_matches_xla(tiny_cfgs):
     rules = [out["parity"].pop(name) for name in ("delta_rule", "gated_delta_net")]
     experts = out["parity"].pop("grouped_experts")
     assert {k["parity"] for k in out["parity"].values()} == {"exact"}
-    # The sixth and seventh are held to the token-by-token recurrence,
+    # The recurrences are held to the token-by-token recurrence,
     # and so is the chunked form beside each: both gaps are in the
     # step's line.
     for rule in rules:
         assert rule["vs"] == "recurrent" and "rounding" in rule["parity"]
         assert rule["chunked"].startswith("max |diff| ")
         assert rule["device_ms_a_call"] == {"pallas": {}, "chunked": {}}
-    # The eighth is held to the ragged_dot chain it replaces and timed
+    # The experts' kernel is held to the ragged_dot chain it replaces and timed
     # beside it; both round to bfloat16 where the layer does.
     assert experts["vs"] == "ragged"
     assert experts["parity"] == "exact" or "rounding" in experts["parity"]
@@ -199,9 +199,7 @@ def test_a_failed_check_raises():
 def test_flagship_shapes_are_preset_three():
     shapes = chip_smoke.kernel_shapes(chip_smoke.flagship_configs())
     assert shapes == {
-        "batch": 512, "nodes": 65, "reuse_nodes": 129, "wave": 32,
-        "actions": 360, "depth": 8, "capacity": 250_000,
-        "learner_steps": 16, "batch_size": 256,
+        "batch": 512, "nodes": 65, "wave": 32, "actions": 360, "depth": 8,
         # The leaf wave of a fast search: 512 lanes x 16 simulations.
         "leaves": 8192, "tokens": 120, "dim": 128, "heads": 4,
         "mlp_dim": 256, "activation": "ReLU", "compute_dtype": "bfloat16",

@@ -186,15 +186,14 @@ class TestMegastepLoop:
         monkeypatch.setenv("ALPHATRIANGLE_PEAK_TFLOPS", "1.0")
         # 2-move chunks keep the fused program's scan short (tier-1
         # compile budget); the loop semantics are chunk-length-free.
-        # Every Pallas backend is enabled (interpret mode on CPU), so
-        # the one-dispatch contract is asserted with the full kernel
-        # library inside the fused program (ops/, docs/KERNELS.md).
+        # The backup's Pallas lowering is on (interpret mode on CPU), so
+        # the one-dispatch contract is asserted with a kernel inside the
+        # fused program (ops/mcts_backup.py, docs/KERNELS.md).
         c = build(
             tmp_path,
             tiny_world_configs,
             ROLLOUT_CHUNK_MOVES=2,
-            PER_SAMPLE_BACKEND="pallas",
-            mcts_kw={"descent_gather": "pallas", "backup_update": "pallas"},
+            mcts_kw={"backup_update": "pallas"},
         )
         params0 = jax.device_get(c.trainer.state.params)
         loop = TrainingLoop(c)
@@ -565,3 +564,97 @@ class TestMegastepCompileCache:
             assert any(p.startswith("self_play_chunk") for p in programs)
         finally:
             reset_compile_cache()
+
+
+# sha256 of the lowered text of (the rollout chunk, the megastep) at a
+# preset's widths, 8 lanes, a ring of 512 rows, batches of 8, the
+# preset's own chunk length and K. Taken on commit 9e5aaf1, where the
+# descent's gather, the PER draw and the subtree promotion could still
+# be lowered by config-chosen Pallas kernels, with this module's code:
+# the one lowering left of each is that commit's default, byte for byte.
+PROGRAM_DIGESTS = {
+    3: (
+        "eb137f563e20a12762c56b58fac605fdeb75507fdf00b1b3934fc929519258e3",
+        "87c734920308428c78fa60cc6076c7f9791be30e9799c946ee23b48af7fab8af",
+    ),
+    4: (
+        "6572dce4558e6ae58a7367882ccc607de80ddcadc05e50049aae74677670c95d",
+        "6c7cc5cbe0782379b927bb4efd03737b12ccf75fb152b6a6195d413f9dd93cf5",
+    ),
+}
+
+
+@pytest.fixture(scope="module")
+def preset_runner():
+    """`direct_runner` at a preset's widths (preset 3 is `flagship-p3`),
+    one per preset, built on first use. The engines are built with the
+    device telemetry a fresh process has (no stat-packs, no beacons):
+    an engine reads it when it is made, and the loops above set it."""
+    from alphatriangle_tpu.config.presets import baseline_preset
+    from alphatriangle_tpu.telemetry.device_stats import reset_device_stats_state
+
+    reset_device_stats_state()
+    runners = {}
+
+    def get(preset: int):
+        if preset not in runners:
+            cfgs = baseline_preset(preset)
+            train = TrainConfig(
+                **{
+                    **cfgs["train"].model_dump(),
+                    "AUTO_RESUME_LATEST": False,
+                    "SELF_PLAY_BATCH_SIZE": 8,
+                    "BUFFER_CAPACITY": 512,
+                    "MIN_BUFFER_SIZE_TO_TRAIN": 512,
+                    "BATCH_SIZE": 8,
+                }
+            )
+            runners[preset] = direct_runner(
+                (cfgs["env"], cfgs["model"], cfgs["mcts"]), train
+            )
+        return runners[preset]
+
+    yield get
+    reset_device_stats_state()
+
+
+def _digest(text: str) -> str:
+    import hashlib
+
+    return hashlib.sha256(text.encode()).hexdigest()
+
+
+@pytest.mark.parametrize("preset", sorted(PROGRAM_DIGESTS))
+def test_the_rollout_chunk_is_the_parents_text(preset, preset_runner):
+    import functools
+
+    import jax.numpy as jnp
+
+    engine = preset_runner(preset).engine
+    text = (
+        jax.jit(functools.partial(engine._chunk, engine.config.ROLLOUT_CHUNK_MOVES))
+        .lower(
+            engine._inference_variables(engine.net.variables, 0),
+            engine._carry,
+            jnp.int32(0),
+        )
+        .as_text()
+    )
+    assert _digest(text) == PROGRAM_DIGESTS[preset][0]
+
+
+@pytest.mark.parametrize("preset", sorted(PROGRAM_DIGESTS))
+def test_the_megastep_is_the_parents_text(preset, preset_runner):
+    """The fused program at its defaults, PER on: the stratified draw
+    is inside it."""
+    import functools
+
+    runner = preset_runner(preset)
+    assert runner.use_per
+    t, k = runner.config.ROLLOUT_CHUNK_MOVES, runner.config.FUSED_LEARNER_STEPS
+    text = (
+        jax.jit(functools.partial(runner._impl, t, k))
+        .lower(*runner._dispatch_args(t, k))
+        .as_text()
+    )
+    assert _digest(text) == PROGRAM_DIGESTS[preset][1]

@@ -1,8 +1,11 @@
-"""Custom-op tests: every ops/ lowering pair must be numerically
-pinned against the other (the Pallas kernels run in interpret mode on
-CPU) — exact for the gather, backup and PER index-select ops, and
-tolerance + fixed-seed arena equality for the bf16 inference path —
-and full searches must be invariant to the backend choice."""
+"""Custom-op tests: the search's and the replay programs' ops held to
+numpy references, exactly, at the trees the presets search; the wave
+backup's Pallas lowering (interpreted on CPU) against its XLA chain,
+alone and through whole searches; the encoder layer's kernel against
+Flax's modules; tolerance + fixed-seed arena equality for the bf16
+inference path."""
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -24,19 +27,42 @@ from alphatriangle_tpu.ops.encoder_layer import (
 )
 
 
+def _tree(preset: int) -> dict:
+    """The tree a preset searches: N nodes x A actions, waves of W to
+    depth D (two games of it: the ops treat every game alike)."""
+    from alphatriangle_tpu.config.presets import baseline_preset
+    from alphatriangle_tpu.mcts.search import tree_geometry
+
+    cfgs = baseline_preset(preset)
+    nodes, wave = tree_geometry(cfgs["mcts"])
+    return dict(
+        batch=2, n=nodes, w=wave, a=cfgs["env"].action_dim,
+        depth=cfgs["mcts"].max_depth,
+    )
+
+
 class TestGatherRows:
-    @pytest.mark.parametrize("mode", ["einsum", "pallas", "take"])
-    def test_matches_numpy(self, mode):
+    def test_matches_numpy(self):
         rng = np.random.default_rng(0)
         stats = rng.random((6, 17, 40)).astype(np.float32)
         idx = rng.integers(0, 17, (6, 5)).astype(np.int32)
-        out = np.asarray(gather_rows(stats, idx, mode))
+        out = np.asarray(gather_rows(stats, idx))
         expect = np.stack([stats[b][idx[b]] for b in range(6)])
         np.testing.assert_array_equal(out, expect)
 
-    def test_unknown_mode_raises(self):
-        with pytest.raises(ValueError, match="unknown gather"):
-            gather_rows(np.zeros((1, 2, 3)), np.zeros((1, 1), np.int32), "x")
+    @pytest.mark.parametrize("preset", [3, 4])
+    def test_matches_numpy_at_the_presets_tree(self, preset):
+        """The descent's read: W rows of the (N, 6A) stat block, at the
+        flagship's 65 x 2,160 and preset 4's 401 x 2,160; the one-hot
+        matmul selects them exactly."""
+        t = _tree(preset)
+        rng = np.random.default_rng(preset)
+        stats = rng.standard_normal((t["batch"], t["n"], 6 * t["a"]))
+        stats = stats.astype(np.float32)
+        idx = rng.integers(0, t["n"], (t["batch"], t["w"])).astype(np.int32)
+        out = np.asarray(jax.jit(gather_rows)(stats, idx))
+        expect = np.stack([stats[b][idx[b]] for b in range(t["batch"])])
+        np.testing.assert_array_equal(out, expect)
 
     def test_jittable_under_vmapped_search_shapes(self):
         # Negative-free int32 indices with K not a multiple of 128
@@ -44,14 +70,11 @@ class TestGatherRows:
         rng = np.random.default_rng(1)
         stats = rng.random((3, 9, 130)).astype(np.float32)
         idx = rng.integers(0, 9, (3, 4)).astype(np.int32)
-        for mode in ("einsum", "pallas", "take"):
-            out = jax.jit(lambda s, i, m=mode: gather_rows(s, i, m))(
-                stats, idx
-            )
-            np.testing.assert_array_equal(
-                np.asarray(out),
-                np.stack([stats[b][idx[b]] for b in range(3)]),
-            )
+        out = jax.jit(gather_rows)(stats, idx)
+        np.testing.assert_array_equal(
+            np.asarray(out),
+            np.stack([stats[b][idx[b]] for b in range(3)]),
+        )
 
 
 def _max_gap(a, b) -> float:
@@ -129,7 +152,6 @@ class TestEncoderLayer:
     def test_the_layer_takes_its_own_variables(self, monkeypatch):
         """Through the module: `fused=True` hands the kernel the
         variables the Flax path declared."""
-        import functools
 
         from alphatriangle_tpu.nn import model as nn_model
 
@@ -237,88 +259,130 @@ class TestEncoderLayer:
             )
 
 
-class TestPerSample:
-    """Stratified PER draw: the Pallas compare-count and XLA
-    searchsorted lowerings share one prefix-sum, so index selection is
-    bit-identical by construction."""
+def _strata(prios, cap: int, k: int, b: int, key) -> np.ndarray:
+    """The (k, b) stratum draws exactly as `per_sample` makes them."""
+    return np.asarray(
+        (
+            jnp.arange(b, dtype=jnp.float32)[None, :]
+            + jax.random.uniform(key, (k, b))
+        )
+        / b
+        * jnp.cumsum(prios[:cap])[-1]
+    )
 
-    # cap off/on the kernel tile boundary, below one tile, K=1.
+
+class TestPerSample:
+    """Stratified PER draw: a searchsorted over the priorities' prefix
+    sum, the vectorized SumTree descent."""
+
+    # Below 512 slots, at 512, off a multiple of 128; K=1.
     @pytest.mark.parametrize("cap,k,b", [(37, 2, 8), (512, 1, 16), (700, 3, 32)])
-    def test_pallas_matches_xla_exactly(self, cap, k, b):
+    def test_matches_numpy_count_below(self, cap, k, b):
+        """Each draw selects the number of prefix sums below it,
+        `#{i : cum[i] < u}` (numpy over the same prefix sum), and its
+        probability is the slot's share of the total."""
         key = jax.random.PRNGKey(3)
         prios = jax.random.uniform(jax.random.PRNGKey(7), (cap + 1,))
         prios = prios.at[cap].set(0.0)  # trash slot
-        idx_x, probs_x = per_sample(prios, cap, k, b, key, mode="xla")
-        idx_p, probs_p = per_sample(prios, cap, k, b, key, mode="pallas")
-        np.testing.assert_array_equal(np.asarray(idx_x), np.asarray(idx_p))
+        idx, probs = per_sample(prios, cap, k, b, key)
+        cum = np.asarray(jnp.cumsum(prios[:cap]))
+        u = _strata(prios, cap, k, b, key)
+        want = np.clip((cum < u[..., None]).sum(-1), 0, cap - 1)
+        np.testing.assert_array_equal(np.asarray(idx), want)
+        p = np.asarray(prios)
         np.testing.assert_array_equal(
-            np.asarray(probs_x), np.asarray(probs_p)
+            np.asarray(probs),
+            np.maximum(p[want], np.float32(1e-12))
+            / np.maximum(cum[-1], np.float32(1e-12)),
         )
-        assert idx_p.dtype == jnp.int32 and probs_p.dtype == jnp.float32
+        assert idx.dtype == jnp.int32 and probs.dtype == jnp.float32
 
-    @pytest.mark.parametrize("mode", ["xla", "pallas"])
-    def test_draw_is_stratified_proportional(self, mode):
+    def test_draw_is_stratified_proportional(self):
         """Each selected slot must bound its stratum draw:
         cum[idx-1] <= u < cum[idx] (the SumTree descent invariant)."""
         cap, k, b = 133, 2, 16
         prios = jax.random.uniform(jax.random.PRNGKey(9), (cap,))
         cum = np.cumsum(np.asarray(prios))
         key = jax.random.PRNGKey(11)
-        idx, _ = per_sample(prios, cap, k, b, key, mode=mode)
-        # Reconstruct the shared stratum draws exactly as per_sample.
-        u = np.asarray(
-            (
-                jnp.arange(b, dtype=jnp.float32)[None, :]
-                + jax.random.uniform(key, (k, b))
-            )
-            / b
-            * jnp.cumsum(prios[:cap])[-1]
-        )
+        idx, _ = per_sample(prios, cap, k, b, key)
+        u = _strata(prios, cap, k, b, key)
         idx = np.asarray(idx)
         assert (u[idx > 0] >= cum[idx[idx > 0] - 1]).all()
         assert (u[idx < cap - 1] <= cum[idx[idx < cap - 1]]).all()
 
-    @pytest.mark.parametrize("mode", ["xla", "pallas"])
-    def test_zero_priority_never_selected(self, mode):
+    def test_zero_priority_never_selected(self):
         """Empty/trash slots have empty cumsum segments."""
         cap = 64
         prios = jnp.zeros((cap,)).at[jnp.array([3, 17, 40])].set(1.0)
-        idx, probs = per_sample(
-            prios, cap, 4, 8, jax.random.PRNGKey(13), mode=mode
-        )
+        idx, probs = per_sample(prios, cap, 4, 8, jax.random.PRNGKey(13))
         assert set(np.asarray(idx).ravel()) <= {3, 17, 40}
         assert (np.asarray(probs) > 0).all()
 
-    def test_unknown_mode_raises(self):
-        with pytest.raises(ValueError, match="unknown PER sample"):
-            per_sample(
-                jnp.ones((8,)), 8, 1, 4, jax.random.PRNGKey(0), mode="x"
-            )
+
+_BACKUP_ARGS = (
+    "e_visits", "e_value", "children", "e_reward", "parents", "actions",
+    "new_child", "rewards", "rec_node", "rec_action", "rec_active", "returns",
+)
+
+
+@functools.partial(jax.jit, static_argnums=range(6))
+def _backup_arrays(seed, batch, n, a, w, depth):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 12)
+    return (
+        jax.random.uniform(ks[0], (batch, n, a)),
+        jax.random.normal(ks[1], (batch, n, a)),
+        jnp.full((batch, n, a), -1.0).at[:, 0, :3].set(1.0),
+        jax.random.normal(ks[2], (batch, n, a)),
+        jax.random.randint(ks[3], (batch, w), 0, n),
+        jax.random.randint(ks[4], (batch, w), 0, a),
+        jnp.where(
+            jax.random.bernoulli(ks[5], 0.5, (batch, w)),
+            jax.random.randint(ks[6], (batch, w), 1, n).astype(jnp.float32),
+            -1.0,
+        ),
+        jax.random.normal(ks[7], (batch, w)),
+        jax.random.randint(ks[8], (batch, w, depth), -1, 3),
+        jax.random.randint(ks[9], (batch, w, depth), -1, 4),
+        jax.random.bernoulli(ks[10], 0.7, (batch, w, depth)),
+        jax.random.normal(ks[11], (batch, w, depth)),
+    )
 
 
 def _backup_operands(seed=2, batch=3, n=9, a=12, w=4, depth=5):
     """Random edge planes + a random (not necessarily consistent)
     descent record, with duplicate (node, action) hits across members
     and levels so update-order semantics are actually exercised."""
-    ks = jax.random.split(jax.random.PRNGKey(seed), 12)
-    return dict(
-        e_visits=jax.random.uniform(ks[0], (batch, n, a)),
-        e_value=jax.random.normal(ks[1], (batch, n, a)),
-        children=jnp.full((batch, n, a), -1.0).at[:, 0, :3].set(1.0),
-        e_reward=jax.random.normal(ks[2], (batch, n, a)),
-        parents=jax.random.randint(ks[3], (batch, w), 0, n),
-        actions=jax.random.randint(ks[4], (batch, w), 0, a),
-        new_child=jnp.where(
-            jax.random.bernoulli(ks[5], 0.5, (batch, w)),
-            jax.random.randint(ks[6], (batch, w), 1, n).astype(jnp.float32),
-            -1.0,
-        ),
-        rewards=jax.random.normal(ks[7], (batch, w)),
-        rec_node=jax.random.randint(ks[8], (batch, w, depth), -1, 3),
-        rec_action=jax.random.randint(ks[9], (batch, w, depth), -1, 4),
-        rec_active=jax.random.bernoulli(ks[10], 0.7, (batch, w, depth)),
-        returns=jax.random.normal(ks[11], (batch, w, depth)),
+    return dict(zip(_BACKUP_ARGS, _backup_arrays(seed, batch, n, a, w, depth)))
+
+
+def _backup_reference(ops: dict) -> tuple:
+    """The scatter math `_wave` originally spelled, as a sequential
+    numpy loop in float64."""
+    ev, eq, ch, er = (
+        np.asarray(ops[k], np.float64)
+        for k in ("e_visits", "e_value", "children", "e_reward")
     )
+    parents, actions = np.asarray(ops["parents"]), np.asarray(ops["actions"])
+    new_child = np.asarray(ops["new_child"])
+    rewards = np.asarray(ops["rewards"])
+    rec_node, rec_action = np.asarray(ops["rec_node"]), np.asarray(ops["rec_action"])
+    rec_active = np.asarray(ops["rec_active"])
+    returns = np.asarray(ops["returns"])
+    batch, w = parents.shape
+    depth = rec_node.shape[-1]
+    for bi in range(batch):
+        for j in range(w):
+            p, ac = parents[bi, j], actions[bi, j]
+            ch[bi, p, ac] = max(ch[bi, p, ac], new_child[bi, j])
+            er[bi, p, ac] = rewards[bi, j]
+        for lvl in range(depth):
+            for j in range(w):
+                nd = max(rec_node[bi, j, lvl], 0)
+                ac = max(rec_action[bi, j, lvl], 0)
+                if rec_active[bi, j, lvl]:
+                    ev[bi, nd, ac] += 1.0
+                    eq[bi, nd, ac] += returns[bi, j, lvl]
+    return ev, eq, ch, er
 
 
 class TestBackupUpdate:
@@ -331,34 +395,26 @@ class TestBackupUpdate:
 
     def test_xla_matches_numpy_reference(self):
         """The op must reproduce the scatter math `_wave` originally
-        spelled, computed here as a sequential numpy loop."""
+        spelled."""
         ops = _backup_operands(seed=5)
-        ev, eq, ch, er = (
-            np.asarray(ops[k], np.float64)
-            for k in ("e_visits", "e_value", "children", "e_reward")
-        )
-        parents, actions = np.asarray(ops["parents"]), np.asarray(ops["actions"])
-        new_child = np.asarray(ops["new_child"])
-        rewards = np.asarray(ops["rewards"])
-        rec_node, rec_action = np.asarray(ops["rec_node"]), np.asarray(ops["rec_action"])
-        rec_active = np.asarray(ops["rec_active"])
-        returns = np.asarray(ops["returns"])
-        batch, w = parents.shape
-        depth = rec_node.shape[-1]
-        for bi in range(batch):
-            for j in range(w):
-                p, ac = parents[bi, j], actions[bi, j]
-                ch[bi, p, ac] = max(ch[bi, p, ac], new_child[bi, j])
-                er[bi, p, ac] = rewards[bi, j]
-            for lvl in range(depth):
-                for j in range(w):
-                    nd = max(rec_node[bi, j, lvl], 0)
-                    ac = max(rec_action[bi, j, lvl], 0)
-                    if rec_active[bi, j, lvl]:
-                        ev[bi, nd, ac] += 1.0
-                        eq[bi, nd, ac] += returns[bi, j, lvl]
         got = backup_update(*ops.values(), mode="xla")
-        for g, want in zip(got, (ev, eq, ch, er)):
+        for g, want in zip(got, _backup_reference(ops)):
+            np.testing.assert_allclose(
+                np.asarray(g), want.astype(np.float32), atol=1e-5
+            )
+
+    @pytest.mark.parametrize("preset", [3, 4])
+    def test_matches_numpy_at_the_presets_tree(self, preset):
+        """A wave's update at the flagship's 65 x 360 planes (waves of
+        32) and preset 4's 401 x 360 (waves of 25), depth 8; the
+        records' nodes and actions narrow, so paths share edges."""
+        t = _tree(preset)
+        ops = _backup_operands(
+            seed=preset, batch=t["batch"], n=t["n"], a=t["a"], w=t["w"],
+            depth=t["depth"],
+        )
+        got = jax.jit(backup_update)(*ops.values())
+        for g, want in zip(got, _backup_reference(ops)):
             np.testing.assert_allclose(
                 np.asarray(g), want.astype(np.float32), atol=1e-5
             )
@@ -447,13 +503,13 @@ def _eager_promote(planes, terminal, actions, max_retained):
 
 
 class TestSubtreePromote:
-    """Root promotion for subtree reuse (docs/KERNELS.md): both
-    lowerings against a literal-BFS numpy reference, including budget
-    truncation and the invalid-promotion lane."""
+    """Root promotion for subtree reuse (docs/KERNELS.md) against a
+    literal-BFS numpy reference: a budget that keeps every reachable
+    node, budgets that truncate, one that keeps only the new root, and
+    the invalid-promotion lane."""
 
-    @pytest.mark.parametrize("mode", ["xla", "pallas"])
-    @pytest.mark.parametrize("max_retained", [8, 3])
-    def test_matches_eager_reference(self, mode, max_retained):
+    @pytest.mark.parametrize("max_retained", [8, 5, 3, 1])
+    def test_matches_eager_reference(self, max_retained):
         planes, terminal, acts = _promote_operands()
         ref_planes, ref_term, ref_sidx, ref_pv, ref_ret = _eager_promote(
             planes, terminal, acts, max_retained
@@ -464,66 +520,10 @@ class TestSubtreePromote:
             jnp.asarray(acts),
             max_retained=max_retained,
             bfs_rounds=4,
-            mode=mode,
         )
         refs = list(ref_planes) + [ref_term, ref_sidx, ref_pv, ref_ret]
         for g, want in zip(got, refs):
             np.testing.assert_array_equal(np.asarray(g), np.asarray(want))
-
-    def test_pallas_matches_xla_exactly(self):
-        planes, terminal, acts = _promote_operands(seed=9)
-        kw = dict(max_retained=5, bfs_rounds=4)
-        out_x = subtree_promote(
-            *[jnp.asarray(p) for p in planes],
-            jnp.asarray(terminal), jnp.asarray(acts), mode="xla", **kw
-        )
-        out_p = subtree_promote(
-            *[jnp.asarray(p) for p in planes],
-            jnp.asarray(terminal), jnp.asarray(acts), mode="pallas", **kw
-        )
-        for g, want in zip(out_p, out_x):
-            np.testing.assert_array_equal(np.asarray(g), np.asarray(want))
-
-    def test_unknown_mode_raises(self):
-        planes, terminal, acts = _promote_operands()
-        with pytest.raises(ValueError, match="unknown subtree_promote"):
-            subtree_promote(
-                *[jnp.asarray(p) for p in planes],
-                jnp.asarray(terminal),
-                jnp.asarray(acts),
-                max_retained=4,
-                bfs_rounds=4,
-                mode="x",
-            )
-
-
-class TestSearchGatherInvariance:
-    @pytest.mark.slow
-    def test_search_identical_across_modes(
-        self, tiny_env_config, tiny_model_config, tiny_mcts_config
-    ):
-        from alphatriangle_tpu.env.engine import TriangleEnv
-        from alphatriangle_tpu.features.core import get_feature_extractor
-        from alphatriangle_tpu.nn.network import NeuralNetwork
-
-        env = TriangleEnv(tiny_env_config)
-        fe = get_feature_extractor(env, tiny_model_config)
-        net = NeuralNetwork(tiny_model_config, tiny_env_config, seed=0)
-        roots = env.reset_batch(
-            jax.random.split(jax.random.PRNGKey(4), 4)
-        )
-        outs = {}
-        for mode in ("einsum", "pallas", "take"):
-            cfg = tiny_mcts_config.model_copy(
-                update={"descent_gather": mode}
-            )
-            mcts = BatchedMCTS(env, fe, net.model, cfg, net.support)
-            outs[mode] = np.asarray(
-                mcts.search(net.variables, roots, jax.random.PRNGKey(5))
-                .visit_counts
-            )
-        np.testing.assert_array_equal(outs["einsum"], outs["take"])
-        np.testing.assert_array_equal(outs["einsum"], outs["pallas"])
 
 
 def _tiny_net(tiny_env_config, tiny_model_config):
